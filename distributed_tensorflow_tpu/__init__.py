@@ -16,23 +16,6 @@ parameter-server trainer (zzy123abc/distributed-tensorflow, ``distributed.py``):
 
 __version__ = "0.1.0"
 
-import jax as _jax
-
-if not hasattr(_jax, "shard_map"):
-    # jax < 0.5 ships shard_map under jax.experimental only (with the
-    # replication check spelled check_rep instead of check_vma); the
-    # codebase uses the stable ``jax.shard_map`` spelling throughout.
-    # Alias once at package import so both jax generations run the same
-    # source.
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _compat_shard_map(f, /, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _exp_shard_map(f, **kwargs)
-
-    _jax.shard_map = _compat_shard_map
-
 from . import config
 from .config import app, flags
 from .cluster.spec import ClusterSpec, is_chief

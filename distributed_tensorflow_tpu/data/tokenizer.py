@@ -11,8 +11,9 @@ text.
 The hot loops — pair counting / merge compaction over the whole corpus for
 training, and rank-by-rank merge application for encoding — run in C++
 (``distributed_tensorflow_tpu/csrc/tokenizer/bpe.cc``) over a ctypes C ABI, the same native-build pattern
-as the coordination service.  A pure-NumPy fallback keeps the module usable
-(slowly) if the native build is unavailable.
+as the coordination service.  A failed native build is an error, as it is
+for the coordination service; the pure-NumPy implementation below is the
+reference the tests hold the C++ to, not a fallback.
 
 Determinism: training is a pure function of (corpus bytes, vocab_size) —
 ties broken toward the numerically smallest pair — so every process in a
@@ -26,7 +27,6 @@ from __future__ import annotations
 import ctypes
 import json
 import os
-import subprocess
 import threading
 
 import numpy as np
@@ -42,16 +42,13 @@ _lib = None
 _lib_lock = threading.Lock()
 
 
-def _load_library() -> ctypes.CDLL | None:
-    """Build (if stale) and load the native library; None if unavailable."""
+def _load_library() -> ctypes.CDLL:
+    """Build (if stale) and load the native library."""
     global _lib
     with _lib_lock:
         if _lib is not None:
             return _lib
-        try:
-            lib = build_and_load(os.path.join(_HERE, _LIB_NAME), _SRC)
-        except (OSError, subprocess.CalledProcessError):
-            return None
+        lib = build_and_load(os.path.join(_HERE, _LIB_NAME), _SRC)
         lib.dtf_bpe_train.restype = ctypes.c_int
         lib.dtf_bpe_train.argtypes = [
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, ctypes.c_int,
@@ -72,7 +69,7 @@ def _as_u8(data) -> np.ndarray:
     return arr
 
 
-# ------------------------------------------------------- NumPy fallback
+# ------------------------------------------------------ NumPy reference
 
 
 def _merge_pass_np(seq: np.ndarray, a: int, b: int, new_id: int) -> np.ndarray:
@@ -168,8 +165,6 @@ class BpeTokenizer:
         arr = _as_u8(data)[:max_train_bytes]
         max_merges = vocab_size - 256
         lib = _load_library()
-        if lib is None:
-            return cls(_train_np(arr, max_merges, min_pair_count))
         out = np.empty((max(max_merges, 1), 2), np.int32)
         n = lib.dtf_bpe_train(
             arr.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)), len(arr),
@@ -185,8 +180,6 @@ class BpeTokenizer:
         if not self.merges or len(arr) == 0:
             return arr.astype(np.int32)
         lib = _load_library()
-        if lib is None:
-            return _encode_np(arr, self.merges)
         merges = np.ascontiguousarray(np.asarray(self.merges, np.int32))
         out = np.empty(len(arr), np.int32)
         n = lib.dtf_bpe_encode(

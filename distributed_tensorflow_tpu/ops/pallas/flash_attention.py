@@ -241,34 +241,15 @@ def _warn_once(key: str, msg: str) -> None:
         warnings.warn(msg, stacklevel=3)
 
 
-def _axis_env_names():
-    """Named axes bound at trace time, or ``None`` when no probe works.
-
-    The axis env has no stable public accessor; probe the known locations
-    across JAX versions rather than silently reporting "not in shard_map"
-    (which would force the dense fallback on multi-chip TPU forever)."""
-    for probe in (lambda: __import__("jax._src.core", fromlist=["core"])
-                  .get_axis_env().axis_names(),
-                  lambda: jax.core.get_axis_env().axis_names()):  # moved alias
-        try:
-            return tuple(probe())
-        except Exception:
-            continue
-    return None
-
-
 def _inside_shard_map() -> bool:
     """True when tracing under shard_map (named axes bound): the kernel then
-    sees per-device local arrays and lowers per-device."""
-    names = _axis_env_names()
-    if names is None:
-        _warn_once(
-            "axis-env-probe",
-            "cannot detect shard_map context (JAX moved the axis-env API); "
-            "assuming a GSPMD hazard — pallas kernels will fall back to "
-            "dense XLA on multi-chip TPU. Report/update _axis_env_names().")
-        return False
-    return bool(names)
+    sees per-device local arrays and lowers per-device.
+
+    The axis env has no public accessor; this is its location in the pinned
+    JAX (pyproject.toml).  If it moves, this raises — a quiet "not in
+    shard_map" would turn every kernel off on several chips."""
+    from jax._src import core
+    return bool(core.get_axis_env().axis_names())
 
 
 def _gspmd_hazard() -> bool:

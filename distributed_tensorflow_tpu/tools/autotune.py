@@ -780,9 +780,8 @@ def main(argv=None) -> int:
                         help="force a JAX platform (cpu/tpu)")
     args = parser.parse_args(argv)
 
-    if args.platform:
-        import jax
-        jax.config.update("jax_platforms", args.platform)
+    from ..utils.backend import configure_backend
+    configure_backend(args.platform)
 
     from ..utils.metrics import MetricsLogger
     from ..utils.telemetry import Telemetry

@@ -109,9 +109,8 @@ def main(argv=None) -> int:
                         help="jax platform override (e.g. cpu)")
     args = parser.parse_args(argv)
 
-    import jax
-    if args.platform:
-        jax.config.update("jax_platforms", args.platform)
+    from ..utils.backend import configure_backend
+    configure_backend(args.platform)
 
     diverged, n = check(args.model, args.steps, args.batch_size, args.seed,
                         args.steps_per_call)

@@ -226,9 +226,8 @@ def main(argv=None) -> int:
     if not args.logdir:
         parser.error("--logdir is required (or use --watch URL)")
 
-    if args.platform:
-        import jax
-        jax.config.update("jax_platforms", args.platform)
+    from ..utils.backend import configure_backend
+    configure_backend(args.platform)
 
     from ..models import gpt as gpt_lib
     from ..serving.engine import DecodeEngine, EngineConfig

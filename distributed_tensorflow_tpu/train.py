@@ -42,6 +42,7 @@ from .training.optimizers import schedule_from_flags
 from .training.preemption import ShutdownSignal
 from .training.supervisor import Supervisor
 from .utils import MetricsLogger, SummaryWriter, faults, profiling
+from .utils.backend import configure_backend
 
 FLAGS = define_training_flags()
 flags.DEFINE_string("mode", "train",
@@ -472,10 +473,9 @@ flags.DEFINE_string("profile_dir", None,
                     "Capture a JAX/XLA profile of the training loop into this "
                     "directory (TensorBoard-loadable)")
 flags.DEFINE_string("platform", None,
-                    "Force a JAX platform ('cpu', 'tpu'). Needed because some "
-                    "environments import jax at interpreter startup, locking in "
-                    "JAX_PLATFORMS before this process can set it; jax.config "
-                    "is still mutable until first backend use.")
+                    "Force a JAX platform ('cpu', 'tpu') for this process; "
+                    "same effect as JAX_PLATFORMS in the environment "
+                    "(default: JAX picks — the TPU where one is attached)")
 flags.DEFINE_string("profile", "",
                     "Run under a tuned run profile "
                     "(tools/autotune.py output, docs/autotune.md): the "
@@ -784,8 +784,7 @@ def run_generate():
 
 
 def main(unused_argv):
-    if FLAGS.platform:
-        jax.config.update("jax_platforms", FLAGS.platform)
+    configure_backend(FLAGS.platform)
 
     # Chaos harness: arm any DTF_CHAOS-specified faults before bring-up so
     # subprocess fault-recovery tests can inject without code changes

@@ -471,9 +471,8 @@ def main(argv=None) -> int:
     parser.add_argument("--platform", default="",
                         help="jax platform override (e.g. cpu)")
     args = parser.parse_args(argv)
-    if args.platform:
-        import jax
-        jax.config.update("jax_platforms", args.platform)
+    from distributed_tensorflow_tpu.utils.backend import configure_backend
+    configure_backend(args.platform)
     server = make_server(args.artifact, port=args.port,
                          max_batch=args.max_batch,
                          wait_ms=args.batch_wait_ms,

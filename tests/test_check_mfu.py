@@ -4,8 +4,6 @@ injected regression and stay quiet on noise within the threshold."""
 
 import json
 
-import pytest
-
 from distributed_tensorflow_tpu.tools import check_mfu
 
 
@@ -70,18 +68,23 @@ def test_cli_exit_codes(tmp_path):
                            "--committed", str(base)]) == 1
 
 
-def test_cli_against_committed_head(capsys):
-    """The default mode (working tree vs HEAD) runs end-to-end against the
-    real repo artifact.  rc may legitimately be 1 mid-development (a fresh
-    bench pass on this host can differ from the committed artifact), so
-    only the mechanism is asserted, not the verdict."""
-    try:
-        rc = check_mfu.main([])
-    except FileNotFoundError:
-        pytest.skip("no working-tree BENCH_DETAILS.json in this checkout")
-    out = capsys.readouterr().out
-    assert rc in (0, 1)
-    assert "[check_mfu]" in out
+def test_cli_against_committed_head(tmp_path, monkeypatch, capsys):
+    """The default mode (working-tree BENCH_DETAILS.json vs the one
+    committed at HEAD) end to end, in a throwaway repository: the repo
+    itself commits no bench artifact."""
+    import subprocess
+    monkeypatch.chdir(tmp_path)
+    git = ["git", "-c", "user.name=t", "-c", "user.email=t@example.com"]
+    subprocess.run([*git, "init", "-q"], check=True)
+    (tmp_path / "BENCH_DETAILS.json").write_text(json.dumps(artifact()))
+    subprocess.run([*git, "add", "BENCH_DETAILS.json"], check=True)
+    subprocess.run([*git, "commit", "-q", "-m", "artifact"], check=True)
+    assert check_mfu.main([]) == 0
+    assert "[check_mfu] PASS" in capsys.readouterr().out
+    (tmp_path / "BENCH_DETAILS.json").write_text(
+        json.dumps(artifact(flagship=58.0)))
+    assert check_mfu.main([]) == 1
+    assert "[check_mfu] FAIL" in capsys.readouterr().out
 
 
 def test_train_step_flops_param_convention():

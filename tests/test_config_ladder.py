@@ -4,23 +4,9 @@ BERT-tiny sync.  Small step counts: these pin the *wiring* (model registry →
 step builder → loop → eval) per rung; convergence is covered by the library
 tests in test_models.py."""
 
-import jax
-import jax.errors
 import pytest
 
 from distributed_tensorflow_tpu.train import FLAGS, main
-
-#: jax 0.4.x on the CPU backend: XLA's SPMD partitioner rejects the
-#: PartitionId instruction that the ring-attention eval path lowers to
-#: ("UNIMPLEMENTED: PartitionId instruction is not supported for SPMD
-#: partitioning").  Training compiles (the step is wrapped in an outer
-#: shard_map); the jitted eval program is what trips it.  Tracked as a
-#: backend limitation, not a repo bug — the strict xfail below runs the
-#: test anyway and LOUDLY flags (XPASS(strict) fails the suite) the
-#: moment an upgraded jax/XLA supports it, so the guard can't go stale.
-_RING_EVAL_PARTITION_ID_BROKEN = (
-    jax.default_backend() == "cpu"
-    and tuple(int(p) for p in jax.__version__.split(".")[:2]) <= (0, 4))
 
 
 def run_main(tmp_path, extra_flags):
@@ -60,11 +46,6 @@ def test_ladder_resnet20_sync(tmp_path):
     assert result.test_accuracy is not None
 
 
-@pytest.mark.xfail(
-    condition=_RING_EVAL_PARTITION_ID_BROKEN,
-    reason="XLA PartitionId unavailable to the SPMD partitioner on the "
-           "CPU backend (jax 0.4.x); auto-unskips on a capable backend",
-    raises=jax.errors.JaxRuntimeError, strict=True)
 def test_sequence_parallel_ring_bert(tmp_path):
     # Long-context path through the CLI: 'seq' mesh axis + ring attention.
     result = run_main(tmp_path, ["--model=bert_tiny", "--sync_replicas=true",

@@ -15,7 +15,6 @@ import subprocess
 import sys
 import time
 
-import jax
 import numpy as np
 import pytest
 
@@ -23,21 +22,6 @@ from helpers import free_port
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TIMEOUT = 300
-
-# jax<=0.4 XLA:CPU cannot run multi-controller computations at all:
-# every worker subprocess dies with "Multiprocess computations aren't
-# implemented on the CPU backend" (rc!=0 -> the parent's returncode
-# asserts fire).  Strict xfail pins the EXACT failure mode so a broken
-# harness (timeout, parse error) still fails loudly, and the tests
-# auto-unskip on a capable backend / newer jax.
-multicontroller_mesh_xfail = pytest.mark.xfail(
-    condition=(jax.default_backend() == "cpu"
-               and tuple(int(p) for p in
-                         jax.__version__.split(".")[:2]) <= (0, 4)),
-    reason="XLA:CPU on jax<=0.4 cannot run cross-process collectives; "
-           "auto-unskips on a capable backend",
-    raises=AssertionError, strict=True)
-
 
 def launch_jaxdist(task, ps_port, worker_ports, logdir, train_steps=24,
                    extra=(), devices=4):
@@ -101,7 +85,6 @@ def parse_losses(out: str) -> dict[int, float]:
 
 
 @pytest.mark.smoke
-@multicontroller_mesh_xfail
 def test_two_process_scanned_steps(tmp_path):
     """Chunked dispatch (--steps_per_call) under cross-process collectives:
     the lax.scan body's AllReduces run K times per launch across both
@@ -179,7 +162,6 @@ def test_two_process_async_mode(tmp_path):
         ps.wait(timeout=10)
 
 
-@multicontroller_mesh_xfail
 def test_two_process_global_mesh_training(tmp_path):
     ps_port = free_port()
     worker_ports = [free_port(), free_port()]
@@ -239,7 +221,6 @@ def test_two_process_global_mesh_training(tmp_path):
 
 
 @pytest.mark.smoke
-@multicontroller_mesh_xfail
 def test_four_process_sync_mnist(tmp_path):
     """VERDICT r4 #6: the multi-controller data plane past 2 processes —
     4 trainer processes x 2 devices each form ONE 8-device global mesh;
@@ -269,7 +250,6 @@ def test_four_process_sync_mnist(tmp_path):
         ps.wait(timeout=10)
 
 
-@multicontroller_mesh_xfail
 def test_two_process_gpt_fsdp_crosses_dcn(tmp_path):
     """VERDICT r4 #6: parallelism COMPOSED with the process boundary — a
     GPT step with FSDP sharding its params over the 8-device data axis
