@@ -53,14 +53,16 @@ from ..models import gpt as gpt_lib
 from ..models.drafting import NGramIndex
 from ..ops.quant import (load_inference_tree, prepare_inference_tree,
                          resolve_kv_dtype, validate_quantize)
-from ..utils import tracing
+from ..utils import profiling, tracing
 from .kv_pool import PageAllocator, reservation_tokens
 from .scheduler import Request
 
 
 def _unix_at(perf_t: float) -> float:
     """Map a ``perf_counter`` stamp onto the epoch clock (spans carry
-    ``t_unix`` so the exporter can align them across hosts)."""
+    ``t_unix`` so the exporter can align them across hosts).  A
+    ``time.monotonic`` stamp maps the same way: off Windows CPython reads
+    one clock (``CLOCK_MONOTONIC``) for both."""
     return time.time() - (time.perf_counter() - perf_t)
 
 
@@ -225,6 +227,10 @@ class DecodeEngine:
 
         self.step_index = 0
         self._admitted_since_step = 0
+        # Since the last step's record: prompt tokens seated, and the
+        # milliseconds their whole-bucket prefills took.
+        self._prompt_tokens_since_step = 0
+        self._prefill_ms_since_step = 0.0
         self._spec_accepted_since_step = 0
         self._spec_rows_last_step = 0
         self._step_fn = self._build_step()
@@ -491,6 +497,10 @@ class DecodeEngine:
         lane is seeded with the last prompt token at position P-1, so the
         resident decode step produces token P like any other step (one
         program for every token)."""
+        with profiling.annotate("serve.admit"):
+            return self._admit(request)
+
+    def _admit(self, request: Request) -> int:
         cfg = self.config
         slot = next(i for i, s in enumerate(self._slots) if s is None)
         P = len(request.prompt)
@@ -531,13 +541,14 @@ class DecodeEngine:
             # next decode step — the stall decomposition (and the
             # serve.prefill span) must record device time on both paths.
             self._jax.block_until_ready(self.pools)
-            self.prefill_ms_total += (time.perf_counter() - t_pre) * 1e3
+            prefill_ms = (time.perf_counter() - t_pre) * 1e3
+            self.prefill_ms_total += prefill_ms
+            self._prefill_ms_since_step += prefill_ms
             if tracer is not None:
                 # chunks=1: the whole bucket landed in one dispatch —
                 # the chunked path's spans count theirs instead.
                 tracer.emit_span(
-                    "serve.prefill", _unix_at(t_pre),
-                    (time.perf_counter() - t_pre) * 1e3,
+                    "serve.prefill", _unix_at(t_pre), prefill_ms,
                     step=self.step_index, parent_id=request.span_root,
                     trace=request.trace, request_id=request.id,
                     tenant=request.tenant, bucket=n_prefill,
@@ -570,6 +581,7 @@ class DecodeEngine:
         self._top_p[slot] = request.top_p
         self._seeds[slot] = request.seed
         self._admitted_since_step += 1
+        self._prompt_tokens_since_step += P
         request.t_admit = time.perf_counter()
         return slot
 
@@ -591,9 +603,6 @@ class DecodeEngine:
             tel = self.telemetry
             tel.counter("serve_requests").inc()
             tel.counter("serve_tokens_out").inc(len(req.tokens))
-            if status == "abandoned":
-                tel.counter("serve_abandoned").inc()
-                tel.counter(f"serve_abandoned[{req.tenant}]").inc()
             # Global + per-tenant latency distributions: the bracketed
             # name renders as a {tenant=...} label on /metricz and feeds
             # watch_serve's per-tenant percentile columns.
@@ -718,10 +727,6 @@ class DecodeEngine:
                     pages=state.prefill_pages,
                     prompt_tokens=state.prompt_len,
                     chunks=state.prefill_chunks, chunk_tokens=C)
-        if self.telemetry is not None:
-            self.telemetry.counter("serve_prefill_chunks").inc(len(rows))
-            self.telemetry.histogram("serve_prefill_chunk_ms").record(
-                dur_ms)
         return dur_ms, len(rows)
 
     def step(self, queue_depth: int = 0) -> list[Request]:
@@ -734,160 +739,193 @@ class DecodeEngine:
         current token plus ``spec_k - 1`` drafts and may emit several
         tokens (the accepted prefix + the free correction), plain lanes
         ride the same dispatch and emit exactly their node-0 sample —
-        token-for-token what the plain step would have produced."""
+        token-for-token what the plain step would have produced.
+
+        The step is strictly serial with the host, in three regions each
+        marked with :func:`profiling.annotate` under ``serve.step``:
+        ``.stage`` (host arrays, uploads, the dispatch), ``.fetch`` (the
+        blocking copy back of the step's outputs) and ``.retire`` (the
+        per-slot loop and the telemetry).  Their four boundaries are
+        stamped once and feed the ``serve_step`` record and the
+        ``serve.decode_round`` span alike."""
         self.apply_pending_swap()
         if self.active_slots == 0:
             return []
+        with profiling.annotate("serve.step"):
+            return self._step(queue_depth)
+
+    def _step(self, queue_depth: int) -> list[Request]:
         jnp = self._jnp
-        prefill_ms, prefill_rows = 0.0, 0
+        prefill_ms, prefill_rows = self._prefill_ms_since_step, 0
         if self.config.prefill_chunk:
             # Prompt chunks first, decode second: a lane whose frontier
             # reaches P-1 in this dispatch gets its real table installed
             # and its seed token rides the decode dispatch BELOW — its
             # first generated token costs no extra step.
-            prefill_ms, prefill_rows = self._advance_prefill()
+            chunk_ms, prefill_rows = self._advance_prefill()
+            prefill_ms += chunk_ms
         spec_mode = (self._spec_step_fn is not None
                      and self._spec_slots_active())
-        t0 = time.perf_counter()
-        if spec_mode:
-            K = self.config.spec_k
-            chunk = np.zeros((self.config.num_slots, K), np.int32)
-            chunk[:, 0] = self._tokens
-            spec_rows = 0
-            for slot, state in enumerate(self._slots):
-                if state is not None and state.spec \
-                        and not state.prefilling:
-                    chunk[slot, 1:] = state.draft(K - 1)
-                    spec_rows += 1
-            greedy, sampled0, self.pools = self._spec_step_fn(
-                self._tree, jnp.asarray(chunk),
-                jnp.asarray(self._positions), jnp.asarray(self._tables),
-                self.pools, jnp.asarray(self._temp),
-                jnp.asarray(self._top_k), jnp.asarray(self._top_p),
-                jnp.asarray(self._seeds))
-            greedy, nxt = np.asarray(greedy), np.asarray(sampled0)
-            self._spec_rows_last_step = spec_rows
-        else:
-            nxt, self.pools = self._step_fn(
-                self._tree, jnp.asarray(self._tokens),
-                jnp.asarray(self._positions), jnp.asarray(self._tables),
-                self.pools, jnp.asarray(self._temp),
-                jnp.asarray(self._top_k), jnp.asarray(self._top_p),
-                jnp.asarray(self._seeds))
-            nxt = np.asarray(nxt)
-            self._spec_rows_last_step = 0
-        now = time.perf_counter()
+        t0 = time.monotonic()
+        with profiling.annotate("serve.step.stage"):
+            if spec_mode:
+                K = self.config.spec_k
+                chunk = np.zeros((self.config.num_slots, K), np.int32)
+                chunk[:, 0] = self._tokens
+                spec_rows = 0
+                for slot, state in enumerate(self._slots):
+                    if state is not None and state.spec \
+                            and not state.prefilling:
+                        chunk[slot, 1:] = state.draft(K - 1)
+                        spec_rows += 1
+                greedy, sampled0, self.pools = self._spec_step_fn(
+                    self._tree, jnp.asarray(chunk),
+                    jnp.asarray(self._positions),
+                    jnp.asarray(self._tables), self.pools,
+                    jnp.asarray(self._temp), jnp.asarray(self._top_k),
+                    jnp.asarray(self._top_p), jnp.asarray(self._seeds))
+                self._spec_rows_last_step = spec_rows
+            else:
+                nxt, self.pools = self._step_fn(
+                    self._tree, jnp.asarray(self._tokens),
+                    jnp.asarray(self._positions),
+                    jnp.asarray(self._tables), self.pools,
+                    jnp.asarray(self._temp), jnp.asarray(self._top_k),
+                    jnp.asarray(self._top_p), jnp.asarray(self._seeds))
+                self._spec_rows_last_step = 0
+        t_staged = time.monotonic()
+        with profiling.annotate("serve.step.fetch"):
+            if spec_mode:
+                greedy, nxt = np.asarray(greedy), np.asarray(sampled0)
+            else:
+                nxt = np.asarray(nxt)
+        now = time.monotonic()
         step_ms = (now - t0) * 1e3
         self.step_index += 1
-        tracer = tracing.active()
-        round_id = 0
-        t_round_unix = 0.0
-        if tracer is not None:
-            # One batched-round span per engine step; the live lanes fan
-            # out below as children carrying their request's trace id, so
-            # the same wall-clock interval appears once on the engine
-            # timeline and once inside every participating request.
-            t_round_unix = _unix_at(t0)
-            round_id = tracer.emit_span(
-                "serve.decode_round", t_round_unix, step_ms,
-                step=self.step_index, parent_id=0,
-                active_slots=self.active_slots,
-                spec_rows=self._spec_rows_last_step,
-                model_step=self.model_step)
-        spec_accepted = 0
-        retired: list[Request] = []
-        for slot, state in enumerate(self._slots):
-            if state is None:
-                continue
-            req = state.request
-            if req.abandoned:
-                retired.append(self._retire(slot, "abandoned"))
-                continue
-            if state.prefilling:
-                # Masked passenger: no tokens this step (its decode-row
-                # writes dropped through the sentinel table).
-                continue
-            if spec_mode and state.spec:
-                # Longest drafted prefix matching the greedy argmaxes,
-                # plus the free correction token — clamped to the lane's
-                # remaining budget.
-                row, g = chunk[slot], greedy[slot]
-                accept = 1
-                while (accept < K and row[accept] == g[accept - 1]
-                       and not (req.eos_id is not None
-                                and row[accept - 1] == req.eos_id)):
-                    accept += 1
-                accept = min(accept, state.budget - state.generated)
-                emitted = [int(t) for t in row[1:accept]]
-                emitted.append(int(g[accept - 1]))
-                req.spec_rounds += 1
-            else:
-                emitted = [int(nxt[slot])]
-            if req.t_first_token is None:
-                req.t_first_token = now
-            done_status = None
-            count = 0
-            for token in emitted:
-                req.tokens.append(token)
-                state.generated += 1
-                count += 1
-                if req.eos_id is not None and token == req.eos_id:
-                    done_status = "ok"
-                    break
-                if state.generated >= state.budget:
-                    done_status = "ok"
-                    break
-            if state.spec:
-                state.commit(emitted[:count])
-                # Count what actually LANDED — an accepted eos truncates
-                # the emission mid-chunk, and the acceptance metric must
-                # not report the tokens the break discarded.
-                spec_accepted += count
+        with profiling.annotate("serve.step.retire"):
+            tracer = tracing.active()
+            round_id = 0
+            t_round_unix = 0.0
             if tracer is not None:
-                _ensure_request_trace(tracer, req)
-                lane_attrs = {}
+                # One batched-round span per engine step, emitted at the
+                # end of the region under an id reserved here (it carries
+                # the region's own duration); the live lanes fan out below
+                # as its children carrying their request's trace id, so the
+                # same wall-clock interval appears once on the engine
+                # timeline and once inside every participating request.
+                t_round_unix = _unix_at(t0)
+                round_id = tracer.allocate_id()
+            spec_accepted = 0
+            retired: list[Request] = []
+            for slot, state in enumerate(self._slots):
+                if state is None:
+                    continue
+                req = state.request
+                if req.abandoned:
+                    retired.append(self._retire(slot, "abandoned"))
+                    continue
+                if state.prefilling:
+                    # Masked passenger: no tokens this step (its decode-row
+                    # writes dropped through the sentinel table).
+                    continue
                 if spec_mode and state.spec:
-                    lane_attrs = {"accepted": count,
-                                  "drafted": K - 1}
-                tracer.emit_span(
-                    "serve.decode_lane", t_round_unix, step_ms,
-                    step=self.step_index, parent_id=round_id,
-                    trace=req.trace, request_id=req.id,
-                    tenant=req.tenant, tokens=count, **lane_attrs)
-            if done_status is not None:
-                retired.append(self._retire(slot, done_status))
-            else:
-                self._tokens[slot] = emitted[count - 1]
-                self._positions[slot] += count
-        self._spec_accepted_since_step = spec_accepted
-        if self.telemetry is not None:
+                    # Longest drafted prefix matching the greedy argmaxes,
+                    # plus the free correction token — clamped to the
+                    # lane's remaining budget.
+                    row, g = chunk[slot], greedy[slot]
+                    accept = 1
+                    while (accept < K and row[accept] == g[accept - 1]
+                           and not (req.eos_id is not None
+                                    and row[accept - 1] == req.eos_id)):
+                        accept += 1
+                    accept = min(accept, state.budget - state.generated)
+                    emitted = [int(t) for t in row[1:accept]]
+                    emitted.append(int(g[accept - 1]))
+                    req.spec_rounds += 1
+                else:
+                    emitted = [int(nxt[slot])]
+                if req.t_first_token is None:
+                    req.t_first_token = now
+                done_status = None
+                count = 0
+                for token in emitted:
+                    req.tokens.append(token)
+                    state.generated += 1
+                    count += 1
+                    if req.eos_id is not None and token == req.eos_id:
+                        done_status = "ok"
+                        break
+                    if state.generated >= state.budget:
+                        done_status = "ok"
+                        break
+                if state.spec:
+                    state.commit(emitted[:count])
+                    # Count what actually LANDED — an accepted eos
+                    # truncates the emission mid-chunk, and the acceptance
+                    # metric must not report the tokens the break
+                    # discarded.
+                    spec_accepted += count
+                if tracer is not None:
+                    _ensure_request_trace(tracer, req)
+                    lane_attrs = {}
+                    if spec_mode and state.spec:
+                        lane_attrs = {"accepted": count,
+                                      "drafted": K - 1}
+                    tracer.emit_span(
+                        "serve.decode_lane", t_round_unix, step_ms,
+                        step=self.step_index, parent_id=round_id,
+                        trace=req.trace, request_id=req.id,
+                        tenant=req.tenant, tokens=count, **lane_attrs)
+                if done_status is not None:
+                    retired.append(self._retire(slot, done_status))
+                else:
+                    self._tokens[slot] = emitted[count - 1]
+                    self._positions[slot] += count
+            self._spec_accepted_since_step = spec_accepted
             tel = self.telemetry
-            tel.histogram("serve_step_ms").record(step_ms)
-            tel.gauge("serve_active_slots").set(self.active_slots)
-            tel.gauge("serve_kv_pages_in_use").set(
-                self.allocator.pages_in_use)
-            tel.gauge("serve_kv_pages_peak").set(self.allocator.peak_in_use)
-            tel.gauge("serve_kv_fragmentation").set(
-                self.allocator.internal_fragmentation())
-            # Resident compiled prefill programs (LRU-bounded) + the
-            # chunk program(s): /statz and /metricz both surface this.
-            tel.gauge("serve_compile_cache").set(
-                len(self._prefill_fns) + len(self._chunk_fns))
-            if spec_accepted:
-                tel.counter("serve_spec_tokens").inc(spec_accepted)
-            tel.emit("serve_step", step=self.step_index,
-                     active_slots=self.active_slots + len(retired),
-                     admitted=self._admitted_since_step,
-                     retired=len(retired), queue_depth=queue_depth,
-                     kv_pages_in_use=self.allocator.pages_in_use,
-                     kv_pages_total=self.config.num_pages,
-                     step_ms=round(step_ms, 3),
-                     spec_rows=self._spec_rows_last_step,
-                     spec_accepted=spec_accepted,
-                     prefill_rows=prefill_rows,
-                     prefill_ms=round(prefill_ms, 3),
-                     model_step=self.model_step)
+            if tel is not None:
+                tel.histogram("serve_step_ms").record(step_ms)
+                tel.gauge("serve_active_slots").set(self.active_slots)
+                tel.gauge("serve_kv_pages_in_use").set(
+                    self.allocator.pages_in_use)
+                # Resident compiled prefill programs (LRU-bounded) + the
+                # chunk program(s): /statz and /metricz both surface this.
+                tel.gauge("serve_compile_cache").set(
+                    len(self._prefill_fns) + len(self._chunk_fns))
+                if spec_accepted:
+                    tel.counter("serve_spec_tokens").inc(spec_accepted)
+            if tracer is not None or tel is not None:
+                # The region's last boundary: everything of the retire
+                # region but the two emits themselves.
+                split_ms = {
+                    "stage_ms": round((t_staged - t0) * 1e3, 3),
+                    "fetch_ms": round((now - t_staged) * 1e3, 3),
+                    "retire_ms": round((time.monotonic() - now) * 1e3, 3)}
+            if tracer is not None:
+                tracer.emit_span(
+                    "serve.decode_round", t_round_unix, step_ms,
+                    step=self.step_index, parent_id=0, span_id=round_id,
+                    active_slots=self.active_slots + len(retired),
+                    spec_rows=self._spec_rows_last_step,
+                    model_step=self.model_step, **split_ms)
+            if tel is not None:
+                tel.emit("serve_step", step=self.step_index,
+                         active_slots=self.active_slots + len(retired),
+                         admitted=self._admitted_since_step,
+                         retired=len(retired), queue_depth=queue_depth,
+                         kv_pages_in_use=self.allocator.pages_in_use,
+                         kv_pages_total=self.config.num_pages,
+                         t_start=round(t0, 6),
+                         step_ms=round(step_ms, 3), **split_ms,
+                         spec_rows=self._spec_rows_last_step,
+                         spec_accepted=spec_accepted,
+                         prompt_tokens=self._prompt_tokens_since_step,
+                         prefill_rows=prefill_rows,
+                         prefill_ms=round(prefill_ms, 3),
+                         model_step=self.model_step)
         self._admitted_since_step = 0
+        self._prompt_tokens_since_step = 0
+        self._prefill_ms_since_step = 0.0
         return retired
 
     def fail_active(self, error: str) -> list[Request]:

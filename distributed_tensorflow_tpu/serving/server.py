@@ -50,7 +50,7 @@ import threading
 import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
-from ..utils import tracing
+from ..utils import profiling, tracing
 from ..utils.telemetry import split_instrument_label
 from .engine import DecodeEngine, _ensure_request_trace
 from .scheduler import FairScheduler, QueueFull, Request
@@ -169,7 +169,6 @@ class ServingServer:
             pass
 
     def _engine_loop_inner(self) -> None:
-        engine, sched = self.engine, self.scheduler
         while True:
             with self._wake:
                 # Idle wait with a timeout, dropping the lock each tick
@@ -182,33 +181,48 @@ class ServingServer:
             if stop:
                 self._slo_tick(force=True)
                 break
-            engine.apply_pending_swap()
-            self._slo_tick()
-            if engine.active_slots == 0 and sched.depth() == 0:
-                continue    # still idle — back to the timed wait
-            admitting = None
-            try:
-                # Admit everything admissible RIGHT NOW (slots + pages),
-                # fair-ordered; then one decode step for the whole batch.
-                while engine.free_slots > 0:
+            if self._have_work():
+                with profiling.annotate("serve.turn"):
+                    self._turn()
+            else:       # still idle — housekeeping, back to the timed wait
+                self.engine.apply_pending_swap()
+                self._slo_tick()
+
+    def _turn(self) -> None:
+        """One turn of the engine thread: housekeeping, admit everything
+        admissible RIGHT NOW (slots + pages), fair-ordered, then one
+        decode step for the whole batch, then release the callers of what
+        it retired.  The turn and its parts are ``profiling.annotate``
+        regions (docs/observability.md, "Serving tracing & SLOs")."""
+        engine, sched = self.engine, self.scheduler
+        engine.apply_pending_swap()
+        self._slo_tick()
+        admitting = None
+        try:
+            while engine.free_slots > 0:
+                with profiling.annotate("serve.schedule"):
                     admitting = sched.next_request(engine.can_admit)
                     if admitting is None:
                         break
                     self._trace_queue(admitting)
-                    engine.admit(admitting)
-                    admitting = None
-                for req in engine.step(queue_depth=sched.depth()):
-                    self._complete(req)
-            except Exception as e:  # noqa: BLE001 — fail loud, stay up
-                msg = f"{type(e).__name__}: {e}"
-                if admitting is not None:
-                    # admit() raised after the pop: pages are freed and
-                    # the lane was never seated, so the request is in
-                    # neither the queue nor a slot — complete it here or
-                    # its caller blocks the full request_timeout_s.
-                    admitting.error = msg
-                    self._complete(admitting)
-                for req in self.engine.fail_active(msg):
+                engine.admit(admitting)
+                admitting = None
+            self._complete_all(engine.step(queue_depth=sched.depth()))
+        except Exception as e:  # noqa: BLE001 — fail loud, stay up
+            msg = f"{type(e).__name__}: {e}"
+            if admitting is not None:
+                # admit() raised after the pop: pages are freed and
+                # the lane was never seated, so the request is in
+                # neither the queue nor a slot — complete it here or
+                # its caller blocks the full request_timeout_s.
+                admitting.error = msg
+                self._complete_all([admitting])
+            self._complete_all(engine.fail_active(msg))
+
+    def _complete_all(self, requests: list[Request]) -> None:
+        if requests:
+            with profiling.annotate("serve.complete"):
+                for req in requests:
                     self._complete(req)
 
     def _trace_queue(self, req: Request) -> None:

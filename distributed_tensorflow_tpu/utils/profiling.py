@@ -8,7 +8,9 @@ The reference's nearest artifacts are a plumbed-but-off
 - :func:`trace` — capture an XLA/TPU profile (TensorBoard-loadable) around a
   code region via ``jax.profiler``;
 - :func:`annotate` — name a host-side region so it shows up on the trace
-  timeline (no-op overhead when no trace is active);
+  timeline (no-op overhead when no trace is active) and, with a tracer
+  installed, in the telemetry stream; the serving engine's turn is marked
+  with it (docs/observability.md, "Serving tracing & SLOs");
 - :class:`Timer` — the reference's ``time_begin``/``time_end`` pattern
   (``distributed.py:133,158``) as a context manager;
 - :func:`device_memory_stats` — per-device HBM usage snapshot, the "is my
@@ -41,63 +43,55 @@ def trace(logdir: str | os.PathLike) -> Iterator[None]:
 
 
 class _Annotation:
-    """The :func:`annotate` region: a ``jax.profiler.TraceAnnotation`` for
-    the XLA timeline plus, when a :mod:`.tracing` tracer is installed, a
-    matching ``kind="span"`` record — so host-side annotations land in the
-    exported cross-worker Chrome trace alongside the loop spans, not only
-    in the profiler's own capture."""
+    """The :func:`annotate` region: a ``jax.profiler.TraceAnnotation`` on
+    the profiler's clock (recorded only while a profiler session runs, so
+    the region shares a timeline with the device operations) plus, when a
+    :mod:`.tracing` tracer is installed, a ``kind="span"`` record.  While
+    the region is open its span id sits on the tracer's per-thread stack,
+    so regions nest: an inner region (and any ``emit_span`` that names no
+    parent) records the enclosing region as its ``parent_id``."""
 
-    __slots__ = ("_name", "_jax_annotation", "_t0_unix", "_t0_perf")
+    __slots__ = ("_name", "_jax_annotation", "_span")
 
     def __init__(self, name: str):
         self._name = name
         self._jax_annotation = jax.profiler.TraceAnnotation(name)
-        self._t0_unix: float | None = None
-        self._t0_perf = 0.0
+        self._span = None
 
     def __enter__(self) -> "_Annotation":
         self._jax_annotation.__enter__()
-        if tracing.active() is not None:
-            self._t0_unix, self._t0_perf = time.time(), time.perf_counter()
+        tracer = tracing.active()
+        if tracer is not None:
+            self._span = tracer.span(self._name, source="annotate")
+            self._span.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
         self._jax_annotation.__exit__(*exc)
-        tracer = tracing.active()
-        if tracer is not None and self._t0_unix is not None:
-            tracer.emit_span(
-                self._name, self._t0_unix,
-                (time.perf_counter() - self._t0_perf) * 1000.0,
-                source="annotate")
-            self._t0_unix = None
+        span, self._span = self._span, None
+        if span is not None:
+            span.__exit__(*exc)
 
 
 def annotate(name: str):
-    """Named host-side region on the profiler timeline (cheap when
-    inactive); with a :mod:`.tracing` tracer installed it also emits a
-    matching ``kind="span"`` telemetry record."""
+    """Named host-side region: the program's one way to mark one.  On the
+    profiler timeline while a profiler session runs; a ``kind="span"``
+    record, nested under the thread's open regions, while a
+    :mod:`.tracing` tracer is installed; with neither, one inactive
+    ``TraceAnnotation`` and one ``is None`` check."""
     return _Annotation(name)
 
 
 class Timer:
     """Wall-clock region timer — ``Training elapsed time`` parity
-    (reference ``distributed.py:133,158-161``).
+    (reference ``distributed.py:133,158-161``)."""
 
-    ``name`` (optional) additionally emits the region as a
-    ``kind="span"`` record when a :mod:`.tracing` tracer is installed —
-    the same path :func:`annotate` uses, for call sites that want the
-    elapsed value AND the trace row.
-    """
-
-    def __init__(self, name: str | None = None):
+    def __init__(self):
         self.elapsed = 0.0
-        self.name = name
         self._t0: float | None = None
-        self._t0_unix = 0.0
 
     def __enter__(self) -> "Timer":
         self._t0 = time.perf_counter()
-        self._t0_unix = time.time()
         return self
 
     def __exit__(self, *exc) -> None:
@@ -107,9 +101,6 @@ class Timer:
         if self._t0 is not None:
             self.elapsed = time.perf_counter() - self._t0
             self._t0 = None
-            if self.name:
-                tracing.emit_span(self.name, self._t0_unix,
-                                  self.elapsed * 1000.0, source="timer")
 
 
 def device_memory_stats() -> list[dict[str, Any]]:

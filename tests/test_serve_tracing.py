@@ -724,3 +724,130 @@ def test_chunked_prefill_span_carries_chunk_count(model_and_params,
     spans = [s for s in capture.spans("serve.prefill")
              if s["request_id"] == req2.id]
     assert len(spans) == 1 and spans[0]["chunks"] == 1
+
+
+# ------------------------------------- the engine's turn, opened (PR 25)
+
+
+REGIONS = {"serve.turn": None, "serve.schedule": "serve.turn",
+           "serve.admit": "serve.turn", "serve.step": "serve.turn",
+           "serve.step.stage": "serve.step",
+           "serve.step.fetch": "serve.step",
+           "serve.step.retire": "serve.step",
+           "serve.complete": "serve.turn"}
+
+
+def test_serve_step_record_splits_the_step_at_the_regions_boundaries(
+        model_and_params, capture):
+    """One set of four stamps feeds the ``serve_step`` record and the
+    ``serve.decode_round`` span; a whole-bucket prefill counts."""
+    model, params = model_and_params
+    engine = DecodeEngine(model, params, EngineConfig(
+        num_slots=2, page_size=4, num_pages=32, max_pages_per_seq=8),
+        telemetry=capture.telemetry)
+    engine.admit(Request([5, 6, 7, 8, 9], 3, tenant="alice"))
+    engine.admit(Request([9, 10], 3, tenant="bob"))
+    walls = []
+    while engine.active_slots:
+        t0 = time.monotonic()
+        engine.step()
+        walls.append((t0, time.monotonic()))
+    steps = [f for kind, _, f in capture.records if kind == "serve_step"]
+    rounds = capture.spans("serve.decode_round")
+    assert len(steps) == len(walls) == len(rounds) == 3
+    for rec, span, (t0, t1) in zip(steps, rounds, walls):
+        assert t0 <= rec["t_start"] <= t1
+        split = [rec[k] for k in ("stage_ms", "fetch_ms", "retire_ms")]
+        assert all(ms >= 0 for ms in split) and rec["stage_ms"] > 0
+        assert sum(split) <= (t1 - t0) * 1e3 + 0.002   # each rounded to 1 us
+        assert rec["stage_ms"] + rec["fetch_ms"] == pytest.approx(
+            rec["step_ms"], abs=0.002)
+        assert [span[k] for k in ("stage_ms", "fetch_ms", "retire_ms")] \
+            == split
+        assert span["dur_ms"] == rec["step_ms"]
+    # Both admissions, whole-bucket prefills, are on the first record only.
+    assert [r["prompt_tokens"] for r in steps] == [7, 0, 0]
+    assert [r["admitted"] for r in steps] == [2, 0, 0]
+    assert steps[0]["prefill_ms"] > 0 and steps[0]["prefill_rows"] == 0
+    assert steps[1]["prefill_ms"] == steps[2]["prefill_ms"] == 0
+    assert steps[0]["prefill_ms"] == pytest.approx(
+        sum(s["dur_ms"] for s in capture.spans("serve.prefill")), abs=0.002)
+    # The lanes still hang under their round, whose id was reserved.
+    for lane in capture.spans("serve.decode_lane"):
+        assert lane["parent_id"] in {r["span_id"] for r in rounds}
+
+
+def test_turn_regions_reach_the_stream_nested_under_the_turn(
+        model_and_params, capture):
+    model, params = model_and_params
+    engine = DecodeEngine(model, params, EngineConfig(
+        num_slots=2, page_size=4, num_pages=32, max_pages_per_seq=8),
+        telemetry=capture.telemetry)
+    srv = ServingServer(engine, FairScheduler(), port=0,
+                        request_timeout_s=60.0,
+                        telemetry=capture.telemetry)
+    srv.start()
+    try:
+        out = ServeClient(f"http://127.0.0.1:{srv.port}").generate(
+            [5, 6, 7, 8], 4, tenant="alice")
+        assert out["tokens_out"] == 4
+    finally:
+        srv.shutdown()
+    regions = [s for s in capture.spans() if s.get("source") == "annotate"]
+    by_id = {s["span_id"]: s for s in regions}
+    assert {s["name"] for s in regions} == set(REGIONS)
+    assert not any(s["name"].startswith("perfbench.")
+                   for s in capture.spans())
+    for s in regions:
+        assert s["thread"] == "serve-engine"
+        parent = REGIONS[s["name"]]
+        if parent is None:
+            assert s["parent_id"] == 0
+        else:
+            assert by_id[s["parent_id"]]["name"] == parent
+            outer = by_id[s["parent_id"]]
+            assert outer["t_unix"] <= s["t_unix"] + 1e-3
+            assert s["dur_ms"] <= outer["dur_ms"] + 1.0
+    count = lambda name: sum(s["name"] == name for s in regions)  # noqa: E731
+    # Four tokens, four turns, each with its step and the step's three
+    # regions; one admission; one completion.
+    assert count("serve.turn") == count("serve.step") == 4
+    assert count("serve.step.stage") == count("serve.step.fetch") \
+        == count("serve.step.retire") == 4
+    assert count("serve.admit") == count("serve.complete") == 1
+    assert count("serve.schedule") >= 4
+    # The after-the-fact spans keep their explicit parents: the round is
+    # still an engine root, the request's spans still hang under its root.
+    assert all(s["parent_id"] == 0
+               for s in capture.spans("serve.decode_round"))
+    root = capture.spans("serve.request")[0]
+    for name in ("serve.queue", "serve.reserve", "serve.prefill"):
+        assert capture.spans(name)[0]["parent_id"] == root["span_id"]
+
+
+def test_no_tracer_and_no_profiler_emit_nothing(model_and_params):
+    """The regions cost an inactive annotation each: no span record with
+    telemetry on and no tracer installed, and the engine serves the same
+    with no telemetry at all."""
+    model, params = model_and_params
+    tracing.clear()
+    logger = MetricsLogger(None)
+    telemetry = Telemetry(logger)
+    kinds = []
+    orig = telemetry.emit
+    telemetry.emit = lambda kind, step=0, **f: (kinds.append(kind),
+                                                orig(kind, step=step, **f))
+    tokens = []
+    for tel in (telemetry, None):
+        engine = DecodeEngine(model, params, EngineConfig(
+            num_slots=2, page_size=4, num_pages=32, max_pages_per_seq=8),
+            telemetry=tel)
+        sched = FairScheduler()
+        req = Request([5, 6, 7], 5, tenant="alice")
+        sched.submit(req)
+        drain(engine, sched)
+        tokens.append(req.tokens)
+    logger.close()
+    assert tokens[0] == tokens[1] and len(tokens[0]) == 5
+    assert "span" not in kinds
+    assert kinds.count("serve_step") == 5

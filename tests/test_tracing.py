@@ -92,22 +92,28 @@ def test_emit_span_after_the_fact_adopts_thread_stack(tmp_path):
     assert spans["child"]["parent_id"] == spans["parent"]["span_id"]
 
 
-def test_annotate_and_timer_emit_matching_spans(tmp_path):
+def test_annotate_emits_nested_spans_and_the_plain_timer_none(tmp_path):
     path, logger, telemetry = make_bus(tmp_path)
     tracing.install(tracing.Tracer(telemetry, run_id="r"))
     with profiling.annotate("host_region"):
+        with profiling.annotate("inner_region"):
+            time.sleep(0.001)
+        # An after-the-fact span that names no parent adopts the open one.
+        tracing.emit_span("after_the_fact", time.time(), 1.0)
+    with profiling.Timer() as t:    # times, and emits nothing
         time.sleep(0.001)
-    with profiling.Timer(name="timed_region") as t:
-        time.sleep(0.001)
-    with profiling.Timer() as anon:  # no name -> no span, still times
-        pass
     logger.close()
-    assert t.elapsed > 0 and anon.elapsed >= 0
+    assert t.elapsed > 0
     spans = {r["name"]: r for r in read_records(path)
              if r.get("kind") == "span"}
-    assert spans["host_region"]["source"] == "annotate"
-    assert spans["timed_region"]["source"] == "timer"
-    assert "Timer" not in spans and len(spans) == 2
+    assert set(spans) == {"host_region", "inner_region", "after_the_fact"}
+    outer, inner = spans["host_region"], spans["inner_region"]
+    assert outer["source"] == inner["source"] == "annotate"
+    assert outer["parent_id"] == 0
+    assert inner["parent_id"] == outer["span_id"]
+    assert spans["after_the_fact"]["parent_id"] == outer["span_id"]
+    assert outer["dur_ms"] >= inner["dur_ms"] > 0
+    assert outer["t_unix"] <= inner["t_unix"]
 
 
 def test_annotate_without_tracer_still_works():
