@@ -35,7 +35,12 @@ Four chips (``--chips 4``, run by the builder):
 - ``dp4``              the 406M sync step on a one-device mesh and on the
                        four-device mesh, same global batch; placement,
                        all-reduce and loss agreement; what attention program
-                       each lowered; then ``train.py`` on the four chips.
+                       each lowered (the four-device program must hold Mosaic
+                       calls: ``flash_attention`` maps its kernel over the
+                       mesh's batch axis; the one-device program on a
+                       four-chip host still lowers dense, because
+                       ``_gspmd_hazard()`` asks ``jax.device_count()``); then
+                       ``train.py`` on the four chips.
 
 Every phase prints one JSON line; a failed assertion or child ends the run
 non-zero.  On success the LAST line is
@@ -733,6 +738,7 @@ def child_dp4(args) -> dict:
         mesh = mesh_lib.data_parallel_mesh(num_devices=n)
         compiled, state, batch, text, compile_s = sync_program(
             jax, size, args.seed, mesh)
+        mosaic = text.count("tpu_custom_call")
         if n == 4:
             check(all(len(x.sharding.device_set) == 4
                       for x in jax.tree.leaves(state.params)),
@@ -742,11 +748,15 @@ def child_dp4(args) -> dict:
                   asserted)
             check("all-reduce" in text,
                   "the four-chip program contains an all-reduce", asserted)
+            if not args.rehearse:
+                check(mosaic > 0, "the four-device program contains Mosaic "
+                      "calls (tpu_custom_call): the flash kernel is mapped "
+                      "over the mesh's batch axis, not dropped for dense "
+                      "XLA", asserted)
         losses = []
         for _ in range(3):
             state, metrics = compiled(state, batch)
             losses.append(float(metrics["loss"]))
-        mosaic = text.count("tpu_custom_call")
         programs[f"{n}_device_mesh"] = dict(
             losses=[round(l, 4) for l in losses], mosaic_calls=mosaic,
             attention=lowered_attention(device, mosaic),

@@ -27,16 +27,25 @@ XLA formulation.
 
 On non-TPU backends the kernels run in interpreter mode, so CPU CI covers
 them.
+
+Several chips: GSPMD cannot partition a Mosaic call.  Where the program is
+traced under :func:`ambient_mesh` (the sync step builders do that) and the
+mesh's only axes of size > 1 are batch axes, :func:`flash_attention` maps
+the kernel over dimension 0 with ``shard_map``; any other multi-chip jit
+outside a shard_map gets the dense XLA formulation.
 """
 
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
 
 _NEG = -1e30
 _LANE = 128
@@ -263,8 +272,10 @@ def _gspmd_hazard() -> bool:
             "gspmd-hazard",
             "pallas kernel requested under a multi-chip jit outside "
             "shard_map: GSPMD cannot partition Mosaic calls, using the "
-            "dense XLA formulation instead (wrap the op in shard_map — "
-            "e.g. the ring attention path — to keep pallas on multi-chip)")
+            "dense XLA formulation instead (flash_attention keeps its "
+            "kernel under a parallel/sync.py step built for a mesh whose "
+            "only non-trivial axes are batch axes; otherwise wrap the op in "
+            "shard_map, as the ring attention path does)")
     return hazard
 
 
@@ -906,25 +917,96 @@ def _dense_reference(q, k, v, kv_mask, *, causal: bool, window: int = 0):
                                  window=window, backend="xla")
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _flash(q, k, v, kv_mask, causal, window):
-    out, _ = _flash_forward(q, k, v, kv_mask, causal=causal, window=window)
-    return out
+def _flash_vjp(forward, backward):
+    """The differentiable kernel call over one pair of forward / backward
+    implementations (``causal`` and ``window`` are static)."""
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+    def _flash(q, k, v, kv_mask, causal, window):
+        out, _ = forward(q, k, v, kv_mask, causal=causal, window=window)
+        return out
+
+    def _flash_fwd(q, k, v, kv_mask, causal, window):
+        out, lse = forward(q, k, v, kv_mask, causal=causal, window=window)
+        return out, (q, k, v, kv_mask, out, lse)
+
+    def _flash_bwd(causal, window, residuals, g):
+        q, k, v, kv_mask, o, lse = residuals
+        dq, dk, dv = backward(q, k, v, kv_mask, o, lse, g, causal=causal,
+                              window=window)
+        return dq, dk, dv, None
+
+    _flash.defvjp(_flash_fwd, _flash_bwd)
+    return _flash
 
 
-def _flash_fwd(q, k, v, kv_mask, causal, window):
-    out, lse = _flash_forward(q, k, v, kv_mask, causal=causal, window=window)
-    return out, (q, k, v, kv_mask, out, lse)
+_flash = _flash_vjp(_flash_forward, _flash_backward)
+
+# The same kernels behind a jit boundary, for the body of the shard_map in
+# :func:`_flash_over_batch_axes` ONLY: a 24-layer step calls one shape 72
+# times, and the boundary has each kernel body traced and lowered once a
+# program instead (2.4 s of a 406M step's set-up, the compiled program
+# unchanged).  Outside a shard_map the boundary is NOT neutral — it keeps the
+# layout transposes around the kernel from fusing with their neighbours (96
+# more copies in the one-chip 406M step) — so :func:`flash_attention` calls
+# ``_flash`` there.
+_flash_per_device = _flash_vjp(
+    jax.jit(_flash_forward, static_argnames=("causal", "window")),
+    jax.jit(_flash_backward, static_argnames=("causal", "window")))
 
 
-def _flash_bwd(causal, window, residuals, g):
-    q, k, v, kv_mask, o, lse = residuals
-    dq, dk, dv = _flash_backward(q, k, v, kv_mask, o, lse, g, causal=causal,
-                                 window=window)
-    return dq, dk, dv, None
+_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "flash_attention_mesh", default=None)
 
 
-_flash.defvjp(_flash_fwd, _flash_bwd)
+@contextlib.contextmanager
+def ambient_mesh(mesh):
+    """Name the ``Mesh`` a program is being traced for, so that
+    :func:`flash_attention` can keep its kernel on several chips.
+
+    The sync step builders (``parallel/sync.py``) trace their bodies under
+    this.  It is a variable of this module and not ``jax.set_mesh``: the
+    explicit ``shard_map(mesh=...)`` below wants the concrete ``Mesh``, and
+    JAX's own ambient mesh is part of how everything else in the program is
+    traced and keyed, which has to stay as it is for one-device programs."""
+    token = _MESH.set(mesh)
+    try:
+        yield
+    finally:
+        _MESH.reset(token)
+
+
+def _batch_axes(batch: int):
+    """``(mesh, axes)`` when the kernel call can be mapped over the ambient
+    mesh's batch axes, else None: a mesh is ambient, it spans several
+    devices, every axis of size > 1 is one that ``batch_sharding`` puts on
+    dimension 0, their product divides ``batch``, and the trace is not
+    inside a shard_map already (there the kernel is per device as it is)."""
+    mesh = _MESH.get()
+    if mesh is None or mesh.size == 1 or _inside_shard_map():
+        return None
+    from ...parallel.mesh import batch_sharding  # parallel/ imports this
+    dim0 = batch_sharding(mesh).spec[0]
+    axes = dim0 if isinstance(dim0, tuple) else (dim0,)
+    wide = {a for a, n in mesh.shape.items() if n > 1}
+    if not wide <= set(axes) or batch % mesh.size:
+        return None
+    return mesh, axes
+
+
+def _flash_over_batch_axes(mesh, axes, q, k, v, kv_mask, causal, window):
+    """``_flash`` on each device's rows: GSPMD cannot partition a Mosaic
+    call, so the call site says how — dimension 0 over the batch axes,
+    nothing to communicate.  The custom VJP sits inside the map, so the
+    backward kernels are per device too; everything around the call
+    (projections, the gradient all-reduce) stays GSPMD's to place."""
+    rows = P(axes)
+    return jax.shard_map(
+        lambda q, k, v, kv_mask: _flash_per_device(
+            q, k, v, kv_mask, causal, window),
+        mesh=mesh, in_specs=(rows, rows, rows, None if kv_mask is None
+                             else rows),
+        out_specs=rows, check_vma=False)(q, k, v, kv_mask)
 
 
 def flash_attention(
@@ -956,10 +1038,16 @@ def flash_attention(
         # right program there.
         return _dense_reference(q, k, v, kv_mask, causal=causal,
                                 window=window)
+    mapped = _batch_axes(q.shape[0])
+    if mapped is not None:
+        return _flash_over_batch_axes(*mapped, q, k, v, kv_mask, causal,
+                                      window)
     if _gspmd_hazard():
-        # Multi-chip jit outside shard_map: GSPMD cannot partition the
-        # Mosaic call — dense XLA partitions fine.  (The ring path wraps its
-        # chunk kernels in shard_map and keeps pallas on multi-chip.)
+        # Multi-chip jit outside shard_map, and no mesh whose batch axes the
+        # call could be mapped over (none ambient, or one with a model, seq,
+        # pipe or expert axis): GSPMD cannot partition the Mosaic call —
+        # dense XLA partitions fine.  (The ring path wraps its chunk kernels
+        # in shard_map and keeps pallas on multi-chip.)
         return _dense_reference(q, k, v, kv_mask, causal=causal,
                                 window=window)
     return _flash(q, k, v, kv_mask, causal, window)

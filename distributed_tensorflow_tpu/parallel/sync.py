@@ -19,12 +19,13 @@ inside one jitted step:
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, wraps
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from ..ops.pallas.flash_attention import ambient_mesh
 from .mesh import DATA_AXIS, num_replicas
 
 # loss_fn signature: (params, batch) -> (scalar_loss, aux_metrics_dict);
@@ -53,9 +54,28 @@ def build_sync_train_step(mesh: Mesh, loss_fn: LossFn, *, donate: bool = True,
     to the metrics as ``grad_norm`` — one extra reduction, observability for
     divergence/clipping decisions.
     """
+    return _build_jit_for_mesh(mesh, _grad_and_update(
+        loss_fn, needs_rng, ema_decay, log_grad_norm), donate)
+
+
+def _build_jit_for_mesh(mesh: Mesh, step, donate: bool):
+    """``jax.jit(step)`` whose body is traced with ``mesh`` ambient for the
+    pallas flash kernel: on a mesh of several devices the op then maps its
+    Mosaic call over the batch axes instead of lowering dense attention
+    (``ops/pallas/flash_attention.py``).  Nothing else reads the mesh: the
+    gradient all-reduce and every placement stay GSPMD's.  A one-device
+    mesh has nothing to map over, and its step is traced as it always was
+    (the wrapper alone cost the 406M step half a second of tracing)."""
     kwargs = {"donate_argnums": (0,)} if donate else {}
-    return jax.jit(_grad_and_update(loss_fn, needs_rng, ema_decay,
-                                    log_grad_norm), **kwargs)
+    if mesh.size == 1:
+        return jax.jit(step, **kwargs)
+
+    @wraps(step)
+    def traced(*args):
+        with ambient_mesh(mesh):
+            return step(*args)
+
+    return jax.jit(traced, **kwargs)
 
 
 def _ema_update(decay: float, ema: Any, params: Any) -> Any:
@@ -114,8 +134,7 @@ def build_stateful_sync_train_step(mesh: Mesh, loss_fn_with_state, *,
         metrics = {"loss": loss, "global_step": new_state.global_step, **aux}
         return new_state, metrics
 
-    kwargs = {"donate_argnums": (0,)} if donate else {}
-    return jax.jit(_step, **kwargs)
+    return _build_jit_for_mesh(mesh, _step, donate)
 
 
 def build_scanned_sync_train_step(mesh: Mesh, loss_fn: LossFn, *,
@@ -145,8 +164,7 @@ def build_scanned_sync_train_step(mesh: Mesh, loss_fn: LossFn, *,
         state, stacked = jax.lax.scan(_one, state, batches, length=num_steps)
         return state, jax.tree.map(lambda m: m[-1], stacked)
 
-    kwargs = {"donate_argnums": (0,)} if donate else {}
-    return jax.jit(_step, **kwargs)
+    return _build_jit_for_mesh(mesh, _step, donate)
 
 
 def build_scanned_stateful_sync_train_step(mesh: Mesh, loss_fn_with_state, *,
@@ -168,8 +186,7 @@ def build_scanned_stateful_sync_train_step(mesh: Mesh, loss_fn_with_state, *,
         state, stacked = jax.lax.scan(_one, state, batches, length=num_steps)
         return state, jax.tree.map(lambda m: m[-1], stacked)
 
-    kwargs = {"donate_argnums": (0,)} if donate else {}
-    return jax.jit(_step, **kwargs)
+    return _build_jit_for_mesh(mesh, _step, donate)
 
 
 def build_accumulating_sync_train_step(mesh: Mesh, loss_fn: LossFn, *,
@@ -237,8 +254,7 @@ def build_accumulating_sync_train_step(mesh: Mesh, loss_fn: LossFn, *,
             metrics["grad_norm"] = grad_norm
         return new_state, metrics
 
-    kwargs = {"donate_argnums": (0,)} if donate else {}
-    return jax.jit(_step, **kwargs)
+    return _build_jit_for_mesh(mesh, _step, donate)
 
 
 def stack_microbatches(batches):

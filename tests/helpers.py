@@ -1,5 +1,6 @@
 """Shared test helpers: tiny MLP bundles and datasets used across the
-step-builder test files, the standalone-TpuServer patch for CLI e2e
+step-builder test files, where a traced program holds its pallas calls,
+the standalone-TpuServer patch for CLI e2e
 tests (no coordination service, no jax.distributed), and the
 deterministic test-port allocator shared by the subprocess suites."""
 
@@ -48,6 +49,26 @@ def free_port() -> int:
             _PORTS_HANDED_OUT.add(port)
             return port
     raise RuntimeError("free_port: port space exhausted")
+
+
+def primitives(jaxpr, inside=False, out=None):
+    """[(primitive name, traced inside a shard_map?)] over a jaxpr and every
+    jaxpr its equations hold."""
+    out = [] if out is None else out
+    for eqn in jaxpr.eqns:
+        out.append((eqn.primitive.name, inside))
+        below = inside or eqn.primitive.name == "shard_map"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            primitives(sub, below, out)
+    return out
+
+
+def kernel_placement(fn, *args):
+    """(pallas calls traced inside a shard_map, pallas calls outside one)."""
+    calls = [inside for name, inside
+             in primitives(jax.make_jaxpr(fn)(*args).jaxpr)
+             if name == "pallas_call"]
+    return sum(calls), len(calls) - sum(calls)
 
 
 def make_mlp_state(mesh, hidden=8, lr=0.1):

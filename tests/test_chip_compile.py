@@ -8,7 +8,11 @@ or times — it guards every later PR against kernels the chip would refuse,
 at no chip time.  A compile that passes is not a chip run.
 """
 
+import dataclasses
+import math
 import os
+import re
+import warnings
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs to /tmp
 
@@ -16,15 +20,21 @@ import jax
 import jax.numpy as jnp
 import pytest
 from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
+from distributed_tensorflow_tpu.models import gpt as gpt_lib
 from distributed_tensorflow_tpu.ops import quant_train
 from distributed_tensorflow_tpu.ops.pallas import flash_attention as flash_lib
 from distributed_tensorflow_tpu.ops.pallas import layer_norm as ln_lib
+from distributed_tensorflow_tpu.parallel import mesh as mesh_lib
+from distributed_tensorflow_tpu.parallel import sync as sync_lib
+from distributed_tensorflow_tpu.training.optimizers import make_optimizer
+from distributed_tensorflow_tpu.training.state import TrainState
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
         topo = topologies.get_topology_desc(platform="tpu",
@@ -32,7 +42,19 @@ def one_chip():
     except Exception as e:  # no TPU compiler in this installation
         pytest.skip(f"cannot describe a v5e topology here: {e!r}")
     assert topo.devices[0].device_kind == "TPU v5 lite"
+    return topo
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The four described devices, for ``mesh_lib.create_mesh(devices=...)``."""
+    assert len(topo.devices) == 4
+    return list(topo.devices)
 
 
 @pytest.fixture(autouse=True)
@@ -96,3 +118,98 @@ def test_int8_gelu_mlp_fwd_bwd_compiles_for_v5e(one_chip):
     fwd_bwd = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))
     assert mosaic_calls(fwd_bwd, arg(M, H, dtype=jnp.bfloat16), arg(H, I),
                         arg(I), arg(I, H), arg(H)) == 4
+
+
+# ------------------------------------------------- the sync step, four chips
+
+_BYTES = {"bf16": 2, "f32": 4, "s32": 4, "u32": 4, "pred": 1}
+_COLLECTIVE = re.compile(
+    r" = (?P<shape>.*?) (?P<kind>all-reduce|all-gather|reduce-scatter|"
+    r"all-to-all|collective-permute|collective-broadcast)(?:-start)?\(")
+
+
+def collectives(text: str) -> dict:
+    """Kind -> sorted [(dtype, bytes)] over the operands of every collective
+    in a compiled program's text (tuple-shaped ones count each member)."""
+    out: dict = {}
+    for line in text.splitlines():
+        m = _COLLECTIVE.search(line)
+        if m is None:
+            continue
+        for dtype, dims in re.findall(r"(\w+)\[([\d,]*)\]", m["shape"]):
+            n = math.prod(int(d) for d in dims.split(",") if d)
+            out.setdefault(m["kind"], []).append((dtype, n * _BYTES[dtype]))
+    return {k: sorted(v) for k, v in out.items()}
+
+
+def compile_wide_step(devices, **axes):
+    """``build_sync_train_step`` over a 2-layer ``GptLM`` at gpt2-medium's
+    widths, 16 rows of 1024 tokens, compiled for a mesh of the described
+    devices as ``perfbench/worker.py:run_train`` compiles its cell's."""
+    mesh = mesh_lib.create_mesh(devices=devices, **axes)
+    cfg = gpt_lib.GptConfig(
+        vocab_size=50257, hidden_size=1024, num_layers=2, num_heads=16,
+        intermediate_size=4096, max_position=1024, dtype="bfloat16",
+        attention_backend="pallas", pos_encoding="learned")
+    model = gpt_lib.GptLM(cfg)
+
+    def loss_fn(params, batch):
+        loss, acc = gpt_lib.lm_loss(model.apply({"params": params}, batch),
+                                    batch)
+        return loss, {"accuracy": acc}
+
+    def fresh_state():   # for its shapes: the same whatever the attention
+        params = gpt_lib.GptLM(dataclasses.replace(
+            cfg, attention_backend="xla")).init(
+                jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]
+        return TrainState.create(None, params, make_optimizer("adam", 3e-4))
+
+    everywhere = NamedSharding(mesh, P())
+    state = jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=everywhere),
+        jax.eval_shape(fresh_state))
+    tokens = jax.ShapeDtypeStruct((16, 1024), jnp.int32,
+                                  sharding=mesh_lib.batch_sharding(mesh))
+    step = sync_lib.build_sync_train_step(mesh, loss_fn)
+    return step.lower(state, tokens).compile()
+
+
+@pytest.fixture
+def four_visible(monkeypatch):
+    monkeypatch.setattr(jax, "device_count", lambda: 4)
+    monkeypatch.setattr(flash_lib, "_warned", set())
+
+
+def test_sync_step_keeps_the_flash_kernel_on_four_chips(
+        four_chips, four_visible, monkeypatch):
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")      # no dense fallback, so no warning
+        mapped = compile_wide_step(four_chips, data=4)
+    # With no mesh ambient the op takes the dense fallback, as before PR 27.
+    monkeypatch.setattr(flash_lib, "_batch_axes", lambda batch: None)
+    with pytest.warns(UserWarning, match="GSPMD cannot partition"):
+        dense = compile_wide_step(four_chips, data=4)
+
+    # A layer: one kernel forward, the dq and the dk/dv kernel backward.
+    assert mapped.as_text().count("tpu_custom_call") == 6
+    assert dense.as_text().count("tpu_custom_call") == 0
+    # The gradient reduction is GSPMD's, byte for byte: the blocks' gradients
+    # in bf16 where the backward pass made them, embedding and head in f32.
+    reduced = collectives(mapped.as_text())
+    assert set(reduced) == {"all-reduce"}, reduced
+    assert reduced == collectives(dense.as_text())
+    assert {dtype for dtype, _ in reduced["all-reduce"]} >= {"bf16", "f32"}
+    # No f32 scores written and read per layer.
+    assert (mapped.memory_analysis().temp_size_in_bytes
+            < dense.memory_analysis().temp_size_in_bytes)
+
+
+def test_sync_step_with_a_model_axis_keeps_the_dense_fallback(
+        four_chips, four_visible):
+    with pytest.warns(UserWarning, match="GSPMD cannot partition") as caught:
+        compiled = compile_wide_step(four_chips, data=2, model=2)
+    assert compiled.as_text().count("tpu_custom_call") == 0
+    # Four attention call sites traced (two layers, and their transposes
+    # come from the same trace), one warning.
+    assert len([w for w in caught
+                if "GSPMD cannot partition" in str(w.message)]) == 1

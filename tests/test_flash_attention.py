@@ -12,7 +12,11 @@ import numpy as np
 import pytest
 
 from distributed_tensorflow_tpu.ops.attention import dot_product_attention
-from distributed_tensorflow_tpu.ops.pallas.flash_attention import flash_attention
+from distributed_tensorflow_tpu.ops.pallas.flash_attention import (
+    ambient_mesh, flash_attention)
+from distributed_tensorflow_tpu.parallel import mesh as mesh_lib
+
+from helpers import kernel_placement, primitives
 
 
 def _qkv(key, B=2, S=32, H=2, D=8, dtype=jnp.float32):
@@ -198,3 +202,61 @@ def test_flash_1024_block_branch_matches_dense():
     got_w = flash_attention(q, k, v, causal=True, window=512)
     want_w = dot_product_attention(q, k, v, causal=True, window=512)
     np.testing.assert_allclose(got_w, want_w, rtol=1e-5, atol=1e-5)
+
+
+# ------------------------------------ the call site on a mesh of several devices
+
+@pytest.mark.parametrize("axes,batch,mapped", [
+    (dict(data=4), 8, True),
+    (dict(data=4), 6, False),             # rows do not divide over the axis
+    (dict(data=2, model=2), 8, False),    # heads over `model`: a later PR's
+    (dict(data=2, seq=2), 8, False),      # the ring path's mesh
+    (dict(data=1), 8, False),             # one device: the plain call
+], ids=["data4", "data4_rows6", "data2_model2", "data2_seq2", "one_device"])
+def test_flash_maps_the_kernel_over_the_ambient_meshs_batch_axes(
+        axes, batch, mapped):
+    n = int(np.prod(list(axes.values())))
+    mesh = mesh_lib.create_mesh(devices=jax.devices()[:n], **axes)
+    q, k, v = _qkv(11, B=batch)
+    kv_mask = jnp.ones((batch, 32), bool).at[:, 29:].set(False)
+
+    def fwd_bwd(q, k, v, kv_mask):
+        def loss(q, k, v):
+            return jnp.sum(flash_attention(q, k, v, kv_mask=kv_mask,
+                                           causal=True) ** 2)
+        with ambient_mesh(mesh):
+            return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    # One kernel forward, the dq and the dk/dv kernel backward.
+    assert kernel_placement(fwd_bwd, q, k, v, kv_mask) == (
+        (3, 0) if mapped else (0, 3))
+    # No mesh ambient: the plain call, as ever.
+    assert kernel_placement(
+        lambda *a: flash_attention(*a, causal=True), q, k, v) == (0, 1)
+
+    got = jax.jit(fwd_bwd)(q, k, v, kv_mask)
+    want = jax.value_and_grad(
+        lambda q, k, v: jnp.sum(dot_product_attention(
+            q, k, v, kv_mask=kv_mask, causal=True) ** 2),
+        argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
+
+
+def test_flash_inside_a_shard_map_is_not_mapped_again():
+    from jax.sharding import PartitionSpec as P
+    mesh = mesh_lib.create_mesh(data=4, devices=jax.devices()[:4])
+    q, k, v = _qkv(12, B=8)
+
+    def whole_step(q, k, v):
+        with ambient_mesh(mesh):
+            return jax.shard_map(
+                lambda q, k, v: flash_attention(q, k, v, causal=True),
+                mesh=mesh, in_specs=P("data"), out_specs=P("data"),
+                check_vma=False)(q, k, v)
+
+    prims = primitives(jax.make_jaxpr(whole_step)(q, k, v).jaxpr)
+    assert [name for name, _ in prims].count("shard_map") == 1
+    np.testing.assert_allclose(
+        jax.jit(whole_step)(q, k, v),
+        dot_product_attention(q, k, v, causal=True), rtol=1e-5, atol=1e-5)

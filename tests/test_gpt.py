@@ -330,3 +330,91 @@ def test_builder_rejects_tiny_bpe_vocab():
     a smaller table would under-cover the byte-fallback id range."""
     with pytest.raises(ValueError, match="257"):
         build_gpt_mini(0.1, tokenizer="bpe", bpe_vocab=100)
+
+
+# --------------------------------------- the pallas backend on a data mesh
+
+def _pallas_step_inputs(mesh, backend="pallas", dropout_rate=0.0, rows=8):
+    import optax
+    bundle = build_gpt_mini(1e-3, seq_len=SEQ, dtype="float32",
+                            attention_backend=backend, tx=optax.sgd(0.5),
+                            dropout_rate=dropout_rate)
+    batch = jax.tree.map(
+        lambda a: jax.device_put(a, mesh_lib.batch_sharding(mesh)),
+        bundle.load_datasets(None).train.next_batch(rows))
+    return bundle, batch
+
+
+def _assert_same_step(got, want, tol=1e-5):
+    (state_a, metrics_a), (state_b, metrics_b) = got, want
+    np.testing.assert_allclose(float(metrics_a["loss"]),
+                               float(metrics_b["loss"]), rtol=tol)
+    # Plain SGD: the parameters' change IS the gradient, times the rate.
+    for a, b in zip(jax.tree.leaves(state_a.params),
+                    jax.tree.leaves(state_b.params)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=tol)
+
+
+def test_pallas_step_on_a_data_mesh_maps_its_kernels_over_the_batch():
+    from helpers import kernel_placement
+    mesh = mesh_lib.create_mesh(data=4, devices=jax.devices()[:4])
+    bundle, batch = _pallas_step_inputs(mesh)
+    step = sync_lib.build_sync_train_step(mesh, bundle.loss_fn, donate=False)
+    state = replicate_state(mesh, bundle.state)
+    # Per layer one kernel forward and two backward, all inside a shard_map.
+    layers = gpt_lib.mini().num_layers
+    assert kernel_placement(step, state, batch) == (3 * layers, 0)
+    mapped = step(state, batch)
+
+    one = mesh_lib.create_mesh(data=1, devices=jax.devices()[:1])
+    bundle1, batch1 = _pallas_step_inputs(one)
+    step1 = sync_lib.build_sync_train_step(one, bundle1.loss_fn, donate=False)
+    assert kernel_placement(step1, bundle1.state, batch1) == (0, 3 * layers)
+    _assert_same_step(mapped,
+                      step1(replicate_state(one, bundle1.state), batch1))
+
+    dense, _ = _pallas_step_inputs(mesh, backend="xla")
+    _assert_same_step(mapped, sync_lib.build_sync_train_step(
+        mesh, dense.loss_fn, donate=False)(state, batch))
+
+
+def _scan_last(body, state, batches):
+    state, stacked = jax.lax.scan(body, state, batches, length=2)
+    return state, jax.tree.map(lambda m: m[-1], stacked)
+
+
+@pytest.mark.parametrize("case", ["replicated", "fsdp", "needs_rng",
+                                  "scanned", "model_axis"])
+def test_pallas_step_on_a_mesh_computes_what_the_unmapped_step_did(case):
+    """The step as it was built before the builders made their mesh ambient
+    (a bare ``jax.jit`` of the same body: GSPMD partitions the interpreted
+    kernel) gives the same loss and the same update: FSDP-placed state, a
+    dropout key drawn once for the global batch and the scanned builder
+    included; a mesh with a ``model`` axis is not mapped at all."""
+    from helpers import kernel_placement
+    from distributed_tensorflow_tpu.parallel.sharding import fsdp_state
+    axes = dict(data=2, model=2) if case == "model_axis" else dict(data=4)
+    mesh = mesh_lib.create_mesh(devices=jax.devices()[:4], **axes)
+    needs_rng = case == "needs_rng"
+    bundle, batch = _pallas_step_inputs(
+        mesh, dropout_rate=0.1 if needs_rng else 0.0)
+    state = (fsdp_state(mesh, bundle.state, min_size=1024) if case == "fsdp"
+             else replicate_state(mesh, bundle.state))
+    body = sync_lib._grad_and_update(bundle.loss_fn, needs_rng)
+    if case == "scanned":
+        batch = jax.tree.map(
+            lambda a: jax.device_put(jnp.stack([a, a[::-1]]),
+                                     mesh_lib.stacked_batch_sharding(mesh)),
+            batch)
+        step = sync_lib.build_scanned_sync_train_step(
+            mesh, bundle.loss_fn, num_steps=2, donate=False)
+        before = jax.jit(lambda s, b: _scan_last(body, s, b))
+    else:
+        step = sync_lib.build_sync_train_step(
+            mesh, bundle.loss_fn, needs_rng=needs_rng, donate=False)
+        before = jax.jit(body)
+    calls = 3 * gpt_lib.mini().num_layers
+    assert kernel_placement(step, state, batch) == (
+        (0, calls) if case == "model_axis" else (calls, 0))
+    assert kernel_placement(before, state, batch) == (0, calls)
+    _assert_same_step(step(state, batch), before(state, batch))
