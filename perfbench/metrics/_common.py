@@ -11,7 +11,7 @@ for both processes.
 
 from __future__ import annotations
 
-from perfbench import costs, peaks, stats
+from perfbench import costs, peaks, spec, stats
 
 GIB = 2.0 ** 30
 
@@ -154,6 +154,15 @@ def collective_pct(ctx) -> float | None:
     return 100.0 * tr["collective_s"] / tr["busy_s"]
 
 
+def costs_of(cfg: dict):
+    """Where a step's operations and bytes are counted: the file the
+    configuration names under ``costs`` (``train_step``, ``prefill`` and
+    ``decode_step`` as ``perfbench/costs.py`` has them), or that file.  The
+    least time and the peaks are shared: a configuration brings its counts,
+    never its own peak."""
+    return spec.named_module(cfg, "costs") if "costs" in cfg else costs
+
+
 def traced_least_seconds(ctx) -> tuple[float, dict] | None:
     """The least time the chip could take for the whole pieces of work that
     ran inside the traced slice, and how much of it each bound sets."""
@@ -162,6 +171,7 @@ def traced_least_seconds(ctx) -> tuple[float, dict] | None:
         return None
     pk = peaks.peaks_for(ctx["device"]["kind"])
     cfg, t0, t1 = ctx["config"], tr["t0"], tr["t1"]
+    counts = costs_of(cfg)
     total, by = 0.0, {"compute": 0.0, "memory": 0.0}
 
     def add(cost, chips=1):
@@ -174,14 +184,14 @@ def traced_least_seconds(ctx) -> tuple[float, dict] | None:
         if ctx["kind"] == "train":
             if s["t_start"] >= t0 and s["t_end"] <= t1:
                 c = ctx["counters"]
-                add(costs.train_step(cfg, c["rows"], c["seq"],
-                                     c["n_params"]), ctx["chips"])
+                add(counts.train_step(cfg, c["rows"], c["seq"],
+                                      c["n_params"]), ctx["chips"])
             continue
         for a, b, plen in s["admits"]:
             if a >= t0 and b <= t1:
-                add(costs.prefill(cfg, plen))
+                add(counts.prefill(cfg, plen))
         if s["context"] and s["t_decode"] >= t0 and s["t_end"] <= t1:
-            add(costs.decode_step(cfg, s["context"]))
+            add(counts.decode_step(cfg, s["context"]))
     return (total, by) if total else None
 
 

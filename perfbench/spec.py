@@ -36,6 +36,12 @@ def load_module(path: str):
     return mod
 
 
+def named_module(cfg: dict, key: str):
+    """The file a configuration names under ``key`` (its ``reference``, its
+    ``layout``, its ``costs``), by its path from the root of the checkout."""
+    return load_module(os.path.join(ROOT, cfg[key]))
+
+
 def benchmark() -> dict:
     return load_json(os.path.join(ROOT, "BENCHMARK.json"))
 
@@ -71,7 +77,8 @@ def cell(name: str, rehearse: bool = False) -> dict:
         config = deep_update(config, config["rehearsal"])
         traffic = deep_update(traffic, traffic["rehearsal"])
     return {"name": name, "chips": entry["chips"], "config": config,
-            "config_name": entry["config"], "traffic": traffic,
+            "config_name": entry["config"],
+            "config_file": config_entry["file"], "traffic": traffic,
             "traffic_name": entry["traffic"], "limits": limits,
             "metrics": metrics_of(bench, name)}
 

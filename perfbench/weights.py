@@ -3,7 +3,10 @@ benchmark.  The harness fills the program's parameter tree from here and the
 plain reference asks for the same leaves by the same names, so the two sides
 hold the same numbers without either taking anything the other has made.
 
-A leaf's values depend on ``(seed, layer index, name within the layer)``.
+Which leaves a configuration has, layer by layer, is its layout's to say
+(``perfbench/layouts/``; the configuration's file names it under ``layout``,
+and gets the dense decoder's where it names none).  A leaf's values depend
+on ``(seed, layer index, name within the layer)`` and on nothing else.
 Kernels are normal / sqrt(fan_in), embeddings and biases normal x a scale
 from the configuration's ``init``, norm scales 1 and norm biases 0.  Values
 are drawn in float32 and rounded to the type the parameters are held in.
@@ -16,52 +19,51 @@ import zlib
 import jax
 import jax.numpy as jnp
 
+from perfbench import spec
+from perfbench.layouts import dense_decoder
 
-def shapes(model: dict) -> tuple[dict, dict]:
-    """``(per-layer leaves, top-level leaves)``: name -> shape, as
-    ``models/gpt.py`` lays them out (the harness checks that it still does)."""
-    h, heads = model["hidden_size"], model["num_heads"]
-    kv = model.get("kv_heads") or heads
-    d, inter, vocab = h // heads, model["intermediate_size"], model["vocab_size"]
-    layer, top = {}, {}
-    norm_bias = model["norm"] == "layernorm"
-    for ln in ("ln_attn", "ln_mlp"):
-        layer[f"{ln}/scale"] = (h,)
-        if norm_bias:
-            layer[f"{ln}/bias"] = (h,)
-    if kv == heads:
-        layer["qkv/kernel"], layer["qkv/bias"] = (h, 3, heads, d), (3, heads, d)
-    else:
-        layer["q_proj/kernel"], layer["q_proj/bias"] = (h, heads, d), (heads, d)
-        layer["kv_proj/kernel"] = (h, 2, kv, d)
-        layer["kv_proj/bias"] = (2, kv, d)
-    layer["out/kernel"], layer["out/bias"] = (heads, d, h), (h,)
-    layer["mlp_in/kernel"], layer["mlp_out/kernel"] = (h, inter), (inter, h)
-    if model["activation"] == "swiglu":
-        layer["mlp_gate/kernel"] = (h, inter)
-    else:
-        layer["mlp_in/bias"], layer["mlp_out/bias"] = (inter,), (h,)
-    top["word_emb/embedding"] = (vocab, h)
-    if model["pos_encoding"] != "rope":
-        top["pos_emb/embedding"] = (model["max_position"], h)
-    top["ln_final/scale"] = (h,)
-    if norm_bias:
-        top["ln_final/bias"] = (h,)
-    top["lm_head/kernel"], top["lm_head/bias"] = (h, vocab), (vocab,)
-    return layer, top
+DENSE_LAYOUT = "perfbench/layouts/dense_decoder.py"
+RULES = {"fan_in", "constant", "uniform"}
 
 
+def layout_file(cfg: dict) -> str:
+    return cfg.get("layout", DENSE_LAYOUT)
 
 
-def leaf(key, name: str, shape, init: dict, dtype):
-    part, what = name.rsplit("/", 1)
-    if part.startswith("ln_"):
+def layout(cfg: dict):
+    """The file that says which leaves a configuration has: the one its
+    ``layout`` key names, or the dense decoder's."""
+    return spec.named_module(cfg, "layout") if "layout" in cfg \
+        else dense_decoder
+
+
+def leaf(key, name: str, described, init: dict, dtype):
+    """One leaf's values.  By default: ``ln_*`` scales 1 and biases 0, a
+    ``kernel`` normal / sqrt(its first axis), an ``embedding`` and anything
+    else (a bias) normal x the configuration's ``init`` scale.  A layout
+    that gives the leaf as a dictionary overrules that with one of
+    ``fan_in`` (a kernel normal / sqrt(fan_in)), ``constant`` (every entry
+    that value) or ``uniform`` ([low, high))."""
+    shape, rule = described, {}
+    if isinstance(described, dict):
+        rule = dict(described)
+        shape = rule.pop("shape")
+        if len(rule) > 1 or set(rule) - RULES:
+            raise ValueError(f"leaf {name!r}: one of {sorted(RULES)} says "
+                             f"how it is drawn, not {sorted(rule)}")
+    part, _, what = name.rpartition("/")
+    if "constant" in rule:
+        return jnp.full(shape, rule["constant"], dtype)
+    if not rule and part.startswith("ln_"):
         return (jnp.ones if what == "scale" else jnp.zeros)(shape, dtype)
     k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
+    if "uniform" in rule:
+        low, high = rule["uniform"]
+        return jax.random.uniform(k, shape, jnp.float32, low,
+                                  high).astype(dtype)
     x = jax.random.normal(k, shape, jnp.float32)
-    if what == "kernel":
-        fan_in = shape[0] * shape[1] if part == "out" else shape[0]
-        x = x * (fan_in ** -0.5)
+    if "fan_in" in rule or what == "kernel":
+        x = x * (rule.get("fan_in", shape[0]) ** -0.5)
     elif what == "embedding":
         x = x * init["embedding_std"]
     else:
@@ -69,18 +71,23 @@ def leaf(key, name: str, shape, init: dict, dtype):
     return x.astype(dtype)
 
 
-def layer_leaves(seed_key, index, model: dict, init: dict, dtype) -> dict:
+def layer_leaves(seed_key, index, model: dict, init: dict, dtype,
+                 leaves: dict | None = None) -> dict:
     """One layer's leaves; ``index`` may be traced, so one compiled program
-    makes every layer."""
+    makes every layer that has these ``leaves`` (a layout's
+    ``layer(model, kind)``; the dense decoder's where none are given)."""
     key = jax.random.fold_in(seed_key, index + 1)
-    return {n: leaf(key, n, s, init, dtype)
-            for n, s in shapes(model)[0].items()}
+    if leaves is None:
+        leaves = dense_decoder.layer(model)
+    return {n: leaf(key, n, s, init, dtype) for n, s in leaves.items()}
 
 
-def top_leaves(seed_key, model: dict, init: dict, dtype) -> dict:
+def top_leaves(seed_key, model: dict, init: dict, dtype,
+               leaves: dict | None = None) -> dict:
     key = jax.random.fold_in(seed_key, 0)
-    return {n: leaf(key, n, s, init, dtype)
-            for n, s in shapes(model)[1].items()}
+    if leaves is None:
+        leaves = dense_decoder.top(model)
+    return {n: leaf(key, n, s, init, dtype) for n, s in leaves.items()}
 
 
 def nest(flat: dict) -> dict:
@@ -95,19 +102,36 @@ def nest(flat: dict) -> dict:
 
 
 class Maker:
-    """The two compiled programs (one layer, the rest) that make a
-    configuration's leaves on the device, built once per process."""
+    """The compiled programs (one for each kind of layer, one for the rest)
+    that make a configuration's leaves on the device, built once per
+    process.  The seed and the layer's index are arguments of a program, so
+    another seed, or another layer of the same kind, compiles nothing."""
 
     def __init__(self, cfg: dict, dtype=None, sharding=None):
         model, init = cfg["model"], cfg["init"]
+        lay = layout(cfg)
+        self.kinds = list(lay.kinds(model))
         self.num_layers = model["num_layers"]
+        if len(self.kinds) != self.num_layers:
+            raise ValueError(
+                f"{layout_file(cfg)} gives {len(self.kinds)} layers a kind, "
+                f"the configuration has {self.num_layers}")
         dtype = jnp.dtype(dtype or cfg["param_dtype"])
         kw = {} if sharding is None else {"out_shardings": sharding}
-        self.layer = jax.jit(
-            lambda s, i: layer_leaves(base_key_from(s), i, model, init,
-                                      dtype), **kw)
-        self.top = jax.jit(
-            lambda s: top_leaves(base_key_from(s), model, init, dtype), **kw)
+
+        def layer_program(leaves):
+            return jax.jit(lambda s, i: layer_leaves(
+                base_key_from(s), i, model, init, dtype, leaves), **kw)
+
+        self._layer = {kind: layer_program(lay.layer(model, kind))
+                       for kind in dict.fromkeys(self.kinds)}
+        top = lay.top(model)
+        self.top = jax.jit(lambda s: top_leaves(
+            base_key_from(s), model, init, dtype, top), **kw)
+
+    def layer(self, halves, i: int) -> dict:
+        """Layer ``i``'s leaves, from the program of its kind."""
+        return self._layer[self.kinds[i]](halves, jnp.int32(i))
 
 
 def program_tree(seed: int, maker: Maker) -> dict:
@@ -117,7 +141,7 @@ def program_tree(seed: int, maker: Maker) -> dict:
     halves = seed_halves(seed)
     flat = dict(make_top(halves))
     for i in range(maker.num_layers):
-        for n, v in make_layer(halves, jnp.int32(i)).items():
+        for n, v in make_layer(halves, i).items():
             flat[f"layer{i}/{n}"] = v
     return nest(flat)
 

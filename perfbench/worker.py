@@ -12,6 +12,7 @@ one is ``{"event": "result", ...}``.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import gc
 import json
 import os
@@ -81,9 +82,24 @@ def memory_peak(jax, devices) -> int:
     return peak
 
 
-def gpt_config(cfg: dict, **over):
+def hashable(value):
+    """JSON's lists as tuples, all the way down: a frozen config is hashed."""
+    if isinstance(value, list):
+        return tuple(hashable(v) for v in value)
+    return value
+
+
+def gpt_config(cell: dict, **over):
+    """The program's config from the configuration file's ``model`` (but
+    ``norm_eps``, which only the reference reads)."""
     from distributed_tensorflow_tpu.models import gpt as gpt_lib
-    model = {k: v for k, v in cfg["model"].items() if k != "norm_eps"}
+    model = {k: hashable(v) for k, v in cell["config"]["model"].items()
+             if k != "norm_eps"}
+    unknown = sorted(set(model) - {f.name for f in dataclasses.fields(
+        gpt_lib.GptConfig)})
+    if unknown:
+        raise SystemExit(f"{cell['config_file']}: \"model\" has {unknown}, "
+                         f"which the program's GptConfig does not have")
     return gpt_lib.GptConfig(**{**model, **over})
 
 
@@ -98,9 +114,10 @@ def check_tree(jax, model, params, cfg) -> int:
     b = {jax.tree_util.keystr(p): x.shape
          for p, x in jax.tree_util.tree_flatten_with_path(params)[0]}
     if a != b:
+        from perfbench import weights
         diff = sorted(set(a.items()) ^ set(b.items()))[:6]
-        raise SystemExit(f"perfbench/weights.py no longer matches "
-                         f"models/gpt.py's parameter tree: {diff}")
+        raise SystemExit(f"{weights.layout_file(cfg)} does not match the "
+                         f"program's parameter tree: {diff}")
     return sum(int(x.size) for x in jax.tree.leaves(params))
 
 
@@ -231,7 +248,7 @@ def run_train(args, cell: dict) -> dict:
     mesh = mesh_lib.data_parallel_mesh(num_devices=chips)
     devices = list(mesh.devices.flat)
     over = cfg["lower_precision"] if args.control else {}
-    gcfg = gpt_config(cfg, **over)
+    gcfg = gpt_config(cell, **over)
     model = gpt_lib.GptLM(gcfg)
     maker = weights.Maker(cfg, sharding=mesh_lib.replicated(mesh))
     rows, seq = tr["batch_per_chip"] * chips, tr["seq_len"]
@@ -294,7 +311,7 @@ def run_train(args, cell: dict) -> dict:
         out = names(change_norms({k: params[k] for k in top}, top))
         del top
         for i in range(maker.num_layers):
-            was = weights.nest(maker.layer(halves, jnp.int32(i)))
+            was = weights.nest(maker.layer(halves, i))
             out.update({f"layer{i}/{k}": v for k, v in names(
                 change_norms(params[f"layer{i}"], was)).items()})
         return out
@@ -398,7 +415,7 @@ def calibrate_train(args, cell, first_steps, reference, device) -> dict:
 
 
 def _load_reference(cfg: dict):
-    return spec.load_module(os.path.join(spec.ROOT, cfg["reference"]))
+    return spec.named_module(cfg, "reference")
 
 
 # ----------------------------------------------------------------- serving
@@ -493,7 +510,7 @@ def run_serve(args, cell: dict) -> dict:
 
     compiles = Compiles(jax)
     phases = {"backend_s": CLOCK() - t_begin}
-    gcfg = gpt_config(cfg)
+    gcfg = gpt_config(cell)
     model = gpt_lib.GptLM(gcfg)
     t0 = CLOCK()
     params = weights.program_tree(args.seed, weights.Maker(cfg))
