@@ -20,8 +20,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
 
+from ..ops import linear_attention as linear_ops
 from ..ops.attention import dot_product_attention
 from ..parallel.sharding import ShardingRules
+
+
+FULL_ATTENTION = "full_attention"
+LINEAR_ATTENTION = "linear_attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -39,8 +44,9 @@ class GptConfig:
     # Route LayerNorms through the fused pallas kernel (--fused_layer_norm);
     # same math and parameter tree as nn.LayerNorm.
     fused_ln: bool = False
-    # Position encoding: "learned" (absolute embedding table, the default) or
-    # "rope" (rotary: q/k rotated per position in each block; no table).
+    # Position encoding: "learned" (absolute embedding table, the default),
+    # "rope" (rotary: q/k rotated per position in each block; no table) or
+    # "none" (no table, no rotation: causality alone orders the tokens).
     pos_encoding: str = "learned"
     # Grouped-query attention: number of K/V heads (0 = num_heads, plain
     # MHA; 1 = MQA).  Query heads share K/V in groups of num_heads/kv_heads,
@@ -71,6 +77,31 @@ class GptConfig:
     # cleanly (flax dot_general injection; ops/quant_train.py
     # int8_dot_general).  Same parameter tree.
     attn_int8: bool = False
+    # Where a sublayer's norm sits: "pre" (on its input, the default) or
+    # "post" (on its OUTPUT, before the residual add: x + norm(f(x)), the
+    # Olmo family's reordered norm; the residual stream itself is never
+    # normed before the final norm).
+    norm_placement: str = "pre"
+    # RMSNorm over the whole projected q and over the whole projected k
+    # (all heads together) in the softmax-attention layers.
+    qk_norm: bool = False
+    # The token mixer of each layer, ``num_layers`` of FULL_ATTENTION /
+    # LINEAR_ATTENTION; empty = every layer full attention (the same
+    # parameter tree and the same programs as before the field existed).
+    # A linear-attention layer is the gated delta rule of
+    # ops/linear_attention.py: per sequence it keeps a fixed-size
+    # recurrent state and a convolution tail where a full layer keeps
+    # keys and values, and its MLP, norms and residual path are the full
+    # layer's.  Only GptLM.__call__, .prefill and .decode_paged carry
+    # that state; every other cache path refuses such a config by name.
+    layer_kinds: tuple = ()
+    linear_num_heads: int = 0          # key heads = value heads
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 4    # taps of the causal depthwise conv
+    # beta = 2 * sigmoid(.) instead of sigmoid(.): the state transition
+    # I - beta k k^T may then have an eigenvalue in (-1, 0).
+    linear_allow_neg_eigval: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -80,10 +111,63 @@ class GptConfig:
     def num_kv_heads(self) -> int:
         return self.kv_heads or self.num_heads
 
+    @property
+    def kinds(self) -> tuple:
+        """The kind of each layer, ``num_layers`` long."""
+        return self.layer_kinds or (FULL_ATTENTION,) * self.num_layers
+
+    @property
+    def has_state_layers(self) -> bool:
+        return LINEAR_ATTENTION in self.layer_kinds
+
+    @property
+    def linear_conv_channels(self) -> int:
+        """Channels the convolution runs over: q, k and v side by side."""
+        return self.linear_num_heads * (2 * self.linear_key_head_dim
+                                        + self.linear_value_head_dim)
+
+    def refuse_state_layers(self, path: str) -> None:
+        """Called first by every cache path that has no place for a
+        linear-attention layer's recurrent state."""
+        if self.has_state_layers:
+            raise ValueError(
+                f"{path} does not carry a linear-attention layer's "
+                f"recurrent state and GptConfig.layer_kinds has "
+                f"{self.layer_kinds.count(LINEAR_ATTENTION)} such layer(s); "
+                "the paths that do are GptLM.__call__, GptLM.prefill and "
+                "GptLM.decode_paged (the serving engine's whole-bucket "
+                "prefill and its decode step)")
+
     def __post_init__(self):
-        if self.pos_encoding not in ("learned", "rope"):
+        if self.pos_encoding not in ("learned", "rope", "none"):
             raise ValueError(f"Unknown pos_encoding {self.pos_encoding!r}; "
-                             "one of ('learned', 'rope')")
+                             "one of ('learned', 'rope', 'none')")
+        if self.norm_placement not in ("pre", "post"):
+            raise ValueError(f"Unknown norm_placement "
+                             f"{self.norm_placement!r}; one of "
+                             "('pre', 'post')")
+        if self.layer_kinds:
+            bad = sorted(set(self.layer_kinds)
+                         - {FULL_ATTENTION, LINEAR_ATTENTION})
+            if bad or len(self.layer_kinds) != self.num_layers:
+                raise ValueError(
+                    f"layer_kinds must name {FULL_ATTENTION!r} or "
+                    f"{LINEAR_ATTENTION!r} for each of num_layers="
+                    f"{self.num_layers} layers, got "
+                    f"{len(self.layer_kinds)} entries"
+                    + (f" with {bad}" if bad else ""))
+        if self.has_state_layers:
+            if min(self.linear_num_heads, self.linear_key_head_dim,
+                   self.linear_value_head_dim) < 1 \
+                    or self.linear_conv_kernel_dim < 2:
+                raise ValueError(
+                    "a linear_attention layer needs linear_num_heads, "
+                    "linear_key_head_dim, linear_value_head_dim >= 1 and "
+                    "linear_conv_kernel_dim >= 2")
+            if self.attention_window or self.attn_int8:
+                raise ValueError(
+                    "layer_kinds with a linear_attention layer composes "
+                    "with neither attention_window nor attn_int8")
         if self.activation not in ("gelu", "swiglu"):
             raise ValueError(f"Unknown activation {self.activation!r}; "
                              "one of ('gelu', 'swiglu')")
@@ -108,7 +192,15 @@ def infer_arch_from_layer0(layer0: dict) -> dict:
     """Architecture knobs a checkpoint's first decoder block reveals —
     ONE definition shared by generate and export (they must reconstruct the
     same model from the same tree): swiglu adds a gate matrix, rmsnorm's
-    norm params carry no bias, GQA's kv projection is [in, 2, G, D]."""
+    norm params carry no bias, GQA's kv projection is [in, 2, G, D].
+    A block tells nothing of its neighbours' kinds, so a checkpoint with a
+    linear-attention layer is refused."""
+    if "A_log" in layer0:
+        raise ValueError(
+            "infer_arch_from_layer0 cannot infer GptConfig.layer_kinds: "
+            "layer0 is a linear_attention layer and says nothing of the "
+            "other layers' kinds; build the GptConfig from the run's "
+            "configuration file")
     arch = {
         "activation": "swiglu" if "mlp_gate" in layer0 else "gelu",
         "norm": ("layernorm" if "bias" in layer0.get("ln_attn", {})
@@ -133,6 +225,10 @@ class RMSNorm(nn.Module):
         rms = jnp.sqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
                        + self.epsilon)
         return ((x32 / rms) * scale).astype(x.dtype)
+
+
+def _inv_softplus(y: jax.Array) -> jax.Array:
+    return y + jnp.log(-jnp.expm1(-y))
 
 
 def _layer_norm(cfg: GptConfig, name: str | None = None) -> nn.Module:
@@ -165,14 +261,58 @@ def apply_rope(x: jax.Array, positions: jax.Array,
 
 class GptBlock(nn.Module):
     """One pre-LN decoder block; ``setup``-style so the training ``__call__``
-    and the KV-cached ``decode_step`` share the same parameters."""
+    and the KV-cached ``decode_step`` share the same parameters.
+
+    ``kind`` selects the token mixer: softmax attention over cached keys and
+    values (FULL_ATTENTION) or the gated delta rule over a recurrent state
+    (LINEAR_ATTENTION: ``linear_mix`` / ``linear_prefill`` /
+    ``linear_decode_step``).  Norms, MLP and the residual path are shared."""
 
     cfg: GptConfig
+    kind: str = FULL_ATTENTION
 
     def setup(self):
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         self.ln_attn = _layer_norm(cfg)
+        self.ln_mlp = _layer_norm(cfg)
+        self._setup_mlp(dtype)
+        self.drop = nn.Dropout(cfg.dropout_rate)
+        if self.kind == LINEAR_ATTENTION:
+            self._setup_linear(dtype)
+        else:
+            self._setup_attention(dtype)
+
+    def _setup_linear(self, dtype):
+        cfg = self.cfg
+        H = cfg.linear_num_heads
+        Dk, Dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
+        flat = {"dtype": dtype, "use_bias": False}
+        self.q_proj = nn.Dense(H * Dk, **flat)
+        self.k_proj = nn.Dense(H * Dk, **flat)
+        self.v_proj = nn.Dense(H * Dv, **flat)
+        self.g_proj = nn.Dense(H * Dv, **flat)      # the output gate
+        self.a_proj = nn.Dense(H, **flat)           # decay, a head
+        self.b_proj = nn.Dense(H, **flat)           # step beta, a head
+        # Depthwise taps over q, k and v side by side, oldest first.
+        self.conv_taps = self.param(
+            "conv_taps", nn.initializers.normal(
+                cfg.linear_conv_kernel_dim ** -0.5),
+            (cfg.linear_conv_kernel_dim, cfg.linear_conv_channels))
+        # Mamba-2's draw: A in [1, 16), a step in [1e-3, 1e-1).
+        self.A_log = self.param(
+            "A_log", lambda key, shape: jnp.log(
+                jax.random.uniform(key, shape, minval=1.0, maxval=16.0)),
+            (H,))
+        self.dt_bias = self.param(
+            "dt_bias", lambda key, shape: _inv_softplus(jnp.exp(
+                jax.random.uniform(key, shape, minval=jnp.log(1e-3),
+                                   maxval=jnp.log(1e-1)))), (H,))
+        self.o_norm = RMSNorm()
+        self.out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), **flat)
+
+    def _setup_attention(self, dtype):
+        cfg = self.cfg
         # attn_int8: same modules, same tree — only the contraction is
         # routed through the int8 matmul (flax's dot_general injection).
         proj_kw = {"dtype": dtype}
@@ -191,7 +331,12 @@ class GptBlock(nn.Module):
             self.kv_proj = nn.DenseGeneral((2, cfg.num_kv_heads,
                                             cfg.head_dim), **proj_kw)
         self.out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), **proj_kw)
-        self.ln_mlp = _layer_norm(cfg)
+        if cfg.qk_norm:
+            self.q_norm = RMSNorm()
+            self.k_norm = RMSNorm()
+
+    def _setup_mlp(self, dtype):
+        cfg = self.cfg
         if cfg.matmul_int8:
             from ..ops.quant_train import Int8Dense
             dense_cls = Int8Dense
@@ -211,13 +356,26 @@ class GptBlock(nn.Module):
         else:
             self.mlp_in = dense_cls(cfg.intermediate_size, dtype=dtype)
             self.mlp_out = dense_cls(cfg.hidden_size, dtype=dtype)
-        self.drop = nn.Dropout(cfg.dropout_rate)
+
+    def _mixer_in(self, x: jax.Array) -> jax.Array:
+        """What the token mixer's projections read: the normed stream, or
+        under ``norm_placement="post"`` the stream itself."""
+        if self.cfg.norm_placement == "post":
+            return x
+        return self.ln_attn(x).astype(jnp.dtype(self.cfg.dtype))
+
+    def _add_mixed(self, x: jax.Array, y: jax.Array,
+                   deterministic: bool = True) -> jax.Array:
+        """The residual add of the token mixer's output ``y``."""
+        if self.cfg.norm_placement == "post":
+            y = self.ln_attn(y).astype(x.dtype)
+        return x + self.drop(y, deterministic=deterministic)
 
     def _qkv(self, x: jax.Array, positions: jax.Array | None = None):
         """Returns q [B,S,H,D] and k/v [B,S,G,D] (G = kv heads; G == H in
         plain MHA)."""
         cfg = self.cfg
-        h = self.ln_attn(x).astype(jnp.dtype(cfg.dtype))
+        h = self._mixer_in(x)
         if cfg.num_kv_heads == cfg.num_heads:
             qkv = self.qkv(h)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
@@ -225,6 +383,9 @@ class GptBlock(nn.Module):
             q = self.q_proj(h)
             kv = self.kv_proj(h)
             k, v = kv[:, :, 0], kv[:, :, 1]
+        if cfg.qk_norm:
+            q = self.q_norm(q.reshape(*q.shape[:2], -1)).reshape(q.shape)
+            k = self.k_norm(k.reshape(*k.shape[:2], -1)).reshape(k.shape)
         if cfg.pos_encoding == "rope":
             if positions is None:
                 positions = jnp.arange(x.shape[1])
@@ -242,8 +403,9 @@ class GptBlock(nn.Module):
 
     def _mlp(self, x: jax.Array, deterministic: bool) -> jax.Array:
         cfg = self.cfg
-        h = self.ln_mlp(x).astype(jnp.dtype(cfg.dtype))
-        if cfg.matmul_int8 and cfg.activation == "gelu":
+        post = cfg.norm_placement == "post"
+        h = x if post else self.ln_mlp(x).astype(jnp.dtype(cfg.dtype))
+        if cfg.matmul_int8 and cfg.activation == "gelu" and not post:
             from ..ops import quant_train
             M = 1
             for d in h.shape[:-1]:
@@ -281,16 +443,98 @@ class GptBlock(nn.Module):
         else:
             h = nn.gelu(self.mlp_in(h))
         h = self.mlp_out(h)
+        if post:
+            h = self.ln_mlp(h).astype(x.dtype)
         return x + self.drop(h, deterministic=deterministic)
 
     def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
+        if self.kind == LINEAR_ATTENTION:
+            return self.linear_mix(x, deterministic)
         q, k, v = self._qkv(x)
         ctx = dot_product_attention(q, self._expand_kv(k), self._expand_kv(v),
                                     causal=True,
                                     window=self.cfg.attention_window,
                                     backend=self.cfg.attention_backend)
-        x = x + self.drop(self.out(ctx), deterministic=deterministic)
+        x = self._add_mixed(x, self.out(ctx), deterministic)
         return self._mlp(x, deterministic)
+
+    # ---------------------------------------------- linear attention
+
+    def _linear_inputs(self, x: jax.Array, tail: jax.Array | None,
+                       keep: jax.Array | None):
+        """The gated delta rule's inputs for ``x`` [B, T, hidden]: the raw
+        q/k/v projections side by side (what the convolution reads, and
+        what its tail keeps), then q [B,T,H,Dk] (unit, scaled by
+        Dk^-1/2), k (unit), v [B,T,H,Dv], the log decay ``g`` and the step
+        ``beta`` [B,T,H], all float32.  Where ``keep`` [B, T] is False the
+        token is made to change nothing (g = 0, beta = 0)."""
+        cfg = self.cfg
+        H, Dk = cfg.linear_num_heads, cfg.linear_key_head_dim
+        B, T = x.shape[:2]
+        h = self._mixer_in(x)
+        raw = jnp.concatenate(
+            [self.q_proj(h), self.k_proj(h), self.v_proj(h)], axis=-1)
+        mixed = nn.silu(linear_ops.causal_conv(raw, self.conv_taps, tail))
+        q, k, v = jnp.split(mixed, [H * Dk, 2 * H * Dk], axis=-1)
+        q = linear_ops.l2_normalize(q.reshape(B, T, H, Dk)) * Dk ** -0.5
+        k = linear_ops.l2_normalize(k.reshape(B, T, H, Dk))
+        v = v.reshape(B, T, H, -1)
+        beta = nn.sigmoid(self.b_proj(h).astype(jnp.float32))
+        if cfg.linear_allow_neg_eigval:
+            beta = 2.0 * beta
+        g = -jnp.exp(self.A_log.astype(jnp.float32)) * nn.softplus(
+            self.a_proj(h).astype(jnp.float32)
+            + self.dt_bias.astype(jnp.float32))
+        if keep is not None:
+            g = jnp.where(keep[..., None], g, 0.0)
+            beta = jnp.where(keep[..., None], beta, 0.0)
+        return h, raw, q, k, v, g, beta
+
+    def _linear_close(self, x: jax.Array, h: jax.Array, o: jax.Array,
+                      deterministic: bool = True) -> jax.Array:
+        """From the rule's output ``o`` [B,T,H,Dv] to the block's: the
+        gated per-head norm, the output projection, the residual add and
+        the MLP."""
+        gate = nn.silu(self.g_proj(h).astype(jnp.float32)).reshape(o.shape)
+        y = (self.o_norm(o) * gate).astype(jnp.dtype(self.cfg.dtype))
+        x = self._add_mixed(x, self.out(y), deterministic)
+        return self._mlp(x, deterministic)
+
+    def linear_mix(self, x: jax.Array, deterministic: bool = True):
+        """The whole sequence from an empty state (the training forward)."""
+        h, _, q, k, v, g, beta = self._linear_inputs(x, None, None)
+        o, _ = linear_ops.gated_delta_chunked(q, k, v, g, beta)
+        return self._linear_close(x, h, o, deterministic)
+
+    def linear_prefill(self, x: jax.Array, state: jax.Array,
+                       tail: jax.Array, lengths: jax.Array):
+        """``x`` [B, T, hidden] through the block from ``state`` /
+        ``tail`` (an empty sequence's: zeros), absorbing only the tokens
+        before ``lengths[b]``: returns (y, the state after token
+        ``lengths[b] - 1``, the ``K - 1`` projections before position
+        ``lengths[b]``).  ``y`` at positions >= ``lengths[b]`` is
+        meaningless."""
+        keep = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
+        h, raw, q, k, v, g, beta = self._linear_inputs(x, tail, keep)
+        o, state = linear_ops.gated_delta_chunked(q, k, v, g, beta, state)
+        new_tail = linear_ops.conv_tail(
+            jnp.concatenate([tail, raw.astype(tail.dtype)], axis=1),
+            lengths + tail.shape[1], tail.shape[1])
+        return self._linear_close(x, h, o), state, new_tail
+
+    def linear_decode_step(self, x: jax.Array, state: jax.Array,
+                           tail: jax.Array, live: jax.Array):
+        """One token a row: ``x`` [B, 1, hidden] against ``state``
+        [B, H, Dv, Dk] and ``tail`` [B, K-1, channels].  A row where
+        ``live`` [B] is False keeps its state and its tail bit for bit."""
+        h, raw, q, k, v, g, beta = self._linear_inputs(x, tail,
+                                                       live[:, None])
+        o, state = linear_ops.gated_delta_step(
+            q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
+        shifted = jnp.concatenate([tail[:, 1:], raw.astype(tail.dtype)],
+                                  axis=1)
+        tail = jnp.where(live[:, None, None], shifted, tail)
+        return self._linear_close(x, h, o[:, None]), state, tail
 
     def _write_prefill(self, cache: jax.Array, fresh: jax.Array) -> jax.Array:
         """Write the prompt's K or V rows into the cache.
@@ -355,7 +599,7 @@ class GptBlock(nn.Module):
                                     causal=True,
                                     window=self.cfg.attention_window,
                                     backend=backend)
-        x = x + self.out(ctx)
+        x = self._add_mixed(x, self.out(ctx))
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
     def _check_ring(self, M: int) -> None:
@@ -476,7 +720,7 @@ class GptBlock(nn.Module):
         valid = (k_slot <= position) | (position >= M)
         ctx = self._attend_cache(q, k_cache, v_cache,
                                  valid[None, None, None, None, :])
-        x = x + self.out(ctx)
+        x = self._add_mixed(x, self.out(ctx))
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
     def decode_step_ragged(self, x: jax.Array, k_cache: jax.Array,
@@ -511,7 +755,7 @@ class GptBlock(nn.Module):
                  | (positions[:, None] >= M))                  # [B, M]
         ctx = self._attend_cache(q, k_cache, v_cache,
                                  valid[:, None, None, None, :])
-        x = x + self.out(ctx)
+        x = self._add_mixed(x, self.out(ctx))
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
     def decode_chunk(self, x: jax.Array, k_cache: jax.Array,
@@ -586,7 +830,7 @@ class GptBlock(nn.Module):
         ctx = self._attend_cache_chunk(
             q, k_cache, v_cache, k, v, prefix_valid,
             chunk_valid[None, None, None, :, :])
-        x = x + self.out(ctx)
+        x = self._add_mixed(x, self.out(ctx))
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
     def decode_chunk_paged(self, x: jax.Array, k_pool: jax.Array,
@@ -639,7 +883,7 @@ class GptBlock(nn.Module):
         ctx = self._attend_cache_chunk(
             q, gather(k_pool), gather(v_pool), k, v, prefix_valid,
             chunk_valid[None, None, None, :, :])
-        x = x + self.out(ctx)
+        x = self._add_mixed(x, self.out(ctx))
         return self._mlp(x, deterministic=True), k_pool, v_pool
 
     def decode_step_paged(self, x: jax.Array, k_pool: jax.Array,
@@ -692,7 +936,7 @@ class GptBlock(nn.Module):
         valid = (s[None, :] <= positions[:, None]) & allocated
         ctx = self._attend_cache(q, gather(k_pool), gather(v_pool),
                                  valid[:, None, None, None, :])
-        x = x + self.out(ctx)
+        x = self._add_mixed(x, self.out(ctx))
         return self._mlp(x, deterministic=True), k_pool, v_pool
 
 
@@ -709,15 +953,15 @@ class GptLM(nn.Module):
         # static_argnums counts self at 0: (self, x, deterministic).
         block_cls = (nn.remat(GptBlock, static_argnums=(2,)) if cfg.remat
                      else GptBlock)
-        self.layers = [block_cls(cfg, name=f"layer{i}")
-                       for i in range(cfg.num_layers)]
+        self.layers = [block_cls(cfg, kind, name=f"layer{i}")
+                       for i, kind in enumerate(cfg.kinds)]
         self.ln_final = _layer_norm(cfg)
         self.lm_head = nn.Dense(cfg.vocab_size)
 
     def _embed(self, input_ids: jax.Array, positions: jax.Array,
                deterministic: bool) -> jax.Array:
         x = self.word_emb(input_ids)
-        if self.cfg.pos_encoding != "rope":
+        if self.cfg.pos_encoding == "learned":
             x = x + self.pos_emb(positions)
         x = self.emb_drop(x, deterministic=deterministic)
         return x.astype(jnp.dtype(self.cfg.dtype))
@@ -737,6 +981,7 @@ class GptLM(nn.Module):
         """One generation step: ``token`` [B] at ``position`` (scalar) against
         per-layer KV caches (see :func:`init_kv_cache`).  Returns
         (logits [B, vocab], new caches)."""
+        self.cfg.refuse_state_layers("GptLM.decode_step")
         x = self._embed(token[:, None], position[None, None], True)
         new_caches = []
         for layer, (k_cache, v_cache) in zip(self.layers, caches):
@@ -759,6 +1004,7 @@ class GptLM(nn.Module):
         ``GptBlock.decode_chunk`` and :func:`spec_tree`): token i then
         embeds at logical position ``positions[b]+depths[i]`` and attends
         only its ancestors — one call verifies a whole draft tree."""
+        self.cfg.refuse_state_layers("GptLM.decode_chunk")
         B, K = tokens.shape
         if depths is None:
             pos = positions[:, None] + jnp.arange(K)[None, :]
@@ -779,6 +1025,7 @@ class GptLM(nn.Module):
         attention.  ONE definition for the speculative verify and the
         chunked prefill — the chunked/whole-bucket parity invariant must
         not be breakable by editing one twin.  Returns (x, new pools)."""
+        self.cfg.refuse_state_layers("GptLM.decode_chunk_paged / GptLM.prefill_chunk_paged")
         B, K = tokens.shape
         pos = positions[:, None] + jnp.arange(K)[None, :]
         x = self._embed(tokens, pos, True)
@@ -822,6 +1069,7 @@ class GptLM(nn.Module):
         cache safe (sliding-window checkpoints; see
         ``GptBlock.decode_step_ragged``).  ``token`` [B].  Returns
         (logits [B, vocab], new caches)."""
+        self.cfg.refuse_state_layers("GptLM.decode_ragged")
         x = self._embed(token[:, None], positions[:, None], True)
         new_caches = []
         for layer, (k_cache, v_cache) in zip(self.layers, caches):
@@ -831,19 +1079,31 @@ class GptLM(nn.Module):
         return self._head(x)[:, 0], new_caches
 
     def decode_paged(self, token: jax.Array, pools, page_tables: jax.Array,
-                     positions: jax.Array):
+                     positions: jax.Array, live: jax.Array | None = None):
         """One token PER ROW against per-layer paged KV pools (see
         ``GptBlock.decode_step_paged``).  ``token`` [B]; ``pools``:
         [(k_pool, v_pool)] per layer; ``page_tables`` [B, MP] shared by
         every layer of a row (each layer has its own pool tensor, the
-        same page geometry); ``positions`` [B].  Returns
+        same page geometry); ``positions`` [B].  A linear-attention
+        layer's entry of ``pools`` is (state [B, H, Dv, Dk], conv tail
+        [B, K-1, channels]), indexed by ROW and not by page, and ``live``
+        [B] says which rows are sequences: a row that is not keeps its
+        entry bit for bit (see :func:`init_kv_pool`).  Returns
         (logits [B, vocab], new pools)."""
+        if self.cfg.has_state_layers and live is None:
+            raise ValueError(
+                "GptLM.decode_paged needs live= [B] for a config whose "
+                "layer_kinds has a linear_attention layer: a recurrent "
+                "state has no sentinel page to drop an idle row's write")
         x = self._embed(token[:, None], positions[:, None], True)
         new_pools = []
-        for layer, (k_pool, v_pool) in zip(self.layers, pools):
-            x, k_pool, v_pool = layer.decode_step_paged(
-                x, k_pool, v_pool, page_tables, positions)
-            new_pools.append((k_pool, v_pool))
+        for layer, entry in zip(self.layers, pools):
+            if layer.kind == LINEAR_ATTENTION:
+                x, *entry = layer.linear_decode_step(x, *entry, live)
+            else:
+                x, *entry = layer.decode_step_paged(x, *entry, page_tables,
+                                                    positions)
+            new_pools.append(tuple(entry))
         return self._head(x)[:, 0], new_pools
 
     def prefill(self, tokens: jax.Array, caches,
@@ -853,14 +1113,33 @@ class GptLM(nn.Module):
         next position [B, vocab], new caches).  ``lengths`` ([B],
         optional): right-padded ragged prompts — pad positions are
         excluded from the cache write (REQUIRED for ring caches, see
-        ``GptBlock.prefill``)."""
+        ``GptBlock.prefill``).
+
+        With a linear-attention layer in ``layer_kinds`` (caches from
+        :func:`init_kv_cache`: such a layer's entry is its state and its
+        convolution tail) ``lengths`` is required and bounds what those
+        layers absorb: their state comes back as after token
+        ``lengths[b] - 1`` and padding changes nothing.  The full layers
+        then write every position of the padded prompt as they do without
+        ``lengths``: whoever decodes next overwrites a position at or
+        past ``lengths[b]`` before reading it (the paged engine's
+        contract), and the returned logits are the last PADDED position's."""
         B, P = tokens.shape
         x = self._embed(tokens, jnp.arange(P)[None], True)
         new_caches = []
-        for layer, (k_cache, v_cache) in zip(self.layers, caches):
-            x, k_cache, v_cache = layer.prefill(x, k_cache, v_cache,
-                                                lengths)
-            new_caches.append((k_cache, v_cache))
+        stateful = self.cfg.has_state_layers
+        if stateful and lengths is None:
+            raise ValueError(
+                "GptLM.prefill needs lengths= [B] for a config whose "
+                "layer_kinds has a linear_attention layer: padding must "
+                "not enter a recurrent state")
+        for layer, entry in zip(self.layers, caches):
+            if layer.kind == LINEAR_ATTENTION:
+                x, *entry = layer.linear_prefill(x, *entry, lengths)
+            else:
+                x, *entry = layer.prefill(x, *entry,
+                                          None if stateful else lengths)
+            new_caches.append(tuple(entry))
         # Only the LAST position's logits matter — slice before the
         # [hidden, vocab] head so its matmul runs on one position, not P.
         return self._head(x[:, -1:])[:, 0], new_caches
@@ -886,25 +1165,54 @@ def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
         max_len = min(max_len, cfg.attention_window)
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
     shape = (batch_size, max_len, cfg.num_kv_heads, cfg.head_dim)
-    return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(cfg.num_layers)]
+    return [_state_entry(cfg, batch_size) if kind == LINEAR_ATTENTION
+            else (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+            for kind in cfg.kinds]
+
+
+def _state_entry(cfg: GptConfig, rows: int):
+    """A linear-attention layer's cache entry for ``rows`` sequences, all
+    empty: (state [rows, H, Dv, Dk] float32, the convolution's tail
+    [rows, K-1, channels] — the raw q/k/v projections of the last K-1
+    tokens, in the type they were computed in)."""
+    return (jnp.zeros((rows, cfg.linear_num_heads,
+                       cfg.linear_value_head_dim, cfg.linear_key_head_dim),
+                      jnp.float32),
+            jnp.zeros((rows, cfg.linear_conv_kernel_dim - 1,
+                       cfg.linear_conv_channels), jnp.dtype(cfg.dtype)))
+
+
+def state_bytes_per_slot(cfg: GptConfig) -> int:
+    """Bytes of recurrent state and convolution tail ONE sequence holds
+    over all linear-attention layers (0 for a config without any)."""
+    n = cfg.kinds.count(LINEAR_ATTENTION)
+    return n * sum(x.size * x.dtype.itemsize
+                   for x in jax.eval_shape(lambda: _state_entry(cfg, 1)))
 
 
 def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
-                 dtype=None):
+                 dtype=None, num_slots: int = 0):
     """Per-layer (k, v) PAGED pool arrays [num_pages, page_size, H, D] —
     the serving tier's shared KV memory (:mod:`..serving.kv_pool` owns the
     page accounting).  Unlike :func:`init_kv_cache` there is no batch
     axis: every resident sequence draws pages from the same pool, so HBM
     is sized by total resident tokens, not num_slots × max_len.  Same
-    dtype lever (``float8_e4m3fn`` halves cache bytes; upcast on read)."""
+    dtype lever (``float8_e4m3fn`` halves cache bytes; upcast on read).
+
+    A linear-attention layer holds no pages: its entry is one fixed-size
+    row per decode SLOT (``num_slots`` of them: state float32, convolution
+    tail), whatever the sequence's length."""
     if cfg.attention_window:
         raise ValueError("paged KV pools need full-cache addressing; "
                          "sliding-window checkpoints are not pageable")
+    if cfg.has_state_layers and num_slots < 1:
+        raise ValueError("init_kv_pool needs num_slots >= 1 for a config "
+                         "whose layer_kinds has a linear_attention layer")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
     shape = (num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
-    return [(jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
-            for _ in range(cfg.num_layers)]
+    return [_state_entry(cfg, num_slots) if kind == LINEAR_ATTENTION
+            else (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+            for kind in cfg.kinds]
 
 
 def lm_loss(logits: jax.Array, tokens: jax.Array,
@@ -1157,6 +1465,7 @@ def generate_cached(model: GptLM, params, prompt: jax.Array, num_tokens: int,
     per-step KV append still runs for already-stopped rows (their writes
     are eos padding) so shapes stay static.
     """
+    model.cfg.refuse_state_layers("generate_cached")
     B, P = prompt.shape
     total = P + num_tokens
     _validate_sampling(model, total, temperature, top_p, rng)
@@ -1250,6 +1559,7 @@ def beam_search_cached(model: GptLM, params, prompt: jax.Array,
     Returns ``(tokens [B, P + num_tokens], logprob [B])`` — the best beam
     per batch row and its cumulative generated-token log-probability.
     """
+    model.cfg.refuse_state_layers("beam_search_cached")
     B, P = prompt.shape
     K = beam_size
     total = P + num_tokens
@@ -1498,6 +1808,7 @@ def generate_cached_speculative(model: GptLM, params, prompt: jax.Array,
     decode steps; ``fallback_at_round`` is None when drafting paid for
     the whole generation).
     """
+    model.cfg.refuse_state_layers("generate_cached_speculative")
     B, P = prompt.shape
     total = P + num_tokens
     _validate_sampling(model, total, 0.0, 0.0, None)
@@ -1916,6 +2227,7 @@ def generate_cached_speculative_device(model: GptLM, params,
     "tokens_generated", "mean_accepted_per_round"}`` (``branch_hits``:
     rounds whose winning leaf sat on the alternate branch).
     """
+    model.cfg.refuse_state_layers("generate_cached_speculative_device")
     B, P = prompt.shape
     total = P + num_tokens
     _validate_sampling(model, total, 0.0, 0.0, None)
@@ -1972,6 +2284,11 @@ def split_params_for_pipeline(params, n_stages: int, num_layers: int):
                          f"pipeline stages={n_stages}")
     per = num_layers // n_stages
     layers = [params[f"layer{i}"] for i in range(num_layers)]
+    if any("A_log" in layer for layer in layers):
+        raise ValueError(
+            "split_params_for_pipeline stacks layers that are all alike; "
+            "these parameters hold a linear_attention layer "
+            "(GptConfig.layer_kinds)")
     stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *layers)
     # [L, ...] -> [n_stages, per, ...]
     stacked = jax.tree.map(
@@ -2017,6 +2334,7 @@ def make_pipelined_gpt_apply(cfg: GptConfig, mesh, *, n_micro: int,
     pipelined region.  Same math as ``GptLM.__call__`` — an equivalence test
     pins it.
     """
+    cfg.refuse_state_layers("make_pipelined_gpt_apply")
     from ..parallel.pipeline import make_pipeline_fn
 
     block = GptBlock(cfg)
@@ -2037,7 +2355,7 @@ def make_pipelined_gpt_apply(cfg: GptConfig, mesh, *, n_micro: int,
     def apply(pp_params, tokens):
         S = tokens.shape[1]
         x = word.apply({"params": pp_params["embed"]["word_emb"]}, tokens)
-        if cfg.pos_encoding != "rope":
+        if cfg.pos_encoding == "learned":
             x = x + pos.apply({"params": pp_params["embed"]["pos_emb"]},
                               jnp.arange(S)[None, :])
         x = x.astype(jnp.dtype(cfg.dtype))
@@ -2054,6 +2372,7 @@ def make_interleaved_gpt_apply(cfg: GptConfig):
     back to the natural layer order and scans the block stack — the plain
     (non-pipelined) forward, used for eval/validation where the schedule
     doesn't matter (GSPMD gathers the chunk shards as needed)."""
+    cfg.refuse_state_layers("make_interleaved_gpt_apply")
     block = GptBlock(cfg)
     word = nn.Embed(cfg.vocab_size, cfg.hidden_size)
     pos = nn.Embed(cfg.max_position, cfg.hidden_size)
@@ -2063,7 +2382,7 @@ def make_interleaved_gpt_apply(cfg: GptConfig):
     def apply(pp_params, tokens):
         S = tokens.shape[1]
         x = word.apply({"params": pp_params["embed"]["word_emb"]}, tokens)
-        if cfg.pos_encoding != "rope":
+        if cfg.pos_encoding == "learned":
             x = x + pos.apply({"params": pp_params["embed"]["pos_emb"]},
                               jnp.arange(S)[None, :])
         x = x.astype(jnp.dtype(cfg.dtype))
@@ -2097,6 +2416,7 @@ def make_1f1b_gpt_train_step_builder(cfg: GptConfig, *, n_micro: int,
     (virtual-chunk) schedule instead — stages leaves then carry the
     [n_virtual, n_pipe, ...] layout.  Returns ``builder(mesh) -> step``.
     """
+    cfg.refuse_state_layers("make_1f1b_gpt_train_step_builder")
     from ..parallel.pipeline import (build_1f1b_pipeline_train_step,
                                      build_interleaved_1f1b_train_step)
 
@@ -2115,7 +2435,7 @@ def make_1f1b_gpt_train_step_builder(cfg: GptConfig, *, n_micro: int,
     def embed_fn(embed_params, batch):
         tokens = batch["tokens"]
         x = word.apply({"params": embed_params["word_emb"]}, tokens)
-        if cfg.pos_encoding != "rope":
+        if cfg.pos_encoding == "learned":
             x = x + pos.apply({"params": embed_params["pos_emb"]},
                               jnp.arange(tokens.shape[1])[None, :])
         return x.astype(jnp.dtype(cfg.dtype))

@@ -206,14 +206,25 @@ class DecodeEngine:
         # checkpoints) — the engine's logical capacity is the tighter of
         # the page-table span and the model's max_position.
         self.capacity = min(cfg.max_seq_len, mcfg.max_position)
+        # Layers that keep a recurrent state a slot beside the pages
+        # (GptConfig.layer_kinds).  Only the whole-bucket prefill and the
+        # plain decode step carry it.
+        self._state_layers = mcfg.kinds.count(gpt_lib.LINEAR_ATTENTION)
+        self._stateful = self._state_layers > 0
+        if self._stateful and (cfg.spec_k or cfg.prefill_chunk):
+            on = "spec_k" if cfg.spec_k else "prefill_chunk"
+            mcfg.refuse_state_layers(f"DecodeEngine with EngineConfig.{on}")
         self._cache_dtype = resolve_kv_dtype(cfg.kv_dtype)
         self._tree = self._prepare_params(params)
         self._pending: tuple[Any, int] | None = None  # (tree, label step)
         self.model_step = 0            # checkpoint step the weights carry
         self.swaps = 0
         self.pools = gpt_lib.init_kv_pool(
-            mcfg, cfg.num_pages, cfg.page_size, dtype=self._cache_dtype)
-        self.allocator = PageAllocator(cfg.num_pages, cfg.page_size)
+            mcfg, cfg.num_pages, cfg.page_size, dtype=self._cache_dtype,
+            num_slots=cfg.num_slots)
+        self.allocator = PageAllocator(
+            cfg.num_pages, cfg.page_size,
+            state_bytes_per_slot=gpt_lib.state_bytes_per_slot(mcfg))
 
         B, MP = cfg.num_slots, cfg.max_pages_per_seq
         self._slots: list[_Slot | None] = [None] * B
@@ -326,8 +337,12 @@ class DecodeEngine:
         def step(tree, tokens, positions, tables, pools, temp, tk, tp,
                  seeds):
             params = self._dequant(tree)
+            # An idle lane's table is all sentinel: its page writes drop
+            # by themselves, its recurrent state has to be told.
+            live = ((tables[:, 0] < self.config.num_pages),) \
+                if self._stateful else ()
             logits, pools = model.apply(
-                {"params": params}, tokens, pools, tables, positions,
+                {"params": params}, tokens, pools, tables, positions, *live,
                 method=gpt_lib.GptLM.decode_paged)
             # Per-row keys folded on the ABSOLUTE index being generated:
             # a sampled stream is reproducible for its (seed, position)s
@@ -384,20 +399,30 @@ class DecodeEngine:
         page = self.config.page_size
         p_len = n_pages * page
 
-        def prefill(tree, tokens, pools, phys):
+        def prefill(tree, tokens, pools, phys, slot=None, absorb=None):
+            """``slot`` and ``absorb``, for a model with recurrent layers
+            only: the lane's slot and how many tokens its state absorbs.
+            The state starts from zeros INSIDE this program and lands on
+            the slot's row whole, so nothing of the row's last tenant
+            survives."""
             params = self._dequant(tree)
             caches = gpt_lib.init_kv_cache(mcfg, 1, p_len,
                                            dtype=self._cache_dtype)
+            lengths = () if absorb is None else (absorb[None],)
             _, caches = model.apply({"params": params}, tokens, caches,
-                                    method=gpt_lib.GptLM.prefill)
+                                    *lengths, method=gpt_lib.GptLM.prefill)
             new_pools = []
-            for (kc, vc), (kp, vp) in zip(caches, pools):
-                kp = kp.at[phys].set(
-                    kc[0].reshape(n_pages, page, *kc.shape[2:]),
-                    mode="drop")
-                vp = vp.at[phys].set(
-                    vc[0].reshape(n_pages, page, *vc.shape[2:]),
-                    mode="drop")
+            for kind, (kc, vc), (kp, vp) in zip(mcfg.kinds, caches, pools):
+                if kind == gpt_lib.LINEAR_ATTENTION:
+                    kp = kp.at[slot].set(kc[0])
+                    vp = vp.at[slot].set(vc[0])
+                else:
+                    kp = kp.at[phys].set(
+                        kc[0].reshape(n_pages, page, *kc.shape[2:]),
+                        mode="drop")
+                    vp = vp.at[phys].set(
+                        vc[0].reshape(n_pages, page, *vc.shape[2:]),
+                        mode="drop")
                 new_pools.append((kp, vp))
             return new_pools
 
@@ -529,9 +554,15 @@ class DecodeEngine:
                 toks = np.zeros((1, p_len), np.int32)
                 toks[0, :P] = request.prompt
                 phys = np.asarray(pages[:n_prefill], np.int32)
+                # The first decode step processes token P-1 AGAIN at
+                # position P-1.  Writing its K/V twice is idempotent;
+                # absorbing it twice into a recurrent state is not, so the
+                # lane seats with the state after tokens 0..P-2.
+                seat = (np.int32(slot), np.int32(P - 1)) \
+                    if self._stateful else ()
                 self.pools = self._prefill_fn(n_prefill)(
                     self._tree, self._jnp.asarray(toks), self.pools,
-                    self._jnp.asarray(phys))
+                    self._jnp.asarray(phys), *seat)
             except Exception:
                 self.allocator.free(request.id)
                 raise
@@ -552,7 +583,8 @@ class DecodeEngine:
                     step=self.step_index, parent_id=request.span_root,
                     trace=request.trace, request_id=request.id,
                     tenant=request.tenant, bucket=n_prefill,
-                    pages=n_prefill, prompt_tokens=P, chunks=1)
+                    pages=n_prefill, prompt_tokens=P, chunks=1,
+                    state_layers=self._state_layers)
         spec = bool(cfg.spec_k) and request.speculative
         state = _Slot(request, cfg.spec_ngram if spec else 0)
         state.table = self.allocator.page_table(request.id,
@@ -802,7 +834,13 @@ class DecodeEngine:
         now = time.monotonic()
         step_ms = (now - t0) * 1e3
         self.step_index += 1
-        with profiling.annotate("serve.step.retire"):
+        # What the lanes of THIS step held in recurrent state, before any
+        # of them retires; on the profiler's event too, where a trace
+        # reader finds it beside the device's operations.
+        held = {"state_slots": self.allocator.state_slots,
+                "state_bytes": self.allocator.state_bytes}
+        with profiling.annotate("serve.step.retire",
+                                **(held if self._stateful else {})):
             tracer = tracing.active()
             round_id = 0
             t_round_unix = 0.0
@@ -915,7 +953,7 @@ class DecodeEngine:
                          retired=len(retired), queue_depth=queue_depth,
                          kv_pages_in_use=self.allocator.pages_in_use,
                          kv_pages_total=self.config.num_pages,
-                         t_start=round(t0, 6),
+                         **held, t_start=round(t0, 6),
                          step_ms=round(step_ms, 3), **split_ms,
                          spec_rows=self._spec_rows_last_step,
                          spec_accepted=spec_accepted,
@@ -963,5 +1001,9 @@ class DecodeEngine:
                 "cap": self.config.prefill_cache_cap,
                 "evictions": self._prefill_evictions,
             },
+            # Recurrent state beside the pages (linear-attention layers):
+            # its peak and its bytes a slot are in the pool's snapshot.
+            "state_slots": self.allocator.state_slots,
+            "state_bytes": self.allocator.state_bytes,
             "kv_pool": self.allocator.snapshot(),
         }

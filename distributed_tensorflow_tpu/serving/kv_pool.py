@@ -18,6 +18,12 @@ needs.  This module owns the page bookkeeping on the host:
   page is reserved but may go unwritten) is reported per pool snapshot —
   the occupancy view the telemetry bus publishes every engine step.
 
+- A sequence's OTHER memory is accounted here too: a model with
+  linear-attention layers keeps, per resident sequence, one fixed-size row
+  of recurrent state per such layer (``state_bytes_per_slot`` in all),
+  indexed by the engine's slot and not by page.  A sequence holds its row
+  exactly as long as it holds pages, so the snapshot reports both.
+
 Device tensors never live here: the allocator hands out page indices and
 sentinel-padded page tables; :mod:`.engine` owns the arrays.
 """
@@ -42,14 +48,21 @@ class PageAllocator:
     sentinel index for "no page" in emitted page tables is ``num_pages``
     itself — out of bounds by exactly one, so the engine's scatters drop
     through it (``mode="drop"``) and gathers zero-fill (``mode="fill"``).
+
+    ``state_bytes_per_slot``: bytes of recurrent state (and convolution
+    tail) a resident sequence holds beside its pages, over all the model's
+    linear-attention layers; 0 for a model that has none.
     """
 
-    def __init__(self, num_pages: int, page_size: int):
+    def __init__(self, num_pages: int, page_size: int,
+                 state_bytes_per_slot: int = 0):
         if num_pages < 1 or page_size < 1:
             raise ValueError(f"need positive pool geometry, got "
                              f"{num_pages} pages x {page_size} slots")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
+        self.state_bytes_per_slot = int(state_bytes_per_slot)
+        self._peak_sequences = 0
         # Never-used pages dispense lowest-first; freed pages append to the
         # right and are reused oldest-freed-first once the fresh run is
         # exhausted (deterministic, testable reuse order).
@@ -81,6 +94,15 @@ class PageAllocator:
     @property
     def sequences(self) -> int:
         return len(self._owned)
+
+    @property
+    def state_slots(self) -> int:
+        """Resident sequences holding a row of recurrent state."""
+        return len(self._owned) if self.state_bytes_per_slot else 0
+
+    @property
+    def state_bytes(self) -> int:
+        return self.state_slots * self.state_bytes_per_slot
 
     def pages_for(self, tokens: int) -> int:
         """Pages needed to hold ``tokens`` token slots."""
@@ -132,6 +154,8 @@ class PageAllocator:
             self._owned[seq_id] = pages
             self._reserved_tokens[seq_id] = int(tokens)
             self._peak_in_use = max(self._peak_in_use, self.pages_in_use)
+            self._peak_sequences = max(self._peak_sequences,
+                                       len(self._owned))
             return list(pages)
 
     def extend(self, seq_id, tokens: int) -> list[int]:
@@ -200,6 +224,11 @@ class PageAllocator:
                 "utilization": round(self.utilization(), 4),
                 "internal_fragmentation": round(
                     self._fragmentation_locked(), 4),
+                "state_bytes_per_slot": self.state_bytes_per_slot,
+                "state_slots": self.state_slots,
+                "state_bytes": self.state_bytes,
+                "state_bytes_peak": (self._peak_sequences
+                                     * self.state_bytes_per_slot),
             }
 
 

@@ -121,7 +121,12 @@ class ServingServer:
             self._http.shutdown()
             self._http.server_close()
         if self._loop_thread is not None:
-            self._loop_thread.join(timeout=10.0)
+            # The loop leaves at the end of its turn.  A turn can end in
+            # work that is not the engine's own, such as writing out a
+            # profile that a wrapper of ``engine.step`` closed (7 s for a
+            # 4 s slice of 130,000 device operations on the chip, PERF.md):
+            # whoever called shutdown() reads that file next.
+            self._loop_thread.join(timeout=60.0)
 
     # ------------------------------------------------------ engine loop
 

@@ -51,18 +51,20 @@ class _Annotation:
     so regions nest: an inner region (and any ``emit_span`` that names no
     parent) records the enclosing region as its ``parent_id``."""
 
-    __slots__ = ("_name", "_jax_annotation", "_span")
+    __slots__ = ("_name", "_stats", "_jax_annotation", "_span")
 
-    def __init__(self, name: str):
+    def __init__(self, name: str, **stats):
         self._name = name
-        self._jax_annotation = jax.profiler.TraceAnnotation(name)
+        self._stats = stats
+        self._jax_annotation = jax.profiler.TraceAnnotation(name, **stats)
         self._span = None
 
     def __enter__(self) -> "_Annotation":
         self._jax_annotation.__enter__()
         tracer = tracing.active()
         if tracer is not None:
-            self._span = tracer.span(self._name, source="annotate")
+            self._span = tracer.span(self._name, source="annotate",
+                                     **self._stats)
             self._span.__enter__()
         return self
 
@@ -73,13 +75,15 @@ class _Annotation:
             span.__exit__(*exc)
 
 
-def annotate(name: str):
+def annotate(name: str, **stats):
     """Named host-side region: the program's one way to mark one.  On the
     profiler timeline while a profiler session runs; a ``kind="span"``
     record, nested under the thread's open regions, while a
     :mod:`.tracing` tracer is installed; with neither, one inactive
-    ``TraceAnnotation`` and one ``is None`` check."""
-    return _Annotation(name)
+    ``TraceAnnotation`` and one ``is None`` check.  ``stats`` (counters
+    known when the region opens) ride on the profiler event as its stats
+    and on the span record as attributes."""
+    return _Annotation(name, **stats)
 
 
 class Timer:

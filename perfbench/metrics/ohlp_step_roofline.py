@@ -1,0 +1,10 @@
+"""The least time the chip could take for the whole steps inside the traced
+slice (the counts of ``perfbench/costs/olmo-hybrid-7b.py``, which the
+configuration names, and the shared peaks) over the device's busy time in
+the trace."""
+
+from perfbench.metrics import _common
+
+
+def read(ctx):
+    return _common.step_roofline_pct(ctx)

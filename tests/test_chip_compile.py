@@ -213,3 +213,75 @@ def test_sync_step_with_a_model_axis_keeps_the_dense_fallback(
     # come from the same trace), one warning.
     assert len([w for w in caught
                 if "GSPMD cannot partition" in str(w.message)]) == 1
+
+
+# ------------------------------------- a recurrent state beside the pages
+
+
+def hybrid_serving_programs(one_chip, buckets):
+    """One period (three linear-attention layers, one full) and the next
+    period's first layer at the published widths of
+    ``perfbench/configs/olmo-hybrid-7b.json``, as the engine compiles it: the decode step over 8 slots and a whole-bucket
+    prefill a page count, with the engine's own closures (their shapes
+    described, nothing placed)."""
+    from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
+                                                           EngineConfig)
+    kinds = (gpt_lib.LINEAR_ATTENTION,) * 3 + (
+        gpt_lib.FULL_ATTENTION, gpt_lib.LINEAR_ATTENTION)
+    cfg = gpt_lib.GptConfig(
+        vocab_size=100352, hidden_size=3840, num_layers=5, num_heads=30,
+        intermediate_size=11008, max_position=4096, dtype="bfloat16",
+        attention_backend="pallas", pos_encoding="none",
+        activation="swiglu", norm="rmsnorm", norm_placement="post",
+        qk_norm=True, layer_kinds=kinds, linear_num_heads=30,
+        linear_key_head_dim=96, linear_value_head_dim=192,
+        linear_allow_neg_eigval=True)
+    model = gpt_lib.GptLM(cfg)
+    econf = EngineConfig(num_slots=8, page_size=16, num_pages=1856,
+                         max_pages_per_seq=232)
+
+    def described(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+    engine = DecodeEngine.__new__(DecodeEngine)     # closures, no arrays
+    engine._jax, engine._jnp, engine.model, engine.config = (
+        jax, jnp, model, econf)
+    engine._stateful, engine._cache_dtype = True, None
+    engine._prefill_fns, engine._prefill_evictions = {}, 0
+    tree = described(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+        jnp.bfloat16)
+    pools = described(jax.eval_shape(lambda: gpt_lib.init_kv_pool(
+        cfg, econf.num_pages, econf.page_size, num_slots=econf.num_slots)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,  # noqa: E731
+                                          sharding=one_chip)
+    B, MP = econf.num_slots, econf.max_pages_per_seq
+    step = engine._build_step().lower(
+        tree, i32(B), i32(B), i32(B, MP), pools, f32(B), i32(B), f32(B),
+        i32(B)).compile()
+    prefills = {
+        n: jax.jit(engine._prefill_fn(n).__wrapped__).lower(
+            tree, i32(1, n * econf.page_size), pools, i32(n), i32(),
+            i32()).compile()
+        for n in buckets}
+    return step, prefills
+
+
+def test_hybrid_serving_programs_compile_for_v5e(one_chip):
+    """The chunked scan, its triangular solve and the one-token rule lower
+    for the chip at 30 heads of 96 x 192, with the flash kernel in the full
+    layer's prefill where the bucket's length lets it in: 1,024 tokens do,
+    1,600 do not (``_layout_ok``; D6's silent fallback)."""
+    step, prefills = hybrid_serving_programs(one_chip, (64, 100))
+    assert step.as_text().count("tpu_custom_call") == 0
+    # (A full layer that is the model's LAST layer loses its call: its
+    # attention output feeds only logits the prefill throws away, so XLA
+    # keeps its K/V and drops the rest.  Here a linear layer follows it.)
+    assert prefills[64].as_text().count("tpu_custom_call") == 1
+    assert prefills[100].as_text().count("tpu_custom_call") == 0
+    for program in (step, *prefills.values()):
+        mem = program.memory_analysis()
+        assert mem.temp_size_in_bytes < 4e9
