@@ -35,9 +35,22 @@ pages and simply continue under the new weights — no drain, no drop.
 
 Single-threaded by contract: exactly one thread (the server's engine
 loop) calls :meth:`admit` / :meth:`step`; :meth:`swap_params` may be
-called from any thread.  Buffers are not donated to the jitted step — the
-test/bench environment is CPU, where donation only warns; flipping
-``donate_argnums`` on for the pool argument is the first TPU-side lever.
+called from any thread.
+
+The pools (and, for linear-attention layers, the recurrent state that
+rides in the same tree) are DONATED to all four programs: each takes
+``pools`` and returns it, the compiled program aliases every leaf's output
+onto its input, and a decode step writes its one row a lane a layer in
+place instead of copying the whole pool first.  So ``self.pools`` is
+rebound from a call's result in the statement that makes the call, and
+nothing may keep the tree it passed in: those arrays are deleted.  JAX
+falls back to a copy, silently, when something else still holds a buffer
+(a ``np.asarray`` view of a leaf does on the CPU), so every dispatch reads
+``is_deleted()`` of one leaf it gave away and the ``serve_step`` record
+says ``pools_in_place`` (:meth:`DecodeEngine.stats`:
+``pool_steps_in_place`` / ``pool_steps_copied``).  A program that raises
+after its dispatch consumed the pools leaves them deleted;
+:meth:`DecodeEngine.fail_active` builds them anew.
 """
 
 from __future__ import annotations
@@ -219,9 +232,7 @@ class DecodeEngine:
         self._pending: tuple[Any, int] | None = None  # (tree, label step)
         self.model_step = 0            # checkpoint step the weights carry
         self.swaps = 0
-        self.pools = gpt_lib.init_kv_pool(
-            mcfg, cfg.num_pages, cfg.page_size, dtype=self._cache_dtype,
-            num_slots=cfg.num_slots)
+        self.pools = self._fresh_pools()
         self.allocator = PageAllocator(
             cfg.num_pages, cfg.page_size,
             state_bytes_per_slot=gpt_lib.state_bytes_per_slot(mcfg))
@@ -244,6 +255,12 @@ class DecodeEngine:
         self._prefill_ms_since_step = 0.0
         self._spec_accepted_since_step = 0
         self._spec_rows_last_step = 0
+        # Whether every dispatch since the last step's record (its own,
+        # the chunk prefill's, the admissions' prefills) consumed the
+        # pools it was donated, and the steps counted either way.
+        self._pools_in_place = True
+        self.pool_steps_in_place = 0
+        self.pool_steps_copied = 0
         self._step_fn = self._build_step()
         self._spec_step_fn = (self._build_spec_step()
                               if cfg.spec_k else None)
@@ -353,7 +370,7 @@ class DecodeEngine:
             nxt = gpt_lib.sample_logits_dynamic(logits, keys, temp, tk, tp)
             return nxt, pools
 
-        return jax.jit(step)
+        return jax.jit(step, donate_argnames=("pools",))
 
     def _build_spec_step(self):
         """The speculative arm's resident step: ONE decode_chunk_paged
@@ -381,7 +398,7 @@ class DecodeEngine:
                 logits[:, 0], keys, temp, tk, tp)
             return greedy, sampled0, pools
 
-        return jax.jit(spec_step)
+        return jax.jit(spec_step, donate_argnames=("pools",))
 
     def _prefill_fn(self, n_pages: int):
         """Jitted prompt prefill writing straight into the pool; one
@@ -426,7 +443,7 @@ class DecodeEngine:
                 new_pools.append((kp, vp))
             return new_pools
 
-        fn = jax.jit(prefill)
+        fn = jax.jit(prefill, donate_argnames=("pools",))
         self._prefill_fns[n_pages] = fn
         while len(self._prefill_fns) > self.config.prefill_cache_cap:
             self._prefill_fns.popitem(last=False)
@@ -452,9 +469,23 @@ class DecodeEngine:
                 {"params": params}, tokens, pools, tables, positions,
                 method=gpt_lib.GptLM.prefill_chunk_paged)
 
-        fn = jax.jit(chunk_prefill)
+        fn = jax.jit(chunk_prefill, donate_argnames=("pools",))
         self._chunk_fns[chunk] = fn
         return fn
+
+    def _fresh_pools(self):
+        cfg = self.config
+        return gpt_lib.init_kv_pool(
+            self.model.cfg, cfg.num_pages, cfg.page_size,
+            dtype=self._cache_dtype, num_slots=cfg.num_slots)
+
+    def _gave_away(self, leaf) -> None:
+        """After a dispatch that was donated the pools; ``leaf`` is one
+        array of the tree it passed in.  Deleted: the program took the
+        buffers and wrote in place.  Alive: something else holds one and
+        JAX copied them instead, without a word, so the step's record
+        says it."""
+        self._pools_in_place &= leaf.is_deleted()
 
     # -------------------------------------------------------- admission
 
@@ -560,9 +591,11 @@ class DecodeEngine:
                 # lane seats with the state after tokens 0..P-2.
                 seat = (np.int32(slot), np.int32(P - 1)) \
                     if self._stateful else ()
+                given = self.pools[0][0]
                 self.pools = self._prefill_fn(n_prefill)(
                     self._tree, self._jnp.asarray(toks), self.pools,
                     self._jnp.asarray(phys), *seat)
+                self._gave_away(given)
             except Exception:
                 self.allocator.free(request.id)
                 raise
@@ -728,9 +761,11 @@ class DecodeEngine:
             rows.append((slot, state, r))
         if not rows:
             return 0.0, 0
+        given = self.pools[0][0]
         self.pools = self._chunk_prefill_fn(C)(
             self._tree, jnp.asarray(tokens), jnp.asarray(positions),
             jnp.asarray(tables), self.pools)
+        self._gave_away(given)
         # Block here so the recorded chunk cost is device time, not
         # dispatch time — the decode step would otherwise absorb it and
         # the prefill_stall_ms decomposition would read zero.
@@ -800,6 +835,7 @@ class DecodeEngine:
                      and self._spec_slots_active())
         t0 = time.monotonic()
         with profiling.annotate("serve.step.stage"):
+            given = self.pools[0][0]
             if spec_mode:
                 K = self.config.spec_k
                 chunk = np.zeros((self.config.num_slots, K), np.int32)
@@ -825,6 +861,7 @@ class DecodeEngine:
                     jnp.asarray(self._temp), jnp.asarray(self._top_k),
                     jnp.asarray(self._top_p), jnp.asarray(self._seeds))
                 self._spec_rows_last_step = 0
+            self._gave_away(given)
         t_staged = time.monotonic()
         with profiling.annotate("serve.step.fetch"):
             if spec_mode:
@@ -839,7 +876,13 @@ class DecodeEngine:
         # reader finds it beside the device's operations.
         held = {"state_slots": self.allocator.state_slots,
                 "state_bytes": self.allocator.state_bytes}
+        in_place = self._pools_in_place
+        if in_place:
+            self.pool_steps_in_place += 1
+        else:
+            self.pool_steps_copied += 1
         with profiling.annotate("serve.step.retire",
+                                pools_in_place=int(in_place),
                                 **(held if self._stateful else {})):
             tracer = tracing.active()
             round_id = 0
@@ -953,7 +996,8 @@ class DecodeEngine:
                          retired=len(retired), queue_depth=queue_depth,
                          kv_pages_in_use=self.allocator.pages_in_use,
                          kv_pages_total=self.config.num_pages,
-                         **held, t_start=round(t0, 6),
+                         **held, pools_in_place=in_place,
+                         t_start=round(t0, 6),
                          step_ms=round(step_ms, 3), **split_ms,
                          spec_rows=self._spec_rows_last_step,
                          spec_accepted=spec_accepted,
@@ -964,16 +1008,25 @@ class DecodeEngine:
         self._admitted_since_step = 0
         self._prompt_tokens_since_step = 0
         self._prefill_ms_since_step = 0.0
+        self._pools_in_place = True
         return retired
 
     def fail_active(self, error: str) -> list[Request]:
-        """Retire every live lane with an error (engine-fatal paths)."""
+        """Retire every live lane with an error (engine-fatal paths).  A
+        program that raised after its dispatch had consumed the donated
+        pools left them deleted: with every lane gone zeroed pools are
+        the right state, so they are built anew and the engine serves
+        the next request."""
         out = []
         for slot, state in enumerate(self._slots):
             if state is None:
                 continue
             state.request.error = error
             out.append(self._retire(slot, "error"))
+        if any(leaf.is_deleted()
+               for leaf in self._jax.tree.leaves(self.pools)):
+            self.pools = self._fresh_pools()
+            self._pools_in_place = True
         return out
 
     def stats(self) -> dict:
@@ -1005,5 +1058,9 @@ class DecodeEngine:
             # its peak and its bytes a slot are in the pool's snapshot.
             "state_slots": self.allocator.state_slots,
             "state_bytes": self.allocator.state_bytes,
+            # Steps whose every dispatch wrote the donated pools in
+            # place, and steps in which JAX copied them instead.
+            "pool_steps_in_place": self.pool_steps_in_place,
+            "pool_steps_copied": self.pool_steps_copied,
             "kv_pool": self.allocator.snapshot(),
         }

@@ -263,11 +263,11 @@ def hybrid_serving_programs(one_chip, buckets):
         tree, i32(B), i32(B), i32(B, MP), pools, f32(B), i32(B), f32(B),
         i32(B)).compile()
     prefills = {
-        n: jax.jit(engine._prefill_fn(n).__wrapped__).lower(
+        n: engine._prefill_fn(n).lower(
             tree, i32(1, n * econf.page_size), pools, i32(n), i32(),
             i32()).compile()
         for n in buckets}
-    return step, prefills
+    return step, prefills, pools
 
 
 def test_hybrid_serving_programs_compile_for_v5e(one_chip):
@@ -275,13 +275,26 @@ def test_hybrid_serving_programs_compile_for_v5e(one_chip):
     for the chip at 30 heads of 96 x 192, with the flash kernel in the full
     layer's prefill where the bucket's length lets it in: 1,024 tokens do,
     1,600 do not (``_layout_ok``; D6's silent fallback)."""
-    step, prefills = hybrid_serving_programs(one_chip, (64, 100))
+    step, prefills, pools = hybrid_serving_programs(one_chip, (64, 100))
     assert step.as_text().count("tpu_custom_call") == 0
     # (A full layer that is the model's LAST layer loses its call: its
     # attention output feeds only logits the prefill throws away, so XLA
     # keeps its K/V and drops the rest.  Here a linear layer follows it.)
     assert prefills[64].as_text().count("tpu_custom_call") == 1
     assert prefills[100].as_text().count("tpu_custom_call") == 0
+    # The engine donates its pools: every K/V pool, and every
+    # linear-attention layer's state and convolution tail, comes out in
+    # the buffer it went in by, and nothing else does.
+    leaves = jax.tree.leaves(pools)
+    assert len(leaves) == 2 * 5
+    # (Bytes as the chip lays an array out: the last axis in whole lanes
+    # of 128, which pads the state's keys of 96 and nothing else here.)
+    pool_bytes = sum(x.size // x.shape[-1] * -(-x.shape[-1] // 128) * 128
+                     * x.dtype.itemsize for x in leaves)
     for program in (step, *prefills.values()):
         mem = program.memory_analysis()
         assert mem.temp_size_in_bytes < 4e9
+        assert mem.alias_size_in_bytes == pool_bytes
+        header = program.as_text().split("\n", 1)[0]   # input_output_alias
+        assert header.count("may-alias") + header.count(
+            "must-alias") == len(leaves)

@@ -87,7 +87,9 @@ def test_chunk_prefill_builder_memo_shape_pinned(tmp_path):
     builder — constructed lazily but memoized through the blessed
     dict-memo shape, and CALLED FROM step() — must pass; the same
     builder without the memo is the r4 retrace class riding back in
-    through this PR and must be flagged."""
+    through this PR and must be flagged.  Both carry the engine's
+    ``donate_argnames``: a keyword beside the jitted function changes
+    neither verdict."""
     findings = lint(tmp_path, {"engine_like.py": """
         import jax
 
@@ -99,7 +101,8 @@ def test_chunk_prefill_builder_memo_shape_pinned(tmp_path):
                 fn = self._chunk_fns.get(chunk)
                 if fn is not None:
                     return fn
-                fn = jax.jit(lambda tree, toks: (tree, toks, chunk))
+                fn = jax.jit(lambda tree, pools: (tree, pools, chunk),
+                             donate_argnames=("pools",))
                 self._chunk_fns[chunk] = fn
                 return fn
 
@@ -113,7 +116,8 @@ def test_chunk_prefill_builder_memo_shape_pinned(tmp_path):
 
         class Engine:
             def _chunk_prefill_fn(self, chunk):
-                return jax.jit(lambda tree, toks: (tree, toks, chunk))
+                return jax.jit(lambda tree, pools: (tree, pools, chunk),
+                               donate_argnames=("pools",))
 
             def step(self, tree, toks):
                 return self._chunk_prefill_fn(4)(tree, toks)

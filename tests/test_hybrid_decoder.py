@@ -125,15 +125,18 @@ def test_a_slot_reused_and_idle_neighbours_change_nothing(
                    Request(prompt(n, n), 10))[0] for n in (30, 19)]
     engine = engine_of(model, params, slots=1)
     first = serve(engine, Request(prompt(30, 30), 10))[0]
-    state_left = np.asarray(engine.pools[0][0])
+    # (Copies, not views: the pools are donated to the next dispatch, and
+    # a view of a leaf would turn that donation into a copy.)
+    state_left = np.array(engine.pools[0][0], copy=True)
     assert np.abs(state_left).max() > 0           # not reset on retire
     second = serve(engine, Request(prompt(19, 19), 10))[0]
     assert [first, second] == alone
     wide = engine_of(model, params, slots=3)
     wide.admit(Request(prompt(30, 30), 10))       # slot 0; 1 and 2 idle
-    before = [np.asarray(x) for x in wide.pools[0]]
+    before = [np.array(x, copy=True) for x in wide.pools[0]]
     wide.step()
-    after = [np.asarray(x) for x in wide.pools[0]]
+    after = [np.array(x, copy=True) for x in wide.pools[0]]
+    assert wide.pool_steps_copied == 0
     for b, a in zip(before, after):
         assert (b[1:] == a[1:]).all() and not (b[0] == a[0]).all()
     both = engine_of(model, params, slots=3)
@@ -282,16 +285,24 @@ def test_what_the_state_needs_is_asked_for_by_name(call, names,
 #: md5 of a dense model's parameter tree (paths, shapes, sums) and of its
 #: lowered programs, taken ON THE PARENT (commit d1e6394, before
 #: ``layer_kinds`` existed) by this file's ``dense_fingerprints`` from a
-#: ``git archive`` of it.  They hold for this sandbox's jax.
+#: ``git archive`` of it.  They hold for this sandbox's jax.  ``step`` and
+#: ``prefill`` are the engine's programs as it jits them, and were renewed
+#: when it began to donate its pools (each pool argument gained
+#: ``tf.aliasing_output``); the same bodies jitted without donation are
+#: still that parent's text (``*_undonated``: its ``step`` / ``prefill``).
 DENSE_GOLDEN = {
     "gpt2": {"tree": "aaa1a7d60ae885e3d2d4d073dadd6d98",
-             "step": "e5d434e2fc470f7df202af6c5d536bd7",
-             "prefill": "ce244c8e84b7d40fe1f490845b913143",
+             "step": "61302b0fb2d22ddac729c90c90df9475",
+             "prefill": "30a7566c149cd53e6ccca433552da62b",
+             "step_undonated": "e5d434e2fc470f7df202af6c5d536bd7",
+             "prefill_undonated": "ce244c8e84b7d40fe1f490845b913143",
              "call": "7137ce905cc4f0b2dfb44c057f4e4108",
              "decode_paged": "399c8cdb9ccf0ce1b485582897135734"},
     "mistral": {"tree": "bda3e6337e210318d71872269ca97b04",
-                "step": "5300fee3c870abf8997a97474aef09be",
-                "prefill": "e4d74639b4bcc9278ba3266c747cc11e",
+                "step": "b14a26f6304d17bafd9bfe65fd318168",
+                "prefill": "c4242292c611c52c864f3b04435de2ea",
+                "step_undonated": "5300fee3c870abf8997a97474aef09be",
+                "prefill_undonated": "e4d74639b4bcc9278ba3266c747cc11e",
                 "call": "9bf6ceb33b379ccf6fc36228f229c7ae",
                 "decode_paged": "48fcd421dff91b33398df5e638887059"},
 }
@@ -315,11 +326,16 @@ def dense_fingerprints(name):
         num_slots=2, page_size=8, num_pages=16, max_pages_per_seq=4))
     i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
     f32 = lambda *s: jnp.zeros(s, jnp.float32)  # noqa: E731
-    out["step"] = md5(eng._step_fn.lower(
-        eng._tree, i32(2), i32(2), i32(2, 4), eng.pools, f32(2), i32(2),
-        f32(2), i32(2)).as_text())
-    out["prefill"] = md5(eng._prefill_fn(2).lower(
-        eng._tree, i32(1, 16), eng.pools, i32(2)).as_text())
+    step_args = (eng._tree, i32(2), i32(2), i32(2, 4), eng.pools, f32(2),
+                 i32(2), f32(2), i32(2))
+    prefill_args = (eng._tree, i32(1, 16), eng.pools, i32(2))
+    for key, fn, args in (("step", eng._step_fn, step_args),
+                          ("prefill", eng._prefill_fn(2), prefill_args)):
+        text = fn.lower(*args).as_text()
+        assert text.count("tf.aliasing_output") == 2 * cfg.num_layers
+        out[key] = md5(text)
+        out[f"{key}_undonated"] = md5(
+            jax.jit(fn.__wrapped__).lower(*args).as_text())
     out["call"] = md5(jax.jit(lambda p, t: m.apply({"params": p}, t)).lower(
         params, i32(2, 16)).as_text())
     out["decode_paged"] = md5(jax.jit(lambda p, *a: m.apply(
