@@ -1,19 +1,19 @@
 #!/usr/bin/env python3
-"""Smoke run of the main path on the chip: the quickest proof that the
-system still starts on a TPU v5e.
+"""Smoke run of the program's command lines on the chip: the quickest proof
+that the system still starts on a TPU v5e.
 
-    python chip_smoke.py              # one chip: four phases
-    python chip_smoke.py --chips 4    # one four-chip host: the dp4 phase only
+    python chip_smoke.py              # one chip: two phases
+    python chip_smoke.py --chips 4    # one four-chip host: train.py on four
 
 This parent process never imports JAX (nor the package, whose ``__init__``
 imports it): a chip belongs to one process at a time, so every phase runs
 in children that take the chip, finish and release it, one after another.
 Children that need the chip are pinned to it through ``JAX_PLATFORMS``
 where the environment does not already say (JAX left to itself carries on
-on the CPU when it finds no TPU), and every child written here first
-asserts ``jax.devices()[0].platform == "tpu"``.
+on the CPU when it finds no TPU), and the probe asserts
+``jax.devices()[0].platform == "tpu"`` before anything else runs.
 
-One chip (the driver runs this):
+One chip:
 
 - ``train_cli``        PS + worker of ``python -m distributed_tensorflow_tpu.train``
                        at the reference's MNIST hyperparameters, then the
@@ -22,35 +22,39 @@ One chip (the driver runs this):
                        the C++ tokenizer builds), served by
                        ``python -m distributed_tensorflow_tpu.tools.serve``,
                        queried over plain HTTP.
-- ``train_wide``       the widest model the repo builds (406M GPT, L=8
-                       H=2048 I=8192 S=1024 bf16, pallas attention) through
-                       ``TrainState`` + ``make_optimizer`` +
-                       ``build_sync_train_step``; reads the compiled program
-                       for Mosaic calls instead of trusting the flag.
-- ``serve_wide``       the same config through ``DecodeEngine`` +
-                       ``FairScheduler`` + ``ServingServer`` over HTTP.
 
-Four chips (``--chips 4``, run by the builder):
+Four chips (``--chips 4``):
 
-- ``dp4``              the 406M sync step on a one-device mesh and on the
-                       four-device mesh, same global batch; placement,
-                       all-reduce and loss agreement; what attention program
-                       each lowered (the four-device program must hold Mosaic
-                       calls: ``flash_attention`` maps its kernel over the
-                       mesh's batch axis; the one-device program on a
-                       four-chip host still lowers dense, because
-                       ``_gspmd_hazard()`` asks ``jax.device_count()``); then
-                       ``train.py`` on the four chips.
+- ``train_cli_dp4``    ``train.py`` alone on the four chips: its records
+                       name four devices of the probed kind, its losses
+                       are finite and fall.
+
+These are the paths no cell of the benchmark runs (the cells build their
+models in code: ROADMAP R7).  What this script used to build by hand at an
+invented width is held by the cells of ``BENCHMARK.json`` on every PR:
+
+- Mosaic calls in the compiled step, on one chip and on the four-device
+  mesh: ``train_mosaic_calls`` in ``train_gpt2m_1chip`` and
+  ``train_gpt2m_dp4``; an all-reduce in the four-device program:
+  ``train_collective_pct`` there;
+- losses of the step against a reference (and, on four chips, against the
+  one-device program): the train cells' ``loss_gap_step1..3``,
+  ``first_grad_gap`` and ``param_change_gap``;
+- a non-zero HBM peak: ``train_hbm_peak_gib`` and ``chat_``/``lp_``/
+  ``ohlp_hbm_peak_gib``;
+- served tokens against a reference, over HTTP through ``ServingServer``,
+  ``FairScheduler`` and ``DecodeEngine``: ``served_logit_gap_mean`` and
+  ``_widest`` in the three serving cells; no compile after warm-up:
+  ``chat_``/``lp_``/``ohlp_compiles_in_window``.
 
 Every phase prints one JSON line; a failed assertion or child ends the run
 non-zero.  On success the LAST line is
 ``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``.
 
-``--rehearse`` runs the same control flow at a tiny size on whatever
-backend JAX finds (the CPU, with pallas interpreted), skips the checks only
-a chip can meet (Mosaic calls, HBM peak), never prints ``"ok": true`` and
-exits 4 when every phase passed.  It is a rehearsal of the script, not a
-run of the system.
+``--rehearse`` runs the same control flow on whatever backend JAX finds (the
+CPU), skips the checks only a chip can meet (the peak table's row, the HBM
+peak), never prints ``"ok": true`` and exits 4 when every phase passed.  It
+is a rehearsal of the script, not a run of the system.
 
 Child logs land in ``chiprun_out/chip_smoke/`` (the compile cache's hit and
 miss counts are read from them).
@@ -71,7 +75,6 @@ import socket
 import subprocess
 import sys
 import tempfile
-import threading
 import time
 import urllib.request
 
@@ -80,18 +83,12 @@ PKG = os.path.join(REPO, "distributed_tensorflow_tpu")
 OUT_DIR = os.path.join(REPO, "chiprun_out", "chip_smoke")
 REHEARSAL_EXIT = 4
 
-#: The widest model the repo builds (bench.py's flagship) and the tiny
-#: stand-in a rehearsal uses.  ``pages``/``table`` size the serving pool.
-WIDE = dict(hidden_size=2048, num_layers=8, num_heads=16,
-            intermediate_size=8192, seq=1024, batch=8,
-            pages=384, table=40, prompts=(64, 100, 250, 512), gen=32)
-TINY = dict(hidden_size=256, num_layers=2, num_heads=2,
-            intermediate_size=512, seq=128, batch=8,
-            pages=64, table=8, prompts=(16, 30, 48, 64), gen=8)
-
-#: dp4's stated tolerance: the one- and four-device programs differ in
-#: reduction order only (bf16 activations, f32 loss).
-DP4_LOSS_RTOL = 2e-2
+#: ``train.py``'s flags for the reference's MNIST job (``distributed.py``'s
+#: hyperparameters, synthetic data), shared by the one- and four-chip legs.
+MNIST_FLAGS = ["--model=mnist_mlp", "--data_dir=/nonexistent",
+               "--hidden_units=100", "--batch_size=100",
+               "--learning_rate=0.01", "--sync_replicas=true",
+               "--steps_per_call=10", "--log_every=50"]
 
 
 def emit(**record) -> None:
@@ -108,7 +105,7 @@ def check(cond, what: str, asserted: list | None = None) -> None:
 
 
 # =====================================================================
-# Parent: process management.  No JAX below this line until "Children".
+# Parent: process management.  No JAX below this line until "The probe".
 # =====================================================================
 
 
@@ -229,17 +226,6 @@ class Smoke:
                                     f"still running after {timeout:.0f}s"))
         return text
 
-    def child(self, name: str, timeout: float) -> dict:
-        """Run one of this file's own children; its last stdout line is its
-        JSON result."""
-        cmd = [sys.executable, os.path.abspath(__file__), "--child", name,
-               "--seed", str(self.seed)]
-        if self.rehearse:
-            cmd.append("--rehearse")
-        text = self.run(name, cmd, timeout)
-        lines = [l for l in text.splitlines() if l.startswith("{")]
-        return json.loads(lines[-1])
-
     def stop(self, proc: subprocess.Popen, timeout: float = 60.0):
         """SIGTERM and wait: the clean-shutdown path.  Returns the exit
         code, or None when the child had to be killed."""
@@ -292,7 +278,14 @@ class Smoke:
              rehearsal=self.rehearse)
 
     def probe(self, want_count: int | None) -> None:
-        self.device = self.child("probe", 300.0)
+        """This file's one child of its own: it takes the chip, says what
+        it found on its last stdout line and lets go."""
+        cmd = [sys.executable, os.path.abspath(__file__), "--child", "probe"]
+        if self.rehearse:
+            cmd.append("--rehearse")
+        text = self.run("probe", cmd, 300.0)
+        self.device = json.loads(
+            [l for l in text.splitlines() if l.startswith("{")][-1])
         emit(phase="probe", device=self.device)
         if want_count is not None:
             check(self.device["count"] == want_count,
@@ -340,12 +333,8 @@ class Smoke:
         def worker(name: str, steps: int) -> tuple[str, list[dict]]:
             metrics = os.path.join(OUT_DIR, f"{name}.jsonl")
             out = self.run(name, [
-                *train, "--job_name=worker", "--model=mnist_mlp",
-                "--data_dir=/nonexistent", "--hidden_units=100",
-                "--batch_size=100", "--learning_rate=0.01",
-                "--sync_replicas=true", f"--train_steps={steps}",
-                "--steps_per_call=10", "--log_every=50",
-                "--save_interval_steps=100",
+                *train, "--job_name=worker", *MNIST_FLAGS,
+                f"--train_steps={steps}", "--save_interval_steps=100",
                 f"--metrics_file={metrics}"], 600.0)
             check(ps.poll() is None,
                   "PS alive while and after the worker held the chip")
@@ -442,37 +431,24 @@ class Smoke:
                     prompt_lengths=lengths, generated=gen,
                     engine_steps=after["engine"]["engine_step"])
 
-    def train_wide(self) -> dict:
-        out = self.child("train_wide", 900.0)
-        self.cache_stats("train_wide")
-        return out
-
-    def serve_wide(self) -> dict:
-        out = self.child("serve_wide", 900.0)
-        self.cache_stats("serve_wide")
-        return out
-
-    def dp4(self) -> dict:
-        out = self.child("dp4", 1500.0)
-        self.cache_stats("dp4")
-        asserted = out["asserted"]
-        # Then the trainer itself on the four chips.
-        metrics = os.path.join(OUT_DIR, "dp4_train_cli.jsonl")
-        self.run("dp4_train_cli", [
+    def train_cli_dp4(self) -> dict:
+        """The trainer itself on the four chips."""
+        asserted: list[str] = []
+        metrics = os.path.join(OUT_DIR, "train_cli_dp4.jsonl")
+        self.run("train_cli_dp4", [
             sys.executable, "-m", "distributed_tensorflow_tpu.train",
             "--job_name=worker", "--task_index=0", "--ps_hosts=",
             f"--worker_hosts=localhost:{free_port()}",
-            "--model=mnist_mlp", "--data_dir=/nonexistent",
-            "--hidden_units=100", "--batch_size=100",
-            "--learning_rate=0.01", "--sync_replicas=true",
-            "--train_steps=200", "--steps_per_call=10", "--log_every=50",
+            *MNIST_FLAGS, "--train_steps=200",
             f"--logdir={os.path.join(self.work, 'mnist4')}",
             f"--metrics_file={metrics}"], 600.0)
         steps = self.check_train_records(read_jsonl(metrics), asserted, 4)
         check(steps[-1]["loss"] < steps[0]["loss"],
               "train.py on four chips: loss decreased", asserted)
-        out["train_cli_losses"] = [steps[0]["loss"], steps[-1]["loss"]]
-        return out
+        self.cache_stats("train_cli_dp4")
+        return dict(asserted=asserted,
+                    losses=[steps[0]["loss"], steps[-1]["loss"]],
+                    hbm_peak_bytes=steps[-1]["hbm_peak_bytes"])
 
 
 def parent(args) -> int:
@@ -485,9 +461,8 @@ def parent(args) -> int:
         # it has touched the checkout.
         smoke.probe(4 if args.chips == 4 else None)
         smoke.prepare()
-        phases = ([smoke.dp4] if args.chips == 4 else
-                  [smoke.train_cli, smoke.train_serve_cli,
-                   smoke.train_wide, smoke.serve_wide])
+        phases = ([smoke.train_cli_dp4] if args.chips == 4 else
+                  [smoke.train_cli, smoke.train_serve_cli])
         for phase in phases:
             t0 = time.monotonic()
             result = phase()
@@ -504,13 +479,12 @@ def parent(args) -> int:
 
 
 # =====================================================================
-# Children: each is one process that takes the chip and releases it.
+# The probe: one process that takes the chip and releases it.
 # =====================================================================
 
 
-def child_setup(args):
-    """First thing in every child written here: the compile cache, then
-    the device.  Returns ``(jax, device_dict)``."""
+def child_probe(args) -> dict:
+    """The compile cache, then the device."""
     from distributed_tensorflow_tpu.utils.backend import configure_backend
     configure_backend()
     import jax
@@ -518,264 +492,8 @@ def child_setup(args):
     if not args.rehearse:
         check(dev.platform == "tpu",
               f"need a TPU, JAX found {dev.platform!r} ({jax.devices()})")
-    return jax, {"platform": dev.platform, "kind": dev.device_kind,
-                 "count": len(jax.devices())}
-
-
-def child_probe(args) -> dict:
-    return child_setup(args)[1]
-
-
-def wide_config(size: dict):
-    import dataclasses
-
-    from distributed_tensorflow_tpu.models import gpt as gpt_lib
-    return dataclasses.replace(
-        gpt_lib.mini(), hidden_size=size["hidden_size"],
-        num_layers=size["num_layers"], num_heads=size["num_heads"],
-        intermediate_size=size["intermediate_size"],
-        max_position=size["seq"], dtype="bfloat16",
-        attention_backend="pallas")
-
-
-def n_params(tree) -> int:
-    import jax
-    return sum(int(x.size) for x in jax.tree.leaves(tree))
-
-
-def peak_hbm() -> int:
-    from distributed_tensorflow_tpu.utils.profiling import (
-        device_memory_stats)
-    return max(d["peak_bytes_in_use"] for d in device_memory_stats())
-
-
-def sync_program(jax, size: dict, seed: int, mesh):
-    """The 406M sync train step as the trainer builds it, compiled for
-    ``mesh``.  Returns ``(compiled, state, batch, text, compile_seconds)``.
-    """
-    import jax.numpy as jnp
-
-    from distributed_tensorflow_tpu.models import gpt as gpt_lib
-    from distributed_tensorflow_tpu.parallel import mesh as mesh_lib
-    from distributed_tensorflow_tpu.parallel import sync as sync_lib
-    from distributed_tensorflow_tpu.parallel.sharding import replicate_tree
-    from distributed_tensorflow_tpu.training.optimizers import make_optimizer
-    from distributed_tensorflow_tpu.training.state import TrainState
-
-    cfg = wide_config(size)
-    model = gpt_lib.GptLM(cfg)
-    tokens = jnp.asarray(gpt_lib.synthetic_lm_batch(
-        seed, size["batch"], size["seq"], cfg)["tokens"])
-    params = model.init(jax.random.PRNGKey(seed), tokens[:1, :8])["params"]
-    apply_fn = lambda p, t: model.apply({"params": p}, t)
-    state = TrainState.create(apply_fn, params, make_optimizer("adam", 3e-4))
-    state = state.replace(
-        params=replicate_tree(mesh, state.params),
-        opt_state=replicate_tree(mesh, state.opt_state),
-        global_step=replicate_tree(mesh, state.global_step))
-
-    def loss_fn(p, batch):
-        loss, acc = gpt_lib.lm_loss(apply_fn(p, batch), batch)
-        return loss, {"accuracy": acc}
-
-    step = sync_lib.build_sync_train_step(mesh, loss_fn)
-    batch = jax.device_put(tokens, mesh_lib.data_sharded(mesh))
-    t0 = time.perf_counter()
-    compiled = step.lower(state, batch).compile()
-    return (compiled, state, batch, compiled.as_text(),
-            time.perf_counter() - t0)
-
-
-def lowered_attention(device: dict, mosaic_calls: int) -> str:
-    """In words, what the compiled step holds for the attention the
-    config asked for."""
-    if device["platform"] != "tpu":
-        return "pallas requested, interpreted (no Mosaic off the chip)"
-    return ("pallas requested, Mosaic kernels lowered" if mosaic_calls
-            else "pallas requested, dense XLA lowered")
-
-
-def child_train_wide(args) -> dict:
-    jax, device = child_setup(args)
-    from distributed_tensorflow_tpu.parallel import mesh as mesh_lib
-    size = TINY if args.rehearse else WIDE
-    asserted: list[str] = []
-    compiled, state, batch, text, compile_s = sync_program(
-        jax, size, args.seed, mesh_lib.data_parallel_mesh())
-    params = n_params(state.params)
-    mosaic = text.count("tpu_custom_call")
-    if not args.rehearse:
-        check(mosaic > 0, "compiled step contains Mosaic calls "
-              "(tpu_custom_call): the kernel is in the program", asserted)
-    losses, step_s = [], []
-    for _ in range(10):   # Adam's first steps on random weights overshoot
-        t0 = time.perf_counter()
-        state, metrics = compiled(state, batch)
-        losses.append(float(metrics["loss"]))  # the fetch ends the step
-        step_s.append(time.perf_counter() - t0)
-    check(all(math.isfinite(l) for l in losses), "losses finite", asserted)
-    check(losses[-1] < losses[0], "loss lower at the last step than at the "
-          "first", asserted)
-    peak = peak_hbm()
-    if not args.rehearse:
-        check(peak > 0, "non-zero peak HBM from memory_stats()", asserted)
-    return dict(asserted=asserted, device=device, n_params=params,
-                mosaic_calls=mosaic,
-                attention=lowered_attention(device, mosaic),
-                losses=[round(l, 4) for l in losses],
-                peak_hbm_bytes=peak,
-                smoke_output_not_a_metric=dict(
-                    compile_seconds=round(compile_s, 1),
-                    step_ms_after_warmup=round(
-                        1e3 * sorted(step_s[2:])[len(step_s[2:]) // 2], 1)))
-
-
-def child_serve_wide(args) -> dict:
-    jax, device = child_setup(args)
-    import jax.numpy as jnp
-
-    from distributed_tensorflow_tpu.models import gpt as gpt_lib
-    from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
-                                                           EngineConfig)
-    from distributed_tensorflow_tpu.serving.scheduler import FairScheduler
-    from distributed_tensorflow_tpu.serving.server import ServingServer
-    from distributed_tensorflow_tpu.utils.metrics import MetricsLogger
-    from distributed_tensorflow_tpu.utils.telemetry import Telemetry
-
-    size = TINY if args.rehearse else WIDE
-    asserted: list[str] = []
-    cfg = wide_config(size)
-    model = gpt_lib.GptLM(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed),
-                        jnp.zeros((1, 8), jnp.int32))["params"]
-
-    compiles = []
-    jax.monitoring.register_event_duration_secs_listener(
-        lambda event, _secs, **_kw: compiles.append(event)
-        if event == "/jax/core/compile/backend_compile_duration" else None)
-
-    engine = DecodeEngine(
-        model, params,
-        EngineConfig(num_slots=8, page_size=16, num_pages=size["pages"],
-                     max_pages_per_seq=size["table"]),
-        telemetry=Telemetry(MetricsLogger(None)))
-    server = ServingServer(engine, FairScheduler(), port=0,
-                           telemetry=engine.telemetry,
-                           meta={"model": "gpt_wide",
-                                 "vocab_size": cfg.vocab_size})
-    server.start()
-    base = f"http://127.0.0.1:{server.port}"
-    try:
-        check(http_json(f"{base}/healthz")["status"] == "ok", "/healthz ok",
-              asserted)
-        gen, vocab = size["gen"], cfg.vocab_size
-        # Warm-up: one request per prompt bucket (a bucket is a prompt's
-        # page count) compiles that bucket's prefill; the first also
-        # compiles the resident decode step.
-        warm = random_prompts(args.seed, vocab, size["prompts"])
-        answers = [post_generate(base, vocab, p, gen) for p in warm]
-        warm_compiles = len(compiles)
-        # The window: the same buckets again (other lengths, other
-        # tokens), all at once, so lanes join and leave mid-decode.
-        errors: list[BaseException] = []
-
-        def one(prompt):
-            try:
-                post_generate(base, vocab, prompt, gen)
-            except BaseException as e:  # re-raised below, on the main thread
-                errors.append(e)
-
-        threads = [threading.Thread(target=one, args=(p,))
-                   for p in random_prompts(args.seed + 1, vocab,
-                                           [n - 3 for n in size["prompts"]])]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(600.0)
-        if errors:
-            raise errors[0]
-        check(not any(t.is_alive() for t in threads),
-              "every windowed request returned")
-        asserted.append(WELL_FORMED)
-        in_window = len(compiles) - warm_compiles
-        check(in_window == 0, "zero compilations after warm-up for "
-              "repeated prompt buckets", asserted)
-        stats = http_json(f"{base}/statz")
-        n_req = 2 * len(size["prompts"])
-        check(sum(t["completed"] for t in stats["tenants"].values()) == n_req
-              and stats["engine"]["engine_step"] > 0
-              and stats["latency"]["serve_ttft_ms"]["count"] == n_req,
-              "statz counters moved", asserted)
-    finally:
-        server.shutdown()
-    peak = peak_hbm()
-    # Not gated: paged engine vs contiguous cache at this width, greedy.
-    # bf16 ties make exact equality the wrong gate.
-    ref = gpt_lib.generate_cached(
-        model, params, jnp.asarray([warm[0]], jnp.int32), gen)
-    ref = [int(t) for t in ref[0, len(warm[0]):]]
-    agree = sum(1 for a, b in zip(answers[0], ref) if a == b)
-    return dict(asserted=asserted, device=device, n_params=n_params(params),
-                requests=n_req, prompt_lengths=size["prompts"],
-                generated=gen, warmup_compilations=warm_compiles,
-                compilations_in_window=in_window,
-                prefill_programs=stats["engine"]["compile_cache"][
-                    "prefill_programs"],
-                engine_steps=stats["engine"]["engine_step"],
-                peak_hbm_bytes=peak,
-                paged_vs_generate_cached_tokens_agree=f"{agree}/{gen}")
-
-
-def child_dp4(args) -> dict:
-    jax, device = child_setup(args)
-    from distributed_tensorflow_tpu.parallel import mesh as mesh_lib
-    size = TINY if args.rehearse else WIDE
-    asserted: list[str] = []
-    check(device["count"] == 4, "four devices visible", asserted)
-
-    programs = {}
-    for n in (1, 4):
-        mesh = mesh_lib.data_parallel_mesh(num_devices=n)
-        compiled, state, batch, text, compile_s = sync_program(
-            jax, size, args.seed, mesh)
-        mosaic = text.count("tpu_custom_call")
-        if n == 4:
-            check(all(len(x.sharding.device_set) == 4
-                      for x in jax.tree.leaves(state.params)),
-                  "every parameter's sharding spans four devices", asserted)
-            check(len({s.device for s in batch.addressable_shards}) == 4,
-                  "the batch's shards sit on four distinct devices",
-                  asserted)
-            check("all-reduce" in text,
-                  "the four-chip program contains an all-reduce", asserted)
-            if not args.rehearse:
-                check(mosaic > 0, "the four-device program contains Mosaic "
-                      "calls (tpu_custom_call): the flash kernel is mapped "
-                      "over the mesh's batch axis, not dropped for dense "
-                      "XLA", asserted)
-        losses = []
-        for _ in range(3):
-            state, metrics = compiled(state, batch)
-            losses.append(float(metrics["loss"]))
-        programs[f"{n}_device_mesh"] = dict(
-            losses=[round(l, 4) for l in losses], mosaic_calls=mosaic,
-            attention=lowered_attention(device, mosaic),
-            all_reduces=text.count("all-reduce("),
-            compile_seconds=round(compile_s, 1))
-        del compiled, state, batch, metrics  # free the chip for the next
-    l1 = programs["1_device_mesh"]["losses"]
-    l4 = programs["4_device_mesh"]["losses"]
-    check(all(math.isfinite(x) for x in l1 + l4),
-          "losses of both programs finite", asserted)
-    check(all(abs(a - b) <= DP4_LOSS_RTOL * abs(a) for a, b in zip(l1, l4)),
-          f"per-step losses agree within rtol {DP4_LOSS_RTOL} "
-          "(8x1 vs 2x4, bf16)", asserted)
-    return dict(asserted=asserted, device=device, programs=programs,
-                peak_hbm_bytes=peak_hbm())
-
-
-CHILDREN = {"probe": child_probe, "train_wide": child_train_wide,
-            "serve_wide": child_serve_wide, "dp4": child_dp4}
+    return {"platform": dev.platform, "kind": dev.device_kind,
+            "count": len(jax.devices())}
 
 
 def main(argv=None) -> int:
@@ -783,19 +501,18 @@ def main(argv=None) -> int:
         description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter)
     parser.add_argument("--chips", type=int, choices=(1, 4), default=1,
-                        help="4: run the dp4 phase (and no other) on a "
-                             "four-chip host")
+                        help="4: run train.py on the four chips of one "
+                             "host (and no other phase)")
     parser.add_argument("--seed", type=int, default=0,
-                        help="seed of the random weights, batches and "
-                             "prompts")
+                        help="seed of the corpus and the prompts")
     parser.add_argument("--rehearse", action="store_true",
-                        help="tiny sizes on whatever backend JAX finds; "
-                             "never prints ok:true (see the module text)")
-    parser.add_argument("--child", choices=sorted(CHILDREN),
+                        help="whatever backend JAX finds; never prints "
+                             "ok:true (see the module text)")
+    parser.add_argument("--child", choices=("probe",),
                         help=argparse.SUPPRESS)
     args = parser.parse_args(argv)
     if args.child:
-        emit(**CHILDREN[args.child](args))
+        emit(**child_probe(args))
         return 0
     return parent(args)
 

@@ -165,8 +165,8 @@ def num_replicas(mesh: Mesh) -> int:
 # TF-Replicator's composition principle (PAPERS.md, 1902.00465): ONE
 # declarative description of the parallelism layout that a single program
 # interprets into any replica/shard topology.  ParallelConfig is that
-# description for this framework — train.py, bench.py, and the autotuner
-# (tools/autotune.py) all construct their mesh + sharding plan through it
+# description for this framework — train.py and the autotuner
+# (tools/autotune.py) both construct their mesh + sharding plan through it
 # instead of plumbing individual axis flags, and the tuner's search space
 # is literally a list of these values.
 
@@ -318,7 +318,7 @@ class ParallelConfig:
 
     def place_state(self, mesh: Mesh, state: Any, rules: Any = None) -> Any:
         """Place a TrainState on ``mesh`` under this layout — the single
-        placement dispatch train.py/bench.py/the tuner share.
+        placement dispatch train.py and the tuner share.
 
         ``rules`` are the model bundle's tensor-parallel ShardingRules
         (or None); they engage only when the mesh has a non-trivial

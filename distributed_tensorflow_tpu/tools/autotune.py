@@ -10,16 +10,16 @@ declarative layouts (``parallel.mesh.ParallelConfig``):
    device prefix, so an 8-device host searches 1/2/4/8-device layouts in
    one process);
 2. **prune** — score every arm with the analytic cost model
-   (``tools.check_mfu.estimate_config_cost``: roofline + per-axis comm
+   (``tools.cost_model.estimate_config_cost``: roofline + per-axis comm
    terms on TPU, the rendezvous-dominated host proxy on CPU) and keep
    only ``--measure_fraction`` of the space (default 40%), the naive
    default layout always included as the comparison baseline;
 3. **measure** — each survivor runs a short timed trial through the
    framework's own step builders (``parallel.sync``), compile time and
    steady-state step time recorded SEPARATELY so a one-off compile never
-   poisons the reward; every trial is crash/timeout-guarded the way
-   bench.py legs are (SIGALRM + exception containment — a layout the
-   backend cannot run is a ``crash`` verdict, not a dead tuner);
+   poisons the reward; every trial is crash/timeout-guarded (SIGALRM +
+   exception containment — a layout the backend cannot run is a
+   ``crash`` verdict, not a dead tuner);
 4. **emit** — the winner becomes a reusable run profile
    (``parallel.mesh.save_run_profile``) that ``train.py
    --profile=<file>`` consumes, and every trial lands on the telemetry
@@ -40,9 +40,9 @@ Usage::
     python -m distributed_tensorflow_tpu.train --profile profile.json ...
 
 Prints ONE final JSON line (searched/pruned/measured counts, winner,
-best-vs-default ratio, profile path) — the bench leg's and CI gate's
-machine contract.  SIGALRM-based trial timeouts assume the main thread;
-run the tuner as its own process (bench.py's autotune leg does).
+best-vs-default ratio, profile path) — the CI gate's machine contract.
+SIGALRM-based trial timeouts assume the main thread; run the tuner as
+its own process (ci.sh's autotune gate does).
 """
 
 from __future__ import annotations
@@ -57,15 +57,14 @@ import sys
 import time
 from typing import Any, Callable
 
-from . import check_mfu as check_mfu_lib
+from . import cost_model
 from ..parallel.mesh import ParallelConfig, save_run_profile
 
 
 class TrialTimeout(BaseException):
     """A tuner trial overran its wall-clock budget (a wedged compile or a
     deadlocked collective); BaseException so the trial's own broad
-    exception containment cannot swallow it — mirrors bench.py's
-    BenchLegTimeout."""
+    exception containment cannot swallow it."""
 
 
 @contextlib.contextmanager
@@ -323,7 +322,7 @@ def enumerate_space(n_devices: int, workload: Workload, *,
 def score_space(space: list[ParallelConfig], workload: Workload, *,
                 cost_profile: str) -> list[dict]:
     """Analytic cost per layout, index-aligned with ``space``."""
-    return [check_mfu_lib.estimate_config_cost(
+    return [cost_model.estimate_config_cost(
         cfg.to_dict(), cost_profile=cost_profile, **{
             k: workload.dims.get(k, 0)
             for k in ("n_params", "tokens_per_step", "num_layers",
@@ -410,10 +409,10 @@ def _run_trial_inner(cfg: ParallelConfig, workload: Workload, *,
             float(jax.tree.leaves(metrics)[0])
             times.append((time.perf_counter() - t0) * 1000.0)
     step_ms = float(np.median(times))
-    peak = check_mfu_lib.peak_flops_per_chip()
+    peak = cost_model.peak_flops_per_chip()
     mfu = None
     if peak:
-        flops = check_mfu_lib.train_step_flops(
+        flops = cost_model.train_step_flops(
             workload.dims["n_params"], workload.dims["tokens_per_step"],
             num_layers=workload.dims.get("num_layers", 0),
             hidden_size=workload.dims.get("hidden_size", 0),
@@ -520,8 +519,8 @@ def run_serving_trial(arm: dict, setup: dict, *, n_requests: int = 12,
                       prompt_len: int = 8, gen_tokens: int = 16,
                       timeout_s: float = 300.0) -> dict:
     """One guarded serving-knob trial: drive the continuous-batching
-    engine in-process (bench.py's ``--mode serve`` pattern — engine +
-    fair scheduler, no sockets) and record the request latency
+    engine in-process (engine + fair scheduler, no sockets) and record
+    the request latency
     distribution plus per-engine-step cost."""
     result = {"config": dict(arm), "describe": _describe_arm(arm),
               "verdict": "ok", "compile_ms": None, "step_ms": None,

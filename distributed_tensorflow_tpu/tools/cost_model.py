@@ -1,45 +1,38 @@
-"""MFU regression guard over the committed bench artifact.
+"""Analytic cost model of a training step: FLOPs, HBM bytes, device peaks.
 
-The flagship MFU numbers in BENCH_DETAILS.json (``gpt_mfu_pct`` and the
-``mfu_by_seq`` ladder) are load-bearing claims in README/PARITY — this tool
-turns them into a pinned contract the way the reference's test suite pinned
-its convergence numbers (SURVEY §4).  It compares a FRESH artifact (a just-
-finished ``bench.py`` pass, usually the uncommitted working-tree
-``BENCH_DETAILS.json``) against the COMMITTED one (``git show
-HEAD:BENCH_DETAILS.json`` by default) and fails when any guarded MFU figure
-drops by more than ``--threshold`` points (default 2.0).
+Two users in the program:
 
-Guarded keys (when present in BOTH artifacts):
+- live MFU.  ``train.py`` prices every optimizer step with
+  :func:`train_step_flops` and the attached chips' peak
+  (:func:`device_peak_flops`, or ``--peak_tflops``) and hands both to
+  ``utils/telemetry.py``, which writes ``mfu`` into each ``train_step``
+  record; ``tools/summarize_run.py`` reads it.
+- the parallelism autotuner (``tools/autotune.py``, docs/autotune.md)
+  prunes its search with :func:`estimate_config_cost` and reports its
+  winner's MFU by the same FLOP count.
 
-- ``extra.gpt_mfu_pct``        — flagship training step
-- ``extra.gpt_dense_mfu_pct``  — dense-attention variant
-- ``extra.mfu_by_seq.*.mfu_pct`` — the sequence-length ladder
+``--config`` scores a run profile's layout without touching a device::
 
-Usage::
+    python -m distributed_tensorflow_tpu.tools.cost_model \
+        --config profile.json [--cost-profile host]
 
-    python -m distributed_tensorflow_tpu.tools.check_mfu            # fresh
-        # working tree vs HEAD
-    python -m distributed_tensorflow_tpu.tools.check_mfu \
-        --fresh new.json --committed old.json --threshold 2.0
-
-Exit status: 0 = no regression (or nothing comparable), 1 = regression.
-A fresh artifact missing a guarded key is NOT a failure — partial bench
-runs refresh only the modes they measured (see bench.py's merge logic) —
-but the skipped comparison is reported so silence never hides a gap.
+The benchmark's roofline shares do not come from here: ``perfbench/`` counts
+its own operations and bytes (``perfbench/costs.py``) against its own peak
+table (``perfbench/peaks.py``); ``tests/test_cost_model.py`` holds the v5e
+peak of the two tables equal.
 """
 
 from __future__ import annotations
 
 import argparse
 import json
-import subprocess
 import sys
 
 # ----------------------------------------------------------- FLOP model
 #
-# The shared MFU arithmetic: bench.py's measurement arms, the live
-# telemetry stream (utils/telemetry.py), and summarize_run all price work
-# with the same convention, so their MFU figures are comparable.
+# The shared MFU arithmetic: the live telemetry stream (utils/telemetry.py),
+# summarize_run and the autotuner all price work with the same convention,
+# so their MFU figures are comparable.
 
 #: bf16 peak TFLOP/s per chip by device kind (dense); public TPU spec
 #: sheets.  Unknown kinds (CPU hosts, new chips) report no peak — MFU is
@@ -84,8 +77,8 @@ def train_step_flops(n_params: int, tokens: int, *, num_layers: int = 0,
     tokens``; backward costs twice the forward, so a train step is ``3x``
     forward.  Pass the transformer dims to additionally credit attention
     score/value work (``4 * L * tokens * kv_len * H`` per forward), which
-    the parameter count misses; a sliding ``window`` caps ``kv_len`` the
-    same way bench.py's ladder does.
+    the parameter count misses; a sliding ``window`` caps ``kv_len`` at
+    ``window + 1``.
     """
     fwd = 2.0 * n_params * tokens
     if num_layers and hidden_size and seq_len:
@@ -125,8 +118,7 @@ def train_step_bytes(n_params: int, tokens: int, *, num_layers: int = 0,
 # - ``host``: the CPU virtual-mesh proxy CI runs on.  XLA:CPU already
 #   threads ONE device's ops across every core, so extra virtual devices
 #   buy no compute — they only add collective rendezvous (N threads
-#   synchronizing per psum; bench.py's scaling arm measured this
-#   decomposition) and per-device dispatch.  This is what makes the
+#   synchronizing per psum) and per-device dispatch.  This is what makes the
 #   model rank dp1 above dp8 on the 2-core CI host, matching the
 #   measured order.
 #
@@ -141,9 +133,10 @@ HOST_FLOPS = 8e9                  # whole-host matmul class (all cores)
 HOST_BYTES_PER_SEC = 10e9
 HOST_RENDEZVOUS_S = 8e-4          # per extra participant per collective
 DISPATCH_S = 3e-4                 # host dispatch per device call
-#: Relative compute scale of the int8 matmul training arm: ~1.15x the
-#: bf16 MXU rate where the fused kernels apply (BASELINE.md int8 ladder);
-#: slightly SLOWER on hosts (no int8 matmul unit, quantize overhead).
+#: Relative compute scale of the int8 matmul training arm: a class number
+#: from the old rig's int8 ladder (BASELINE.md; not measured on this tree,
+#: ROADMAP S10); slightly SLOWER on hosts (no int8 matmul unit, quantize
+#: overhead).
 QUANT_COMPUTE_SCALE = {"tpu": {"off": 1.0, "int8": 0.87},
                        "host": {"off": 1.0, "int8": 1.05}}
 
@@ -270,110 +263,31 @@ def score_profile(profile: dict, *, cost_profile: str = "tpu",
         peak_flops_per_sec=peak_flops_per_sec, cost_profile=cost_profile)
 
 
-def _mfu_figures(artifact: dict) -> dict[str, float]:
-    """Flatten an artifact's guarded MFU figures to {name: pct}."""
-    extra = artifact.get("extra", artifact)
-    out: dict[str, float] = {}
-    for key in ("gpt_mfu_pct", "gpt_dense_mfu_pct"):
-        v = extra.get(key)
-        if isinstance(v, (int, float)):
-            out[key] = float(v)
-    ladder = extra.get("mfu_by_seq")
-    if isinstance(ladder, dict):
-        for rung, entry in sorted(ladder.items()):
-            v = entry.get("mfu_pct") if isinstance(entry, dict) else None
-            if isinstance(v, (int, float)):
-                out[f"mfu_by_seq.{rung}"] = float(v)
-    return out
-
-
-def compare(fresh: dict, committed: dict, threshold: float = 2.0,
-            print_fn=print) -> list[str]:
-    """Return the list of regression descriptions (empty = clean)."""
-    f, c = _mfu_figures(fresh), _mfu_figures(committed)
-    regressions: list[str] = []
-    for name, base in sorted(c.items()):
-        if name not in f:
-            print_fn(f"[check_mfu] SKIP {name}: not in the fresh artifact "
-                     f"(partial bench run)")
-            continue
-        cur, delta = f[name], f[name] - base
-        if delta < -threshold:
-            regressions.append(
-                f"{name}: {base:.2f} -> {cur:.2f} "
-                f"({delta:+.2f} pts, threshold -{threshold})")
-            print_fn(f"[check_mfu] REGRESSION {regressions[-1]}")
-        else:
-            print_fn(f"[check_mfu] ok {name}: {base:.2f} -> {cur:.2f} "
-                     f"({delta:+.2f})")
-    return regressions
-
-
-def _load_committed(ref: str, path: str) -> dict:
-    out = subprocess.run(["git", "show", f"{ref}:{path}"],
-                         capture_output=True, text=True, check=True)
-    return json.loads(out.stdout)
-
-
 def main(argv=None) -> int:
     parser = argparse.ArgumentParser(
         description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter)
-    parser.add_argument("--fresh", default="BENCH_DETAILS.json",
-                        help="freshly measured artifact (default: working "
-                             "tree BENCH_DETAILS.json)")
-    parser.add_argument("--committed", default=None,
-                        help="baseline artifact file; default: the "
-                             "committed BENCH_DETAILS.json at --ref")
-    parser.add_argument("--ref", default="HEAD",
-                        help="git ref for the committed baseline")
-    parser.add_argument("--threshold", type=float, default=2.0,
-                        help="max tolerated MFU drop in points")
-    parser.add_argument("--config", default=None,
-                        help="score a run profile's parallel layout "
-                             "analytically (no devices touched) instead "
-                             "of comparing bench artifacts: prints the "
-                             "cost-model decomposition as JSON "
+    parser.add_argument("--config", required=True,
+                        help="run profile whose parallel layout is scored "
+                             "analytically (no devices touched): prints "
+                             "the cost-model decomposition as JSON "
                              "(docs/autotune.md)")
     parser.add_argument("--cost-profile", default="tpu",
                         choices=("tpu", "host"),
-                        help="--config cost model flavor: tpu roofline "
-                             "or the CPU virtual-mesh host proxy")
+                        help="cost model flavor: tpu roofline or the CPU "
+                             "virtual-mesh host proxy")
     args = parser.parse_args(argv)
 
-    if args.config is not None:
-        from ..parallel.mesh import load_run_profile
-        try:
-            profile = load_run_profile(args.config)
-            cost = score_profile(profile, cost_profile=args.cost_profile)
-        except (OSError, ValueError) as e:
-            print(f"[check_mfu] --config failed: {e}", file=sys.stderr)
-            return 1
-        print(json.dumps({"profile": args.config,
-                          "parallel": profile["parallel"], **cost},
-                         indent=2, sort_keys=True))
-        return 0
-
-    with open(args.fresh) as fh:
-        fresh = json.load(fh)
-    if args.committed is not None:
-        with open(args.committed) as fh:
-            committed = json.load(fh)
-    else:
-        try:
-            committed = _load_committed(args.ref, "BENCH_DETAILS.json")
-        except (subprocess.CalledProcessError, FileNotFoundError) as e:
-            print(f"[check_mfu] no committed baseline readable at "
-                  f"{args.ref}:BENCH_DETAILS.json ({e}); nothing to guard")
-            return 0
-
-    regressions = compare(fresh, committed, threshold=args.threshold)
-    if regressions:
-        print(f"[check_mfu] FAIL: {len(regressions)} MFU regression(s) "
-              f"exceed {args.threshold} points")
+    from ..parallel.mesh import load_run_profile
+    try:
+        profile = load_run_profile(args.config)
+        cost = score_profile(profile, cost_profile=args.cost_profile)
+    except (OSError, ValueError) as e:
+        print(f"[cost_model] --config failed: {e}", file=sys.stderr)
         return 1
-    print("[check_mfu] PASS: no MFU regression beyond "
-          f"{args.threshold} points")
+    print(json.dumps({"profile": args.config,
+                      "parallel": profile["parallel"], **cost},
+                     indent=2, sort_keys=True))
     return 0
 
 

@@ -295,18 +295,15 @@ flags.DEFINE_boolean("gpt_matmul_int8", False,
                      "+ input-gradient matmuls, full-precision weight "
                      "gradients (SwitchBack; ops/quant_train.py). Same "
                      "checkpoint tree as bf16; convergence tracks bf16 "
-                     "within ~2%. On v5e the gelu MLP runs through fused "
-                     "pallas kernels (epilogue/NT-backward fusion) and "
-                     "measures 1.017x over bf16 end-to-end — see the "
-                     "bench gpt_int8_note and BASELINE.md's int8 ladder")
+                     "within ~2%. On the chip the gelu MLP runs through "
+                     "fused pallas kernels (epilogue/NT-backward fusion). "
+                     "Its speed against bf16 is not measured on this tree "
+                     "(ROADMAP S10)")
 flags.DEFINE_boolean("gpt_attn_int8", False,
                      "Also route gpt_mini's ATTENTION projections "
-                     "(qkv/out) through the int8 path. Honest status: "
-                     "measured a WASH on v5e (0.997x vs the MLP-only int8 "
-                     "step — layout churn cancels the MXU gain at these "
-                     "shapes; reproduced by the bench's "
-                     "gpt_int8_attn_vs_mlp_only arm, ladder in "
-                     "BASELINE.md); kept for rigs/shapes where it pays")
+                     "(qkv/out) through the int8 path. Its speed against "
+                     "the MLP-only int8 step is not measured on this tree "
+                     "(ROADMAP S10)")
 flags.DEFINE_boolean("gen_speculative_device", True,
                      "Run --gen_speculative ENTIRELY on device (draft + "
                      "verify + accept in one lax.while_loop): one dispatch "
@@ -314,8 +311,8 @@ flags.DEFINE_boolean("gen_speculative_device", True,
                      "trip per round, with a cached compiled program, "
                      "incremental n-gram index drafting, tree "
                      "verification, and adaptive K (docs/speculative.md; "
-                     "measured r6: 5.9x plain on repetitive text, ~3x on "
-                     "random, vs the host loop's 0.7x). The DEFAULT "
+                     "its speed against plain decoding is not measured on "
+                     "this tree, ROADMAP S10). The DEFAULT "
                      "speculative path; set false for the host loop's "
                      "per-round stats and explicit fallback telemetry")
 flags.DEFINE_float("label_smoothing", 0.0,
@@ -332,10 +329,9 @@ flags.DEFINE_boolean("log_grad_norm", False,
 flags.DEFINE_boolean("fused_layer_norm", False,
                      "Route transformer LayerNorms through the pallas "
                      "kernel (ops/pallas/layer_norm.py); same math and "
-                     "parameter tree as nn.LayerNorm. NOT a perf lever on "
-                     "TPU: measured ~parity (0.99-1.06x) with XLA's own LN "
-                     "fusion, and the step profile puts all elementwise "
-                     "work at ~3% of device time (bench.py --mode profile)")
+                     "parameter tree as nn.LayerNorm. Its speed against "
+                     "XLA's own LN fusion is not measured on this tree "
+                     "(ROADMAP S10)")
 flags.DEFINE_string("optimizer", "",
                     "Override the model's optimizer: sgd | momentum | "
                     "nesterov | adam | adamw | lamb | adagrad | rmsprop | "
@@ -456,7 +452,7 @@ flags.DEFINE_boolean("telemetry", True,
 flags.DEFINE_float("peak_tflops", 0.0,
                    "Per-chip peak TFLOP/s for the telemetry MFU figure "
                    "(0 = auto from the device kind table in "
-                   "tools/check_mfu.py; set explicitly on unknown chips "
+                   "tools/cost_model.py; set explicitly on unknown chips "
                    "or CPU smoke runs to get a non-null mfu)")
 flags.DEFINE_float("health_report_every", 10.0,
                    "Seconds between cluster-health telemetry snapshots "
@@ -1506,13 +1502,13 @@ def main(unused_argv):
 
     # Unified run telemetry (docs/observability.md): one kind-tagged JSONL
     # stream per host carrying the step-time breakdown, live MFU (priced
-    # with the bench artifacts' FLOP model), HBM watermarks, and cluster
+    # by tools/cost_model.py), HBM watermarks, and cluster
     # health — everything tools/summarize_run.py needs for a run report.
     telemetry = None
     health_reporter = None
     if metrics_path and FLAGS.telemetry:
         import numpy as _np
-        from .tools import check_mfu as check_mfu_lib
+        from .tools import cost_model
         from .utils.telemetry import SCHEMA_VERSION, Telemetry
         # Count on the bundle's tree: the live state may be per-replica
         # stacked (async mode), which would inflate the FLOP model.
@@ -1527,18 +1523,18 @@ def main(unused_argv):
         if FLAGS.model == "gpt_mini":
             from .models import gpt as _gpt_lib
             _cfg = _gpt_lib.mini()
-            flops_per_step = check_mfu_lib.train_step_flops(
+            flops_per_step = cost_model.train_step_flops(
                 n_params, tokens, num_layers=_cfg.num_layers,
                 hidden_size=_cfg.hidden_size, seq_len=FLAGS.bert_seq_len,
                 window=FLAGS.attention_window)
         else:
-            flops_per_step = check_mfu_lib.train_step_flops(n_params, tokens)
+            flops_per_step = cost_model.train_step_flops(n_params, tokens)
         if FLAGS.grad_accum_steps > 1:
             # Each optimizer step consumed accum_steps microbatches.
             flops_per_step *= FLAGS.grad_accum_steps
         peak = (FLAGS.peak_tflops * 1e12 * jax.device_count()
                 if FLAGS.peak_tflops > 0
-                else check_mfu_lib.device_peak_flops())
+                else cost_model.device_peak_flops())
         telemetry = Telemetry(metrics_logger, flops_per_step=flops_per_step,
                               peak_flops_per_sec=peak)
         # Crash flight recorder (docs/observability.md): the bus keeps a

@@ -1,6 +1,6 @@
 """Process-level JAX backend setup, shared by every entry point that may
 initialise a backend (``train.py``, the serve/export/autotune/determinism
-tools, ``examples/serve.py``, ``bench.py`` and the children of
+tools, ``examples/serve.py``, ``perfbench/worker.py`` and the children of
 ``chip_smoke.py``).
 
 Two decisions live here so no entry point carries its own copy:

@@ -3,7 +3,7 @@
 Before this module the observability pieces were fragmented: the training
 loop pushed ad-hoc records at :class:`~.metrics.MetricsLogger`, profiling
 snapshots lived in :mod:`.profiling`, cluster heartbeats stayed inside the
-coordination service, and the FLOP/MFU arithmetic hid in bench.py.  The
+coordination service, and the FLOP/MFU arithmetic had no home.  The
 :class:`Telemetry` bus unifies them:
 
 - **events** — kind-tagged JSONL records (``train_step``, ``eval``,
@@ -19,8 +19,8 @@ coordination service, and the FLOP/MFU arithmetic hid in bench.py.  The
 - **streaming histograms** — p50/p95/p99 of step time, host data-wait,
   barrier waits... in constant memory (log-bucketed counts, no sample
   storage), so a million-step run summarizes as cheaply as a 20-step one;
-- **MFU** — the live utilization figure, priced with the same FLOP model
-  as the bench artifacts (:mod:`..tools.check_mfu`);
+- **MFU** — the live utilization figure, priced with the FLOP model the
+  autotuner also uses (:mod:`..tools.cost_model`);
 - **crash flight recorder** — a constant-memory ring of the last N
   records (spans included) that :meth:`Telemetry.dump_flight` writes to
   ``<metrics_file>.flight`` when the process is about to die (SIGTERM via

@@ -10,7 +10,7 @@ import os
 import sys
 
 # Force CPU even when a real TPU is attached: tests validate *semantics* on an
-# 8-device virtual mesh; benchmarks (bench.py) use the real chip.
+# 8-device virtual mesh; the benchmark (perfbench/run.py) uses the real chip.
 os.environ["JAX_PLATFORMS"] = "cpu"
 _flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in _flags:

@@ -12,7 +12,7 @@ import pytest
 from distributed_tensorflow_tpu.parallel.mesh import (
     ParallelConfig, load_run_profile)
 from distributed_tensorflow_tpu.tools import autotune as at
-from distributed_tensorflow_tpu.tools import check_mfu as check_mfu_lib
+from distributed_tensorflow_tpu.tools import cost_model
 from distributed_tensorflow_tpu.tools import summarize_run
 
 
@@ -25,7 +25,7 @@ def test_host_cost_model_ranks_dp1_over_dp8():
     # must rank the small layouts ahead (matching the measured order the
     # exhaustive fixture below pins).
     wl = at.mlp_workload(batch_size=256, hidden=64)
-    costs = {dp: check_mfu_lib.estimate_config_cost(
+    costs = {dp: cost_model.estimate_config_cost(
         {"data": dp}, cost_profile="host", **{
             k: wl.dims.get(k, 0)
             for k in ("n_params", "tokens_per_step")})["est_step_ms"]
@@ -36,13 +36,13 @@ def test_host_cost_model_ranks_dp1_over_dp8():
 def test_tpu_cost_model_rewards_parallelism_on_big_models():
     dims = dict(n_params=10 ** 9, tokens_per_step=8 * 1024,
                 num_layers=24, hidden_size=2048, seq_len=1024)
-    dp1 = check_mfu_lib.estimate_config_cost({"data": 1},
-                                             cost_profile="tpu", **dims)
-    dp8 = check_mfu_lib.estimate_config_cost({"data": 8},
-                                             cost_profile="tpu", **dims)
+    dp1 = cost_model.estimate_config_cost({"data": 1},
+                                          cost_profile="tpu", **dims)
+    dp8 = cost_model.estimate_config_cost({"data": 8},
+                                          cost_profile="tpu", **dims)
     assert dp8["est_step_ms"] < dp1["est_step_ms"]
     # The pipeline bubble and the comm terms are live.
-    pp = check_mfu_lib.estimate_config_cost(
+    pp = cost_model.estimate_config_cost(
         {"data": 1, "pipe": 2, "microbatch": 4}, cost_profile="tpu",
         **dims)
     assert pp["bubble"] == pytest.approx(0.25)
@@ -54,10 +54,10 @@ def test_config_mode_scores_profile_without_devices(tmp_path):
     path = str(tmp_path / "p.json")
     save_run_profile(path, ParallelConfig(data=2),
                      workload={"n_params": 1000, "tokens_per_step": 64})
-    cost = check_mfu_lib.score_profile(load_run_profile(path),
-                                       cost_profile="host")
+    cost = cost_model.score_profile(load_run_profile(path),
+                                    cost_profile="host")
     assert cost["est_step_ms"] > 0 and cost["degree"] == 2
-    rc = check_mfu_lib.main(["--config", path, "--cost-profile", "host"])
+    rc = cost_model.main(["--config", path, "--cost-profile", "host"])
     assert rc == 0
 
 
@@ -480,7 +480,7 @@ def test_pipeline_space_never_carries_quant_arms():
 
 
 def test_autotune_cli_headline_contract(tmp_path):
-    # The CLI's one-line machine contract (bench leg + CI gate parse it):
+    # The CLI's one-line machine contract (the CI gate parses it):
     # run a real 2-arm tune end to end through main().
     out = str(tmp_path / "profile.json")
     trials = str(tmp_path / "trials.jsonl")
