@@ -1254,6 +1254,25 @@ def synthetic_lm_batch(seed: int, batch_size: int, seq_len: int,
     return {"tokens": toks.astype(np.int32)}
 
 
+def _sort_descending(logits: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``logits`` [B, V] sorted high to low along the vocabulary, and the
+    index each value came from: ``(sorted_logits, order)``.
+
+    ONE two-operand sort carries the indices along with the keys, so the
+    sorted values come out of the sort itself.  ``order`` is what
+    ``jnp.argsort(-logits)`` returns (the same stable sort of the same
+    keys, ties in index order) and the values are bit-equal to
+    ``take_along_axis(logits, order)`` (negation is exact) without that
+    element-wise gather over the vocabulary, which cost fifteen times
+    the sort on a TPU v5e (PERF.md, PR 30).
+    """
+    iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape,
+                                    logits.ndim - 1)
+    neg_sorted, order = jax.lax.sort((-logits, iota), dimension=-1,
+                                     num_keys=1, is_stable=True)
+    return -neg_sorted, order
+
+
 def sample_logits(step_logits: jax.Array, rng: jax.Array, *,
                   temperature: float = 1.0, top_k: int = 0,
                   top_p: float = 0.0) -> jax.Array:
@@ -1269,8 +1288,7 @@ def sample_logits(step_logits: jax.Array, rng: jax.Array, *,
         kth = jax.lax.top_k(logits, top_k)[0][:, -1:]
         logits = jnp.where(logits < kth, neg, logits)
     if 0.0 < top_p < 1.0:
-        order = jnp.argsort(-logits, axis=-1)
-        sorted_logits = jnp.take_along_axis(logits, order, axis=-1)
+        sorted_logits, order = _sort_descending(logits)
         probs = jax.nn.softmax(sorted_logits, axis=-1)
         # Exclusive cumulative mass: the first token is always kept.
         keep_sorted = (jnp.cumsum(probs, axis=-1) - probs) < top_p
@@ -1294,8 +1312,19 @@ def sample_logits_dynamic(step_logits: jax.Array, key: jax.Array,
     (highest-probability token always kept), filters compose.  Rows with
     ``temperature[b] <= 0`` take the greedy argmax.  Selection is
     Gumbel-max over the filtered scaled logits (= categorical sampling),
-    computed in sorted space: one argsort serves the k-threshold, the
-    nucleus mass, and the final gather.
+    computed in sorted space: one sort serves the k-threshold, the
+    nucleus mass, and the final pick.
+
+    The sampler does no vocabulary-wide work its inputs do not ask for.
+    A call whose rows are ALL greedy takes the argmax and nothing else:
+    the sorted-space path lies under a ``lax.cond`` on
+    ``any(temperature > 0)``, decided on the device inside the one
+    compiled program, so the caller compiles nothing twice and chooses
+    nothing.  (Keep the call outside any ``vmap``: under one the cond
+    becomes a select and both arms run.)  One sampled row runs the whole
+    path for the batch, as before; its greedy rows still take the argmax.
+    Either way the tokens are those the unconditional form returns, bit
+    for bit.
 
     ``key``: a TYPED prng key — scalar (one draw for the whole batch) or
     [B] (one key per row).  Per-row keys are what make a served sample
@@ -1304,28 +1333,35 @@ def sample_logits_dynamic(step_logits: jax.Array, key: jax.Array,
     shared the device call (see ``export_gpt_decode``'s key schedule).
     """
     V = step_logits.shape[-1]
-    t = jnp.maximum(temperature, 1e-6)[:, None]
-    order = jnp.argsort(-step_logits, axis=-1)                  # [B, V]
-    sl = jnp.take_along_axis(step_logits, order, axis=-1) / t
-    probs = jax.nn.softmax(sl, axis=-1)
-    idx = jnp.arange(V)[None, :]
-    keep_k = (top_k[:, None] <= 0) | (idx < top_k[:, None])
-    p = top_p[:, None]
-    excl = jnp.cumsum(probs, axis=-1) - probs   # exclusive mass
-    keep_p = ~((p > 0.0) & (p < 1.0)) | (excl < p)
-    neg = jnp.finfo(sl.dtype).min
-    filt = jnp.where(keep_k & keep_p, sl, neg)
-    if key.ndim == 1:   # typed keys: ndim 1 == one key per row
-        u = jax.vmap(lambda k: jax.random.uniform(
-            k, (V,), minval=1e-20, maxval=1.0))(key)
-    else:
-        u = jax.random.uniform(key, filt.shape, minval=1e-20, maxval=1.0)
-    gumbel = -jnp.log(-jnp.log(u))
-    samp_sorted = jnp.argmax(filt + gumbel, axis=-1)
-    sampled = jnp.take_along_axis(order, samp_sorted[:, None],
-                                  axis=-1)[:, 0]
-    greedy = jnp.argmax(step_logits, axis=-1)
-    return jnp.where(temperature > 0.0, sampled, greedy).astype(jnp.int32)
+
+    def greedy_arm():
+        return jnp.argmax(step_logits, axis=-1).astype(jnp.int32)
+
+    def sampled_arm():
+        t = jnp.maximum(temperature, 1e-6)[:, None]
+        sl, order = _sort_descending(step_logits)                # [B, V]
+        sl = sl / t
+        probs = jax.nn.softmax(sl, axis=-1)
+        idx = jnp.arange(V)[None, :]
+        keep_k = (top_k[:, None] <= 0) | (idx < top_k[:, None])
+        p = top_p[:, None]
+        excl = jnp.cumsum(probs, axis=-1) - probs   # exclusive mass
+        keep_p = ~((p > 0.0) & (p < 1.0)) | (excl < p)
+        neg = jnp.finfo(sl.dtype).min
+        filt = jnp.where(keep_k & keep_p, sl, neg)
+        if key.ndim == 1:   # typed keys: ndim 1 == one key per row
+            u = jax.vmap(lambda k: jax.random.uniform(
+                k, (V,), minval=1e-20, maxval=1.0))(key)
+        else:
+            u = jax.random.uniform(key, filt.shape, minval=1e-20,
+                                   maxval=1.0)
+        gumbel = -jnp.log(-jnp.log(u))
+        samp_sorted = jnp.argmax(filt + gumbel, axis=-1)
+        sampled = jnp.take_along_axis(order, samp_sorted[:, None],
+                                      axis=-1)[:, 0]
+        return jnp.where(temperature > 0.0, sampled, greedy_arm())
+
+    return jax.lax.cond(jnp.any(temperature > 0.0), sampled_arm, greedy_arm)
 
 
 def _next_token(step_logits, rng, temperature, top_k, top_p):

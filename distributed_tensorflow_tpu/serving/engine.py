@@ -51,6 +51,15 @@ says ``pools_in_place`` (:meth:`DecodeEngine.stats`:
 ``pool_steps_in_place`` / ``pool_steps_copied``).  A program that raises
 after its dispatch consumed the pools leaves them deleted;
 :meth:`DecodeEngine.fail_active` builds them anew.
+
+Sampling is ``gpt_lib.sample_logits_dynamic`` inside the step's one
+compiled program.  It sorts the vocabulary only where a lane of the batch
+has ``temperature > 0``; a batch of greedy lanes takes an argmax and
+nothing else (a ``lax.cond`` on the temperatures the step is handed
+anyway, so no second program and no option).  The host knows the same
+from its own ``_temp`` array: each ``serve_step`` record says
+``sampled_lanes``, and :meth:`DecodeEngine.stats` counts
+``sample_steps_greedy`` / ``sample_steps_sampled``.
 """
 
 from __future__ import annotations
@@ -261,6 +270,10 @@ class DecodeEngine:
         self._pools_in_place = True
         self.pool_steps_in_place = 0
         self.pool_steps_copied = 0
+        # Steps whose lanes were all greedy (the sampler took its argmax
+        # arm) and steps with a lane at temperature > 0 (it sorted).
+        self.sample_steps_greedy = 0
+        self.sample_steps_sampled = 0
         self._step_fn = self._build_step()
         self._spec_step_fn = (self._build_spec_step()
                               if cfg.spec_k else None)
@@ -836,6 +849,9 @@ class DecodeEngine:
         t0 = time.monotonic()
         with profiling.annotate("serve.step.stage"):
             given = self.pools[0][0]
+            # The predicate the sampler evaluates on the device, read off
+            # the host's copy of the same array before anything retires.
+            sampled_lanes = int(np.count_nonzero(self._temp > 0.0))
             if spec_mode:
                 K = self.config.spec_k
                 chunk = np.zeros((self.config.num_slots, K), np.int32)
@@ -881,8 +897,13 @@ class DecodeEngine:
             self.pool_steps_in_place += 1
         else:
             self.pool_steps_copied += 1
+        if sampled_lanes:
+            self.sample_steps_sampled += 1
+        else:
+            self.sample_steps_greedy += 1
         with profiling.annotate("serve.step.retire",
                                 pools_in_place=int(in_place),
+                                sampled_lanes=sampled_lanes,
                                 **(held if self._stateful else {})):
             tracer = tracing.active()
             round_id = 0
@@ -997,6 +1018,7 @@ class DecodeEngine:
                          kv_pages_in_use=self.allocator.pages_in_use,
                          kv_pages_total=self.config.num_pages,
                          **held, pools_in_place=in_place,
+                         sampled_lanes=sampled_lanes,
                          t_start=round(t0, 6),
                          step_ms=round(step_ms, 3), **split_ms,
                          spec_rows=self._spec_rows_last_step,
@@ -1062,5 +1084,9 @@ class DecodeEngine:
             # place, and steps in which JAX copied them instead.
             "pool_steps_in_place": self.pool_steps_in_place,
             "pool_steps_copied": self.pool_steps_copied,
+            # Steps whose lanes were all greedy, where the sampler is an
+            # argmax, and steps in which some lane sampled.
+            "sample_steps_greedy": self.sample_steps_greedy,
+            "sample_steps_sampled": self.sample_steps_sampled,
             "kv_pool": self.allocator.snapshot(),
         }

@@ -289,19 +289,24 @@ def test_what_the_state_needs_is_asked_for_by_name(call, names,
 #: ``prefill`` are the engine's programs as it jits them, and were renewed
 #: when it began to donate its pools (each pool argument gained
 #: ``tf.aliasing_output``); the same bodies jitted without donation are
-#: still that parent's text (``*_undonated``: its ``step`` / ``prefill``).
+#: still that parent's text (``prefill_undonated``: its ``prefill``).  Both
+#: ``step`` texts were renewed once more when the sampler inside them
+#: stopped gathering the vocabulary and went under a ``cond``
+#: (``sample_logits_dynamic``; ``tests/test_sampler.py`` holds its tokens to
+#: the old body's); ``decode_paged``, the step without its sampler, is
+#: still that parent's.
 DENSE_GOLDEN = {
     "gpt2": {"tree": "aaa1a7d60ae885e3d2d4d073dadd6d98",
-             "step": "61302b0fb2d22ddac729c90c90df9475",
+             "step": "d34f787364257851d460ba094db7e009",
              "prefill": "30a7566c149cd53e6ccca433552da62b",
-             "step_undonated": "e5d434e2fc470f7df202af6c5d536bd7",
+             "step_undonated": "a66dab1e40fd658c4668799419f2f837",
              "prefill_undonated": "ce244c8e84b7d40fe1f490845b913143",
              "call": "7137ce905cc4f0b2dfb44c057f4e4108",
              "decode_paged": "399c8cdb9ccf0ce1b485582897135734"},
     "mistral": {"tree": "bda3e6337e210318d71872269ca97b04",
-                "step": "b14a26f6304d17bafd9bfe65fd318168",
+                "step": "756b6892c1115b57c2088c113233cbee",
                 "prefill": "c4242292c611c52c864f3b04435de2ea",
-                "step_undonated": "5300fee3c870abf8997a97474aef09be",
+                "step_undonated": "a2308abef8956a99a3f0247a5bf076a5",
                 "prefill_undonated": "e4d74639b4bcc9278ba3266c747cc11e",
                 "call": "9bf6ceb33b379ccf6fc36228f229c7ae",
                 "decode_paged": "48fcd421dff91b33398df5e638887059"},
