@@ -21,12 +21,16 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ..ops import linear_attention as linear_ops
+from ..ops import routed_experts as experts_ops
 from ..ops.attention import dot_product_attention
 from ..parallel.sharding import ShardingRules
 
 
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
+#: What ``GptConfig.kinds`` calls a full-attention layer under
+#: ``latent_kv_rank`` (never written in ``layer_kinds``).
+LATENT_ATTENTION = "latent_attention"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,6 +106,40 @@ class GptConfig:
     # beta = 2 * sigmoid(.) instead of sigmoid(.): the state transition
     # I - beta k k^T may then have an eigenvalue in (-1, 0).
     linear_allow_neg_eigval: bool = False
+    # The rotation's base (pos_encoding="rope", and the latent layers').
+    rope_base: float = 10000.0
+    # Latent attention (MLA): ``latent_kv_rank`` > 0 makes every layer's
+    # token mixer the latent one.  A token's keys and values are then ONE
+    # row of ``latent_kv_rank + qk_rope_head_dim`` entries shared by all
+    # heads (the normed latent and one rotated key, an array each), which
+    # is all a cache holds of it; queries go through a rank of
+    # ``latent_q_rank``.  A head scores over ``qk_nope_head_dim`` entries
+    # expanded from the latent plus the ``qk_rope_head_dim`` rotated ones,
+    # and reads values of ``v_head_dim``.  EXPANDED where the whole sequence
+    # is at hand (``__call__``, ``prefill``: per-head keys and values
+    # through the attention backend), ABSORBED in ``decode_paged`` (the
+    # expansion folded into the query and the output, scores over the
+    # cached rows themselves).  Only those three paths carry the row; every
+    # other cache path refuses such a config by name, as they do a sparse
+    # MLP's.
+    latent_kv_rank: int = 0
+    latent_q_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Sparse MLP (ops/routed_experts.py): ``num_experts`` > 0 gives every
+    # layer after the first ``first_dense_layers`` (which keep the dense
+    # MLP at ``intermediate_size``) ``num_experts`` routed gated experts
+    # of ``expert_intermediate_size``, ``experts_per_token`` of them a
+    # token by sigmoid scores (renormalised, times
+    # ``routed_scaling_factor``; no token is dropped), beside
+    # ``num_shared_experts`` that every token passes.
+    num_experts: int = 0
+    experts_per_token: int = 0
+    expert_intermediate_size: int = 0
+    num_shared_experts: int = 0
+    routed_scaling_factor: float = 1.0
+    first_dense_layers: int = 0
 
     @property
     def head_dim(self) -> int:
@@ -114,7 +152,21 @@ class GptConfig:
     @property
     def kinds(self) -> tuple:
         """The kind of each layer, ``num_layers`` long."""
+        if self.latent_kv_rank:
+            return (LATENT_ATTENTION,) * self.num_layers
         return self.layer_kinds or (FULL_ATTENTION,) * self.num_layers
+
+    @property
+    def sparse_layers(self) -> tuple:
+        """Whether each layer's MLP is the routed experts', ``num_layers``
+        long."""
+        return tuple(bool(self.num_experts) and i >= self.first_dense_layers
+                     for i in range(self.num_layers))
+
+    @property
+    def latent_row_dim(self) -> int:
+        """Entries of the one row a token keeps a latent layer."""
+        return self.latent_kv_rank + self.qk_rope_head_dim
 
     @property
     def has_state_layers(self) -> bool:
@@ -127,8 +179,10 @@ class GptConfig:
                                         + self.linear_value_head_dim)
 
     def refuse_state_layers(self, path: str) -> None:
-        """Called first by every cache path that has no place for a
-        linear-attention layer's recurrent state."""
+        """Called first by every cache path that holds per-head K and V
+        rows around a dense MLP and nothing else: no place for a
+        linear-attention layer's recurrent state, for a latent row, nor for
+        a routed-expert MLP's histogram and idle lanes."""
         if self.has_state_layers:
             raise ValueError(
                 f"{path} does not carry a linear-attention layer's "
@@ -137,6 +191,13 @@ class GptConfig:
                 "the paths that do are GptLM.__call__, GptLM.prefill and "
                 "GptLM.decode_paged (the serving engine's whole-bucket "
                 "prefill and its decode step)")
+        for field, what in (("latent_kv_rank", "a latent-attention row"),
+                            ("num_experts", "a routed-expert MLP")):
+            if getattr(self, field):
+                raise ValueError(
+                    f"{path} does not carry {what} and GptConfig.{field} is "
+                    f"{getattr(self, field)}; the paths that do are "
+                    "GptLM.__call__, GptLM.prefill and GptLM.decode_paged")
 
     def __post_init__(self):
         if self.pos_encoding not in ("learned", "rope", "none"):
@@ -168,6 +229,45 @@ class GptConfig:
                 raise ValueError(
                     "layer_kinds with a linear_attention layer composes "
                     "with neither attention_window nor attn_int8")
+        if self.latent_kv_rank:
+            if min(self.latent_q_rank, self.qk_nope_head_dim,
+                   self.qk_rope_head_dim, self.v_head_dim) < 1 \
+                    or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent_kv_rank needs latent_q_rank, qk_nope_head_dim, "
+                    "v_head_dim >= 1 and an even qk_rope_head_dim >= 2")
+            if self.qk_nope_head_dim + self.qk_rope_head_dim \
+                    != self.v_head_dim:
+                raise ValueError(
+                    "the attention backends take one head size: "
+                    "qk_nope_head_dim + qk_rope_head_dim must equal "
+                    f"v_head_dim, got {self.qk_nope_head_dim} + "
+                    f"{self.qk_rope_head_dim} and {self.v_head_dim}")
+            if self.layer_kinds or self.kv_heads or self.attention_window \
+                    or self.attn_int8 or self.qk_norm \
+                    or self.pos_encoding != "none":
+                raise ValueError(
+                    "latent_kv_rank composes with none of layer_kinds, "
+                    "kv_heads, attention_window, attn_int8 and qk_norm, "
+                    "and rotates inside the mixer: pos_encoding must be "
+                    "'none'")
+        if self.num_experts:
+            if not 1 <= self.experts_per_token <= self.num_experts \
+                    or self.expert_intermediate_size < 1 \
+                    or self.num_shared_experts < 0 \
+                    or not 0 <= self.first_dense_layers <= self.num_layers:
+                raise ValueError(
+                    "num_experts needs 1 <= experts_per_token <= "
+                    "num_experts, expert_intermediate_size >= 1, "
+                    "num_shared_experts >= 0 and 0 <= first_dense_layers "
+                    "<= num_layers")
+            if self.activation != "swiglu" or self.matmul_int8 \
+                    or self.norm_placement != "pre":
+                raise ValueError(
+                    "num_experts: the experts are gated SiLU MLPs behind a "
+                    "norm on their input (activation='swiglu', "
+                    "norm_placement='pre'), and matmul_int8 has no grouped "
+                    "form")
         if self.activation not in ("gelu", "swiglu"):
             raise ValueError(f"Unknown activation {self.activation!r}; "
                              "one of ('gelu', 'swiglu')")
@@ -200,6 +300,12 @@ def infer_arch_from_layer0(layer0: dict) -> dict:
             "infer_arch_from_layer0 cannot infer GptConfig.layer_kinds: "
             "layer0 is a linear_attention layer and says nothing of the "
             "other layers' kinds; build the GptConfig from the run's "
+            "configuration file")
+    if "kv_a" in layer0 or "router" in layer0:
+        raise ValueError(
+            "infer_arch_from_layer0 cannot infer a latent-attention or "
+            "routed-expert GptConfig (ranks, head sizes, experts a token, "
+            "the leading dense layers); build the GptConfig from the run's "
             "configuration file")
     arch = {
         "activation": "swiglu" if "mlp_gate" in layer0 else "gelu",
@@ -264,24 +370,74 @@ class GptBlock(nn.Module):
     and the KV-cached ``decode_step`` share the same parameters.
 
     ``kind`` selects the token mixer: softmax attention over cached keys and
-    values (FULL_ATTENTION) or the gated delta rule over a recurrent state
+    values (FULL_ATTENTION), the gated delta rule over a recurrent state
     (LINEAR_ATTENTION: ``linear_mix`` / ``linear_prefill`` /
-    ``linear_decode_step``).  Norms, MLP and the residual path are shared."""
+    ``linear_decode_step``) or softmax attention over one cached latent row
+    a token (LATENT_ATTENTION: ``latent_mix`` / ``latent_prefill`` /
+    ``latent_decode_step_paged``).  ``sparse`` selects the MLP: the dense
+    one, or routed experts beside shared ones.  Norms and the residual
+    path are shared."""
 
     cfg: GptConfig
     kind: str = FULL_ATTENTION
+    sparse: bool = False
 
     def setup(self):
         cfg = self.cfg
         dtype = jnp.dtype(cfg.dtype)
         self.ln_attn = _layer_norm(cfg)
         self.ln_mlp = _layer_norm(cfg)
-        self._setup_mlp(dtype)
+        if self.sparse:
+            self._setup_experts(dtype)
+        else:
+            self._setup_mlp(dtype)
         self.drop = nn.Dropout(cfg.dropout_rate)
         if self.kind == LINEAR_ATTENTION:
             self._setup_linear(dtype)
+        elif self.kind == LATENT_ATTENTION:
+            self._setup_latent(dtype)
         else:
             self._setup_attention(dtype)
+
+    def _setup_latent(self, dtype):
+        cfg = self.cfg
+        flat = {"dtype": dtype, "use_bias": False}
+        H = cfg.num_heads
+        self.q_a = nn.Dense(cfg.latent_q_rank, **flat)
+        self.q_a_norm = RMSNorm()
+        self.q_b = nn.DenseGeneral(
+            (H, cfg.qk_nope_head_dim + cfg.qk_rope_head_dim), **flat)
+        # The latent and the one rotary key of a token, side by side.
+        self.kv_a = nn.Dense(cfg.latent_row_dim, **flat)
+        self.kv_a_norm = RMSNorm()
+        # Latent -> a head's un-rotated key part and its value, side by
+        # side: [latent_kv_rank, H, qk_nope_head_dim + v_head_dim].
+        self.kv_b = nn.DenseGeneral(
+            (H, cfg.qk_nope_head_dim + cfg.v_head_dim), **flat)
+        self.out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), **flat)
+
+    def _setup_experts(self, dtype):
+        cfg = self.cfg
+        E, I = cfg.num_experts, cfg.expert_intermediate_size
+        # Applied in float32 whatever type the kernel is stored in: the
+        # fourth and fifth score of a token are often a rounding apart.
+        self.router = nn.Dense(E, dtype=jnp.float32, use_bias=False)
+        # Steers which experts are chosen, never their weights.
+        self.router_bias = self.param("router_bias", nn.initializers.zeros,
+                                      (E,))
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        self.experts_gate = self.param("experts_gate", init,
+                                       (E, cfg.hidden_size, I))
+        self.experts_up = self.param("experts_up", init,
+                                     (E, cfg.hidden_size, I))
+        self.experts_down = self.param("experts_down", init,
+                                       (E, I, cfg.hidden_size))
+        if cfg.num_shared_experts:
+            shared = {"dtype": dtype, "use_bias": False}
+            self.shared_in = nn.Dense(I * cfg.num_shared_experts, **shared)
+            self.shared_gate = nn.Dense(I * cfg.num_shared_experts, **shared)
+            self.shared_out = nn.Dense(cfg.hidden_size, **shared)
 
     def _setup_linear(self, dtype):
         cfg = self.cfg
@@ -389,8 +545,8 @@ class GptBlock(nn.Module):
         if cfg.pos_encoding == "rope":
             if positions is None:
                 positions = jnp.arange(x.shape[1])
-            q = apply_rope(q, positions)
-            k = apply_rope(k, positions)
+            q = apply_rope(q, positions, cfg.rope_base)
+            k = apply_rope(k, positions, cfg.rope_base)
         return q, k, v
 
     def _expand_kv(self, kv: jax.Array) -> jax.Array:
@@ -401,7 +557,41 @@ class GptBlock(nn.Module):
             return kv
         return jnp.repeat(kv, groups, axis=2)
 
-    def _mlp(self, x: jax.Array, deterministic: bool) -> jax.Array:
+    def _experts(self, x: jax.Array, deterministic: bool,
+                 live: jax.Array | None = None) -> jax.Array:
+        """The sparse MLP: every token through its ``experts_per_token``
+        routed experts and through the shared ones.  ``live`` [B] (the
+        decode step's): a row that is no sequence is routed nowhere.  How
+        many (token, expert) pairs each expert got is sown as
+        ``routing/counts`` [E] for whoever applies the model with that
+        collection mutable (the serving engine's step)."""
+        cfg = self.cfg
+        dtype = jnp.dtype(cfg.dtype)
+        h = self.ln_mlp(x).astype(dtype)
+        flat = h.reshape(-1, cfg.hidden_size)
+        with jax.named_scope("moe.route"):
+            chosen, weights = experts_ops.route(
+                self.router(flat), self.router_bias, cfg.experts_per_token,
+                cfg.routed_scaling_factor)
+        with jax.named_scope("moe.experts"):
+            rows = None if live is None else jnp.repeat(
+                live, flat.shape[0] // live.shape[0])
+            y, counts = experts_ops.routed_experts(
+                flat, chosen, weights, self.experts_gate.astype(dtype),
+                self.experts_up.astype(dtype),
+                self.experts_down.astype(dtype), rows)
+        self.sow("routing", "counts", counts)
+        y = y.reshape(x.shape)
+        if cfg.num_shared_experts:
+            with jax.named_scope("moe.shared"):
+                y = y + self.shared_out(
+                    nn.silu(self.shared_gate(h)) * self.shared_in(h))
+        return x + self.drop(y, deterministic=deterministic)
+
+    def _mlp(self, x: jax.Array, deterministic: bool,
+             live: jax.Array | None = None) -> jax.Array:
+        if self.sparse:
+            return self._experts(x, deterministic, live)
         cfg = self.cfg
         post = cfg.norm_placement == "post"
         h = x if post else self.ln_mlp(x).astype(jnp.dtype(cfg.dtype))
@@ -450,6 +640,8 @@ class GptBlock(nn.Module):
     def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
         if self.kind == LINEAR_ATTENTION:
             return self.linear_mix(x, deterministic)
+        if self.kind == LATENT_ATTENTION:
+            return self.latent_mix(x, deterministic)
         q, k, v = self._qkv(x)
         ctx = dot_product_attention(q, self._expand_kv(k), self._expand_kv(v),
                                     causal=True,
@@ -535,6 +727,122 @@ class GptBlock(nn.Module):
                                   axis=1)
         tail = jnp.where(live[:, None, None], shifted, tail)
         return self._linear_close(x, h, o[:, None]), state, tail
+
+    # ---------------------------------------------- latent attention
+
+    def _latent_q_row(self, x: jax.Array, positions: jax.Array):
+        """What both forms share, for ``x`` [B, T, hidden] at ``positions``
+        ([T] or [B, T]): a head's un-rotated query part [B,T,H,nope], its
+        rotated part [B,T,H,rope], and the token's cache row in its two
+        parts: the latent AFTER its norm [B,T,latent_kv_rank] and the one
+        key all heads share AFTER its rotation [B,T,rope]."""
+        cfg = self.cfg
+        h = self._mixer_in(x)
+        q = self.q_b(self.q_a_norm(self.q_a(h)))
+        q_nope, q_rot = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+        q_rot = apply_rope(q_rot, positions, cfg.rope_base)
+        kv = self.kv_a(h)
+        latent = self.kv_a_norm(kv[..., :cfg.latent_kv_rank])
+        k_rot = apply_rope(kv[..., None, cfg.latent_kv_rank:], positions,
+                           cfg.rope_base)[:, :, 0]
+        return q_nope, q_rot, latent, k_rot
+
+    def _latent_attend(self, x: jax.Array, backend: str):
+        """EXPANDED form over the whole sequence: per-head keys (the part
+        expanded from the latent beside the shared rotated key) and values
+        through the attention backend.  Returns (the mixer's output
+        [B,T,hidden], the cache row's two parts)."""
+        cfg = self.cfg
+        q_nope, q_rot, latent, k_rot = self._latent_q_row(
+            x, jnp.arange(x.shape[1]))
+        with jax.named_scope("mla.expand"):
+            k_nope, v = jnp.split(self.kv_b(latent),
+                                  [cfg.qk_nope_head_dim], axis=-1)
+            k = jnp.concatenate([k_nope, jnp.broadcast_to(
+                k_rot[:, :, None, :], (*k_nope.shape[:3],
+                                       cfg.qk_rope_head_dim))], axis=-1)
+            q = jnp.concatenate([q_nope, q_rot], axis=-1)
+        ctx = dot_product_attention(q, k, v, causal=True, backend=backend)
+        return self.out(ctx), latent, k_rot
+
+    def latent_mix(self, x: jax.Array, deterministic: bool = True):
+        """The whole sequence, nothing cached (the training forward)."""
+        y, _, _ = self._latent_attend(x, self.cfg.attention_backend)
+        return self._mlp(self._add_mixed(x, y, deterministic), deterministic)
+
+    def latent_prefill(self, x: jax.Array, latent_cache: jax.Array,
+                       key_cache: jax.Array):
+        """The prompt's P tokens in one causal pass, their rows written to
+        the caches ([B, M, latent_kv_rank] and [B, M, rope]) at [0, P)."""
+        backend = ("xla" if self.cfg.attention_backend in ("ring", "ulysses")
+                   else self.cfg.attention_backend)
+        y, latent, k_rot = self._latent_attend(x, backend)
+        x = self._add_mixed(x, y)
+        return (self._mlp(x, deterministic=True),
+                self._write_prefill(latent_cache, latent),
+                self._write_prefill(key_cache, k_rot))
+
+    def latent_decode_step_paged(self, x: jax.Array, latent_pool: jax.Array,
+                                 key_pool: jax.Array,
+                                 page_table: jax.Array,
+                                 positions: jax.Array,
+                                 live: jax.Array | None = None):
+        """One token a row against the PAGED pools of latent rows
+        ([num_pages, page_size, latent_kv_rank] and [.., rope]; addressing,
+        sentinel and masks as in :meth:`decode_step_paged`), in the
+        ABSORBED form: with ``kv_b`` split a head into W^K [latent, nope]
+        and W^V [latent, v], the query's un-rotated part is folded through
+        W^K into the latent's space and scored against the cached latents
+        themselves, its rotated part against the cached rotated keys, the
+        weights average the cached LATENTS, and W^V expands that one
+        average a head.  The same mathematics as the expanded form; no key
+        or value is ever expanded over the context."""
+        cfg = self.cfg
+        num_pages, page = latent_pool.shape[0], latent_pool.shape[1]
+        B, MP = page_table.shape
+        q_nope, q_rot, latent, k_rot = self._latent_q_row(
+            x, positions[:, None])
+        lpage = (positions // page).astype(jnp.int32)
+        off = (positions % page).astype(jnp.int32)
+        phys = jnp.take_along_axis(
+            page_table, jnp.clip(lpage, 0, MP - 1)[:, None], axis=1)[:, 0]
+        latent_pool = latent_pool.at[phys, off].set(
+            latent[:, 0].astype(latent_pool.dtype), mode="drop")
+        key_pool = key_pool.at[phys, off].set(
+            k_rot[:, 0].astype(key_pool.dtype), mode="drop")
+
+        def gather(pool):
+            # A sentinel entry of the table reads the pool's last page
+            # instead of zeros (no pass over the gathered rows to blank
+            # them): whatever is there gets a weight of exactly 0 from
+            # ``valid`` below.
+            return jnp.take(pool, page_table, axis=0, mode="clip").reshape(
+                B, MP * page, -1)
+        s = jnp.arange(MP * page)
+        allocated = jnp.take_along_axis(
+            page_table, (s[None, :] // page), axis=1) < num_pages  # [B, S]
+        valid = (s[None, :] <= positions[:, None]) & allocated
+        compute = q_nope.dtype
+        with jax.named_scope("mla.absorb"):
+            w_k, w_v = jnp.split(
+                self.kv_b.variables["params"]["kernel"].astype(compute),
+                [cfg.qk_nope_head_dim], axis=-1)
+            latents = gather(latent_pool).astype(compute)
+            scale = 1.0 / jnp.sqrt(jnp.float32(
+                cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
+            logits = (jnp.einsum(
+                "bhc,bsc->bhs", jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_k),
+                latents, preferred_element_type=jnp.float32) + jnp.einsum(
+                "bhr,bsr->bhs", q_rot[:, 0],
+                gather(key_pool).astype(compute),
+                preferred_element_type=jnp.float32)) * scale
+            logits = jnp.where(valid[:, None, :], logits,
+                               jnp.finfo(jnp.float32).min)
+            weights = jax.nn.softmax(logits, axis=-1).astype(compute)
+            mean = jnp.einsum("bhs,bsc->bhc", weights, latents)
+            ctx = jnp.einsum("bhc,chd->bhd", mean, w_v)
+        x = self._add_mixed(x, self.out(ctx[:, None]))
+        return self._mlp(x, True, live), latent_pool, key_pool
 
     def _write_prefill(self, cache: jax.Array, fresh: jax.Array) -> jax.Array:
         """Write the prompt's K or V rows into the cache.
@@ -953,8 +1261,9 @@ class GptLM(nn.Module):
         # static_argnums counts self at 0: (self, x, deterministic).
         block_cls = (nn.remat(GptBlock, static_argnums=(2,)) if cfg.remat
                      else GptBlock)
-        self.layers = [block_cls(cfg, kind, name=f"layer{i}")
-                       for i, kind in enumerate(cfg.kinds)]
+        self.layers = [block_cls(cfg, kind, sparse, name=f"layer{i}")
+                       for i, (kind, sparse) in enumerate(
+                           zip(cfg.kinds, cfg.sparse_layers))]
         self.ln_final = _layer_norm(cfg)
         self.lm_head = nn.Dense(cfg.vocab_size)
 
@@ -1088,8 +1397,11 @@ class GptLM(nn.Module):
         layer's entry of ``pools`` is (state [B, H, Dv, Dk], conv tail
         [B, K-1, channels]), indexed by ROW and not by page, and ``live``
         [B] says which rows are sequences: a row that is not keeps its
-        entry bit for bit (see :func:`init_kv_pool`).  Returns
-        (logits [B, vocab], new pools)."""
+        entry bit for bit (see :func:`init_kv_pool`).  A latent-attention
+        layer's entry is its two pools of row parts; a routed-expert MLP
+        routes a row that ``live`` says is no sequence nowhere (without
+        ``live`` every row is routed).  Returns (logits [B, vocab], new
+        pools)."""
         if self.cfg.has_state_layers and live is None:
             raise ValueError(
                 "GptLM.decode_paged needs live= [B] for a config whose "
@@ -1100,6 +1412,9 @@ class GptLM(nn.Module):
         for layer, entry in zip(self.layers, pools):
             if layer.kind == LINEAR_ATTENTION:
                 x, *entry = layer.linear_decode_step(x, *entry, live)
+            elif layer.kind == LATENT_ATTENTION:
+                x, *entry = layer.latent_decode_step_paged(
+                    x, *entry, page_tables, positions, live)
             else:
                 x, *entry = layer.decode_step_paged(x, *entry, page_tables,
                                                     positions)
@@ -1123,8 +1438,15 @@ class GptLM(nn.Module):
         then write every position of the padded prompt as they do without
         ``lengths``: whoever decodes next overwrites a position at or
         past ``lengths[b]`` before reading it (the paged engine's
-        contract), and the returned logits are the last PADDED position's."""
+        contract), and the returned logits are the last PADDED position's.
+        A latent-attention layer writes every position's row and takes no
+        ``lengths``."""
         B, P = tokens.shape
+        if lengths is not None and self.cfg.latent_kv_rank:
+            raise ValueError(
+                "GptLM.prefill takes no lengths= for a config with "
+                "latent_kv_rank: a latent layer writes every position of "
+                "the padded prompt")
         x = self._embed(tokens, jnp.arange(P)[None], True)
         new_caches = []
         stateful = self.cfg.has_state_layers
@@ -1136,6 +1458,8 @@ class GptLM(nn.Module):
         for layer, entry in zip(self.layers, caches):
             if layer.kind == LINEAR_ATTENTION:
                 x, *entry = layer.linear_prefill(x, *entry, lengths)
+            elif layer.kind == LATENT_ATTENTION:
+                x, *entry = layer.latent_prefill(x, *entry)
             else:
                 x, *entry = layer.prefill(x, *entry,
                                           None if stateful else lengths)
@@ -1164,10 +1488,37 @@ def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
     if cfg.attention_window:
         max_len = min(max_len, cfg.attention_window)
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
-    shape = (batch_size, max_len, cfg.num_kv_heads, cfg.head_dim)
     return [_state_entry(cfg, batch_size) if kind == LINEAR_ATTENTION
-            else (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+            else _rows_entry(cfg, kind, (batch_size, max_len), dtype)
             for kind in cfg.kinds]
+
+
+def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype):
+    """A layer's cache entry of one row a token, zeroed, ``lead`` being the
+    axes that address a token: (keys, values) [*lead, G, D] a full-attention
+    layer; a latent one the row's two parts, (the normed latent [*lead,
+    latent_kv_rank], the rotated key all heads share [*lead,
+    qk_rope_head_dim]): no head axis and no values of their own,
+    ``latent_row_dim`` entries a token together.  Two arrays and not one
+    of their sum: 512 entries fill whole lanes of 128 and the chip keeps
+    the array as it is indexed, where it laid one array of 576 out with
+    the PAGES minor-most and every decode step copied every pool into the
+    indexed order and back (8.2 of a step's 24.2 ms; PERF.md, PR 35)."""
+    if kind == LATENT_ATTENTION:
+        return (jnp.zeros((*lead, cfg.latent_kv_rank), dtype),
+                jnp.zeros((*lead, cfg.qk_rope_head_dim), dtype))
+    shape = (*lead, cfg.num_kv_heads, cfg.head_dim)
+    return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+
+def kv_row_bytes_per_token(cfg: GptConfig, dtype=None) -> int:
+    """Bytes ONE cached token holds over all layers' pages (a
+    linear-attention layer holds none)."""
+    dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
+    return sum(x.size * x.dtype.itemsize
+               for kind in cfg.kinds if kind != LINEAR_ATTENTION
+               for x in jax.eval_shape(
+                   lambda k=kind: _rows_entry(cfg, k, (1,), dtype)))
 
 
 def _state_entry(cfg: GptConfig, rows: int):
@@ -1201,7 +1552,9 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
 
     A linear-attention layer holds no pages: its entry is one fixed-size
     row per decode SLOT (``num_slots`` of them: state float32, convolution
-    tail), whatever the sequence's length."""
+    tail), whatever the sequence's length.  A latent-attention layer's entry
+    is its row's two parts, [num_pages, page_size, latent_kv_rank] and
+    [num_pages, page_size, qk_rope_head_dim] (:func:`_rows_entry`)."""
     if cfg.attention_window:
         raise ValueError("paged KV pools need full-cache addressing; "
                          "sliding-window checkpoints are not pageable")
@@ -1209,9 +1562,8 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
         raise ValueError("init_kv_pool needs num_slots >= 1 for a config "
                          "whose layer_kinds has a linear_attention layer")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
-    shape = (num_pages, page_size, cfg.num_kv_heads, cfg.head_dim)
     return [_state_entry(cfg, num_slots) if kind == LINEAR_ATTENTION
-            else (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+            else _rows_entry(cfg, kind, (num_pages, page_size), dtype)
             for kind in cfg.kinds]
 
 

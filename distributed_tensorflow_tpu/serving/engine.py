@@ -233,10 +233,19 @@ class DecodeEngine:
         # plain decode step carry it.
         self._state_layers = mcfg.kinds.count(gpt_lib.LINEAR_ATTENTION)
         self._stateful = self._state_layers > 0
-        if self._stateful and (cfg.spec_k or cfg.prefill_chunk):
+        # Layers whose MLP is routed experts: the step hands their routing
+        # histogram [sparse layers, experts] back with its tokens.
+        self._sparse_layers = sum(mcfg.sparse_layers)
+        if cfg.spec_k or cfg.prefill_chunk:
+            # Neither carries a recurrent state, a latent row or a
+            # routed-expert MLP; a no-op for any other model.
             on = "spec_k" if cfg.spec_k else "prefill_chunk"
             mcfg.refuse_state_layers(f"DecodeEngine with EngineConfig.{on}")
         self._cache_dtype = resolve_kv_dtype(cfg.kv_dtype)
+        # Bytes a cached token holds over all layers' pools; the prefill
+        # span names them where they are latent rows (else 0).
+        row_bytes = gpt_lib.kv_row_bytes_per_token(mcfg, self._cache_dtype)
+        self._latent_row_bytes = row_bytes if mcfg.latent_kv_rank else 0
         self._tree = self._prepare_params(params)
         self._pending: tuple[Any, int] | None = None  # (tree, label step)
         self.model_step = 0            # checkpoint step the weights carry
@@ -244,7 +253,8 @@ class DecodeEngine:
         self.pools = self._fresh_pools()
         self.allocator = PageAllocator(
             cfg.num_pages, cfg.page_size,
-            state_bytes_per_slot=gpt_lib.state_bytes_per_slot(mcfg))
+            state_bytes_per_slot=gpt_lib.state_bytes_per_slot(mcfg),
+            row_bytes_per_token=row_bytes)
 
         B, MP = cfg.num_slots, cfg.max_pages_per_seq
         self._slots: list[_Slot | None] = [None] * B
@@ -274,6 +284,9 @@ class DecodeEngine:
         # arm) and steps with a lane at temperature > 0 (it sorted).
         self.sample_steps_greedy = 0
         self.sample_steps_sampled = 0
+        # Running sums of the steps' routing counters (_routing_counters).
+        self.moe = dict.fromkeys(("experts_touched", "expert_slots",
+                                  "expert_tokens_max", "routed_tokens"), 0)
         self._step_fn = self._build_step()
         self._spec_step_fn = (self._build_spec_step()
                               if cfg.spec_k else None)
@@ -370,10 +383,12 @@ class DecodeEngine:
             # An idle lane's table is all sentinel: its page writes drop
             # by themselves, its recurrent state has to be told.
             live = ((tables[:, 0] < self.config.num_pages),) \
-                if self._stateful else ()
-            logits, pools = model.apply(
+                if self._stateful or self._sparse_layers else ()
+            sown = {"mutable": ["routing"]} if self._sparse_layers else {}
+            out = model.apply(
                 {"params": params}, tokens, pools, tables, positions, *live,
-                method=gpt_lib.GptLM.decode_paged)
+                method=gpt_lib.GptLM.decode_paged, **sown)
+            (logits, pools), routing = out if sown else (out, None)
             # Per-row keys folded on the ABSOLUTE index being generated:
             # a sampled stream is reproducible for its (seed, position)s
             # no matter which other requests shared the batch.
@@ -381,6 +396,13 @@ class DecodeEngine:
                 lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
                     seeds, positions + 1)
             nxt = gpt_lib.sample_logits_dynamic(logits, keys, temp, tk, tp)
+            if routing is not None:
+                # The histogram rides behind the tokens in the one array
+                # the host fetches anyway: no second copy to wait for.
+                counts = [routing["routing"][f"layer{i}"]["counts"][0]
+                          for i, sparse in enumerate(model.cfg.sparse_layers)
+                          if sparse]
+                nxt = jnp.concatenate([nxt, *counts])
             return nxt, pools
 
         return jax.jit(step, donate_argnames=("pools",))
@@ -441,20 +463,17 @@ class DecodeEngine:
             lengths = () if absorb is None else (absorb[None],)
             _, caches = model.apply({"params": params}, tokens, caches,
                                     *lengths, method=gpt_lib.GptLM.prefill)
-            new_pools = []
-            for kind, (kc, vc), (kp, vp) in zip(mcfg.kinds, caches, pools):
+            def land(kind, cache, pool):
                 if kind == gpt_lib.LINEAR_ATTENTION:
-                    kp = kp.at[slot].set(kc[0])
-                    vp = vp.at[slot].set(vc[0])
-                else:
-                    kp = kp.at[phys].set(
-                        kc[0].reshape(n_pages, page, *kc.shape[2:]),
-                        mode="drop")
-                    vp = vp.at[phys].set(
-                        vc[0].reshape(n_pages, page, *vc.shape[2:]),
-                        mode="drop")
-                new_pools.append((kp, vp))
-            return new_pools
+                    return pool.at[slot].set(cache[0])
+                return pool.at[phys].set(
+                    cache[0].reshape(n_pages, page, *cache.shape[2:]),
+                    mode="drop")
+
+            # An entry is (keys, values), a latent layer's (latents,
+            # rotated keys), or (state, convolution tail).
+            return [tuple(land(kind, c, p) for c, p in zip(cache, pool))
+                    for kind, cache, pool in zip(mcfg.kinds, caches, pools)]
 
         fn = jax.jit(prefill, donate_argnames=("pools",))
         self._prefill_fns[n_pages] = fn
@@ -630,7 +649,9 @@ class DecodeEngine:
                     trace=request.trace, request_id=request.id,
                     tenant=request.tenant, bucket=n_prefill,
                     pages=n_prefill, prompt_tokens=P, chunks=1,
-                    state_layers=self._state_layers)
+                    state_layers=self._state_layers,
+                    sparse_layers=self._sparse_layers,
+                    latent_row_bytes=self._latent_row_bytes)
         spec = bool(cfg.spec_k) and request.speculative
         state = _Slot(request, cfg.spec_ngram if spec else 0)
         state.table = self.allocator.page_table(request.id,
@@ -884,6 +905,7 @@ class DecodeEngine:
                 greedy, nxt = np.asarray(greedy), np.asarray(sampled0)
             else:
                 nxt = np.asarray(nxt)
+        routed = self._routing_counters(nxt[self.config.num_slots:])
         now = time.monotonic()
         step_ms = (now - t0) * 1e3
         self.step_index += 1
@@ -904,7 +926,8 @@ class DecodeEngine:
         with profiling.annotate("serve.step.retire",
                                 pools_in_place=int(in_place),
                                 sampled_lanes=sampled_lanes,
-                                **(held if self._stateful else {})):
+                                **(held if self._stateful else {}),
+                                **routed):
             tracer = tracing.active()
             round_id = 0
             t_round_unix = 0.0
@@ -1018,7 +1041,7 @@ class DecodeEngine:
                          kv_pages_in_use=self.allocator.pages_in_use,
                          kv_pages_total=self.config.num_pages,
                          **held, pools_in_place=in_place,
-                         sampled_lanes=sampled_lanes,
+                         sampled_lanes=sampled_lanes, **routed,
                          t_start=round(t0, 6),
                          step_ms=round(step_ms, 3), **split_ms,
                          spec_rows=self._spec_rows_last_step,
@@ -1032,6 +1055,23 @@ class DecodeEngine:
         self._prefill_ms_since_step = 0.0
         self._pools_in_place = True
         return retired
+
+    def _routing_counters(self, counts: np.ndarray) -> dict:
+        """What the routed-expert layers saw this step, from the histogram
+        the step fetched behind its tokens ([sparse layers x experts],
+        live lanes only); empty for a model without such layers.  Over the
+        sparse layers: routed experts that got a token, how many there
+        are, the most tokens one expert got in one layer, and all the
+        (token, expert) pairs (live lanes x experts a token x layers)."""
+        if not self._sparse_layers:
+            return {}
+        routed = {"experts_touched": int(np.count_nonzero(counts)),
+                  "expert_slots": int(counts.size),
+                  "expert_tokens_max": int(counts.max()),
+                  "routed_tokens": int(counts.sum())}
+        for key, value in routed.items():
+            self.moe[key] += value
+        return routed
 
     def fail_active(self, error: str) -> list[Request]:
         """Retire every live lane with an error (engine-fatal paths).  A
@@ -1088,5 +1128,8 @@ class DecodeEngine:
             # argmax, and steps in which some lane sampled.
             "sample_steps_greedy": self.sample_steps_greedy,
             "sample_steps_sampled": self.sample_steps_sampled,
+            # Running sums of the steps' routing counters; zeros for a
+            # model whose MLPs are all dense.
+            "moe": dict(self.moe),
             "kv_pool": self.allocator.snapshot(),
         }

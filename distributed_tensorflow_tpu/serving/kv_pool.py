@@ -52,16 +52,21 @@ class PageAllocator:
     ``state_bytes_per_slot``: bytes of recurrent state (and convolution
     tail) a resident sequence holds beside its pages, over all the model's
     linear-attention layers; 0 for a model that has none.
+    ``row_bytes_per_token``: bytes one cached token holds over all layers'
+    pools (keys and values a head, or one latent row); reported, never
+    used to decide anything.
     """
 
     def __init__(self, num_pages: int, page_size: int,
-                 state_bytes_per_slot: int = 0):
+                 state_bytes_per_slot: int = 0,
+                 row_bytes_per_token: int = 0):
         if num_pages < 1 or page_size < 1:
             raise ValueError(f"need positive pool geometry, got "
                              f"{num_pages} pages x {page_size} slots")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.state_bytes_per_slot = int(state_bytes_per_slot)
+        self.row_bytes_per_token = int(row_bytes_per_token)
         self._peak_sequences = 0
         # Never-used pages dispense lowest-first; freed pages append to the
         # right and are reused oldest-freed-first once the fresh run is
@@ -224,6 +229,7 @@ class PageAllocator:
                 "utilization": round(self.utilization(), 4),
                 "internal_fragmentation": round(
                     self._fragmentation_locked(), 4),
+                "row_bytes_per_token": self.row_bytes_per_token,
                 "state_bytes_per_slot": self.state_bytes_per_slot,
                 "state_slots": self.state_slots,
                 "state_bytes": self.state_bytes,

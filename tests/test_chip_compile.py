@@ -248,6 +248,7 @@ def hybrid_serving_programs(one_chip, buckets):
     engine._jax, engine._jnp, engine.model, engine.config = (
         jax, jnp, model, econf)
     engine._stateful, engine._cache_dtype = True, None
+    engine._sparse_layers = 0
     engine._prefill_fns, engine._prefill_evictions = {}, 0
     tree = described(jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
@@ -296,5 +297,74 @@ def test_hybrid_serving_programs_compile_for_v5e(one_chip):
         assert mem.temp_size_in_bytes < 4e9
         assert mem.alias_size_in_bytes == pool_bytes
         header = program.as_text().split("\n", 1)[0]   # input_output_alias
+        assert header.count("may-alias") + header.count(
+            "must-alias") == len(leaves)
+
+
+# ------------------------ one latent row a token, and routed experts
+
+
+def test_latent_and_routed_expert_programs_compile_for_v5e(one_chip):
+    """The leading dense layer and two sparse layers at the published widths
+    of ``perfbench/configs/glm-4.7-flash.json``, as the engine compiles them:
+    the decode step over 16 slots of 528 pages (absorbed attention over the
+    rows, three grouped products a sparse layer, the histogram behind the
+    tokens) and a whole-bucket prefill (the flash kernel at a head of 256,
+    the grouped products at 4 x 1,024 rows), pools donated."""
+    from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
+                                                           EngineConfig)
+    from perfbench import spec, worker
+    config = spec.load_json(os.path.join(spec.HERE, "configs",
+                                         "glm-4.7-flash.json"))
+    cfg = dataclasses.replace(worker.gpt_config(
+        {"config": config, "config_file": "glm-4.7-flash.json"}),
+        num_layers=3, vocab_size=32768)
+    model = gpt_lib.GptLM(cfg)
+    econf = EngineConfig(num_slots=16, page_size=16, num_pages=8448,
+                         max_pages_per_seq=528)
+
+    def described(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+    engine = DecodeEngine.__new__(DecodeEngine)     # closures, no arrays
+    engine._jax, engine._jnp, engine.model, engine.config = (
+        jax, jnp, model, econf)
+    engine._stateful, engine._cache_dtype, engine._sparse_layers = (
+        False, None, 2)
+    engine._prefill_fns, engine._prefill_evictions = {}, 0
+    tree = described(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+        jnp.bfloat16)
+    pools = described(jax.eval_shape(lambda: gpt_lib.init_kv_pool(
+        cfg, econf.num_pages, econf.page_size)))
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,  # noqa: E731
+                                          sharding=one_chip)
+    B, MP = econf.num_slots, econf.max_pages_per_seq
+    lowered = engine._build_step().lower(
+        tree, i32(B), i32(B), i32(B, MP), pools, f32(B), i32(B), f32(B),
+        i32(B))
+    # 16 tokens and behind them 2 layers x 64 experts of histogram
+    assert lowered.out_info[0].shape == (B + 2 * 64,)
+    step = lowered.compile()
+    prefill = engine._prefill_fn(64).lower(
+        tree, i32(1, 1024), pools, i32(64)).compile()
+    assert step.as_text().count("tpu_custom_call") == 2 * 3
+    # The last layer's mixer and experts feed only logits the prefill
+    # throws away: two flash calls and ONE layer's grouped products stay.
+    assert prefill.as_text().count("tpu_custom_call") == 2 + 3
+    leaves = jax.tree.leaves(pools)
+    assert [x.shape for x in leaves] == [(8448, 16, 512),
+                                         (8448, 16, 64)] * 3
+    # (no padding: 512 fills whole lanes of 128, and the chip lays the
+    # rotated keys' 64 out with the pages minor-most)
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    for program in (step, prefill):
+        mem = program.memory_analysis()
+        assert mem.temp_size_in_bytes < 2e9
+        assert mem.alias_size_in_bytes == pool_bytes
+        header = program.as_text().split("\n", 1)[0]
         assert header.count("may-alias") + header.count(
             "must-alias") == len(leaves)
