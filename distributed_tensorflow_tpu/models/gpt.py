@@ -922,11 +922,12 @@ class GptBlock(nn.Module):
     def _attend_cache(self, q: jax.Array, k_cache: jax.Array,
                       v_cache: jax.Array, valid: jax.Array) -> jax.Array:
         """Grouped attention of ``q`` [B, Q, H, D] against the cache —
-        the ONE cached-attention body every decode variant
+        the ONE cached-attention body every contiguous decode variant
         (:meth:`decode_step` / :meth:`decode_step_ragged` /
         :meth:`decode_chunk`) shares; only cache addressing and the
         ``valid`` mask (broadcastable to [B, G, R, Q, M]) differ per
-        caller.
+        caller.  (The paged step attends rows it leaves flat:
+        :meth:`_attend_rows`.)
 
         Caches may ride a narrower dtype than compute (float8 KV): upcast
         ON READ — XLA fuses the cast into the einsum, so HBM traffic is
@@ -951,6 +952,36 @@ class GptBlock(nn.Module):
         ctx = jnp.einsum("bgrqk,bkgd->bqgrd", weights.astype(compute),
                          v_cache.astype(compute))
         return ctx.reshape(B, Q, cfg.num_heads, depth)
+
+    def _attend_rows(self, q: jax.Array, k_rows: jax.Array,
+                     v_rows: jax.Array, valid: jax.Array) -> jax.Array:
+        """:meth:`_attend_cache` for ONE query a row, ``q`` [B, 1, H, D],
+        against rows gathered from a paged pool and left as the pool holds
+        them, flat: ``k_rows``/``v_rows`` [B, M, G * D]; ``valid`` [B, M].
+
+        The same sums, placed so that the rows are never re-laid out: a
+        query head is widened to the whole row, zero outside its own kv
+        head's D lanes, so scores and weighted values are two plain
+        matmuls over [M, G * D] and the head's own D lanes of the result
+        are its context.  Splitting the gathered rows into [B, M, G, D]
+        instead costs the chip two more passes over them a layer (a
+        float32 copy of the keys among them: PERF.md, PR 37); the G-fold
+        products ride in the shadow of reading the rows once.
+        """
+        B, _, H, depth = q.shape
+        G = self.cfg.num_kv_heads
+        scale = 1.0 / jnp.sqrt(jnp.float32(depth))
+        own = (jnp.arange(H)[:, None] // (H // G)
+               == jnp.arange(G)[None, :])[None, :, :, None]     # [1,H,G,1]
+        wide = jnp.where(own, q[:, 0, :, None, :], 0).reshape(B, H, -1)
+        logits = jnp.einsum("bhc,bkc->bhk", wide, k_rows.astype(q.dtype),
+                            preferred_element_type=jnp.float32) * scale
+        logits = jnp.where(valid[:, None, :], logits,
+                           jnp.finfo(jnp.float32).min)
+        weights = jax.nn.softmax(logits, axis=-1)
+        ctx = jnp.einsum("bhk,bkc->bhc", weights.astype(q.dtype),
+                         v_rows.astype(q.dtype)).reshape(B, H, G, depth)
+        return jnp.where(own, ctx, 0).sum(axis=2)[:, None]
 
     def _attend_cache_chunk(self, q: jax.Array, k_cache: jax.Array,
                             v_cache: jax.Array, k_new: jax.Array,
@@ -1157,7 +1188,10 @@ class GptBlock(nn.Module):
         whose logical page falls OUTSIDE the page table (drafts past the
         row's reservation) are routed through the OOB sentinel and drop —
         never clamped onto the last real page, which may hold committed
-        K/V.
+        K/V.  The pools' row is flat, [num_pages, page_size, G * D]
+        (:func:`init_kv_pool`; PR 37); the gathered rows get their head
+        axis back for :meth:`_attend_cache_chunk` (two passes over them on
+        the chip that :meth:`decode_step_paged` avoids; no cell runs this).
         """
         cfg = self.cfg
         if cfg.attention_window:
@@ -1177,12 +1211,12 @@ class GptBlock(nn.Module):
         phys = jnp.where(lpage < MP, phys, num_pages)  # OOB -> sentinel
         # Cache-dtype round trip before attending (see decode_chunk).
         k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
-        k_pool = k_pool.at[phys, off].set(k, mode="drop")
-        v_pool = v_pool.at[phys, off].set(v, mode="drop")
+        k_pool = k_pool.at[phys, off].set(k.reshape(B, K, -1), mode="drop")
+        v_pool = v_pool.at[phys, off].set(v.reshape(B, K, -1), mode="drop")
         def gather(pool):
             rows = jnp.take(pool, page_table, axis=0, mode="fill",
-                            fill_value=0)                 # [B,MP,page,G,D]
-            return rows.reshape(B, MP * page, *pool.shape[2:])
+                            fill_value=0)                 # [B,MP,page,G*D]
+            return rows.reshape(B, MP * page, *k.shape[2:])
         s = jnp.arange(MP * page)
         allocated = jnp.take_along_axis(
             page_table, (s[None, :] // page), axis=1) < num_pages  # [B, S]
@@ -1201,7 +1235,10 @@ class GptBlock(nn.Module):
         decode body (:mod:`..serving.engine`).
 
         The pool holds every resident sequence's cache as fixed-size pages
-        (``k_pool``/``v_pool``: [num_pages, page_size, G, D]); row ``b``'s
+        (``k_pool``/``v_pool``: [num_pages, page_size, G * D], the row
+        flat so that the chip keeps the pool as the scatter below indexes
+        it, and attended flat, :meth:`_attend_rows`: :func:`init_kv_pool`,
+        PR 37); row ``b``'s
         logical position ``p`` lives at physical page
         ``page_table[b, p // page_size]``, offset ``p % page_size``.
         ``page_table`` [B, MP] uses ``num_pages`` itself as the
@@ -1228,22 +1265,21 @@ class GptBlock(nn.Module):
         off = (positions % page).astype(jnp.int32)
         phys = jnp.take_along_axis(
             page_table, jnp.clip(lpage, 0, MP - 1)[:, None], axis=1)[:, 0]
-        k_pool = k_pool.at[phys, off].set(k[:, 0].astype(k_pool.dtype),
-                                          mode="drop")
-        v_pool = v_pool.at[phys, off].set(v[:, 0].astype(v_pool.dtype),
-                                          mode="drop")
-        # Gather each row's pages into a contiguous [B, MP*page, G, D]
+        k_pool = k_pool.at[phys, off].set(
+            k.reshape(B, -1).astype(k_pool.dtype), mode="drop")
+        v_pool = v_pool.at[phys, off].set(
+            v.reshape(B, -1).astype(v_pool.dtype), mode="drop")
+        # Gather each row's pages into a contiguous [B, MP*page, G*D]
         # view; sentinel pages read as zeros (mode="fill") and stay masked.
         def gather(pool):
             rows = jnp.take(pool, page_table, axis=0, mode="fill",
-                            fill_value=0)                 # [B,MP,page,G,D]
-            return rows.reshape(B, MP * page, *pool.shape[2:])
+                            fill_value=0)                 # [B,MP,page,G*D]
+            return rows.reshape(B, MP * page, -1)
         s = jnp.arange(MP * page)
         allocated = jnp.take_along_axis(
             page_table, (s[None, :] // page), axis=1) < num_pages  # [B, S]
         valid = (s[None, :] <= positions[:, None]) & allocated
-        ctx = self._attend_cache(q, gather(k_pool), gather(v_pool),
-                                 valid[:, None, None, None, :])
+        ctx = self._attend_rows(q, gather(k_pool), gather(v_pool), valid)
         x = self._add_mixed(x, self.out(ctx))
         return self._mlp(x, deterministic=True), k_pool, v_pool
 
@@ -1493,11 +1529,13 @@ def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
             for kind in cfg.kinds]
 
 
-def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype):
+def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype,
+                flat: bool = False):
     """A layer's cache entry of one row a token, zeroed, ``lead`` being the
     axes that address a token: (keys, values) [*lead, G, D] a full-attention
-    layer; a latent one the row's two parts, (the normed latent [*lead,
-    latent_kv_rank], the rotated key all heads share [*lead,
+    layer, or with ``flat`` (the paged pool's form, :func:`init_kv_pool`)
+    [*lead, G * D]; a latent one the row's two parts, (the normed latent
+    [*lead, latent_kv_rank], the rotated key all heads share [*lead,
     qk_rope_head_dim]): no head axis and no values of their own,
     ``latent_row_dim`` entries a token together.  Two arrays and not one
     of their sum: 512 entries fill whole lanes of 128 and the chip keeps
@@ -1507,7 +1545,8 @@ def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype):
     if kind == LATENT_ATTENTION:
         return (jnp.zeros((*lead, cfg.latent_kv_rank), dtype),
                 jnp.zeros((*lead, cfg.qk_rope_head_dim), dtype))
-    shape = (*lead, cfg.num_kv_heads, cfg.head_dim)
+    shape = ((*lead, cfg.num_kv_heads * cfg.head_dim) if flat
+             else (*lead, cfg.num_kv_heads, cfg.head_dim))
     return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
@@ -1543,12 +1582,20 @@ def state_bytes_per_slot(cfg: GptConfig) -> int:
 
 def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
                  dtype=None, num_slots: int = 0):
-    """Per-layer (k, v) PAGED pool arrays [num_pages, page_size, H, D] —
+    """Per-layer (k, v) PAGED pool arrays [num_pages, page_size, G * D] —
     the serving tier's shared KV memory (:mod:`..serving.kv_pool` owns the
     page accounting).  Unlike :func:`init_kv_cache` there is no batch
     axis: every resident sequence draws pages from the same pool, so HBM
     is sized by total resident tokens, not num_slots × max_len.  Same
     dtype lever (``float8_e4m3fn`` halves cache bytes; upcast on read).
+
+    A token's row is held FLAT, its kv heads side by side, so that the
+    chip keeps the pool as the decode step's two-axis scatter
+    ``pool.at[page, offset]`` indexes it: a head axis of 30 it laid out
+    above the page's tokens, and copied every pool there and back on every
+    step (sixteen copies of 228 MB; PERF.md, PR 37).  The head axis exists
+    only on what is written; the decode step attends the gathered rows
+    flat as well (``GptBlock._attend_rows``).
 
     A linear-attention layer holds no pages: its entry is one fixed-size
     row per decode SLOT (``num_slots`` of them: state float32, convolution
@@ -1563,7 +1610,8 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
                          "whose layer_kinds has a linear_attention layer")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
     return [_state_entry(cfg, num_slots) if kind == LINEAR_ATTENTION
-            else _rows_entry(cfg, kind, (num_pages, page_size), dtype)
+            else _rows_entry(cfg, kind, (num_pages, page_size), dtype,
+                             flat=True)
             for kind in cfg.kinds]
 
 

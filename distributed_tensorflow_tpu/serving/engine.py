@@ -467,8 +467,7 @@ class DecodeEngine:
                 if kind == gpt_lib.LINEAR_ATTENTION:
                     return pool.at[slot].set(cache[0])
                 return pool.at[phys].set(
-                    cache[0].reshape(n_pages, page, *cache.shape[2:]),
-                    mode="drop")
+                    cache[0].reshape(n_pages, page, -1), mode="drop")
 
             # An entry is (keys, values), a latent layer's (latents,
             # rotated keys), or (state, convolution tail).

@@ -218,24 +218,13 @@ def test_sync_step_with_a_model_axis_keeps_the_dense_fallback(
 # ------------------------------------- a recurrent state beside the pages
 
 
-def hybrid_serving_programs(one_chip, buckets):
-    """One period (three linear-attention layers, one full) and the next
-    period's first layer at the published widths of
-    ``perfbench/configs/olmo-hybrid-7b.json``, as the engine compiles it: the decode step over 8 slots and a whole-bucket
-    prefill a page count, with the engine's own closures (their shapes
-    described, nothing placed)."""
+def serving_programs(one_chip, cfg, buckets, stateful):
+    """``cfg`` as the engine compiles it under ``longprompt_closed16``'s
+    settings: the decode step over 8 slots and a whole-bucket prefill a
+    page count, with the engine's own closures (their shapes described,
+    nothing placed)."""
     from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
                                                            EngineConfig)
-    kinds = (gpt_lib.LINEAR_ATTENTION,) * 3 + (
-        gpt_lib.FULL_ATTENTION, gpt_lib.LINEAR_ATTENTION)
-    cfg = gpt_lib.GptConfig(
-        vocab_size=100352, hidden_size=3840, num_layers=5, num_heads=30,
-        intermediate_size=11008, max_position=4096, dtype="bfloat16",
-        attention_backend="pallas", pos_encoding="none",
-        activation="swiglu", norm="rmsnorm", norm_placement="post",
-        qk_norm=True, layer_kinds=kinds, linear_num_heads=30,
-        linear_key_head_dim=96, linear_value_head_dim=192,
-        linear_allow_neg_eigval=True)
     model = gpt_lib.GptLM(cfg)
     econf = EngineConfig(num_slots=8, page_size=16, num_pages=1856,
                          max_pages_per_seq=232)
@@ -247,7 +236,7 @@ def hybrid_serving_programs(one_chip, buckets):
     engine = DecodeEngine.__new__(DecodeEngine)     # closures, no arrays
     engine._jax, engine._jnp, engine.model, engine.config = (
         jax, jnp, model, econf)
-    engine._stateful, engine._cache_dtype = True, None
+    engine._stateful, engine._cache_dtype = stateful, None
     engine._sparse_layers = 0
     engine._prefill_fns, engine._prefill_evictions = {}, 0
     tree = described(jax.eval_shape(lambda: model.init(
@@ -263,19 +252,82 @@ def hybrid_serving_programs(one_chip, buckets):
     step = engine._build_step().lower(
         tree, i32(B), i32(B), i32(B, MP), pools, f32(B), i32(B), f32(B),
         i32(B)).compile()
+    lane = (i32(), i32()) if stateful else ()       # slot, absorb
     prefills = {
         n: engine._prefill_fn(n).lower(
-            tree, i32(1, n * econf.page_size), pools, i32(n), i32(),
-            i32()).compile()
+            tree, i32(1, n * econf.page_size), pools, i32(n), *lane).compile()
         for n in buckets}
     return step, prefills, pools
+
+
+def hybrid_serving_programs(one_chip, buckets):
+    """One period (three linear-attention layers, one full) and the next
+    period's first layer at the published widths of
+    ``perfbench/configs/olmo-hybrid-7b.json``."""
+    kinds = (gpt_lib.LINEAR_ATTENTION,) * 3 + (
+        gpt_lib.FULL_ATTENTION, gpt_lib.LINEAR_ATTENTION)
+    cfg = gpt_lib.GptConfig(
+        vocab_size=100352, hidden_size=3840, num_layers=5, num_heads=30,
+        intermediate_size=11008, max_position=4096, dtype="bfloat16",
+        attention_backend="pallas", pos_encoding="none",
+        activation="swiglu", norm="rmsnorm", norm_placement="post",
+        qk_norm=True, layer_kinds=kinds, linear_num_heads=30,
+        linear_key_head_dim=96, linear_value_head_dim=192,
+        linear_allow_neg_eigval=True)
+    return serving_programs(one_chip, cfg, buckets, stateful=True)
+
+
+def relayouts(program, floor: int) -> list:
+    """The instructions of a compiled program's ENTRY computation that only
+    re-lay an array out (``copy``, a ``reshape`` that is no bitcast,
+    ``transpose``: each a pass of its own over its operand on the chip)
+    and whose result holds ``floor`` bytes or more."""
+    text = program.as_text()
+    found = []
+    for line in text[text.index("\nENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* "
+                     r"(copy|reshape|transpose)\(", line)
+        if m and math.prod(int(d) for d in m[2].split(",") if d) * _BYTES[
+                m[1]] >= floor:
+            found.append(line.strip()[:160])
+    return found
+
+
+def pools_donated_and_uncopied(programs, pools, n_leaves, temp_below):
+    """The engine donates its pools: every leaf comes out in the buffer it
+    went in by, and nothing else does; and no program re-lays out as many
+    bytes as a K/V pool holds, which is also what the decode step gathers
+    of one (8 lanes of 232 pages): the hybrid's sixteen pool copies a step
+    before PR 37, the two passes over the gathered rows that a head axis
+    split off them AFTER the gather costs instead, and the dense model's
+    heads-major copy of them."""
+    leaves = jax.tree.leaves(pools)
+    floor = min(x.size * x.dtype.itemsize for x in leaves
+                if x.shape[0] == 1856)
+    assert len(leaves) == n_leaves
+    # (Bytes as the chip lays an array out: the last axis in whole lanes
+    # of 128, which pads the state's keys of 96 and nothing else here.)
+    pool_bytes = sum(x.size // x.shape[-1] * -(-x.shape[-1] // 128) * 128
+                     * x.dtype.itemsize for x in leaves)
+    for program in programs:
+        mem = program.memory_analysis()
+        assert mem.temp_size_in_bytes < temp_below
+        assert mem.alias_size_in_bytes == pool_bytes
+        header = program.as_text().split("\n", 1)[0]   # input_output_alias
+        assert header.count("may-alias") + header.count(
+            "must-alias") == len(leaves)
+        assert relayouts(program, floor) == []
 
 
 def test_hybrid_serving_programs_compile_for_v5e(one_chip):
     """The chunked scan, its triangular solve and the one-token rule lower
     for the chip at 30 heads of 96 x 192, with the flash kernel in the full
     layer's prefill where the bucket's length lets it in: 1,024 tokens do,
-    1,600 do not (``_layout_ok``; D6's silent fallback)."""
+    1,600 do not (``_layout_ok``; D6's silent fallback).  The K/V pools'
+    row is flat, [1856, 16, 30 * 128], the chip keeps it as the step
+    indexes it and the step attends the gathered rows as they are: no
+    pool-sized relayout (four copies a full layer with a head axis of 30;
+    PR 37)."""
     step, prefills, pools = hybrid_serving_programs(one_chip, (64, 100))
     assert step.as_text().count("tpu_custom_call") == 0
     # (A full layer that is the model's LAST layer loses its call: its
@@ -283,22 +335,30 @@ def test_hybrid_serving_programs_compile_for_v5e(one_chip):
     # keeps its K/V and drops the rest.  Here a linear layer follows it.)
     assert prefills[64].as_text().count("tpu_custom_call") == 1
     assert prefills[100].as_text().count("tpu_custom_call") == 0
-    # The engine donates its pools: every K/V pool, and every
-    # linear-attention layer's state and convolution tail, comes out in
-    # the buffer it went in by, and nothing else does.
-    leaves = jax.tree.leaves(pools)
-    assert len(leaves) == 2 * 5
-    # (Bytes as the chip lays an array out: the last axis in whole lanes
-    # of 128, which pads the state's keys of 96 and nothing else here.)
-    pool_bytes = sum(x.size // x.shape[-1] * -(-x.shape[-1] // 128) * 128
-                     * x.dtype.itemsize for x in leaves)
-    for program in (step, *prefills.values()):
-        mem = program.memory_analysis()
-        assert mem.temp_size_in_bytes < 4e9
-        assert mem.alias_size_in_bytes == pool_bytes
-        header = program.as_text().split("\n", 1)[0]   # input_output_alias
-        assert header.count("may-alias") + header.count(
-            "must-alias") == len(leaves)
+    assert [x.shape for x in pools[3]] == [(1856, 16, 3840)] * 2
+    pools_donated_and_uncopied((step, *prefills.values()), pools,
+                               n_leaves=2 * 5, temp_below=4e9)
+
+
+def test_dense_serving_programs_keep_their_pools_for_v5e(one_chip):
+    """The same step and prefill at ``perfbench/configs/mistral-7b.json``'s
+    widths (8 K/V heads of 128, two layers): the flat row [1856, 16, 1024]
+    is scattered into in place as its four-axis form was, and attended
+    flat it loses that form's heads-major copy of the gathered rows (two a
+    layer), so a later pool shape or gather cannot bring a relayout to
+    either configuration without a red test."""
+    cfg = gpt_lib.GptConfig(
+        vocab_size=32000, hidden_size=4096, num_layers=2, num_heads=32,
+        kv_heads=8, intermediate_size=14336, max_position=4096,
+        dtype="bfloat16", attention_backend="pallas", pos_encoding="rope",
+        activation="swiglu", norm="rmsnorm")
+    step, prefills, pools = serving_programs(one_chip, cfg, (64,),
+                                             stateful=False)
+    assert [x.shape for x in jax.tree.leaves(pools)] == [(1856, 16, 1024)] * 4
+    # The flash kernel in both layers' prefill but the last's (above).
+    assert prefills[64].as_text().count("tpu_custom_call") == 1
+    pools_donated_and_uncopied((step, *prefills.values()), pools,
+                               n_leaves=2 * 2, temp_below=2e9)
 
 
 # ------------------------ one latent row a token, and routed experts

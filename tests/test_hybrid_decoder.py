@@ -293,23 +293,27 @@ def test_what_the_state_needs_is_asked_for_by_name(call, names,
 #: ``step`` texts were renewed once more when the sampler inside them
 #: stopped gathering the vocabulary and went under a ``cond``
 #: (``sample_logits_dynamic``; ``tests/test_sampler.py`` holds its tokens to
-#: the old body's); ``decode_paged``, the step without its sampler, is
-#: still that parent's.
+#: the old body's).  Every program that takes a pool was renewed in PR 37,
+#: which holds a K/V pool's row flat ([pages, page, G * D]: the pool
+#: arguments, the scatter's update and the gathered rows' reshape change
+#: and nothing else; ``tests/test_serving.py`` holds logits and pool
+#: contents to the contiguous-cache path bit for bit); ``tree`` and
+#: ``call`` are still that parent's.
 DENSE_GOLDEN = {
     "gpt2": {"tree": "aaa1a7d60ae885e3d2d4d073dadd6d98",
-             "step": "d34f787364257851d460ba094db7e009",
-             "prefill": "30a7566c149cd53e6ccca433552da62b",
-             "step_undonated": "a66dab1e40fd658c4668799419f2f837",
-             "prefill_undonated": "ce244c8e84b7d40fe1f490845b913143",
+             "step": "a6d23b5975a3ff3973d31b9ebd5fea95",
+             "prefill": "b321613069b738430f2f6b72023a2735",
+             "step_undonated": "d440c06743f0e3649e5e42f810720778",
+             "prefill_undonated": "c249197197803e701f8fe9976bd6bce5",
              "call": "7137ce905cc4f0b2dfb44c057f4e4108",
-             "decode_paged": "399c8cdb9ccf0ce1b485582897135734"},
+             "decode_paged": "c767fea6233fb8a37c5dc64bc36f2e59"},
     "mistral": {"tree": "bda3e6337e210318d71872269ca97b04",
-                "step": "756b6892c1115b57c2088c113233cbee",
-                "prefill": "c4242292c611c52c864f3b04435de2ea",
-                "step_undonated": "a2308abef8956a99a3f0247a5bf076a5",
-                "prefill_undonated": "e4d74639b4bcc9278ba3266c747cc11e",
+                "step": "fbc7a70a8f556d530b4cd245aff4897d",
+                "prefill": "8d0f30cdf494a28a79ecda8eb8b6b474",
+                "step_undonated": "846c642a289a463747cea459fb691e8a",
+                "prefill_undonated": "4bb06b654ff1d7d641cc0982100989d4",
                 "call": "9bf6ceb33b379ccf6fc36228f229c7ae",
-                "decode_paged": "48fcd421dff91b33398df5e638887059"},
+                "decode_paged": "aea24ead126ac52e7080c6d061988f63"},
 }
 DENSE = {"gpt2": {}, "mistral": dict(pos_encoding="rope", kv_heads=2,
                                      activation="swiglu", norm="rmsnorm")}

@@ -22,6 +22,7 @@ Tolerances, with their reasons:
 """
 
 import dataclasses
+import hashlib
 import os
 
 import jax
@@ -381,7 +382,8 @@ def test_config_is_validated_like_layer_kinds(fields, message,
 
 def test_default_config_keeps_its_tree_and_its_kinds():
     """``GptConfig()`` names no latent rank and no expert: the parent's
-    leaves, the parent's kinds, the parent's (keys, values) pool entries."""
+    leaves, the parent's kinds, (keys, values) pool entries of a flat row
+    (4 heads of 32 side by side; PR 37) and the parent's bytes a token."""
     cfg = gpt_lib.GptConfig()
     assert cfg.kinds == ("full_attention",) * 4
     assert cfg.sparse_layers == (False,) * 4 and cfg.rope_base == 10000.0
@@ -393,5 +395,44 @@ def test_default_config_keeps_its_tree_and_its_kinds():
                             "lm_head", "ln_final", "pos_emb", "word_emb"]
     pools = gpt_lib.init_kv_pool(cfg, 4, 8)
     assert [tuple(x.shape for x in e) for e in pools] == [
-        ((4, 8, 4, 32), (4, 8, 4, 32))] * 4
+        ((4, 8, 128), (4, 8, 128))] * 4
     assert gpt_lib.kv_row_bytes_per_token(cfg) == 4 * 2 * 4 * 32 * 2
+
+
+#: md5 of the engine's lowered decode step and whole-bucket prefill at the
+#: rehearsal size as the configuration's file gives it (bfloat16), taken ON
+#: THE PARENT of PR 37 (commit 6906e1f) from a ``git archive`` of it by this
+#: function.  That PR holds a K/V pool's row flat and leaves the latent
+#: branch of ``_rows_entry`` alone: this cell's programs are its control.
+#: They hold for this sandbox's jax.
+LATENT_GOLDEN = {
+    "": ("07abcc0ac96000145ee3f272ab63434f",
+         "52ef68edfeb167989f1c0bec73b4d225"),
+    "float8": ("883f74dcf508cb7ca68e298119b91a52",
+               "5e22a5286132f9e503e71766de912d7b"),
+}
+
+
+@pytest.mark.parametrize("kv_dtype", sorted(LATENT_GOLDEN),
+                         ids=["bfloat16", "float8"])
+def test_the_latent_cells_programs_are_the_parents(kv_dtype):
+    config = spec.load_json(CONFIG)
+    config = spec.deep_update(config, config["rehearsal"])
+    model = gpt_lib.GptLM(worker.gpt_config(
+        {"config": config, "config_file": CONFIG}))
+    params = jax.tree.map(
+        lambda x: jnp.zeros(x.shape, x.dtype), jax.eval_shape(
+            lambda: model.init(jax.random.PRNGKey(0),
+                               jnp.zeros((1, 8), jnp.int32))["params"]))
+    eng = DecodeEngine(model, params, EngineConfig(
+        num_slots=2, page_size=8, num_pages=16, max_pages_per_seq=4,
+        kv_dtype=kv_dtype))
+    i32 = lambda *s: jnp.zeros(s, jnp.int32)  # noqa: E731
+    f32 = lambda *s: jnp.zeros(s, jnp.float32)  # noqa: E731
+    step = eng._step_fn.lower(
+        eng._tree, i32(2), i32(2), i32(2, 4), eng.pools, f32(2), i32(2),
+        f32(2), i32(2))
+    prefill = eng._prefill_fn(2).lower(eng._tree, i32(1, 16), eng.pools,
+                                       i32(2))
+    assert tuple(hashlib.md5(x.as_text().encode()).hexdigest()
+                 for x in (step, prefill)) == LATENT_GOLDEN[kv_dtype]

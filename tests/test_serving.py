@@ -638,9 +638,9 @@ def test_chunk_paged_matches_step_paged_sequence(model_and_params):
         new = []
         for (kc, vc), (kp, vp) in zip(caches, pools):
             kp = kp.at[jnp.asarray([0, 1])].set(
-                kc[0].reshape(2, 4, *kc.shape[2:]))
+                kc[0].reshape(2, 4, -1))
             vp = vp.at[jnp.asarray([0, 1])].set(
-                vc[0].reshape(2, 4, *vc.shape[2:]))
+                vc[0].reshape(2, 4, -1))
             new.append((kp, vp))
         return new
 
@@ -685,6 +685,129 @@ def test_chunk_paged_oob_drafts_never_touch_real_pages(model_and_params):
         assert not np.array_equal(ka[1, 2:], kb[1, 2:]) or ka[1, 2:].any()
         np.testing.assert_array_equal(ka[0], kb[0])
         np.testing.assert_array_equal(ka[2:], kb[2:])
+
+
+# ------------------------------------------- the pool's row held flat
+
+_PAGES, _PAGE, _MP = 9, 4, 3     # the pool; a lane's table: 12 slots
+_TABLES = np.asarray([[2, 5, _PAGES], [0, 3, 7], [_PAGES] * _MP], np.int32)
+_POSITIONS = np.asarray([6, 7, 0], np.int32)     # lane 2 idle
+
+
+def _flat_row_case(heads, kv_heads, kv_dtype):
+    """A two-layer model, contiguous caches [3, 12, G, D] of junk in the
+    pool's dtype, and junk pools that hold each live lane's allocated
+    pages of the same rows, built in the [.., G, D] view and handed over
+    as :func:`init_kv_pool` shapes them."""
+    cfg = small_cfg(hidden_size=8 * heads, num_heads=heads,
+                    kv_heads=kv_heads)
+    model = gpt_lib.GptLM(cfg)
+    params = model.init(jax.random.PRNGKey(1),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    rng = np.random.default_rng([heads, kv_heads])
+    caches, pools, views = [], [], []
+    for entry in gpt_lib.init_kv_pool(cfg, _PAGES, _PAGE, dtype=kv_dtype):
+        cache_pair, pool_pair, view_pair = [], [], []
+        for leaf in entry:
+            assert leaf.shape == (_PAGES, _PAGE, kv_heads * 8)
+            assert leaf.dtype == jnp.dtype(kv_dtype)
+            cache = rng.normal(size=(3, _MP * _PAGE, kv_heads, 8))
+            view = rng.normal(size=(_PAGES, _PAGE, kv_heads, 8))
+            for lane, table in enumerate(_TABLES):
+                for i, page in enumerate(table):
+                    if page < _PAGES:
+                        view[page] = cache[lane, i * _PAGE:(i + 1) * _PAGE]
+            cache_pair.append(jnp.asarray(cache, kv_dtype))
+            view_pair.append(np.asarray(jnp.asarray(view, kv_dtype)))
+            pool_pair.append(jnp.asarray(view, kv_dtype).reshape(leaf.shape))
+        caches.append(tuple(cache_pair))
+        pools.append(tuple(pool_pair))
+        views.append(view_pair)
+    return cfg, model, params, caches, pools, views
+
+
+def _expect_pools(views, caches_after, pools_after, written):
+    """Every pool leaf, viewed [.., G, D], is what it was, except that the
+    live lanes' slots ``written`` (lane -> logical positions) hold what
+    the contiguous path wrote there: bit for bit, idle lane included."""
+    for view_pair, cache_pair, pool_pair in zip(views, caches_after,
+                                                pools_after):
+        for view, cache, pool in zip(view_pair, cache_pair, pool_pair):
+            want = view.copy()
+            for lane, slots in written.items():
+                for s in slots:
+                    want[_TABLES[lane, s // _PAGE], s % _PAGE] = np.asarray(
+                        cache)[lane, s]
+            assert pool.dtype == cache.dtype
+            np.testing.assert_array_equal(
+                np.asarray(pool).reshape(want.shape).view(np.uint8),
+                want.view(np.uint8))
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "float8_e4m3fn"])
+@pytest.mark.parametrize("heads,kv_heads", [(8, 8), (6, 3)],
+                         ids=["kv8", "kv3"])
+@pytest.mark.parametrize("program", ["step", "chunk", "landing"])
+def test_flat_pool_row_is_the_contiguous_cache(
+        program, heads, kv_heads, kv_dtype):
+    """PR 37 holds a K/V pool's row flat, [pages, page, G * D]: the paged
+    decode step, the paged chunk and the prefill's landing give the pool
+    contents (viewed [.., G, D]) of the contiguous-cache path bit for bit
+    and its logits (the chunk's bit for bit, the step's to float32
+    rounding), whether G is a multiple of 8 or not and whatever the pool's
+    dtype; an idle lane writes nowhere; a row's bytes are what they
+    were."""
+    cfg, model, params, caches, pools, views = _flat_row_case(
+        heads, kv_heads, kv_dtype)
+    itemsize = jnp.dtype(kv_dtype).itemsize
+    assert gpt_lib.kv_row_bytes_per_token(cfg, kv_dtype) == (
+        2 * 2 * kv_heads * 8 * itemsize) == sum(
+            x.nbytes for x in jax.tree.leaves(pools)) // (_PAGES * _PAGE)
+    tables, positions = jnp.asarray(_TABLES), jnp.asarray(_POSITIONS)
+    apply = lambda method, *a: jax.jit(  # noqa: E731
+        lambda *a: model.apply({"params": params}, *a, method=method))(*a)
+    if program == "step":
+        token = jnp.asarray([3, 9, 0], jnp.int32)
+        want, caches_after = apply(gpt_lib.GptLM.decode_ragged, token,
+                                   caches, positions)
+        got, pools_after = apply(gpt_lib.GptLM.decode_paged, token, pools,
+                                 tables, positions)
+        written = {0: [6], 1: [7]}
+    elif program == "chunk":
+        # Lane 0's chunk runs past its two pages: slots 8, 9 drop.
+        chunk = jnp.asarray([[3, 9, 4, 1], [7, 7, 2, 5], [0] * 4], jnp.int32)
+        want, caches_after = apply(gpt_lib.GptLM.decode_chunk, chunk,
+                                   caches, positions)
+        got, pools_after = apply(gpt_lib.GptLM.decode_chunk_paged, chunk,
+                                 pools, tables, positions)
+        written = {0: [6, 7], 1: [7, 8, 9, 10]}
+    else:
+        engine = DecodeEngine(model, params, EngineConfig(
+            num_slots=3, page_size=_PAGE, num_pages=_PAGES,
+            max_pages_per_seq=_MP,
+            kv_dtype={"float8_e4m3fn": "float8"}.get(kv_dtype, kv_dtype)))
+        assert [x.shape for x in jax.tree.leaves(engine.pools)] == [
+            x.shape for x in jax.tree.leaves(pools)]
+        toks = jnp.asarray(np.random.default_rng(5).integers(
+            0, cfg.vocab_size, (1, _MP * _PAGE)), jnp.int32)
+        want, caches_after = None, apply(
+            gpt_lib.GptLM.prefill, toks, gpt_lib.init_kv_cache(
+                cfg, 1, _MP * _PAGE, dtype=kv_dtype))[1]
+        # The bucket's last page is beyond the lane's allocation: dropped.
+        pools_after = engine._prefill_fn(_MP)(
+            engine._tree, toks, pools, tables[0])
+        written = {0: range(2 * _PAGE)}
+    _expect_pools(views, caches_after, pools_after, written)
+    if want is not None:
+        # The chunk attends through ``_attend_cache_chunk`` like the
+        # contiguous path: equal.  The step attends the FLAT rows
+        # (``_attend_rows``): the same float32 sums with zeros among them,
+        # in another order: a few units in the last place of logits of
+        # size 1-3 (sound readings here 1e-6; a wrong row reads 1e-1).
+        got, want = np.asarray(got[:2]), np.asarray(want[:2])
+        assert np.isfinite(got).all() and np.abs(want).max() > 1
+        np.testing.assert_allclose(
+            got, want, rtol=0, atol=0 if program == "chunk" else 5e-6)
 
 
 def test_engine_spec_parity_and_multi_token_rounds(model_and_params):
