@@ -81,10 +81,12 @@ class GptConfig:
     # cleanly (flax dot_general injection; ops/quant_train.py
     # int8_dot_general).  Same parameter tree.
     attn_int8: bool = False
-    # Where a sublayer's norm sits: "pre" (on its input, the default) or
+    # Where a sublayer's norm sits: "pre" (on its input, the default),
     # "post" (on its OUTPUT, before the residual add: x + norm(f(x)), the
     # Olmo family's reordered norm; the residual stream itself is never
-    # normed before the final norm).
+    # normed before the final norm) or "sandwich" (one on its input AND one
+    # on its output, x + norm(f(norm(x))): four norms a block, the output
+    # ones named ``ln_attn_post`` / ``ln_mlp_post``).
     norm_placement: str = "pre"
     # RMSNorm over the whole projected q and over the whole projected k
     # (all heads together) in the softmax-attention layers.
@@ -140,6 +142,22 @@ class GptConfig:
     num_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     first_dense_layers: int = 0
+    # A weight-shared loop: the stack of ``num_layers`` blocks is applied
+    # ``loop_steps`` times over the SAME weights, the final norm after
+    # every application (the normed stream is what the next one starts
+    # from, and what the head reads after the last).  Each application
+    # attends its OWN keys and values, so a cached token holds
+    # ``loop_steps * num_layers`` rows: a layer's cache entry gains a
+    # leading axis of ``loop_steps`` (:func:`init_kv_cache`) and its pool
+    # holds ``loop_steps`` runs of pages (:func:`init_kv_pool`).  Only
+    # GptLM.__call__, .prefill and .decode_paged walk the loop; every
+    # other cache path refuses such a config by name.
+    loop_steps: int = 1
+    # With ``loop_steps`` > 1: a Dense(1) over the normed stream after
+    # every application, lambda_t = sigmoid(.), from which the mass that
+    # leaves at each step is sown (``loop/exit_mass``, :func:`exit_masses`).
+    # Nothing here acts on it: every token runs every step.
+    exit_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -180,9 +198,10 @@ class GptConfig:
 
     def refuse_state_layers(self, path: str) -> None:
         """Called first by every cache path that holds per-head K and V
-        rows around a dense MLP and nothing else: no place for a
-        linear-attention layer's recurrent state, for a latent row, nor for
-        a routed-expert MLP's histogram and idle lanes."""
+        rows around a dense MLP and walks the stack once: no place for a
+        linear-attention layer's recurrent state, for a latent row, for a
+        routed-expert MLP's histogram and idle lanes, nor for the rows of
+        a weight-shared loop's further steps."""
         if self.has_state_layers:
             raise ValueError(
                 f"{path} does not carry a linear-attention layer's "
@@ -198,15 +217,32 @@ class GptConfig:
                     f"{path} does not carry {what} and GptConfig.{field} is "
                     f"{getattr(self, field)}; the paths that do are "
                     "GptLM.__call__, GptLM.prefill and GptLM.decode_paged")
+        if self.loop_steps > 1:
+            raise ValueError(
+                f"{path} does not walk a weight-shared loop (a cache row a "
+                f"loop step a layer) and GptConfig.loop_steps is "
+                f"{self.loop_steps}; the paths that do are GptLM.__call__, "
+                "GptLM.prefill and GptLM.decode_paged")
 
     def __post_init__(self):
         if self.pos_encoding not in ("learned", "rope", "none"):
             raise ValueError(f"Unknown pos_encoding {self.pos_encoding!r}; "
                              "one of ('learned', 'rope', 'none')")
-        if self.norm_placement not in ("pre", "post"):
+        if self.norm_placement not in ("pre", "post", "sandwich"):
             raise ValueError(f"Unknown norm_placement "
                              f"{self.norm_placement!r}; one of "
-                             "('pre', 'post')")
+                             "('pre', 'post', 'sandwich')")
+        if self.loop_steps < 1 or (self.exit_gate and self.loop_steps < 2):
+            raise ValueError(
+                f"loop_steps must be >= 1 (got {self.loop_steps}), and "
+                "exit_gate needs a loop to leave: loop_steps >= 2")
+        if self.loop_steps > 1 and (self.layer_kinds or self.latent_kv_rank
+                                    or self.num_experts
+                                    or self.attention_window):
+            raise ValueError(
+                "loop_steps > 1 walks full-attention layers around dense "
+                "MLPs: it composes with none of layer_kinds, "
+                "latent_kv_rank, num_experts and attention_window")
         if self.layer_kinds:
             bad = sorted(set(self.layer_kinds)
                          - {FULL_ATTENTION, LINEAR_ATTENTION})
@@ -314,6 +350,8 @@ def infer_arch_from_layer0(layer0: dict) -> dict:
     }
     if "kv_proj" in layer0:
         arch["kv_heads"] = int(layer0["kv_proj"]["kernel"].shape[-2])
+    if "ln_attn_post" in layer0:
+        arch["norm_placement"] = "sandwich"
     return arch
 
 
@@ -387,6 +425,9 @@ class GptBlock(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         self.ln_attn = _layer_norm(cfg)
         self.ln_mlp = _layer_norm(cfg)
+        if cfg.norm_placement == "sandwich":
+            self.ln_attn_post = _layer_norm(cfg)
+            self.ln_mlp_post = _layer_norm(cfg)
         if self.sparse:
             self._setup_experts(dtype)
         else:
@@ -525,6 +566,8 @@ class GptBlock(nn.Module):
         """The residual add of the token mixer's output ``y``."""
         if self.cfg.norm_placement == "post":
             y = self.ln_attn(y).astype(x.dtype)
+        elif self.cfg.norm_placement == "sandwich":
+            y = self.ln_attn_post(y).astype(x.dtype)
         return x + self.drop(y, deterministic=deterministic)
 
     def _qkv(self, x: jax.Array, positions: jax.Array | None = None):
@@ -635,6 +678,8 @@ class GptBlock(nn.Module):
         h = self.mlp_out(h)
         if post:
             h = self.ln_mlp(h).astype(x.dtype)
+        elif cfg.norm_placement == "sandwich":
+            h = self.ln_mlp_post(h).astype(x.dtype)
         return x + self.drop(h, deterministic=deterministic)
 
     def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
@@ -1302,6 +1347,9 @@ class GptLM(nn.Module):
                            zip(cfg.kinds, cfg.sparse_layers))]
         self.ln_final = _layer_norm(cfg)
         self.lm_head = nn.Dense(cfg.vocab_size)
+        if cfg.exit_gate:
+            # Applied in float32 whatever type its kernel is stored in.
+            self.exit_gate = nn.Dense(1, dtype=jnp.float32)
 
     def _embed(self, input_ids: jax.Array, positions: jax.Array,
                deterministic: bool) -> jax.Array:
@@ -1314,10 +1362,58 @@ class GptLM(nn.Module):
     def _head(self, x: jax.Array) -> jax.Array:
         return self.lm_head(self.ln_final(x))
 
+    def _loop(self, stack, x: jax.Array, carry=None, rows=None):
+        """The stack ``loop_steps`` times over the same weights (``cfg.
+        loop_steps`` > 1): ``stack(mdl, x, carry, t, rows_t) -> (x, carry,
+        rows_t)`` is one application, step ``t`` of the loop; the final
+        norm follows each, and what it gives is what the next application
+        starts from.  ``carry`` is handed from step to step whole (the
+        paged pools, which a step addresses by ``t``); ``rows`` is sliced
+        along its leading axis, a slice a step, and comes back stacked the
+        same way (contiguous caches).  Returns the normed stream after the
+        last step (the HEAD's input: no further norm), the carry and the
+        rows.
+
+        One ``scan`` and not ``loop_steps`` copies of the stack: the
+        compiled program holds ``num_layers`` layer applications whatever
+        the loop count.  Sows ``loop/steps_run`` (per sequence, the steps
+        it ran: all of them) and ``loop/exit_mass`` (:func:`exit_masses`)
+        for whoever applies the model with ``loop`` mutable."""
+        cfg = self.cfg
+
+        def step(mdl, state, step_in):
+            x, carry = state
+            t, rows_t = step_in
+            with jax.named_scope("loop.step"):
+                x, carry, rows_t = stack(mdl, x, carry, t, rows_t)
+                x = mdl.ln_final(x).astype(x.dtype)
+            gate = None
+            if cfg.exit_gate:
+                with jax.named_scope("loop.exit_gate"):
+                    gate = mdl.exit_gate(x)[..., 0]
+            return (x, carry), (rows_t, gate)
+
+        (x, carry), (rows, gates) = nn.scan(
+            step, variable_broadcast="params",
+            split_rngs={"params": False, "dropout": True},
+            length=cfg.loop_steps)(
+                self, (x, carry), (jnp.arange(cfg.loop_steps), rows))
+        self.sow("loop", "steps_run", jnp.full(
+            x.shape[:1], cfg.loop_steps, jnp.int32))
+        self.sow("loop", "exit_mass", exit_masses(
+            gates, cfg.loop_steps, x.shape[:-1]))
+        return x, carry, rows
+
     def __call__(self, input_ids: jax.Array,
                  deterministic: bool = True) -> jax.Array:
         S = input_ids.shape[1]
         x = self._embed(input_ids, jnp.arange(S)[None, :], deterministic)
+        if self.cfg.loop_steps > 1:
+            def stack(mdl, x, carry, t, rows):
+                for layer in mdl.layers:
+                    x = layer(x, deterministic)
+                return x, carry, rows
+            return self.lm_head(self._loop(stack, x)[0])
         for layer in self.layers:
             x = layer(x, deterministic)
         return self._head(x)  # [B, S, vocab]
@@ -1436,14 +1532,30 @@ class GptLM(nn.Module):
         entry bit for bit (see :func:`init_kv_pool`).  A latent-attention
         layer's entry is its two pools of row parts; a routed-expert MLP
         routes a row that ``live`` says is no sequence nowhere (without
-        ``live`` every row is routed).  Returns (logits [B, vocab], new
-        pools)."""
+        ``live`` every row is routed).  With ``cfg.loop_steps`` > 1 the
+        stack is walked that many times (:meth:`_loop`), step ``t`` writing
+        and attending its own run of pages of each layer's pool.  Returns
+        (logits [B, vocab], new pools)."""
         if self.cfg.has_state_layers and live is None:
             raise ValueError(
                 "GptLM.decode_paged needs live= [B] for a config whose "
                 "layer_kinds has a linear_attention layer: a recurrent "
                 "state has no sentinel page to drop an idle row's write")
         x = self._embed(token[:, None], positions[:, None], True)
+        if self.cfg.loop_steps > 1:
+            def stack(mdl, x, pools, t, rows):
+                # Step t's rows lie in its own run of pages of each pool.
+                tables = loop_step_pages(page_tables, t,
+                                         pools[0][0].shape[0],
+                                         mdl.cfg.loop_steps)
+                new_pools = []
+                for layer, entry in zip(mdl.layers, pools):
+                    x, *entry = layer.decode_step_paged(x, *entry, tables,
+                                                        positions)
+                    new_pools.append(tuple(entry))
+                return x, new_pools, rows
+            x, new_pools, _ = self._loop(stack, x, list(pools))
+            return self.lm_head(x)[:, 0], new_pools
         new_pools = []
         for layer, entry in zip(self.layers, pools):
             if layer.kind == LINEAR_ATTENTION:
@@ -1476,7 +1588,9 @@ class GptLM(nn.Module):
         past ``lengths[b]`` before reading it (the paged engine's
         contract), and the returned logits are the last PADDED position's.
         A latent-attention layer writes every position's row and takes no
-        ``lengths``."""
+        ``lengths``.  With ``cfg.loop_steps`` > 1 a layer's entry has a
+        leading axis of that length, a slice a loop step
+        (:func:`init_kv_cache`)."""
         B, P = tokens.shape
         if lengths is not None and self.cfg.latent_kv_rank:
             raise ValueError(
@@ -1491,6 +1605,15 @@ class GptLM(nn.Module):
                 "GptLM.prefill needs lengths= [B] for a config whose "
                 "layer_kinds has a linear_attention layer: padding must "
                 "not enter a recurrent state")
+        if self.cfg.loop_steps > 1:
+            def stack(mdl, x, carry, t, caches):
+                new_caches = []
+                for layer, entry in zip(mdl.layers, caches):
+                    x, *entry = layer.prefill(x, *entry, lengths)
+                    new_caches.append(tuple(entry))
+                return x, carry, new_caches
+            x, _, new_caches = self._loop(stack, x, rows=list(caches))
+            return self.lm_head(x[:, -1:])[:, 0], new_caches
         for layer, entry in zip(self.layers, caches):
             if layer.kind == LINEAR_ATTENTION:
                 x, *entry = layer.linear_prefill(x, *entry, lengths)
@@ -1505,9 +1628,39 @@ class GptLM(nn.Module):
         return self._head(x[:, -1:])[:, 0], new_caches
 
 
+def exit_masses(gates: jax.Array | None, steps: int,
+                shape: tuple) -> jax.Array:
+    """The mass that leaves a weight-shared loop at each of its ``steps``
+    steps, [steps, *shape] float32, from the exit gate's logits ``gates``
+    of the same shape: lambda_t = sigmoid(gate_t), p_t = lambda_t x the
+    product of (1 - lambda_j) over the steps before, and the LAST step
+    takes what is left (its own gate decides nothing).  Without a gate all
+    of it leaves at the last step."""
+    if gates is None:
+        return jnp.zeros((steps, *shape), jnp.float32).at[-1].set(1.0)
+    lam = jax.nn.sigmoid(gates.astype(jnp.float32))[:-1]
+    stay = jnp.cumprod(1.0 - lam, axis=0)
+    before = jnp.concatenate([jnp.ones_like(stay[:1]), stay[:-1]])
+    return jnp.concatenate([lam * before, stay[-1:]])
+
+
+def loop_step_pages(pages: jax.Array, step, rows: int,
+                    steps: int) -> jax.Array:
+    """Where loop step ``step``'s rows of ``pages`` lie in a pool of
+    ``rows`` = ``steps`` x num_pages pages (:func:`init_kv_pool`): step t
+    holds the run ``[t x num_pages, (t + 1) x num_pages)``.  The
+    not-allocated sentinel (``num_pages``, one past a step's run) becomes
+    ``rows``, one past the pool, so that a write through it still drops
+    and a gather still fills."""
+    num_pages = rows // steps
+    return jnp.where(pages < num_pages, pages + step * num_pages, rows)
+
+
 def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
                   dtype=None):
-    """Per-layer (k, v) cache arrays [B, max_len, H, D].
+    """Per-layer (k, v) cache arrays [B, max_len, H, D]; with
+    ``cfg.loop_steps`` > 1 [loop_steps, B, max_len, H, D], a loop step's
+    own keys and values a slice of the leading axis.
 
     ``dtype`` overrides the compute dtype — ``float8_e4m3fn`` halves the
     cache's HBM bytes vs bf16 (the long-context decode-bandwidth lever;
@@ -1524,8 +1677,10 @@ def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
     if cfg.attention_window:
         max_len = min(max_len, cfg.attention_window)
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
+    lead = (batch_size, max_len) if cfg.loop_steps == 1 \
+        else (cfg.loop_steps, batch_size, max_len)
     return [_state_entry(cfg, batch_size) if kind == LINEAR_ATTENTION
-            else _rows_entry(cfg, kind, (batch_size, max_len), dtype)
+            else _rows_entry(cfg, kind, lead, dtype)
             for kind in cfg.kinds]
 
 
@@ -1552,12 +1707,14 @@ def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype,
 
 def kv_row_bytes_per_token(cfg: GptConfig, dtype=None) -> int:
     """Bytes ONE cached token holds over all layers' pages (a
-    linear-attention layer holds none)."""
+    linear-attention layer holds none; a weight-shared loop holds a row a
+    step a layer)."""
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
-    return sum(x.size * x.dtype.itemsize
-               for kind in cfg.kinds if kind != LINEAR_ATTENTION
-               for x in jax.eval_shape(
-                   lambda k=kind: _rows_entry(cfg, k, (1,), dtype)))
+    return cfg.loop_steps * sum(
+        x.size * x.dtype.itemsize
+        for kind in cfg.kinds if kind != LINEAR_ATTENTION
+        for x in jax.eval_shape(
+            lambda k=kind: _rows_entry(cfg, k, (1,), dtype)))
 
 
 def _state_entry(cfg: GptConfig, rows: int):
@@ -1601,7 +1758,15 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
     row per decode SLOT (``num_slots`` of them: state float32, convolution
     tail), whatever the sequence's length.  A latent-attention layer's entry
     is its row's two parts, [num_pages, page_size, latent_kv_rank] and
-    [num_pages, page_size, qk_rope_head_dim] (:func:`_rows_entry`)."""
+    [num_pages, page_size, qk_rope_head_dim] (:func:`_rows_entry`).
+
+    With ``cfg.loop_steps`` > 1 a layer's pool holds ``loop_steps`` runs of
+    ``num_pages`` pages, [loop_steps * num_pages, page_size, G * D]: page
+    ``p`` of loop step ``t`` is row ``t * num_pages + p``
+    (:func:`loop_step_pages`).  One array a layer and not one a step: the
+    scatter and the gather address a step's run by an offset, the same two
+    operations as without a loop, and the compiled step carries
+    ``num_layers`` pairs of buffers through its loop in place."""
     if cfg.attention_window:
         raise ValueError("paged KV pools need full-cache addressing; "
                          "sliding-window checkpoints are not pageable")
@@ -1610,8 +1775,8 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
                          "whose layer_kinds has a linear_attention layer")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
     return [_state_entry(cfg, num_slots) if kind == LINEAR_ATTENTION
-            else _rows_entry(cfg, kind, (num_pages, page_size), dtype,
-                             flat=True)
+            else _rows_entry(cfg, kind, (cfg.loop_steps * num_pages,
+                                         page_size), dtype, flat=True)
             for kind in cfg.kinds]
 
 

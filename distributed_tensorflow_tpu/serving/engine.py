@@ -236,9 +236,15 @@ class DecodeEngine:
         # Layers whose MLP is routed experts: the step hands their routing
         # histogram [sparse layers, experts] back with its tokens.
         self._sparse_layers = sum(mcfg.sparse_layers)
+        # Times the stack is applied to a token over the same weights
+        # (GptConfig.loop_steps): a cached token holds that many rows a
+        # layer, and the step hands back, behind its tokens, how many
+        # steps each lane ran and where its exit gate expects it to leave.
+        self._loop_steps = mcfg.loop_steps
         if cfg.spec_k or cfg.prefill_chunk:
             # Neither carries a recurrent state, a latent row or a
-            # routed-expert MLP; a no-op for any other model.
+            # routed-expert MLP, nor walks a weight-shared loop; a no-op
+            # for any other model.
             on = "spec_k" if cfg.spec_k else "prefill_chunk"
             mcfg.refuse_state_layers(f"DecodeEngine with EngineConfig.{on}")
         self._cache_dtype = resolve_kv_dtype(cfg.kv_dtype)
@@ -287,6 +293,9 @@ class DecodeEngine:
         # Running sums of the steps' routing counters (_routing_counters).
         self.moe = dict.fromkeys(("experts_touched", "expert_slots",
                                   "expert_tokens_max", "routed_tokens"), 0)
+        # Running sums of the steps' loop counters (_loop_counters).
+        self.loop = dict.fromkeys(("loop_steps_run", "loop_tokens",
+                                   "exit_step_expected_milli"), 0)
         self._step_fn = self._build_step()
         self._spec_step_fn = (self._build_spec_step()
                               if cfg.spec_k else None)
@@ -382,13 +391,15 @@ class DecodeEngine:
             params = self._dequant(tree)
             # An idle lane's table is all sentinel: its page writes drop
             # by themselves, its recurrent state has to be told.
+            looped = model.cfg.loop_steps > 1
             live = ((tables[:, 0] < self.config.num_pages),) \
-                if self._stateful or self._sparse_layers else ()
-            sown = {"mutable": ["routing"]} if self._sparse_layers else {}
+                if self._stateful or self._sparse_layers or looped else ()
+            sown = {"mutable": ["routing"]} if self._sparse_layers else \
+                {"mutable": ["loop"]} if looped else {}
             out = model.apply(
                 {"params": params}, tokens, pools, tables, positions, *live,
                 method=gpt_lib.GptLM.decode_paged, **sown)
-            (logits, pools), routing = out if sown else (out, None)
+            (logits, pools), aux = out if sown else (out, None)
             # Per-row keys folded on the ABSOLUTE index being generated:
             # a sampled stream is reproducible for its (seed, position)s
             # no matter which other requests shared the batch.
@@ -396,13 +407,25 @@ class DecodeEngine:
                 lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
                     seeds, positions + 1)
             nxt = gpt_lib.sample_logits_dynamic(logits, keys, temp, tk, tp)
-            if routing is not None:
+            if self._sparse_layers:
                 # The histogram rides behind the tokens in the one array
                 # the host fetches anyway: no second copy to wait for.
-                counts = [routing["routing"][f"layer{i}"]["counts"][0]
+                counts = [aux["routing"][f"layer{i}"]["counts"][0]
                           for i, sparse in enumerate(model.cfg.sparse_layers)
                           if sparse]
                 nxt = jnp.concatenate([nxt, *counts])
+            if looped:
+                # Two numbers a lane behind the tokens, the same way: the
+                # loop steps it ran, and its expected exit step, the sum
+                # of t x (mass leaving at step t), float32 bit for bit in
+                # the array's int32.  An idle lane reads 0 and 0.0.
+                loop = aux["loop"]
+                masses = loop["exit_mass"][0][..., 0]            # [R, B]
+                at = jnp.arange(1, masses.shape[0] + 1, dtype=masses.dtype)
+                expected = jnp.where(live[0], at @ masses, 0.0)
+                nxt = jnp.concatenate([
+                    nxt, jnp.where(live[0], loop["steps_run"][0], 0),
+                    jax.lax.bitcast_convert_type(expected, jnp.int32)])
             return nxt, pools
 
         return jax.jit(step, donate_argnames=("pools",))
@@ -446,7 +469,7 @@ class DecodeEngine:
         if fn is not None:
             self._prefill_fns.move_to_end(n_pages)
             return fn
-        jax = self._jax
+        jax, jnp = self._jax, self._jnp
         model, mcfg = self.model, self.model.cfg
         page = self.config.page_size
         p_len = n_pages * page
@@ -466,6 +489,15 @@ class DecodeEngine:
             def land(kind, cache, pool):
                 if kind == gpt_lib.LINEAR_ATTENTION:
                     return pool.at[slot].set(cache[0])
+                if mcfg.loop_steps > 1:
+                    # A run of pages a loop step: [R, 1, P, G, D] lands
+                    # on the R runs of the prompt's pages.
+                    R = mcfg.loop_steps
+                    runs = gpt_lib.loop_step_pages(
+                        phys[None, :], jnp.arange(R)[:, None],
+                        pool.shape[0], R)
+                    return pool.at[runs.reshape(-1)].set(
+                        cache.reshape(R * n_pages, page, -1), mode="drop")
                 return pool.at[phys].set(
                     cache[0].reshape(n_pages, page, -1), mode="drop")
 
@@ -650,7 +682,11 @@ class DecodeEngine:
                     pages=n_prefill, prompt_tokens=P, chunks=1,
                     state_layers=self._state_layers,
                     sparse_layers=self._sparse_layers,
-                    latent_row_bytes=self._latent_row_bytes)
+                    latent_row_bytes=self._latent_row_bytes,
+                    loop_steps=self._loop_steps,
+                    cache_rows=self._loop_steps * (
+                        len(self.pools) - self._state_layers),
+                    row_bytes=self.allocator.row_bytes_per_token)
         spec = bool(cfg.spec_k) and request.speculative
         state = _Slot(request, cfg.spec_ngram if spec else 0)
         state.table = self.allocator.page_table(request.id,
@@ -905,6 +941,7 @@ class DecodeEngine:
             else:
                 nxt = np.asarray(nxt)
         routed = self._routing_counters(nxt[self.config.num_slots:])
+        looped = self._loop_counters(nxt[self.config.num_slots:])
         now = time.monotonic()
         step_ms = (now - t0) * 1e3
         self.step_index += 1
@@ -926,7 +963,7 @@ class DecodeEngine:
                                 pools_in_place=int(in_place),
                                 sampled_lanes=sampled_lanes,
                                 **(held if self._stateful else {}),
-                                **routed):
+                                **routed, **looped):
             tracer = tracing.active()
             round_id = 0
             t_round_unix = 0.0
@@ -1040,7 +1077,7 @@ class DecodeEngine:
                          kv_pages_in_use=self.allocator.pages_in_use,
                          kv_pages_total=self.config.num_pages,
                          **held, pools_in_place=in_place,
-                         sampled_lanes=sampled_lanes, **routed,
+                         sampled_lanes=sampled_lanes, **routed, **looped,
                          t_start=round(t0, 6),
                          step_ms=round(step_ms, 3), **split_ms,
                          spec_rows=self._spec_rows_last_step,
@@ -1071,6 +1108,24 @@ class DecodeEngine:
         for key, value in routed.items():
             self.moe[key] += value
         return routed
+
+    def _loop_counters(self, behind: np.ndarray) -> dict:
+        """What a weight-shared loop did this step, from the two numbers a
+        lane the step fetched behind its tokens; empty for a model that
+        walks its stack once.  Over the live lanes: the loop steps they
+        ran, how many lanes (tokens) that was, and the sum of their
+        expected exit steps in thousandths (a whole number, as a
+        profiler event's stats are read)."""
+        if self._loop_steps < 2:
+            return {}
+        ran, expected = behind.reshape(2, -1)
+        looped = {"loop_steps_run": int(ran.sum()),
+                  "loop_tokens": int(np.count_nonzero(ran)),
+                  "exit_step_expected_milli": int(round(
+                      1e3 * float(expected.view(np.float32).sum())))}
+        for key, value in looped.items():
+            self.loop[key] += value
+        return looped
 
     def fail_active(self, error: str) -> list[Request]:
         """Retire every live lane with an error (engine-fatal paths).  A
@@ -1130,5 +1185,8 @@ class DecodeEngine:
             # Running sums of the steps' routing counters; zeros for a
             # model whose MLPs are all dense.
             "moe": dict(self.moe),
+            # Running sums of the steps' loop counters; zeros for a model
+            # that walks its stack once.
+            "loop": dict(self.loop),
             "kv_pool": self.allocator.snapshot(),
         }

@@ -53,8 +53,11 @@ class PageAllocator:
     tail) a resident sequence holds beside its pages, over all the model's
     linear-attention layers; 0 for a model that has none.
     ``row_bytes_per_token``: bytes one cached token holds over all layers'
-    pools (keys and values a head, or one latent row); reported, never
-    used to decide anything.
+    pools (keys and values a head, or one latent row; a row a LOOP STEP a
+    layer where the stack is walked several times over the same weights,
+    whose pools hold that many runs of ``num_pages`` pages under the one
+    page table this allocator hands out); reported, never used to decide
+    anything.
     """
 
     def __init__(self, num_pages: int, page_size: int,

@@ -218,16 +218,17 @@ def test_sync_step_with_a_model_axis_keeps_the_dense_fallback(
 # ------------------------------------- a recurrent state beside the pages
 
 
-def serving_programs(one_chip, cfg, buckets, stateful):
+def serving_programs(one_chip, cfg, buckets, stateful, num_pages=1856,
+                     max_pages_per_seq=232):
     """``cfg`` as the engine compiles it under ``longprompt_closed16``'s
-    settings: the decode step over 8 slots and a whole-bucket prefill a
-    page count, with the engine's own closures (their shapes described,
-    nothing placed)."""
+    settings (or another pool's): the decode step over 8 slots and a
+    whole-bucket prefill a page count, with the engine's own closures
+    (their shapes described, nothing placed)."""
     from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
                                                            EngineConfig)
     model = gpt_lib.GptLM(cfg)
-    econf = EngineConfig(num_slots=8, page_size=16, num_pages=1856,
-                         max_pages_per_seq=232)
+    econf = EngineConfig(num_slots=8, page_size=16, num_pages=num_pages,
+                         max_pages_per_seq=max_pages_per_seq)
 
     def described(tree, dtype=None):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
@@ -277,18 +278,27 @@ def hybrid_serving_programs(one_chip, buckets):
     return serving_programs(one_chip, cfg, buckets, stateful=True)
 
 
-def relayouts(program, floor: int) -> list:
+def relayouts(program, floor: int, bodies: bool = False) -> list:
     """The instructions of a compiled program's ENTRY computation that only
     re-lay an array out (``copy``, a ``reshape`` that is no bitcast,
     ``transpose``: each a pass of its own over its operand on the chip)
-    and whose result holds ``floor`` bytes or more."""
+    and whose result holds ``floor`` bytes or more.  With ``bodies``, of
+    every computation that is no fusion's: a program whose layers run
+    inside a ``while`` has them in its body, not in ENTRY."""
     text = program.as_text()
+    if bodies:
+        text = "\n".join(
+            comp for comp in re.split(
+                r"\n(?=(?:ENTRY )?%\S+ \(.*\) -> .* \{\n)", text)
+            if not comp.lstrip().startswith("%fused_computation"))
+    else:
+        text = text[text.index("\nENTRY "):]
     found = []
-    for line in text[text.index("\nENTRY "):].splitlines():
+    for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* "
                      r"(copy|reshape|transpose)\(", line)
-        if m and math.prod(int(d) for d in m[2].split(",") if d) * _BYTES[
-                m[1]] >= floor:
+        if m and math.prod(int(d) for d in m[2].split(",") if d) * _BYTES.get(
+                m[1], 1) >= floor:
             found.append(line.strip()[:160])
     return found
 
@@ -428,3 +438,42 @@ def test_latent_and_routed_expert_programs_compile_for_v5e(one_chip):
         header = program.as_text().split("\n", 1)[0]
         assert header.count("may-alias") + header.count(
             "must-alias") == len(leaves)
+
+
+# ------------------------- a stack walked four times over the same weights
+
+
+def test_looped_serving_programs_compile_for_v5e(one_chip):
+    """Two of the 48 layers at the published widths of
+    ``perfbench/configs/ouro-2.6b.json`` under ``reasoning_closed16``'s
+    engine settings, walked four times: the decode step is ONE ``while``
+    around the layers (not four copies of them), a layer's pool holds its
+    four runs of pages in one array [4 x 384, 16, 2048] which the loop
+    carries in place (donated, aliased, no relayout of a pool's size in the
+    body or outside it), and the prefill keeps the flash kernel in every
+    layer of the loop."""
+    from perfbench import spec, worker
+    config = spec.load_json(os.path.join(spec.HERE, "configs",
+                                         "ouro-2.6b.json"))
+    cfg = dataclasses.replace(worker.gpt_config(
+        {"config": config, "config_file": "ouro-2.6b.json"}),
+        num_layers=2, vocab_size=8192)
+    step, prefills, pools = serving_programs(
+        one_chip, cfg, (16,), stateful=False, num_pages=384,
+        max_pages_per_seq=48)
+    leaves = jax.tree.leaves(pools)
+    assert [x.shape for x in leaves] == [(4 * 384, 16, 2048)] * 4
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    for program in (step, prefills[16]):
+        text = program.as_text()
+        assert text.count(" while(") == 1
+        mem = program.memory_analysis()
+        assert mem.temp_size_in_bytes < 1e9
+        assert mem.alias_size_in_bytes == pool_bytes
+        header = text.split("\n", 1)[0]
+        assert header.count("may-alias") + header.count(
+            "must-alias") == len(leaves)
+        assert relayouts(program, pool_bytes // len(leaves),
+                         bodies=True) == []
+    assert step.as_text().count("tpu_custom_call") == 0
+    assert prefills[16].as_text().count("tpu_custom_call") == 2

@@ -35,9 +35,15 @@ CONFIGS = {
             gpt_lib.FULL_ATTENTION,),
         linear_num_heads=2, linear_key_head_dim=8,
         linear_value_head_dim=16),
+    # Three loop steps over the same two layers: a pool a layer, a run of
+    # pages a step.
+    "looped": dict(
+        num_layers=2, pos_encoding="rope", norm="rmsnorm",
+        activation="swiglu", norm_placement="sandwich", loop_steps=3,
+        exit_gate=True),
 }
 #: What makes an engine run each program (a model with recurrent layers
-#: is refused ``spec_k`` and ``prefill_chunk``).
+#: or a weight-shared loop is refused ``spec_k`` and ``prefill_chunk``).
 PROGRAMS = {"step": {}, "prefill": {}, "spec_step": dict(spec_k=3),
             "chunk_prefill": dict(prefill_chunk=4)}
 CASES = [(c, p) for c in CONFIGS for p in PROGRAMS
