@@ -833,8 +833,9 @@ class GptBlock(nn.Module):
                                  positions: jax.Array,
                                  live: jax.Array | None = None):
         """One token a row against the PAGED pools of latent rows
-        ([num_pages, page_size, latent_kv_rank] and [.., rope]; addressing,
-        sentinel and masks as in :meth:`decode_step_paged`), in the
+        ([num_pages + 1, page_size, latent_kv_rank] and [.., rope];
+        addressing, sentinel page and masks as in
+        :meth:`decode_step_paged`), in the
         ABSORBED form: with ``kv_b`` split a head into W^K [latent, nope]
         and W^V [latent, v], the query's un-rotated part is folded through
         W^K into the latent's space and scored against the cached latents
@@ -843,43 +844,36 @@ class GptBlock(nn.Module):
         average a head.  The same mathematics as the expanded form; no key
         or value is ever expanded over the context."""
         cfg = self.cfg
-        num_pages, page = latent_pool.shape[0], latent_pool.shape[1]
-        B, MP = page_table.shape
+        sentinel, page = latent_pool.shape[0] - 1, latent_pool.shape[1]
+        MP = page_table.shape[1]
         q_nope, q_rot, latent, k_rot = self._latent_q_row(
             x, positions[:, None])
         lpage = (positions // page).astype(jnp.int32)
         off = (positions % page).astype(jnp.int32)
-        phys = jnp.take_along_axis(
-            page_table, jnp.clip(lpage, 0, MP - 1)[:, None], axis=1)[:, 0]
+        phys = written_pages(jnp.take_along_axis(
+            page_table, jnp.clip(lpage, 0, MP - 1)[:, None], axis=1)[:, 0],
+            latent_pool.shape[0])
         latent_pool = latent_pool.at[phys, off].set(
             latent[:, 0].astype(latent_pool.dtype), mode="drop")
         key_pool = key_pool.at[phys, off].set(
             k_rot[:, 0].astype(key_pool.dtype), mode="drop")
-
-        def gather(pool):
-            # A sentinel entry of the table reads the pool's last page
-            # instead of zeros (no pass over the gathered rows to blank
-            # them): whatever is there gets a weight of exactly 0 from
-            # ``valid`` below.
-            return jnp.take(pool, page_table, axis=0, mode="clip").reshape(
-                B, MP * page, -1)
         s = jnp.arange(MP * page)
         allocated = jnp.take_along_axis(
-            page_table, (s[None, :] // page), axis=1) < num_pages  # [B, S]
+            page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
         valid = (s[None, :] <= positions[:, None]) & allocated
         compute = q_nope.dtype
         with jax.named_scope("mla.absorb"):
             w_k, w_v = jnp.split(
                 self.kv_b.variables["params"]["kernel"].astype(compute),
                 [cfg.qk_nope_head_dim], axis=-1)
-            latents = gather(latent_pool).astype(compute)
+            latents = gather_pages(latent_pool, page_table).astype(compute)
             scale = 1.0 / jnp.sqrt(jnp.float32(
                 cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
             logits = (jnp.einsum(
                 "bhc,bsc->bhs", jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_k),
                 latents, preferred_element_type=jnp.float32) + jnp.einsum(
                 "bhr,bsr->bhs", q_rot[:, 0],
-                gather(key_pool).astype(compute),
+                gather_pages(key_pool, page_table).astype(compute),
                 preferred_element_type=jnp.float32)) * scale
             logits = jnp.where(valid[:, None, :], logits,
                                jnp.finfo(jnp.float32).min)
@@ -1231,9 +1225,11 @@ class GptBlock(nn.Module):
         admits only slots before ``positions[b]``, junk written past the
         frontier stays unread until real tokens overwrite it.  Writes
         whose logical page falls OUTSIDE the page table (drafts past the
-        row's reservation) are routed through the OOB sentinel and drop —
-        never clamped onto the last real page, which may hold committed
-        K/V.  The pools' row is flat, [num_pages, page_size, G * D]
+        row's reservation) go one past the pool and drop, as a write
+        through a sentinel entry does (:func:`written_pages`) — never
+        clamped onto the last real page, which may hold committed K/V,
+        nor onto the page of zeros.  The pools' row is flat,
+        [num_pages + 1, page_size, G * D]
         (:func:`init_kv_pool`; PR 37); the gathered rows get their head
         axis back for :meth:`_attend_cache_chunk` (two passes over them on
         the chip that :meth:`decode_step_paged` avoids; no cell runs this).
@@ -1244,7 +1240,7 @@ class GptBlock(nn.Module):
                 "paged decode needs full-cache addressing (position == "
                 "logical slot); the windowed ring cache is not pageable — "
                 "use sequential decode_step instead")
-        num_pages, page = k_pool.shape[0], k_pool.shape[1]
+        sentinel, page = k_pool.shape[0] - 1, k_pool.shape[1]
         B, MP = page_table.shape
         K = x.shape[1]
         pos = positions[:, None] + jnp.arange(K)[None, :]        # [B, K]
@@ -1253,18 +1249,18 @@ class GptBlock(nn.Module):
         off = (pos % page).astype(jnp.int32)
         phys = jnp.take_along_axis(page_table,
                                    jnp.clip(lpage, 0, MP - 1), axis=1)
-        phys = jnp.where(lpage < MP, phys, num_pages)  # OOB -> sentinel
+        phys = written_pages(jnp.where(lpage < MP, phys, sentinel),
+                             k_pool.shape[0])
         # Cache-dtype round trip before attending (see decode_chunk).
         k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
         k_pool = k_pool.at[phys, off].set(k.reshape(B, K, -1), mode="drop")
         v_pool = v_pool.at[phys, off].set(v.reshape(B, K, -1), mode="drop")
         def gather(pool):
-            rows = jnp.take(pool, page_table, axis=0, mode="fill",
-                            fill_value=0)                 # [B,MP,page,G*D]
-            return rows.reshape(B, MP * page, *k.shape[2:])
+            return gather_pages(pool, page_table).reshape(
+                B, MP * page, *k.shape[2:])
         s = jnp.arange(MP * page)
         allocated = jnp.take_along_axis(
-            page_table, (s[None, :] // page), axis=1) < num_pages  # [B, S]
+            page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
         prefix_valid = (s[None, :] < positions[:, None]) & allocated
         chunk_valid = (jnp.arange(K)[:, None] >= jnp.arange(K)[None, :])
         ctx = self._attend_cache_chunk(
@@ -1280,17 +1276,21 @@ class GptBlock(nn.Module):
         decode body (:mod:`..serving.engine`).
 
         The pool holds every resident sequence's cache as fixed-size pages
-        (``k_pool``/``v_pool``: [num_pages, page_size, G * D], the row
-        flat so that the chip keeps the pool as the scatter below indexes
-        it, and attended flat, :meth:`_attend_rows`: :func:`init_kv_pool`,
-        PR 37); row ``b``'s
+        (``k_pool``/``v_pool``: [num_pages + 1, page_size, G * D], the
+        row flat so that the chip keeps the pool as the scatter below
+        indexes it, and attended flat, :meth:`_attend_rows`:
+        :func:`init_kv_pool`, PR 37); row ``b``'s
         logical position ``p`` lives at physical page
         ``page_table[b, p // page_size]``, offset ``p % page_size``.
-        ``page_table`` [B, MP] uses ``num_pages`` itself as the
-        not-allocated sentinel: the write scatter routes through it OUT OF
-        BOUNDS and drops (an idle slot writes nowhere — same
-        drop-don't-clip discipline as :meth:`decode_chunk`), and the
-        gather fills zeros that the validity mask keeps unread.
+        ``page_table`` [B, MP] uses ``num_pages`` as the not-allocated
+        sentinel, and that is the pool's LAST page: all zeros, handed out
+        by no allocator and never written.  The gather reads it like any
+        page, in bounds, so no pass blanks the gathered rows (PR 39), and
+        what it reads is zeros under a weight of zero: a row's output
+        depends on no page the row does not own.  A write through the
+        sentinel (an idle slot's) is sent one past the pool and drops
+        (:func:`written_pages` — same drop-don't-clip discipline as
+        :meth:`decode_chunk`).
 
         Distinct slots never share a page (the allocator's invariant), so
         the per-row scatter has no duplicate indices.  Full-cache
@@ -1303,28 +1303,24 @@ class GptBlock(nn.Module):
                 "paged decode needs full-cache addressing (position == "
                 "logical slot); the windowed ring cache is not pageable — "
                 "use sequential decode_step instead")
-        num_pages, page = k_pool.shape[0], k_pool.shape[1]
+        sentinel, page = k_pool.shape[0] - 1, k_pool.shape[1]
         B, MP = page_table.shape
         q, k, v = self._qkv(x, positions=positions[:, None])  # [B,1,*,D]
         lpage = (positions // page).astype(jnp.int32)
         off = (positions % page).astype(jnp.int32)
-        phys = jnp.take_along_axis(
-            page_table, jnp.clip(lpage, 0, MP - 1)[:, None], axis=1)[:, 0]
+        phys = written_pages(jnp.take_along_axis(
+            page_table, jnp.clip(lpage, 0, MP - 1)[:, None], axis=1)[:, 0],
+            k_pool.shape[0])
         k_pool = k_pool.at[phys, off].set(
             k.reshape(B, -1).astype(k_pool.dtype), mode="drop")
         v_pool = v_pool.at[phys, off].set(
             v.reshape(B, -1).astype(v_pool.dtype), mode="drop")
-        # Gather each row's pages into a contiguous [B, MP*page, G*D]
-        # view; sentinel pages read as zeros (mode="fill") and stay masked.
-        def gather(pool):
-            rows = jnp.take(pool, page_table, axis=0, mode="fill",
-                            fill_value=0)                 # [B,MP,page,G*D]
-            return rows.reshape(B, MP * page, -1)
         s = jnp.arange(MP * page)
         allocated = jnp.take_along_axis(
-            page_table, (s[None, :] // page), axis=1) < num_pages  # [B, S]
+            page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
         valid = (s[None, :] <= positions[:, None]) & allocated
-        ctx = self._attend_rows(q, gather(k_pool), gather(v_pool), valid)
+        ctx = self._attend_rows(q, gather_pages(k_pool, page_table),
+                                gather_pages(v_pool, page_table), valid)
         x = self._add_mixed(x, self.out(ctx))
         return self._mlp(x, deterministic=True), k_pool, v_pool
 
@@ -1647,13 +1643,32 @@ def exit_masses(gates: jax.Array | None, steps: int,
 def loop_step_pages(pages: jax.Array, step, rows: int,
                     steps: int) -> jax.Array:
     """Where loop step ``step``'s rows of ``pages`` lie in a pool of
-    ``rows`` = ``steps`` x num_pages pages (:func:`init_kv_pool`): step t
-    holds the run ``[t x num_pages, (t + 1) x num_pages)``.  The
+    ``rows`` = ``steps`` x num_pages + 1 pages (:func:`init_kv_pool`):
+    step t holds the run ``[t x num_pages, (t + 1) x num_pages)``.  The
     not-allocated sentinel (``num_pages``, one past a step's run) becomes
-    ``rows``, one past the pool, so that a write through it still drops
-    and a gather still fills."""
-    num_pages = rows // steps
-    return jnp.where(pages < num_pages, pages + step * num_pages, rows)
+    ``rows - 1``, the one page of zeros after the last run: the sentinel
+    of a pool of that size, to a gather and to :func:`written_pages`."""
+    num_pages = (rows - 1) // steps
+    return jnp.where(pages < num_pages, pages + step * num_pages, rows - 1)
+
+
+def written_pages(pages: jax.Array, rows: int) -> jax.Array:
+    """``pages`` as a WRITE addresses a pool of ``rows`` pages: the last
+    page is the sentinel's, all zeros and never written, so an entry that
+    names it (an idle or passenger lane's, a page never allocated) goes
+    one past the pool, where ``.at[...].set(mode="drop")`` drops it."""
+    return jnp.where(pages < rows - 1, pages, rows)
+
+
+def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
+    """Every row's pages side by side, [B, MP * page_size, row].  In
+    bounds by construction, a sentinel entry reading the pool's last page
+    (zeros: :func:`init_kv_pool`), so nothing is filled in afterwards:
+    ``mode="fill"`` cost a pass of its own over the gathered rows, 1.4 ms
+    a full layer in the hybrid's step (PERF.md, PR 39)."""
+    B, MP = page_table.shape
+    return jnp.take(pool, page_table, axis=0, mode="clip").reshape(
+        B, MP * pool.shape[1], -1)
 
 
 def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
@@ -1739,9 +1754,9 @@ def state_bytes_per_slot(cfg: GptConfig) -> int:
 
 def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
                  dtype=None, num_slots: int = 0):
-    """Per-layer (k, v) PAGED pool arrays [num_pages, page_size, G * D] —
-    the serving tier's shared KV memory (:mod:`..serving.kv_pool` owns the
-    page accounting).  Unlike :func:`init_kv_cache` there is no batch
+    """Per-layer (k, v) PAGED pool arrays [num_pages + 1, page_size, G * D]
+    — the serving tier's shared KV memory (:mod:`..serving.kv_pool` owns
+    the page accounting).  Unlike :func:`init_kv_cache` there is no batch
     axis: every resident sequence draws pages from the same pool, so HBM
     is sized by total resident tokens, not num_slots × max_len.  Same
     dtype lever (``float8_e4m3fn`` halves cache bytes; upcast on read).
@@ -1754,19 +1769,27 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
     only on what is written; the decode step attends the gathered rows
     flat as well (``GptBlock._attend_rows``).
 
+    The page after the allocator's ``num_pages`` is the SENTINEL's: the
+    index a page table holds where a row has no page names it, it is all
+    zeros from here on, no allocator hands it out and nothing writes it
+    (:func:`written_pages`).  So a decode step's gather is in bounds
+    whatever the table holds, reads zeros where ``mode="fill"`` wrote
+    them, and a row reads no page it does not own (PR 39).
+
     A linear-attention layer holds no pages: its entry is one fixed-size
     row per decode SLOT (``num_slots`` of them: state float32, convolution
     tail), whatever the sequence's length.  A latent-attention layer's entry
-    is its row's two parts, [num_pages, page_size, latent_kv_rank] and
-    [num_pages, page_size, qk_rope_head_dim] (:func:`_rows_entry`).
+    is its row's two parts, [num_pages + 1, page_size, latent_kv_rank] and
+    [num_pages + 1, page_size, qk_rope_head_dim] (:func:`_rows_entry`).
 
     With ``cfg.loop_steps`` > 1 a layer's pool holds ``loop_steps`` runs of
-    ``num_pages`` pages, [loop_steps * num_pages, page_size, G * D]: page
-    ``p`` of loop step ``t`` is row ``t * num_pages + p``
-    (:func:`loop_step_pages`).  One array a layer and not one a step: the
-    scatter and the gather address a step's run by an offset, the same two
-    operations as without a loop, and the compiled step carries
-    ``num_layers`` pairs of buffers through its loop in place."""
+    ``num_pages`` pages and the one sentinel page after the last,
+    [loop_steps * num_pages + 1, page_size, G * D]: page ``p`` of loop
+    step ``t`` is row ``t * num_pages + p`` (:func:`loop_step_pages`).  One
+    array a layer and not one a step: the scatter and the gather address a
+    step's run by an offset, the same two operations as without a loop, and
+    the compiled step carries ``num_layers`` pairs of buffers through its
+    loop in place."""
     if cfg.attention_window:
         raise ValueError("paged KV pools need full-cache addressing; "
                          "sliding-window checkpoints are not pageable")
@@ -1775,7 +1798,7 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
                          "whose layer_kinds has a linear_attention layer")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
     return [_state_entry(cfg, num_slots) if kind == LINEAR_ATTENTION
-            else _rows_entry(cfg, kind, (cfg.loop_steps * num_pages,
+            else _rows_entry(cfg, kind, (cfg.loop_steps * num_pages + 1,
                                          page_size), dtype, flat=True)
             for kind in cfg.kinds]
 

@@ -60,6 +60,15 @@ anyway, so no second program and no option).  The host knows the same
 from its own ``_temp`` array: each ``serve_step`` record says
 ``sampled_lanes``, and :meth:`DecodeEngine.stats` counts
 ``sample_steps_greedy`` / ``sample_steps_sampled``.
+
+A page table's entry where a lane holds no page is the SENTINEL,
+``num_pages``: the pools' one page past the allocator's range, all zeros,
+never written (``gpt_lib.init_kv_pool``).  The step's gather reads it like
+any page, so how much of a step's gather reads zeros is a count the host
+can make from its own ``_tables``: each ``serve_step`` record says
+``table_pages`` (slots x ``max_pages_per_seq``) and ``table_pages_held``
+(the entries that name a page a lane owns), and
+:meth:`DecodeEngine.stats` sums both.
 """
 
 from __future__ import annotations
@@ -290,6 +299,11 @@ class DecodeEngine:
         # arm) and steps with a lane at temperature > 0 (it sorted).
         self.sample_steps_greedy = 0
         self.sample_steps_sampled = 0
+        # Running sums over the steps: the entries of the page table the
+        # step gathered through, and those of them that named a page a
+        # lane holds (the others read the sentinel's page of zeros).
+        self.table_pages = 0
+        self.table_pages_held = 0
         # Running sums of the steps' routing counters (_routing_counters).
         self.moe = dict.fromkeys(("experts_touched", "expert_slots",
                                   "expert_tokens_max", "routed_tokens"), 0)
@@ -495,10 +509,12 @@ class DecodeEngine:
                     R = mcfg.loop_steps
                     runs = gpt_lib.loop_step_pages(
                         phys[None, :], jnp.arange(R)[:, None],
-                        pool.shape[0], R)
-                    return pool.at[runs.reshape(-1)].set(
+                        pool.shape[0], R).reshape(-1)
+                    return pool.at[
+                        gpt_lib.written_pages(runs, pool.shape[0])].set(
                         cache.reshape(R * n_pages, page, -1), mode="drop")
-                return pool.at[phys].set(
+                return pool.at[
+                    gpt_lib.written_pages(phys, pool.shape[0])].set(
                     cache[0].reshape(n_pages, page, -1), mode="drop")
 
             # An entry is (keys, values), a latent layer's (latents,
@@ -908,6 +924,11 @@ class DecodeEngine:
             # The predicate the sampler evaluates on the device, read off
             # the host's copy of the same array before anything retires.
             sampled_lanes = int(np.count_nonzero(self._temp > 0.0))
+            # What the gather of this dispatch reads: all of the table,
+            # of which this many entries are pages and not the sentinel.
+            table = {"table_pages": self._tables.size,
+                     "table_pages_held": int(np.count_nonzero(
+                         self._tables < self.config.num_pages))}
             if spec_mode:
                 K = self.config.spec_k
                 chunk = np.zeros((self.config.num_slots, K), np.int32)
@@ -959,9 +980,11 @@ class DecodeEngine:
             self.sample_steps_sampled += 1
         else:
             self.sample_steps_greedy += 1
+        self.table_pages += table["table_pages"]
+        self.table_pages_held += table["table_pages_held"]
         with profiling.annotate("serve.step.retire",
                                 pools_in_place=int(in_place),
-                                sampled_lanes=sampled_lanes,
+                                sampled_lanes=sampled_lanes, **table,
                                 **(held if self._stateful else {}),
                                 **routed, **looped):
             tracer = tracing.active()
@@ -1076,7 +1099,7 @@ class DecodeEngine:
                          retired=len(retired), queue_depth=queue_depth,
                          kv_pages_in_use=self.allocator.pages_in_use,
                          kv_pages_total=self.config.num_pages,
-                         **held, pools_in_place=in_place,
+                         **held, pools_in_place=in_place, **table,
                          sampled_lanes=sampled_lanes, **routed, **looped,
                          t_start=round(t0, 6),
                          step_ms=round(step_ms, 3), **split_ms,
@@ -1182,6 +1205,10 @@ class DecodeEngine:
             # argmax, and steps in which some lane sampled.
             "sample_steps_greedy": self.sample_steps_greedy,
             "sample_steps_sampled": self.sample_steps_sampled,
+            # Page-table entries the steps gathered through, and those of
+            # them that named a held page and not the sentinel's zeros.
+            "table_pages": self.table_pages,
+            "table_pages_held": self.table_pages_held,
             # Running sums of the steps' routing counters; zeros for a
             # model whose MLPs are all dense.
             "moe": dict(self.moe),

@@ -46,8 +46,14 @@ class PageAllocator:
 
     ``num_pages`` physical pages of ``page_size`` token slots each.  The
     sentinel index for "no page" in emitted page tables is ``num_pages``
-    itself — out of bounds by exactly one, so the engine's scatters drop
-    through it (``mode="drop"``) and gathers zero-fill (``mode="fill"``).
+    itself — one past this allocator's range, and in the engine's pools
+    (``models/gpt.init_kv_pool``) a page of its own: all zeros, never
+    handed out here and never written, so a gather through the sentinel
+    is in bounds and reads zeros, and a sequence reads no page it does
+    not own.  A write through it is sent past the pool and drops
+    (``gpt_lib.written_pages``).  Capacity, admission and occupancy count
+    ``num_pages``; a freed page keeps what its last owner wrote, under a
+    weight of zero until its next owner overwrites it.
 
     ``state_bytes_per_slot``: bytes of recurrent state (and convolution
     tail) a resident sequence holds beside its pages, over all the model's
@@ -204,7 +210,7 @@ class PageAllocator:
 
     def page_table(self, seq_id, max_pages: int) -> np.ndarray:
         """[max_pages] int32 physical-page row for the engine, padded with
-        the OOB sentinel (``num_pages``)."""
+        the sentinel (``num_pages``: the pools' page of zeros)."""
         pages = self._owned.get(seq_id, ())
         if len(pages) > max_pages:
             raise ValueError(

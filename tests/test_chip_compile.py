@@ -293,27 +293,46 @@ def relayouts(program, floor: int, bodies: bool = False) -> list:
             if not comp.lstrip().startswith("%fused_computation"))
     else:
         text = text[text.index("\nENTRY "):]
+    return _instructions(text, "copy|reshape|transpose", floor)
+
+
+def _instructions(text: str, kinds: str, floor: int) -> list:
+    """The lines of HLO ``text`` whose instruction is one of ``kinds`` (a
+    regex alternation) and whose result holds ``floor`` bytes or more."""
     found = []
     for line in text.splitlines():
         m = re.match(r"\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]*)\]\S* "
-                     r"(copy|reshape|transpose)\(", line)
+                     rf"({kinds})\(", line)
         if m and math.prod(int(d) for d in m[2].split(",") if d) * _BYTES.get(
                 m[1], 1) >= floor:
             found.append(line.strip()[:160])
     return found
 
 
+def gathered_selects(program, floor: int) -> list:
+    """The ``select`` instructions of a compiled program, in a fusion's
+    body or outside one, whose result holds ``floor`` bytes or more: a
+    pass over the rows a decode step gathered through its page table that
+    blanks a sentinel entry's (``jnp.take(..., mode="fill")``: two a full
+    layer before PR 39, 1.4 ms each in the hybrid's step as a fusion of
+    their own, folded into the scores' read in the dense and the looped
+    step).  The step's other selects (the mask over the scores, the
+    embedding's take) are a hundredth of that size."""
+    return _instructions(program.as_text(), "select", floor)
+
+
 def pools_donated_and_uncopied(programs, pools, n_leaves, temp_below):
     """The engine donates its pools: every leaf comes out in the buffer it
     went in by, and nothing else does; and no program re-lays out as many
-    bytes as a K/V pool holds, which is also what the decode step gathers
-    of one (8 lanes of 232 pages): the hybrid's sixteen pool copies a step
-    before PR 37, the two passes over the gathered rows that a head axis
-    split off them AFTER the gather costs instead, and the dense model's
-    heads-major copy of them."""
+    bytes as the decode step gathers of a K/V pool (8 lanes of 232 pages:
+    all but one page of it), nor blanks them: the hybrid's sixteen pool
+    copies a step before PR 37, the two passes over the gathered rows that
+    a head axis split off them AFTER the gather costs instead, the dense
+    model's heads-major copy of them, and the zero-fill of sentinel
+    entries before PR 39 (the step is ``programs[0]``)."""
     leaves = jax.tree.leaves(pools)
     floor = min(x.size * x.dtype.itemsize for x in leaves
-                if x.shape[0] == 1856)
+                if x.shape[0] == 1857) * 1856 // 1857
     assert len(leaves) == n_leaves
     # (Bytes as the chip lays an array out: the last axis in whole lanes
     # of 128, which pads the state's keys of 96 and nothing else here.)
@@ -327,6 +346,7 @@ def pools_donated_and_uncopied(programs, pools, n_leaves, temp_below):
         assert header.count("may-alias") + header.count(
             "must-alias") == len(leaves)
         assert relayouts(program, floor) == []
+    assert gathered_selects(programs[0], floor) == []
 
 
 def test_hybrid_serving_programs_compile_for_v5e(one_chip):
@@ -334,10 +354,11 @@ def test_hybrid_serving_programs_compile_for_v5e(one_chip):
     for the chip at 30 heads of 96 x 192, with the flash kernel in the full
     layer's prefill where the bucket's length lets it in: 1,024 tokens do,
     1,600 do not (``_layout_ok``; D6's silent fallback).  The K/V pools'
-    row is flat, [1856, 16, 30 * 128], the chip keeps it as the step
-    indexes it and the step attends the gathered rows as they are: no
-    pool-sized relayout (four copies a full layer with a head axis of 30;
-    PR 37)."""
+    row is flat, [1856 + 1, 16, 30 * 128] with the sentinel's page of
+    zeros, the chip keeps it as the step indexes it and the step attends
+    the gathered rows as they are: no pool-sized relayout (four copies a
+    full layer with a head axis of 30; PR 37) and no pass that blanks the
+    gathered rows (two a full layer; PR 39)."""
     step, prefills, pools = hybrid_serving_programs(one_chip, (64, 100))
     assert step.as_text().count("tpu_custom_call") == 0
     # (A full layer that is the model's LAST layer loses its call: its
@@ -345,18 +366,18 @@ def test_hybrid_serving_programs_compile_for_v5e(one_chip):
     # keeps its K/V and drops the rest.  Here a linear layer follows it.)
     assert prefills[64].as_text().count("tpu_custom_call") == 1
     assert prefills[100].as_text().count("tpu_custom_call") == 0
-    assert [x.shape for x in pools[3]] == [(1856, 16, 3840)] * 2
+    assert [x.shape for x in pools[3]] == [(1857, 16, 3840)] * 2
     pools_donated_and_uncopied((step, *prefills.values()), pools,
                                n_leaves=2 * 5, temp_below=4e9)
 
 
 def test_dense_serving_programs_keep_their_pools_for_v5e(one_chip):
     """The same step and prefill at ``perfbench/configs/mistral-7b.json``'s
-    widths (8 K/V heads of 128, two layers): the flat row [1856, 16, 1024]
+    widths (8 K/V heads of 128, two layers): the flat row [1857, 16, 1024]
     is scattered into in place as its four-axis form was, and attended
     flat it loses that form's heads-major copy of the gathered rows (two a
-    layer), so a later pool shape or gather cannot bring a relayout to
-    either configuration without a red test."""
+    layer), so a later pool shape or gather cannot bring a relayout or a
+    blanking pass to either configuration without a red test."""
     cfg = gpt_lib.GptConfig(
         vocab_size=32000, hidden_size=4096, num_layers=2, num_heads=32,
         kv_heads=8, intermediate_size=14336, max_position=4096,
@@ -364,7 +385,7 @@ def test_dense_serving_programs_keep_their_pools_for_v5e(one_chip):
         activation="swiglu", norm="rmsnorm")
     step, prefills, pools = serving_programs(one_chip, cfg, (64,),
                                              stateful=False)
-    assert [x.shape for x in jax.tree.leaves(pools)] == [(1856, 16, 1024)] * 4
+    assert [x.shape for x in jax.tree.leaves(pools)] == [(1857, 16, 1024)] * 4
     # The flash kernel in both layers' prefill but the last's (above).
     assert prefills[64].as_text().count("tpu_custom_call") == 1
     pools_donated_and_uncopied((step, *prefills.values()), pools,
@@ -378,9 +399,10 @@ def test_latent_and_routed_expert_programs_compile_for_v5e(one_chip):
     """The leading dense layer and two sparse layers at the published widths
     of ``perfbench/configs/glm-4.7-flash.json``, as the engine compiles them:
     the decode step over 16 slots of 528 pages (absorbed attention over the
-    rows, three grouped products a sparse layer, the histogram behind the
-    tokens) and a whole-bucket prefill (the flash kernel at a head of 256,
-    the grouped products at 4 x 1,024 rows), pools donated."""
+    rows as they were gathered, three grouped products a sparse layer, the
+    histogram behind the tokens) and a whole-bucket prefill (the flash
+    kernel at a head of 256, the grouped products at 4 x 1,024 rows), pools
+    donated."""
     from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
                                                            EngineConfig)
     from perfbench import spec, worker
@@ -422,15 +444,20 @@ def test_latent_and_routed_expert_programs_compile_for_v5e(one_chip):
     prefill = engine._prefill_fn(64).lower(
         tree, i32(1, 1024), pools, i32(64)).compile()
     assert step.as_text().count("tpu_custom_call") == 2 * 3
+    # (what the step gathers of a latent pool: 16 lanes of 528 pages)
+    assert gathered_selects(step, 16 * 528 * 16 * 512 * 2) == []
     # The last layer's mixer and experts feed only logits the prefill
     # throws away: two flash calls and ONE layer's grouped products stay.
     assert prefill.as_text().count("tpu_custom_call") == 2 + 3
     leaves = jax.tree.leaves(pools)
-    assert [x.shape for x in leaves] == [(8448, 16, 512),
-                                         (8448, 16, 64)] * 3
-    # (no padding: 512 fills whole lanes of 128, and the chip lays the
-    # rotated keys' 64 out with the pages minor-most)
-    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert [x.shape for x in leaves] == [(8449, 16, 512),
+                                         (8449, 16, 64)] * 3
+    # (512 fills whole lanes of 128; the chip lays the rotated keys' 64 out
+    # with the PAGES minor-most, as it did, in whole lanes of 128 pages:
+    # the sentinel's page makes 8,449 of them, held as 8,576)
+    pool_bytes = sum(
+        (x.shape[0] if x.shape[-1] % 128 == 0 else -(-x.shape[0] // 128) * 128)
+        * x.size // x.shape[0] * x.dtype.itemsize for x in leaves)
     for program in (step, prefill):
         mem = program.memory_analysis()
         assert mem.temp_size_in_bytes < 2e9
@@ -448,10 +475,11 @@ def test_looped_serving_programs_compile_for_v5e(one_chip):
     ``perfbench/configs/ouro-2.6b.json`` under ``reasoning_closed16``'s
     engine settings, walked four times: the decode step is ONE ``while``
     around the layers (not four copies of them), a layer's pool holds its
-    four runs of pages in one array [4 x 384, 16, 2048] which the loop
-    carries in place (donated, aliased, no relayout of a pool's size in the
-    body or outside it), and the prefill keeps the flash kernel in every
-    layer of the loop."""
+    four runs of pages and the sentinel's page in one array [4 x 384 + 1,
+    16, 2048] which the loop carries in place (donated, aliased, no
+    relayout of a pool's size in the body or outside it, no pass that
+    blanks what a loop step gathered of its run), and the prefill keeps the
+    flash kernel in every layer of the loop."""
     from perfbench import spec, worker
     config = spec.load_json(os.path.join(spec.HERE, "configs",
                                          "ouro-2.6b.json"))
@@ -462,7 +490,7 @@ def test_looped_serving_programs_compile_for_v5e(one_chip):
         one_chip, cfg, (16,), stateful=False, num_pages=384,
         max_pages_per_seq=48)
     leaves = jax.tree.leaves(pools)
-    assert [x.shape for x in leaves] == [(4 * 384, 16, 2048)] * 4
+    assert [x.shape for x in leaves] == [(4 * 384 + 1, 16, 2048)] * 4
     pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
     for program in (step, prefills[16]):
         text = program.as_text()
@@ -476,4 +504,6 @@ def test_looped_serving_programs_compile_for_v5e(one_chip):
         assert relayouts(program, pool_bytes // len(leaves),
                          bodies=True) == []
     assert step.as_text().count("tpu_custom_call") == 0
+    # (what a loop step gathers of a pool: 8 lanes of 48 pages)
+    assert gathered_selects(step, 8 * 48 * 16 * 2048 * 2) == []
     assert prefills[16].as_text().count("tpu_custom_call") == 2

@@ -297,23 +297,27 @@ def test_what_the_state_needs_is_asked_for_by_name(call, names,
 #: which holds a K/V pool's row flat ([pages, page, G * D]: the pool
 #: arguments, the scatter's update and the gathered rows' reshape change
 #: and nothing else; ``tests/test_serving.py`` holds logits and pool
-#: contents to the contiguous-cache path bit for bit); ``tree`` and
+#: contents to the contiguous-cache path bit for bit), and again in PR 39,
+#: which gives every paged pool the sentinel's page of zeros (a row more in
+#: the pool arguments, the gather without its fill, a write through the
+#: sentinel sent past the pool; ``tests/test_sentinel_page.py`` and the same
+#: ``tests/test_serving.py`` cases hold what that keeps); ``tree`` and
 #: ``call`` are still that parent's.
 DENSE_GOLDEN = {
     "gpt2": {"tree": "aaa1a7d60ae885e3d2d4d073dadd6d98",
-             "step": "a6d23b5975a3ff3973d31b9ebd5fea95",
-             "prefill": "b321613069b738430f2f6b72023a2735",
-             "step_undonated": "d440c06743f0e3649e5e42f810720778",
-             "prefill_undonated": "c249197197803e701f8fe9976bd6bce5",
+             "step": "5ec05fc1bc67d297e1edb18f3179c647",
+             "prefill": "10f03c9dc28018088d5830757b5eff88",
+             "step_undonated": "09b3ef05876e3976c4aa5046b16f24b8",
+             "prefill_undonated": "e7a3c9262b5c6d797f7e08527d39bf57",
              "call": "7137ce905cc4f0b2dfb44c057f4e4108",
-             "decode_paged": "c767fea6233fb8a37c5dc64bc36f2e59"},
+             "decode_paged": "b3b2745df852210b96534af5b448bfea"},
     "mistral": {"tree": "bda3e6337e210318d71872269ca97b04",
-                "step": "fbc7a70a8f556d530b4cd245aff4897d",
-                "prefill": "8d0f30cdf494a28a79ecda8eb8b6b474",
-                "step_undonated": "846c642a289a463747cea459fb691e8a",
-                "prefill_undonated": "4bb06b654ff1d7d641cc0982100989d4",
+                "step": "0e7c64373e54105cdfc2d83448201063",
+                "prefill": "14102b8e4ea2976c947655f984d4ab96",
+                "step_undonated": "61a96231c44fc330351e0e74729c6013",
+                "prefill_undonated": "9ac1a5c0306a82e5eccdcac356d6db7e",
                 "call": "9bf6ceb33b379ccf6fc36228f229c7ae",
-                "decode_paged": "aea24ead126ac52e7080c6d061988f63"},
+                "decode_paged": "d39a1d686e2ead09b2a542b7a9e6937d"},
 }
 DENSE = {"gpt2": {}, "mistral": dict(pos_encoding="rope", kv_heads=2,
                                      activation="swiglu", norm="rmsnorm")}

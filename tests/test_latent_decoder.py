@@ -180,10 +180,11 @@ def test_pool_is_one_row_a_token_and_the_allocator_says_its_bytes(
     cfg = model.cfg
     pools = gpt_lib.init_kv_pool(cfg, 96, PAGE)
     assert [tuple(x.shape for x in entry) for entry in pools] == [
-        ((96, PAGE, 32), (96, PAGE, 8))] * 3
+        ((97, PAGE, 32), (97, PAGE, 8))] * 3
     total = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(pools))
-    # pages x page x (latent + rotary key) x itemsize x layers
-    assert total == 96 * PAGE * 40 * 4 * 3
+    # (pages + the sentinel's) x page x (latent + rotary key) x itemsize
+    # x layers
+    assert total == 97 * PAGE * 40 * 4 * 3
     engine = engine_of(model, params)
     assert engine.stats()["kv_pool"]["row_bytes_per_token"] == 40 * 4 * 3
     fp8 = engine_of(model, params, quantize="int8", kv_dtype="float8")
@@ -281,6 +282,7 @@ def test_retire_region_carries_the_counters_for_a_sparse_model_only(
     retire = [s for n, s in seen if n == "serve.step.retire"]
     assert len(retire) == 2
     assert set(retire[0]) == {"pools_in_place", "sampled_lanes",
+                              "table_pages", "table_pages_held",
                               "experts_touched", "expert_slots",
                               "expert_tokens_max", "routed_tokens"}
     assert retire[0]["pools_in_place"] == 1
@@ -292,7 +294,8 @@ def test_retire_region_carries_the_counters_for_a_sparse_model_only(
     engine = engine_of(dense, dparams)
     serve(engine, Request([1, 2, 3], 2))
     assert [s for n, s in seen if n == "serve.step.retire"] == [
-        {"pools_in_place": 1, "sampled_lanes": 0}] * 2
+        {"pools_in_place": 1, "sampled_lanes": 0, "table_pages": 3 * 12,
+         "table_pages_held": 1}] * 2
     assert engine.stats()["moe"]["expert_slots"] == 0
 
 
@@ -383,7 +386,8 @@ def test_config_is_validated_like_layer_kinds(fields, message,
 def test_default_config_keeps_its_tree_and_its_kinds():
     """``GptConfig()`` names no latent rank and no expert: the parent's
     leaves, the parent's kinds, (keys, values) pool entries of a flat row
-    (4 heads of 32 side by side; PR 37) and the parent's bytes a token."""
+    (4 heads of 32 side by side; PR 37) with the sentinel's page after the
+    allocator's four (PR 39), and the parent's bytes a token."""
     cfg = gpt_lib.GptConfig()
     assert cfg.kinds == ("full_attention",) * 4
     assert cfg.sparse_layers == (False,) * 4 and cfg.rope_base == 10000.0
@@ -395,7 +399,7 @@ def test_default_config_keeps_its_tree_and_its_kinds():
                             "lm_head", "ln_final", "pos_emb", "word_emb"]
     pools = gpt_lib.init_kv_pool(cfg, 4, 8)
     assert [tuple(x.shape for x in e) for e in pools] == [
-        ((4, 8, 128), (4, 8, 128))] * 4
+        ((4 + 1, 8, 128), (4 + 1, 8, 128))] * 4
     assert gpt_lib.kv_row_bytes_per_token(cfg) == 4 * 2 * 4 * 32 * 2
 
 
@@ -403,13 +407,16 @@ def test_default_config_keeps_its_tree_and_its_kinds():
 #: rehearsal size as the configuration's file gives it (bfloat16), taken ON
 #: THE PARENT of PR 37 (commit 6906e1f) from a ``git archive`` of it by this
 #: function.  That PR holds a K/V pool's row flat and leaves the latent
-#: branch of ``_rows_entry`` alone: this cell's programs are its control.
-#: They hold for this sandbox's jax.
+#: branch of ``_rows_entry`` alone: this cell's programs were its control.
+#: Renewed in PR 39, which gives the latent pools the sentinel's page of
+#: zeros as it gives every paged pool (a sentinel entry of the table read
+#: ANOTHER lane's latents before; ``tests/test_sentinel_page.py``).  They
+#: hold for this sandbox's jax.
 LATENT_GOLDEN = {
-    "": ("07abcc0ac96000145ee3f272ab63434f",
-         "52ef68edfeb167989f1c0bec73b4d225"),
-    "float8": ("883f74dcf508cb7ca68e298119b91a52",
-               "5e22a5286132f9e503e71766de912d7b"),
+    "": ("4c441d479ce85551e7cba276e1441bf1",
+         "67b20a056996e2faf982299f7e1d7d84"),
+    "float8": ("991e2639716c05df18e3c08ada3c31fd",
+               "e5b80a0ecfe6167ff804fda8e7b7eb89"),
 }
 
 

@@ -153,7 +153,7 @@ def paged(model, params, P, steps=1):
     pools = gpt_lib.init_kv_pool(CFG, PAGES, PAGE)
     runs = gpt_lib.loop_step_pages(tables[None, :, :3],
                                    jnp.arange(R)[:, None, None],
-                                   R * PAGES, R)            # [R, 2, 3]
+                                   R * PAGES + 1, R)        # [R, 2, 3]
     pools = [tuple(pool.at[runs.reshape(-1)].set(
         c.reshape(R * 2 * 3, PAGE, -1)) for c, pool in zip(cache, entry))
         for cache, entry in zip(caches, pools)]
@@ -188,11 +188,11 @@ def test_row_t_l_holds_step_t_of_layer_l_and_nothing_else(
     """After a prefill of 6 and one decoded token, the pool of layer ``l``
     holds, in loop step ``t``'s run of pages, the straight-line forward's
     keys and values of application (t, l) at positions 0..6, and zeros
-    everywhere else."""
+    everywhere else, the sentinel's page after the last run among them."""
     model, params = model_and_params
     _, pools, tables = paged(model, params, 6)
     assert [tuple(x.shape for x in e) for e in pools] == [
-        ((R * PAGES, PAGE, HIDDEN),) * 2] * L
+        ((R * PAGES + 1, PAGE, HIDDEN),) * 2] * L
     for layer, entry in enumerate(pools):
         for which, pool in enumerate(entry):
             held = np.zeros(pool.shape, bool)
@@ -204,7 +204,7 @@ def test_row_t_l_holds_step_t_of_layer_l_and_nothing_else(
                     assert np.abs(rows - want[b][2][t][layer][which][
                         :7]).max() < LOGIT_TOL
                     held[t * PAGES + np.asarray(tables[b, :2])] = True
-            held = held.reshape(R * PAGES * PAGE, -1)
+            held = held.reshape((R * PAGES + 1) * PAGE, -1)
             for b in range(2):     # position 7 of a lane's second page
                 for t in range(R):
                     held[(t * PAGES + int(tables[b, 1])) * PAGE + 3] = False
@@ -244,9 +244,13 @@ def test_a_cached_token_holds_a_row_a_step_a_layer():
     assert gpt_lib.kv_row_bytes_per_token(CFG) == R * L * layer_row
     assert gpt_lib.kv_row_bytes_per_token(CFG, "float8_e4m3fn") \
         == R * L * layer_row // 4
-    # the not-allocated sentinel of a step's run lies one past the POOL
+    # the not-allocated sentinel of a step's run is the pool's LAST page,
+    # the one page of zeros after the last run; a write through it goes
+    # one past the pool
     pages = jnp.asarray([[0, 11, 12]])
-    assert gpt_lib.loop_step_pages(pages, 2, 36, 3).tolist() == [[24, 35, 36]]
+    runs = gpt_lib.loop_step_pages(pages, 2, 37, 3)
+    assert runs.tolist() == [[24, 35, 36]]
+    assert gpt_lib.written_pages(runs, 37).tolist() == [[24, 35, 37]]
 
 
 # --------------------------------------------------------- the engine
@@ -276,7 +280,7 @@ def test_the_engine_serves_the_straight_line_forwards_tokens(
     assert engine.stats()["kv_pool"]["row_bytes_per_token"] \
         == R * L * 2 * HIDDEN * 4
     assert [tuple(x.shape for x in e) for e in engine.pools] == [
-        ((R * 24, PAGE, HIDDEN),) * 2] * L
+        ((R * 24 + 1, PAGE, HIDDEN),) * 2] * L
     for P in (5, 9, 6):
         req = Request(TOKENS[0, :P].tolist(), 11 - P)
         engine.validate(req)
@@ -350,6 +354,7 @@ def test_retire_region_and_prefill_span_say_what_the_loop_holds(
     retire = [s for n, s in seen if n == "serve.step.retire"]
     assert len(retire) == 2
     assert set(retire[0]) == {"pools_in_place", "sampled_lanes",
+                              "table_pages", "table_pages_held",
                               "loop_steps_run", "loop_tokens",
                               "exit_step_expected_milli"}
     assert retire[0]["loop_steps_run"] == R and retire[0]["loop_tokens"] == 1
@@ -365,7 +370,8 @@ def test_retire_region_and_prefill_span_say_what_the_loop_holds(
     while engine.active_slots:
         engine.step()
     assert [s for n, s in seen if n == "serve.step.retire"] == [
-        {"pools_in_place": 1, "sampled_lanes": 0}] * 2
+        {"pools_in_place": 1, "sampled_lanes": 0, "table_pages": 3 * 8,
+         "table_pages_held": 2}] * 2
     assert engine.stats()["loop"]["loop_tokens"] == 0
 
 
@@ -466,28 +472,30 @@ TOYS = {
 #: ``git archive`` of it, with ``loop_steps`` and ``norm_placement`` left
 #: out of the call.  They hold for this sandbox's jax.  (``forward``,
 #: ``step`` and ``prefill`` of the first two are ``tests/test_hybrid_
-#: decoder.py``'s ``DENSE_GOLDEN`` too.)
+#: decoder.py``'s ``DENSE_GOLDEN`` too.)  ``step`` and ``prefill``, the
+#: programs that take a pool, were renewed in PR 39 (the sentinel's page:
+#: see there); ``tree``, ``forward`` and ``gradient`` are that parent's.
 GOLDEN = {
     "gpt2": {"tree": "3e7b6f16765211549f82057f5cca19fc",
              "forward": "7137ce905cc4f0b2dfb44c057f4e4108",
              "gradient": "6f5db47e76edc4c825c9992a6e6b527b",
-             "step": "a6d23b5975a3ff3973d31b9ebd5fea95",
-             "prefill": "b321613069b738430f2f6b72023a2735"},
+             "step": "5ec05fc1bc67d297e1edb18f3179c647",
+             "prefill": "10f03c9dc28018088d5830757b5eff88"},
     "mistral": {"tree": "16b51521a654c46c4d36da276efcfa0f",
                 "forward": "9bf6ceb33b379ccf6fc36228f229c7ae",
                 "gradient": "2688c1abe66bf9aca517141d175d5a80",
-                "step": "fbc7a70a8f556d530b4cd245aff4897d",
-                "prefill": "8d0f30cdf494a28a79ecda8eb8b6b474"},
+                "step": "0e7c64373e54105cdfc2d83448201063",
+                "prefill": "14102b8e4ea2976c947655f984d4ab96"},
     "hybrid": {"tree": "375fc190a908ae35f971b0d27989e1d8",
                "forward": "6b1d6ebd7623140ac611417744881995",
                "gradient": "cae4005cb5a97232d1b12fd9fea381ad",
-               "step": "c2384e33f59439160d008d99f9b9f1a8",
-               "prefill": "4d2c1aaa466380a4fd0eccb934fcc1e8"},
+               "step": "f8f3b12a6c8351a1e18a6e630f6ebfab",
+               "prefill": "e2b5f13e4aece8dfa6f51981fbfaaa79"},
     "latent": {"tree": "5d2de07b3a1f89be550bee1201fd1d22",
                "forward": "00a4194facfe1108845c006fd25d6fba",
                "gradient": "0adc222b3f8690bbc25e41b55cff9bdb",
-               "step": "ccd0c950391a2220e99b024cede7f545",
-               "prefill": "94cf764090d1d19cdfba913e6d268e87"},
+               "step": "ee52bc1e7e748e4438255a4e7baf6be9",
+               "prefill": "39ce17c3db1b874338915b98526581fc"},
 }
 
 

@@ -321,7 +321,9 @@ def test_the_profilers_retire_event_carries_sampled_lanes(monkeypatch):
     engine.admit(Request(PROMPT, 2))
     engine.step()
     retire = [s for n, s in seen if n == "serve.step.retire"]
-    assert retire == [{"pools_in_place": 1, "sampled_lanes": 2}]
+    # (beside it PR 39's count of the table: three lanes of 4 pages)
+    assert retire == [{"pools_in_place": 1, "sampled_lanes": 2,
+                       "table_pages": 3 * 8, "table_pages_held": 12}]
 
 
 def with_sampler(engine, fn, monkeypatch):

@@ -1,0 +1,246 @@
+"""The not-allocated sentinel of a page table is a PAGE: the one after the
+allocator's ``num_pages`` in every paged pool, all zeros, handed out by
+nobody and never written (PR 39; ``gpt_lib.init_kv_pool``).  The decode
+step's gather reads it like any page, so no pass blanks the gathered rows,
+and what holds is exact: **a lane's output depends on no page it does not
+own at that step**, non-finite values there included, in every paged form
+(K/V rows in a dense and in a hybrid decoder, the K/V chunk, a loop step's
+run of pages, a latent row's two parts).  On the parent of PR 39 the K/V
+cases hold too (``mode="fill"`` wrote the zeros) and the latent ones FAIL:
+its gather clipped a sentinel entry onto the pool's last real page, whose
+rows reach every lane's weighted sum as ``0 x row``.
+
+Tiny widths, float32, on the CPU; equal means bit for bit.
+"""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from distributed_tensorflow_tpu.models import gpt as gpt_lib
+from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
+                                                       EngineConfig)
+from distributed_tensorflow_tpu.serving.scheduler import Request
+from distributed_tensorflow_tpu.utils import profiling
+from distributed_tensorflow_tpu.utils.telemetry import Telemetry
+
+BASE = dict(vocab_size=64, hidden_size=32, num_layers=2, num_heads=4,
+            intermediate_size=48, max_position=64, dtype="float32")
+CONFIGS = {
+    "dense": dict(pos_encoding="rope", kv_heads=2, activation="swiglu",
+                  norm="rmsnorm"),
+    "hybrid": dict(
+        num_layers=4, pos_encoding="none", norm="rmsnorm",
+        activation="swiglu", norm_placement="post", qk_norm=True,
+        layer_kinds=("linear_attention",) * 3 + ("full_attention",),
+        linear_num_heads=2, linear_key_head_dim=8, linear_value_head_dim=16),
+    "looped": dict(pos_encoding="rope", activation="swiglu", norm="rmsnorm",
+                   norm_placement="sandwich", loop_steps=3, exit_gate=True),
+    "latent": dict(
+        num_layers=3, pos_encoding="none", activation="swiglu",
+        norm="rmsnorm", rope_base=1e6, latent_kv_rank=32, latent_q_rank=48,
+        qk_nope_head_dim=24, qk_rope_head_dim=8, v_head_dim=32,
+        num_experts=8, experts_per_token=2, expert_intermediate_size=32,
+        num_shared_experts=1, routed_scaling_factor=1.8,
+        first_dense_layers=1),
+}
+PAGES, PAGE, MP = 12, 4, 4
+S = PAGES                                  # the sentinel
+#: Three lanes of different lengths and an idle one.  Lane 1 fills its
+#: table; page 11, the allocator's last (what a clipped sentinel read), and
+#: pages 1, 4, 6, 8 are free.
+TABLES = np.asarray([[2, 5, S, S], [0, 3, 7, 9], [10, S, S, S], [S] * MP],
+                    np.int32)
+POSITIONS = np.asarray([6, 13, 2, 0], np.int32)
+TOKENS = np.asarray([3, 9, 17, 0], np.int32)
+
+
+def model_of(name):
+    model = gpt_lib.GptLM(gpt_lib.GptConfig(**{**BASE, **CONFIGS[name]}))
+    return model, model.init(jax.random.key(39),
+                             jnp.zeros((1, 8), jnp.int32))["params"]
+
+
+def paged(kind):
+    return kind != gpt_lib.LINEAR_ATTENTION
+
+
+def junk_pools(cfg, seed=0):
+    """Pools as a server that has run for a while holds them: every page
+    but the sentinel's holds what some owner wrote (a freed page is never
+    blanked), every slot a recurrent state."""
+    keys = iter(jax.random.split(jax.random.key(seed), 64))
+    pools = gpt_lib.init_kv_pool(cfg, PAGES, PAGE, num_slots=len(TABLES))
+    return [tuple(
+        jax.random.normal(next(keys), x.shape, x.dtype).at[-1].set(0)
+        if paged(kind) else jax.random.normal(next(keys), x.shape,
+                                                   x.dtype)
+        for x in entry) for kind, entry in zip(cfg.kinds, pools)]
+
+
+def not_owned_by(cfg, lane):
+    """[rows] bool over a pool's pages: those ``lane`` does not own, in
+    every loop step's run; never the sentinel's page, which is nobody's."""
+    own = set(TABLES[lane][TABLES[lane] < S].tolist())
+    other = np.asarray([p not in own for p in range(PAGES)])
+    return np.concatenate([np.tile(other, cfg.loop_steps), [False]])
+
+
+def poisoned(cfg, pools, lane, value):
+    rows = jnp.asarray(not_owned_by(cfg, lane))
+    return [tuple(jnp.where(rows[:, None, None], value, x)
+                  if paged(kind) else x for x in entry)
+            for kind, entry in zip(cfg.kinds, pools)]
+
+
+def sentinel_pages_are_zero(cfg, pools):
+    return all(not np.asarray(x[-1]).any()
+               for kind, entry in zip(cfg.kinds, pools) if paged(kind)
+               for x in entry)
+
+
+def run(model, params, program, pools):
+    tables, positions = jnp.asarray(TABLES), jnp.asarray(POSITIONS)
+    if program == "chunk":
+        # Lane 0's four tokens run past its two pages (positions 8, 9 have
+        # no page), lane 1's past its table (16: no entry at all).
+        chunk = jnp.stack([jnp.asarray(TOKENS)] * 4, axis=1) + jnp.arange(4)
+        return model.apply({"params": params}, chunk, pools, tables,
+                           positions,
+                           method=gpt_lib.GptLM.decode_chunk_paged)
+    live = tables[:, 0] < S
+    return model.apply({"params": params}, jnp.asarray(TOKENS), pools,
+                       tables, positions, live,
+                       method=gpt_lib.GptLM.decode_paged)
+
+
+FORMS = [("dense", "step"), ("dense", "chunk"), ("hybrid", "step"),
+         ("looped", "step"), ("latent", "step")]
+
+
+@pytest.mark.parametrize("value", [np.nan, np.inf], ids=["nan", "inf"])
+@pytest.mark.parametrize("name,program", FORMS,
+                         ids=["-".join(f) for f in FORMS])
+def test_a_lanes_logits_depend_on_no_page_it_does_not_own(
+        name, program, value):
+    model, params = model_of(name)
+    cfg = model.cfg
+    pools = junk_pools(cfg)
+    assert sentinel_pages_are_zero(cfg, pools)
+    assert all(x.shape[0] == cfg.loop_steps * PAGES + 1
+               for kind, entry in zip(cfg.kinds, pools) if paged(kind)
+               for x in entry)
+    want, after = jax.jit(lambda p: run(model, params, program, p))(pools)
+    want = np.asarray(want)
+    assert np.isfinite(want[:3]).all() and np.abs(want[:3]).max() > 0.1
+    assert sentinel_pages_are_zero(cfg, after)
+    for lane in range(3):
+        got, after = jax.jit(lambda p: run(model, params, program, p))(
+            poisoned(cfg, pools, lane, value))
+        got = np.asarray(got)
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(got[lane].view(np.uint32),
+                                      want[lane].view(np.uint32))
+        # (the poison was there to be read: a lane that owns a poisoned
+        # page reads it)
+        assert not np.isfinite(got[[i for i in range(3) if i != lane]]).all()
+        assert sentinel_pages_are_zero(cfg, after)
+
+
+def test_a_write_through_the_sentinel_goes_past_the_pool():
+    rows = 3 * PAGES + 1
+    pages = jnp.asarray([[0, PAGES - 1, PAGES]])
+    runs = gpt_lib.loop_step_pages(pages, 2, rows, 3)
+    assert runs.tolist() == [[2 * PAGES, 3 * PAGES - 1, rows - 1]]
+    assert gpt_lib.written_pages(runs, rows).tolist() == [
+        [2 * PAGES, 3 * PAGES - 1, rows]]
+    pool = jnp.ones((PAGES + 1, PAGE, 8)).at[-1].set(0)
+    table = jnp.asarray([[1, PAGES]])
+    assert gpt_lib.gather_pages(pool, table).shape == (1, 2 * PAGE, 8)
+    assert np.asarray(gpt_lib.gather_pages(pool, table))[0, PAGE:].max() == 0
+    wrote = pool.at[gpt_lib.written_pages(table[0], PAGES + 1)].set(
+        7.0, mode="drop")
+    assert np.asarray(wrote[1]).min() == 7 and not np.asarray(wrote[-1]).any()
+
+
+# ------------------------------------------------------------ the engine
+
+
+class Rows:
+    def __init__(self):
+        self.rows = []
+
+    def log(self, step, **fields):
+        self.rows.append(fields)
+
+
+def engine_of(name, records=None, **kw):
+    model, params = model_of(name)
+    return DecodeEngine(model, params, EngineConfig(
+        num_slots=3, page_size=PAGE, num_pages=PAGES, max_pages_per_seq=MP,
+        **kw), telemetry=None if records is None else Telemetry(records))
+
+
+@pytest.mark.parametrize("name,kw", [
+    ("dense", {}), ("dense", {"spec_k": 4}), ("dense", {"prefill_chunk": 3}),
+    ("hybrid", {}), ("looped", {}), ("latent", {})],
+    ids=["dense", "dense-spec", "dense-chunked", "hybrid", "looped",
+         "latent"])
+def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
+        name, kw, monkeypatch):
+    """A short run with admissions and retirements, a slot reused, a
+    prompt that ends inside a page and one that fills its bucket, (with
+    ``spec_k``) drafts past a lane's reservation: after every prefill and
+    every step the sentinel's page of every pool is all zeros, the
+    allocator never hands it out, and ``table_pages`` /
+    ``table_pages_held`` on the ``serve_step`` record, on the profiler's
+    retire event and in ``engine.stats()`` are a NumPy count of the table
+    each dispatch was handed."""
+    seen = []
+    real = profiling.annotate
+    monkeypatch.setattr(profiling, "annotate", lambda name, **stats: (
+        seen.append((name, stats)), real(name, **stats))[1])
+    records = Rows()
+    engine = engine_of(name, records, **kw)
+    cfg = engine.model.cfg
+    assert engine.allocator.num_pages == PAGES
+    assert engine.stats()["kv_pool"]["num_pages"] == PAGES
+    counted = []
+
+    def counting(fn):
+        def dispatch(tree, tokens, positions, tables, *rest):
+            table = np.asarray(tables)
+            counted.append({"table_pages": table.size,
+                            "table_pages_held": int((table < PAGES).sum())})
+            return fn(tree, tokens, positions, tables, *rest)
+        return dispatch
+    engine._step_fn = counting(engine._step_fn)
+    if engine._spec_step_fn is not None:
+        engine._spec_step_fn = counting(engine._spec_step_fn)
+
+    rng = np.random.default_rng(39)
+    waiting = [Request(rng.integers(0, 64, P).tolist(), n,
+                       speculative="spec_k" in kw)
+               for P, n in ((5, 4), (8, 3), (3, 6), (6, 2), (1, 9))]
+    while waiting or engine.active_slots:
+        while waiting and engine.can_admit(waiting[0]):
+            engine.admit(waiting.pop(0))
+            assert sentinel_pages_are_zero(cfg, engine.pools)
+        assert all(PAGES not in engine.allocator.owned(s.request.id)
+                   for s in engine._slots if s is not None)
+        engine.step()
+        assert sentinel_pages_are_zero(cfg, engine.pools)
+    steps = [r for r in records.rows if r.get("kind") == "serve_step"]
+    assert len(steps) == len(counted) > 8
+    assert [{k: r[k] for k in counted[0]} for r in steps] == counted
+    retire = [s for n, s in seen if n == "serve.step.retire"]
+    assert [{k: s[k] for k in counted[0]} for s in retire] == counted
+    stats = engine.stats()
+    assert stats["table_pages"] == 3 * MP * len(counted)
+    assert stats["table_pages_held"] == sum(
+        c["table_pages_held"] for c in counted)
+    # some entries were held and some read the sentinel's page
+    assert 0 < stats["table_pages_held"] < stats["table_pages"]
+    assert all(c["table_pages"] == 3 * MP for c in counted)
+    assert stats["pool_steps_copied"] == 0

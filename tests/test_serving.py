@@ -698,7 +698,8 @@ def _flat_row_case(heads, kv_heads, kv_dtype):
     """A two-layer model, contiguous caches [3, 12, G, D] of junk in the
     pool's dtype, and junk pools that hold each live lane's allocated
     pages of the same rows, built in the [.., G, D] view and handed over
-    as :func:`init_kv_pool` shapes them."""
+    as :func:`init_kv_pool` shapes them: the sentinel's page after the
+    allocator's ``_PAGES``, all zeros."""
     cfg = small_cfg(hidden_size=8 * heads, num_heads=heads,
                     kv_heads=kv_heads)
     model = gpt_lib.GptLM(cfg)
@@ -709,10 +710,11 @@ def _flat_row_case(heads, kv_heads, kv_dtype):
     for entry in gpt_lib.init_kv_pool(cfg, _PAGES, _PAGE, dtype=kv_dtype):
         cache_pair, pool_pair, view_pair = [], [], []
         for leaf in entry:
-            assert leaf.shape == (_PAGES, _PAGE, kv_heads * 8)
+            assert leaf.shape == (_PAGES + 1, _PAGE, kv_heads * 8)
             assert leaf.dtype == jnp.dtype(kv_dtype)
             cache = rng.normal(size=(3, _MP * _PAGE, kv_heads, 8))
-            view = rng.normal(size=(_PAGES, _PAGE, kv_heads, 8))
+            view = rng.normal(size=(_PAGES + 1, _PAGE, kv_heads, 8))
+            view[_PAGES] = 0
             for lane, table in enumerate(_TABLES):
                 for i, page in enumerate(table):
                     if page < _PAGES:
@@ -729,7 +731,8 @@ def _flat_row_case(heads, kv_heads, kv_dtype):
 def _expect_pools(views, caches_after, pools_after, written):
     """Every pool leaf, viewed [.., G, D], is what it was, except that the
     live lanes' slots ``written`` (lane -> logical positions) hold what
-    the contiguous path wrote there: bit for bit, idle lane included."""
+    the contiguous path wrote there: bit for bit, idle lane included, and
+    the sentinel's page still all zeros."""
     for view_pair, cache_pair, pool_pair in zip(views, caches_after,
                                                 pools_after):
         for view, cache, pool in zip(view_pair, cache_pair, pool_pair):
@@ -762,7 +765,8 @@ def test_flat_pool_row_is_the_contiguous_cache(
     itemsize = jnp.dtype(kv_dtype).itemsize
     assert gpt_lib.kv_row_bytes_per_token(cfg, kv_dtype) == (
         2 * 2 * kv_heads * 8 * itemsize) == sum(
-            x.nbytes for x in jax.tree.leaves(pools)) // (_PAGES * _PAGE)
+            x.nbytes for x in jax.tree.leaves(pools)) // (
+                (_PAGES + 1) * _PAGE)
     tables, positions = jnp.asarray(_TABLES), jnp.asarray(_POSITIONS)
     apply = lambda method, *a: jax.jit(  # noqa: E731
         lambda *a: model.apply({"params": params}, *a, method=method))(*a)
