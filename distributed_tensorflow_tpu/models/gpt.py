@@ -24,6 +24,7 @@ from ..ops import linear_attention as linear_ops
 from ..ops import routed_experts as experts_ops
 from ..ops.attention import dot_product_attention
 from ..parallel.sharding import ShardingRules
+from ..utils import profiling
 
 
 FULL_ATTENTION = "full_attention"
@@ -561,36 +562,40 @@ class GptBlock(nn.Module):
             return x
         return self.ln_attn(x).astype(jnp.dtype(self.cfg.dtype))
 
-    def _add_mixed(self, x: jax.Array, y: jax.Array,
+    def _add_mixed(self, x: jax.Array, ctx: jax.Array,
                    deterministic: bool = True) -> jax.Array:
-        """The residual add of the token mixer's output ``y``."""
-        if self.cfg.norm_placement == "post":
-            y = self.ln_attn(y).astype(x.dtype)
-        elif self.cfg.norm_placement == "sandwich":
-            y = self.ln_attn_post(y).astype(x.dtype)
-        return x + self.drop(y, deterministic=deterministic)
+        """The token mixer's ``ctx`` through its out projection, and the
+        residual add."""
+        with profiling.region("attn.out"):
+            y = self.out(ctx)
+            if self.cfg.norm_placement == "post":
+                y = self.ln_attn(y).astype(x.dtype)
+            elif self.cfg.norm_placement == "sandwich":
+                y = self.ln_attn_post(y).astype(x.dtype)
+            return x + self.drop(y, deterministic=deterministic)
 
     def _qkv(self, x: jax.Array, positions: jax.Array | None = None):
         """Returns q [B,S,H,D] and k/v [B,S,G,D] (G = kv heads; G == H in
         plain MHA)."""
-        cfg = self.cfg
-        h = self._mixer_in(x)
-        if cfg.num_kv_heads == cfg.num_heads:
-            qkv = self.qkv(h)
-            q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-        else:
-            q = self.q_proj(h)
-            kv = self.kv_proj(h)
-            k, v = kv[:, :, 0], kv[:, :, 1]
-        if cfg.qk_norm:
-            q = self.q_norm(q.reshape(*q.shape[:2], -1)).reshape(q.shape)
-            k = self.k_norm(k.reshape(*k.shape[:2], -1)).reshape(k.shape)
-        if cfg.pos_encoding == "rope":
-            if positions is None:
-                positions = jnp.arange(x.shape[1])
-            q = apply_rope(q, positions, cfg.rope_base)
-            k = apply_rope(k, positions, cfg.rope_base)
-        return q, k, v
+        with profiling.region("attn.qkv"):
+            cfg = self.cfg
+            h = self._mixer_in(x)
+            if cfg.num_kv_heads == cfg.num_heads:
+                qkv = self.qkv(h)
+                q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+            else:
+                q = self.q_proj(h)
+                kv = self.kv_proj(h)
+                k, v = kv[:, :, 0], kv[:, :, 1]
+            if cfg.qk_norm:
+                q = self.q_norm(q.reshape(*q.shape[:2], -1)).reshape(q.shape)
+                k = self.k_norm(k.reshape(*k.shape[:2], -1)).reshape(k.shape)
+            if cfg.pos_encoding == "rope":
+                if positions is None:
+                    positions = jnp.arange(x.shape[1])
+                q = apply_rope(q, positions, cfg.rope_base)
+                k = apply_rope(k, positions, cfg.rope_base)
+            return q, k, v
 
     def _expand_kv(self, kv: jax.Array) -> jax.Array:
         """Broadcast G kv heads up to the H query heads (on-chip repeat —
@@ -612,11 +617,11 @@ class GptBlock(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         h = self.ln_mlp(x).astype(dtype)
         flat = h.reshape(-1, cfg.hidden_size)
-        with jax.named_scope("moe.route"):
+        with profiling.region("moe.route"):
             chosen, weights = experts_ops.route(
                 self.router(flat), self.router_bias, cfg.experts_per_token,
                 cfg.routed_scaling_factor)
-        with jax.named_scope("moe.experts"):
+        with profiling.region("moe.experts"):
             rows = None if live is None else jnp.repeat(
                 live, flat.shape[0] // live.shape[0])
             y, counts = experts_ops.routed_experts(
@@ -626,61 +631,62 @@ class GptBlock(nn.Module):
         self.sow("routing", "counts", counts)
         y = y.reshape(x.shape)
         if cfg.num_shared_experts:
-            with jax.named_scope("moe.shared"):
+            with profiling.region("moe.shared"):
                 y = y + self.shared_out(
                     nn.silu(self.shared_gate(h)) * self.shared_in(h))
         return x + self.drop(y, deterministic=deterministic)
 
     def _mlp(self, x: jax.Array, deterministic: bool,
              live: jax.Array | None = None) -> jax.Array:
-        if self.sparse:
-            return self._experts(x, deterministic, live)
-        cfg = self.cfg
-        post = cfg.norm_placement == "post"
-        h = x if post else self.ln_mlp(x).astype(jnp.dtype(cfg.dtype))
-        if cfg.matmul_int8 and cfg.activation == "gelu" and not post:
-            from ..ops import quant_train
-            M = 1
-            for d in h.shape[:-1]:
-                M *= d
-            if quant_train.use_fused_mlp(M, cfg.hidden_size,
-                                         cfg.intermediate_size):
-                # Whole-MLP fused path: both layers' params come from the
-                # SAME submodules (identical checkpoint tree), computation
-                # runs through the pallas kernels with bias/gelu fused
-                # (see ops/quant_train.int8_gelu_mlp).
-                w_in, b_in = self.mlp_in(h, return_params=True)
-                w_out, b_out = self.mlp_out(
-                    jnp.zeros((0, cfg.intermediate_size), h.dtype),
-                    return_params=True)
-                # The residual add stays OUTSIDE the kernels by default:
-                # folding it into the second kernel's epilogue measured
-                # 7 ms/step slower (the extra input block degrades
-                # pipelining more than the saved XLA add pass).  The
-                # fused form stays wired behind FUSED_MLP_RESIDUAL so
-                # the trade re-measures in one line — dropout must be a
-                # no-op for it (the fused add cannot see the mask).
-                h2 = h.reshape(M, cfg.hidden_size)
-                if (quant_train.FUSED_MLP_RESIDUAL
-                        and (deterministic or cfg.dropout_rate == 0.0)):
-                    y = quant_train.int8_gelu_mlp_res(
-                        h2, w_in, b_in, w_out, b_out,
-                        x.reshape(M, cfg.hidden_size))
-                    return y.reshape(x.shape)
-                y = quant_train.int8_gelu_mlp(h2, w_in, b_in, w_out,
-                                              b_out)
-                return x + self.drop(y.reshape(x.shape),
-                                     deterministic=deterministic)
-        if cfg.activation == "swiglu":
-            h = nn.silu(self.mlp_gate(h)) * self.mlp_in(h)
-        else:
-            h = nn.gelu(self.mlp_in(h))
-        h = self.mlp_out(h)
-        if post:
-            h = self.ln_mlp(h).astype(x.dtype)
-        elif cfg.norm_placement == "sandwich":
-            h = self.ln_mlp_post(h).astype(x.dtype)
-        return x + self.drop(h, deterministic=deterministic)
+        with profiling.region("mlp"):
+            if self.sparse:
+                return self._experts(x, deterministic, live)
+            cfg = self.cfg
+            post = cfg.norm_placement == "post"
+            h = x if post else self.ln_mlp(x).astype(jnp.dtype(cfg.dtype))
+            if cfg.matmul_int8 and cfg.activation == "gelu" and not post:
+                from ..ops import quant_train
+                M = 1
+                for d in h.shape[:-1]:
+                    M *= d
+                if quant_train.use_fused_mlp(M, cfg.hidden_size,
+                                             cfg.intermediate_size):
+                    # Whole-MLP fused path: both layers' params come from the
+                    # SAME submodules (identical checkpoint tree), computation
+                    # runs through the pallas kernels with bias/gelu fused
+                    # (see ops/quant_train.int8_gelu_mlp).
+                    w_in, b_in = self.mlp_in(h, return_params=True)
+                    w_out, b_out = self.mlp_out(
+                        jnp.zeros((0, cfg.intermediate_size), h.dtype),
+                        return_params=True)
+                    # The residual add stays OUTSIDE the kernels by default:
+                    # folding it into the second kernel's epilogue measured
+                    # 7 ms/step slower (the extra input block degrades
+                    # pipelining more than the saved XLA add pass).  The
+                    # fused form stays wired behind FUSED_MLP_RESIDUAL so
+                    # the trade re-measures in one line — dropout must be a
+                    # no-op for it (the fused add cannot see the mask).
+                    h2 = h.reshape(M, cfg.hidden_size)
+                    if (quant_train.FUSED_MLP_RESIDUAL
+                            and (deterministic or cfg.dropout_rate == 0.0)):
+                        y = quant_train.int8_gelu_mlp_res(
+                            h2, w_in, b_in, w_out, b_out,
+                            x.reshape(M, cfg.hidden_size))
+                        return y.reshape(x.shape)
+                    y = quant_train.int8_gelu_mlp(h2, w_in, b_in, w_out,
+                                                  b_out)
+                    return x + self.drop(y.reshape(x.shape),
+                                         deterministic=deterministic)
+            if cfg.activation == "swiglu":
+                h = nn.silu(self.mlp_gate(h)) * self.mlp_in(h)
+            else:
+                h = nn.gelu(self.mlp_in(h))
+            h = self.mlp_out(h)
+            if post:
+                h = self.ln_mlp(h).astype(x.dtype)
+            elif cfg.norm_placement == "sandwich":
+                h = self.ln_mlp_post(h).astype(x.dtype)
+            return x + self.drop(h, deterministic=deterministic)
 
     def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
         if self.kind == LINEAR_ATTENTION:
@@ -688,11 +694,12 @@ class GptBlock(nn.Module):
         if self.kind == LATENT_ATTENTION:
             return self.latent_mix(x, deterministic)
         q, k, v = self._qkv(x)
-        ctx = dot_product_attention(q, self._expand_kv(k), self._expand_kv(v),
-                                    causal=True,
-                                    window=self.cfg.attention_window,
-                                    backend=self.cfg.attention_backend)
-        x = self._add_mixed(x, self.out(ctx), deterministic)
+        with profiling.region("attn.scores"):
+            ctx = dot_product_attention(
+                q, self._expand_kv(k), self._expand_kv(v), causal=True,
+                window=self.cfg.attention_window,
+                backend=self.cfg.attention_backend)
+        x = self._add_mixed(x, ctx, deterministic)
         return self._mlp(x, deterministic)
 
     # ---------------------------------------------- linear attention
@@ -705,36 +712,39 @@ class GptBlock(nn.Module):
         Dk^-1/2), k (unit), v [B,T,H,Dv], the log decay ``g`` and the step
         ``beta`` [B,T,H], all float32.  Where ``keep`` [B, T] is False the
         token is made to change nothing (g = 0, beta = 0)."""
-        cfg = self.cfg
-        H, Dk = cfg.linear_num_heads, cfg.linear_key_head_dim
-        B, T = x.shape[:2]
-        h = self._mixer_in(x)
-        raw = jnp.concatenate(
-            [self.q_proj(h), self.k_proj(h), self.v_proj(h)], axis=-1)
-        mixed = nn.silu(linear_ops.causal_conv(raw, self.conv_taps, tail))
-        q, k, v = jnp.split(mixed, [H * Dk, 2 * H * Dk], axis=-1)
-        q = linear_ops.l2_normalize(q.reshape(B, T, H, Dk)) * Dk ** -0.5
-        k = linear_ops.l2_normalize(k.reshape(B, T, H, Dk))
-        v = v.reshape(B, T, H, -1)
-        beta = nn.sigmoid(self.b_proj(h).astype(jnp.float32))
-        if cfg.linear_allow_neg_eigval:
-            beta = 2.0 * beta
-        g = -jnp.exp(self.A_log.astype(jnp.float32)) * nn.softplus(
-            self.a_proj(h).astype(jnp.float32)
-            + self.dt_bias.astype(jnp.float32))
-        if keep is not None:
-            g = jnp.where(keep[..., None], g, 0.0)
-            beta = jnp.where(keep[..., None], beta, 0.0)
-        return h, raw, q, k, v, g, beta
+        with profiling.region("attn.qkv"):
+            cfg = self.cfg
+            H, Dk = cfg.linear_num_heads, cfg.linear_key_head_dim
+            B, T = x.shape[:2]
+            h = self._mixer_in(x)
+            raw = jnp.concatenate(
+                [self.q_proj(h), self.k_proj(h), self.v_proj(h)], axis=-1)
+            mixed = nn.silu(linear_ops.causal_conv(raw, self.conv_taps, tail))
+            q, k, v = jnp.split(mixed, [H * Dk, 2 * H * Dk], axis=-1)
+            q = linear_ops.l2_normalize(q.reshape(B, T, H, Dk)) * Dk ** -0.5
+            k = linear_ops.l2_normalize(k.reshape(B, T, H, Dk))
+            v = v.reshape(B, T, H, -1)
+            beta = nn.sigmoid(self.b_proj(h).astype(jnp.float32))
+            if cfg.linear_allow_neg_eigval:
+                beta = 2.0 * beta
+            g = -jnp.exp(self.A_log.astype(jnp.float32)) * nn.softplus(
+                self.a_proj(h).astype(jnp.float32)
+                + self.dt_bias.astype(jnp.float32))
+            if keep is not None:
+                g = jnp.where(keep[..., None], g, 0.0)
+                beta = jnp.where(keep[..., None], beta, 0.0)
+            return h, raw, q, k, v, g, beta
 
     def _linear_close(self, x: jax.Array, h: jax.Array, o: jax.Array,
                       deterministic: bool = True) -> jax.Array:
         """From the rule's output ``o`` [B,T,H,Dv] to the block's: the
         gated per-head norm, the output projection, the residual add and
         the MLP."""
-        gate = nn.silu(self.g_proj(h).astype(jnp.float32)).reshape(o.shape)
-        y = (self.o_norm(o) * gate).astype(jnp.dtype(self.cfg.dtype))
-        x = self._add_mixed(x, self.out(y), deterministic)
+        with profiling.region("attn.out"):
+            gate = nn.silu(
+                self.g_proj(h).astype(jnp.float32)).reshape(o.shape)
+            y = (self.o_norm(o) * gate).astype(jnp.dtype(self.cfg.dtype))
+        x = self._add_mixed(x, y, deterministic)
         return self._mlp(x, deterministic)
 
     def linear_mix(self, x: jax.Array, deterministic: bool = True):
@@ -754,9 +764,10 @@ class GptBlock(nn.Module):
         keep = jnp.arange(x.shape[1])[None, :] < lengths[:, None]
         h, raw, q, k, v, g, beta = self._linear_inputs(x, tail, keep)
         o, state = linear_ops.gated_delta_chunked(q, k, v, g, beta, state)
-        new_tail = linear_ops.conv_tail(
-            jnp.concatenate([tail, raw.astype(tail.dtype)], axis=1),
-            lengths + tail.shape[1], tail.shape[1])
+        with profiling.region("cache.write"):
+            new_tail = linear_ops.conv_tail(
+                jnp.concatenate([tail, raw.astype(tail.dtype)], axis=1),
+                lengths + tail.shape[1], tail.shape[1])
         return self._linear_close(x, h, o), state, new_tail
 
     def linear_decode_step(self, x: jax.Array, state: jax.Array,
@@ -768,9 +779,10 @@ class GptBlock(nn.Module):
                                                        live[:, None])
         o, state = linear_ops.gated_delta_step(
             q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], state)
-        shifted = jnp.concatenate([tail[:, 1:], raw.astype(tail.dtype)],
-                                  axis=1)
-        tail = jnp.where(live[:, None, None], shifted, tail)
+        with profiling.region("cache.write"):
+            shifted = jnp.concatenate(
+                [tail[:, 1:], raw.astype(tail.dtype)], axis=1)
+            tail = jnp.where(live[:, None, None], shifted, tail)
         return self._linear_close(x, h, o[:, None]), state, tail
 
     # ---------------------------------------------- latent attention
@@ -781,34 +793,36 @@ class GptBlock(nn.Module):
         rotated part [B,T,H,rope], and the token's cache row in its two
         parts: the latent AFTER its norm [B,T,latent_kv_rank] and the one
         key all heads share AFTER its rotation [B,T,rope]."""
-        cfg = self.cfg
-        h = self._mixer_in(x)
-        q = self.q_b(self.q_a_norm(self.q_a(h)))
-        q_nope, q_rot = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
-        q_rot = apply_rope(q_rot, positions, cfg.rope_base)
-        kv = self.kv_a(h)
-        latent = self.kv_a_norm(kv[..., :cfg.latent_kv_rank])
-        k_rot = apply_rope(kv[..., None, cfg.latent_kv_rank:], positions,
-                           cfg.rope_base)[:, :, 0]
-        return q_nope, q_rot, latent, k_rot
+        with profiling.region("attn.qkv"):
+            cfg = self.cfg
+            h = self._mixer_in(x)
+            q = self.q_b(self.q_a_norm(self.q_a(h)))
+            q_nope, q_rot = jnp.split(q, [cfg.qk_nope_head_dim], axis=-1)
+            q_rot = apply_rope(q_rot, positions, cfg.rope_base)
+            kv = self.kv_a(h)
+            latent = self.kv_a_norm(kv[..., :cfg.latent_kv_rank])
+            k_rot = apply_rope(kv[..., None, cfg.latent_kv_rank:], positions,
+                               cfg.rope_base)[:, :, 0]
+            return q_nope, q_rot, latent, k_rot
 
     def _latent_attend(self, x: jax.Array, backend: str):
         """EXPANDED form over the whole sequence: per-head keys (the part
         expanded from the latent beside the shared rotated key) and values
-        through the attention backend.  Returns (the mixer's output
-        [B,T,hidden], the cache row's two parts)."""
+        through the attention backend.  Returns (the heads' contexts
+        [B,T,H,v_head_dim], the cache row's two parts)."""
         cfg = self.cfg
         q_nope, q_rot, latent, k_rot = self._latent_q_row(
             x, jnp.arange(x.shape[1]))
-        with jax.named_scope("mla.expand"):
+        with profiling.region("mla.expand"):
             k_nope, v = jnp.split(self.kv_b(latent),
                                   [cfg.qk_nope_head_dim], axis=-1)
             k = jnp.concatenate([k_nope, jnp.broadcast_to(
                 k_rot[:, :, None, :], (*k_nope.shape[:3],
                                        cfg.qk_rope_head_dim))], axis=-1)
             q = jnp.concatenate([q_nope, q_rot], axis=-1)
-        ctx = dot_product_attention(q, k, v, causal=True, backend=backend)
-        return self.out(ctx), latent, k_rot
+        with profiling.region("attn.scores"):
+            ctx = dot_product_attention(q, k, v, causal=True, backend=backend)
+        return ctx, latent, k_rot
 
     def latent_mix(self, x: jax.Array, deterministic: bool = True):
         """The whole sequence, nothing cached (the training forward)."""
@@ -848,21 +862,23 @@ class GptBlock(nn.Module):
         MP = page_table.shape[1]
         q_nope, q_rot, latent, k_rot = self._latent_q_row(
             x, positions[:, None])
-        lpage = (positions // page).astype(jnp.int32)
-        off = (positions % page).astype(jnp.int32)
-        phys = written_pages(jnp.take_along_axis(
-            page_table, jnp.clip(lpage, 0, MP - 1)[:, None], axis=1)[:, 0],
-            latent_pool.shape[0])
-        latent_pool = latent_pool.at[phys, off].set(
-            latent[:, 0].astype(latent_pool.dtype), mode="drop")
-        key_pool = key_pool.at[phys, off].set(
-            k_rot[:, 0].astype(key_pool.dtype), mode="drop")
-        s = jnp.arange(MP * page)
-        allocated = jnp.take_along_axis(
-            page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
-        valid = (s[None, :] <= positions[:, None]) & allocated
+        with profiling.region("cache.write"):
+            lpage = (positions // page).astype(jnp.int32)
+            off = (positions % page).astype(jnp.int32)
+            phys = written_pages(jnp.take_along_axis(
+                page_table, jnp.clip(lpage, 0, MP - 1)[:, None],
+                axis=1)[:, 0], latent_pool.shape[0])
+            latent_pool = latent_pool.at[phys, off].set(
+                latent[:, 0].astype(latent_pool.dtype), mode="drop")
+            key_pool = key_pool.at[phys, off].set(
+                k_rot[:, 0].astype(key_pool.dtype), mode="drop")
+        with profiling.region("cache.gather"):
+            s = jnp.arange(MP * page)
+            allocated = jnp.take_along_axis(
+                page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
+            valid = (s[None, :] <= positions[:, None]) & allocated
         compute = q_nope.dtype
-        with jax.named_scope("mla.absorb"):
+        with profiling.region("mla.absorb"):
             w_k, w_v = jnp.split(
                 self.kv_b.variables["params"]["kernel"].astype(compute),
                 [cfg.qk_nope_head_dim], axis=-1)
@@ -880,7 +896,7 @@ class GptBlock(nn.Module):
             weights = jax.nn.softmax(logits, axis=-1).astype(compute)
             mean = jnp.einsum("bhs,bsc->bhc", weights, latents)
             ctx = jnp.einsum("bhc,chd->bhd", mean, w_v)
-        x = self._add_mixed(x, self.out(ctx[:, None]))
+        x = self._add_mixed(x, ctx[:, None])
         return self._mlp(x, True, live), latent_pool, key_pool
 
     def _write_prefill(self, cache: jax.Array, fresh: jax.Array) -> jax.Array:
@@ -890,12 +906,13 @@ class GptBlock(nn.Module):
         cache (sliding window, M < P): only the last M positions matter —
         position p lives at slot ``p % M``, which for the contiguous tail
         is a roll by ``(P - M) % M``."""
-        P, M = fresh.shape[1], cache.shape[1]
-        fresh = fresh.astype(cache.dtype)
-        if P <= M:
-            return jax.lax.dynamic_update_slice_in_dim(cache, fresh, 0,
-                                                       axis=1)
-        return jnp.roll(fresh[:, P - M:], (P - M) % M, axis=1)
+        with profiling.region("cache.write"):
+            P, M = fresh.shape[1], cache.shape[1]
+            fresh = fresh.astype(cache.dtype)
+            if P <= M:
+                return jax.lax.dynamic_update_slice_in_dim(cache, fresh, 0,
+                                                           axis=1)
+            return jnp.roll(fresh[:, P - M:], (P - M) % M, axis=1)
 
     def _write_prefill_ragged(self, cache: jax.Array, fresh: jax.Array,
                               lengths: jax.Array) -> jax.Array:
@@ -911,14 +928,15 @@ class GptBlock(nn.Module):
         ragged-safe: with slot reuse, a junk pad written at slot ``s``
         would alias a masked-in real position — so it is never written.
         """
-        P, M = fresh.shape[1], cache.shape[1]
-        lb1 = (lengths - 1).astype(jnp.int32)                    # [B]
-        s = jnp.arange(M)
-        p_star = lb1[:, None] - ((lb1[:, None] - s[None, :]) % M)  # [B, M]
-        src = jnp.take_along_axis(
-            fresh, jnp.clip(p_star, 0, P - 1)[..., None, None], axis=1)
-        return jnp.where((p_star >= 0)[..., None, None],
-                         src.astype(cache.dtype), cache)
+        with profiling.region("cache.write"):
+            P, M = fresh.shape[1], cache.shape[1]
+            lb1 = (lengths - 1).astype(jnp.int32)                    # [B]
+            s = jnp.arange(M)
+            p_star = lb1[:, None] - ((lb1[:, None] - s[None, :]) % M)  # [B, M]
+            src = jnp.take_along_axis(
+                fresh, jnp.clip(p_star, 0, P - 1)[..., None, None], axis=1)
+            return jnp.where((p_star >= 0)[..., None, None],
+                             src.astype(cache.dtype), cache)
 
     def prefill(self, x: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
                 lengths: jax.Array | None = None):
@@ -942,11 +960,11 @@ class GptBlock(nn.Module):
         # XLA attention for them.
         backend = ("xla" if self.cfg.attention_backend in ("ring", "ulysses")
                    else self.cfg.attention_backend)
-        ctx = dot_product_attention(q, self._expand_kv(k), self._expand_kv(v),
-                                    causal=True,
-                                    window=self.cfg.attention_window,
-                                    backend=backend)
-        x = self._add_mixed(x, self.out(ctx))
+        with profiling.region("attn.scores"):
+            ctx = dot_product_attention(
+                q, self._expand_kv(k), self._expand_kv(v), causal=True,
+                window=self.cfg.attention_window, backend=backend)
+        x = self._add_mixed(x, ctx)
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
     def _check_ring(self, M: int) -> None:
@@ -976,21 +994,22 @@ class GptBlock(nn.Module):
         into [G, H/G] and attends the G-head cache directly — no
         materialized H-head expansion, so cache reads stay at G heads.
         """
-        cfg = self.cfg
-        depth = q.shape[-1]
-        scale = 1.0 / jnp.sqrt(jnp.float32(depth))
-        compute = q.dtype
-        B, Q = q.shape[0], q.shape[1]
-        G, R = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
-        qg = q.reshape(B, Q, G, R, depth)
-        logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg,
-                            k_cache.astype(compute),
-                            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(valid, logits, jnp.finfo(jnp.float32).min)
-        weights = jax.nn.softmax(logits, axis=-1)
-        ctx = jnp.einsum("bgrqk,bkgd->bqgrd", weights.astype(compute),
-                         v_cache.astype(compute))
-        return ctx.reshape(B, Q, cfg.num_heads, depth)
+        with profiling.region("attn.scores"):
+            cfg = self.cfg
+            depth = q.shape[-1]
+            scale = 1.0 / jnp.sqrt(jnp.float32(depth))
+            compute = q.dtype
+            B, Q = q.shape[0], q.shape[1]
+            G, R = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
+            qg = q.reshape(B, Q, G, R, depth)
+            logits = jnp.einsum("bqgrd,bkgd->bgrqk", qg,
+                                k_cache.astype(compute),
+                                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(valid, logits, jnp.finfo(jnp.float32).min)
+            weights = jax.nn.softmax(logits, axis=-1)
+            ctx = jnp.einsum("bgrqk,bkgd->bqgrd", weights.astype(compute),
+                             v_cache.astype(compute))
+            return ctx.reshape(B, Q, cfg.num_heads, depth)
 
     def _attend_rows(self, q: jax.Array, k_rows: jax.Array,
                      v_rows: jax.Array, valid: jax.Array) -> jax.Array:
@@ -1007,20 +1026,21 @@ class GptBlock(nn.Module):
         float32 copy of the keys among them: PERF.md, PR 37); the G-fold
         products ride in the shadow of reading the rows once.
         """
-        B, _, H, depth = q.shape
-        G = self.cfg.num_kv_heads
-        scale = 1.0 / jnp.sqrt(jnp.float32(depth))
-        own = (jnp.arange(H)[:, None] // (H // G)
-               == jnp.arange(G)[None, :])[None, :, :, None]     # [1,H,G,1]
-        wide = jnp.where(own, q[:, 0, :, None, :], 0).reshape(B, H, -1)
-        logits = jnp.einsum("bhc,bkc->bhk", wide, k_rows.astype(q.dtype),
-                            preferred_element_type=jnp.float32) * scale
-        logits = jnp.where(valid[:, None, :], logits,
-                           jnp.finfo(jnp.float32).min)
-        weights = jax.nn.softmax(logits, axis=-1)
-        ctx = jnp.einsum("bhk,bkc->bhc", weights.astype(q.dtype),
-                         v_rows.astype(q.dtype)).reshape(B, H, G, depth)
-        return jnp.where(own, ctx, 0).sum(axis=2)[:, None]
+        with profiling.region("attn.scores"):
+            B, _, H, depth = q.shape
+            G = self.cfg.num_kv_heads
+            scale = 1.0 / jnp.sqrt(jnp.float32(depth))
+            own = (jnp.arange(H)[:, None] // (H // G)
+                   == jnp.arange(G)[None, :])[None, :, :, None]     # [1,H,G,1]
+            wide = jnp.where(own, q[:, 0, :, None, :], 0).reshape(B, H, -1)
+            logits = jnp.einsum("bhc,bkc->bhk", wide, k_rows.astype(q.dtype),
+                                preferred_element_type=jnp.float32) * scale
+            logits = jnp.where(valid[:, None, :], logits,
+                               jnp.finfo(jnp.float32).min)
+            weights = jax.nn.softmax(logits, axis=-1)
+            ctx = jnp.einsum("bhk,bkc->bhc", weights.astype(q.dtype),
+                             v_rows.astype(q.dtype)).reshape(B, H, G, depth)
+            return jnp.where(own, ctx, 0).sum(axis=2)[:, None]
 
     def _attend_cache_chunk(self, q: jax.Array, k_cache: jax.Array,
                             v_cache: jax.Array, k_new: jax.Array,
@@ -1044,26 +1064,28 @@ class GptBlock(nn.Module):
         (the key set is identical), so chunk logits equal sequential
         decode logits to float tolerance.
         """
-        cfg = self.cfg
-        depth = q.shape[-1]
-        scale = 1.0 / jnp.sqrt(jnp.float32(depth))
-        compute = q.dtype
-        B, K, M = q.shape[0], q.shape[1], k_cache.shape[1]
-        G, R = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
-        qg = q.reshape(B, K, G, R, depth)
-        neg = jnp.finfo(jnp.float32).min
-        lp = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache.astype(compute),
-                        preferred_element_type=jnp.float32) * scale
-        lp = jnp.where(prefix_valid[:, None, None, None, :], lp, neg)
-        lc = jnp.einsum("bqgrd,bjgd->bgrqj", qg, k_new.astype(compute),
-                        preferred_element_type=jnp.float32) * scale
-        lc = jnp.where(chunk_valid, lc, neg)
-        w = jax.nn.softmax(jnp.concatenate([lp, lc], axis=-1), axis=-1)
-        ctx = (jnp.einsum("bgrqk,bkgd->bqgrd", w[..., :M].astype(compute),
-                          v_cache.astype(compute))
-               + jnp.einsum("bgrqj,bjgd->bqgrd", w[..., M:].astype(compute),
-                            v_new.astype(compute)))
-        return ctx.reshape(B, K, cfg.num_heads, depth)
+        with profiling.region("attn.scores"):
+            cfg = self.cfg
+            depth = q.shape[-1]
+            scale = 1.0 / jnp.sqrt(jnp.float32(depth))
+            compute = q.dtype
+            B, K, M = q.shape[0], q.shape[1], k_cache.shape[1]
+            G, R = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
+            qg = q.reshape(B, K, G, R, depth)
+            neg = jnp.finfo(jnp.float32).min
+            lp = jnp.einsum("bqgrd,bkgd->bgrqk", qg, k_cache.astype(compute),
+                            preferred_element_type=jnp.float32) * scale
+            lp = jnp.where(prefix_valid[:, None, None, None, :], lp, neg)
+            lc = jnp.einsum("bqgrd,bjgd->bgrqj", qg, k_new.astype(compute),
+                            preferred_element_type=jnp.float32) * scale
+            lc = jnp.where(chunk_valid, lc, neg)
+            w = jax.nn.softmax(jnp.concatenate([lp, lc], axis=-1), axis=-1)
+            ctx = (jnp.einsum("bgrqk,bkgd->bqgrd", w[..., :M].astype(compute),
+                              v_cache.astype(compute))
+                   + jnp.einsum("bgrqj,bjgd->bqgrd",
+                                w[..., M:].astype(compute),
+                                v_new.astype(compute)))
+            return ctx.reshape(B, K, cfg.num_heads, depth)
 
     def decode_step(self, x: jax.Array, k_cache: jax.Array,
                     v_cache: jax.Array, position: jax.Array):
@@ -1085,10 +1107,11 @@ class GptBlock(nn.Module):
         self._check_ring(M)
         slot = position % M
         q, k, v = self._qkv(x, positions=position[None])  # [B, 1, H, D]
-        k_cache = jax.lax.dynamic_update_slice_in_dim(
-            k_cache, k.astype(k_cache.dtype), slot, axis=1)
-        v_cache = jax.lax.dynamic_update_slice_in_dim(
-            v_cache, v.astype(v_cache.dtype), slot, axis=1)
+        with profiling.region("cache.write"):
+            k_cache = jax.lax.dynamic_update_slice_in_dim(
+                k_cache, k.astype(k_cache.dtype), slot, axis=1)
+            v_cache = jax.lax.dynamic_update_slice_in_dim(
+                v_cache, v.astype(v_cache.dtype), slot, axis=1)
         # Slot s holds absolute position  position - ((position - s) mod M)
         # ∈ [position - M + 1, position]: with M == attention_window every
         # written slot is inside the band BY CONSTRUCTION (training's
@@ -1098,7 +1121,7 @@ class GptBlock(nn.Module):
         valid = (k_slot <= position) | (position >= M)
         ctx = self._attend_cache(q, k_cache, v_cache,
                                  valid[None, None, None, None, :])
-        x = self._add_mixed(x, self.out(ctx))
+        x = self._add_mixed(x, ctx)
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
     def decode_step_ragged(self, x: jax.Array, k_cache: jax.Array,
@@ -1124,16 +1147,17 @@ class GptBlock(nn.Module):
         slot = (positions % M).astype(jnp.int32)
         q, k, v = self._qkv(x, positions=positions[:, None])  # [B,1,G,D]
         rows = jnp.arange(B)
-        k_cache = k_cache.at[rows, slot].set(k[:, 0].astype(k_cache.dtype),
-                                             mode="drop")
-        v_cache = v_cache.at[rows, slot].set(v[:, 0].astype(v_cache.dtype),
-                                             mode="drop")
+        with profiling.region("cache.write"):
+            k_cache = k_cache.at[rows, slot].set(
+                k[:, 0].astype(k_cache.dtype), mode="drop")
+            v_cache = v_cache.at[rows, slot].set(
+                v[:, 0].astype(v_cache.dtype), mode="drop")
         k_slot = jnp.arange(M)
         valid = ((k_slot[None, :] <= positions[:, None])
                  | (positions[:, None] >= M))                  # [B, M]
         ctx = self._attend_cache(q, k_cache, v_cache,
                                  valid[:, None, None, None, :])
-        x = self._add_mixed(x, self.out(ctx))
+        x = self._add_mixed(x, ctx)
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
     def decode_chunk(self, x: jax.Array, k_cache: jax.Array,
@@ -1199,8 +1223,9 @@ class GptBlock(nn.Module):
         # finisher) deliberately let already-finished rows' positions run
         # past capacity, and an OOB write must vanish — a clamping
         # primitive here would corrupt the last cache slot.
-        k_cache = k_cache.at[rows, slot].set(k, mode="drop")
-        v_cache = v_cache.at[rows, slot].set(v, mode="drop")
+        with profiling.region("cache.write"):
+            k_cache = k_cache.at[rows, slot].set(k, mode="drop")
+            v_cache = v_cache.at[rows, slot].set(v, mode="drop")
         # Committed prefix: slots strictly before the row's frontier.
         # Slots at/past it hold this chunk (attended fresh) or junk from
         # rejected speculative writes — masked until real tokens arrive.
@@ -1208,7 +1233,7 @@ class GptBlock(nn.Module):
         ctx = self._attend_cache_chunk(
             q, k_cache, v_cache, k, v, prefix_valid,
             chunk_valid[None, None, None, :, :])
-        x = self._add_mixed(x, self.out(ctx))
+        x = self._add_mixed(x, ctx)
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
     def decode_chunk_paged(self, x: jax.Array, k_pool: jax.Array,
@@ -1245,28 +1270,32 @@ class GptBlock(nn.Module):
         K = x.shape[1]
         pos = positions[:, None] + jnp.arange(K)[None, :]        # [B, K]
         q, k, v = self._qkv(x, positions=pos)                    # [B,K,*,D]
-        lpage = (pos // page).astype(jnp.int32)
-        off = (pos % page).astype(jnp.int32)
-        phys = jnp.take_along_axis(page_table,
-                                   jnp.clip(lpage, 0, MP - 1), axis=1)
-        phys = written_pages(jnp.where(lpage < MP, phys, sentinel),
-                             k_pool.shape[0])
-        # Cache-dtype round trip before attending (see decode_chunk).
-        k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
-        k_pool = k_pool.at[phys, off].set(k.reshape(B, K, -1), mode="drop")
-        v_pool = v_pool.at[phys, off].set(v.reshape(B, K, -1), mode="drop")
+        with profiling.region("cache.write"):
+            lpage = (pos // page).astype(jnp.int32)
+            off = (pos % page).astype(jnp.int32)
+            phys = jnp.take_along_axis(page_table,
+                                       jnp.clip(lpage, 0, MP - 1), axis=1)
+            phys = written_pages(jnp.where(lpage < MP, phys, sentinel),
+                                 k_pool.shape[0])
+            # Cache-dtype round trip before attending (see decode_chunk).
+            k, v = k.astype(k_pool.dtype), v.astype(v_pool.dtype)
+            k_pool = k_pool.at[phys, off].set(k.reshape(B, K, -1),
+                                              mode="drop")
+            v_pool = v_pool.at[phys, off].set(v.reshape(B, K, -1),
+                                              mode="drop")
         def gather(pool):
             return gather_pages(pool, page_table).reshape(
                 B, MP * page, *k.shape[2:])
-        s = jnp.arange(MP * page)
-        allocated = jnp.take_along_axis(
-            page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
-        prefix_valid = (s[None, :] < positions[:, None]) & allocated
+        with profiling.region("cache.gather"):
+            s = jnp.arange(MP * page)
+            allocated = jnp.take_along_axis(
+                page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
+            prefix_valid = (s[None, :] < positions[:, None]) & allocated
         chunk_valid = (jnp.arange(K)[:, None] >= jnp.arange(K)[None, :])
         ctx = self._attend_cache_chunk(
             q, gather(k_pool), gather(v_pool), k, v, prefix_valid,
             chunk_valid[None, None, None, :, :])
-        x = self._add_mixed(x, self.out(ctx))
+        x = self._add_mixed(x, ctx)
         return self._mlp(x, deterministic=True), k_pool, v_pool
 
     def decode_step_paged(self, x: jax.Array, k_pool: jax.Array,
@@ -1306,22 +1335,24 @@ class GptBlock(nn.Module):
         sentinel, page = k_pool.shape[0] - 1, k_pool.shape[1]
         B, MP = page_table.shape
         q, k, v = self._qkv(x, positions=positions[:, None])  # [B,1,*,D]
-        lpage = (positions // page).astype(jnp.int32)
-        off = (positions % page).astype(jnp.int32)
-        phys = written_pages(jnp.take_along_axis(
-            page_table, jnp.clip(lpage, 0, MP - 1)[:, None], axis=1)[:, 0],
-            k_pool.shape[0])
-        k_pool = k_pool.at[phys, off].set(
-            k.reshape(B, -1).astype(k_pool.dtype), mode="drop")
-        v_pool = v_pool.at[phys, off].set(
-            v.reshape(B, -1).astype(v_pool.dtype), mode="drop")
-        s = jnp.arange(MP * page)
-        allocated = jnp.take_along_axis(
-            page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
-        valid = (s[None, :] <= positions[:, None]) & allocated
+        with profiling.region("cache.write"):
+            lpage = (positions // page).astype(jnp.int32)
+            off = (positions % page).astype(jnp.int32)
+            phys = written_pages(jnp.take_along_axis(
+                page_table, jnp.clip(lpage, 0, MP - 1)[:, None],
+                axis=1)[:, 0], k_pool.shape[0])
+            k_pool = k_pool.at[phys, off].set(
+                k.reshape(B, -1).astype(k_pool.dtype), mode="drop")
+            v_pool = v_pool.at[phys, off].set(
+                v.reshape(B, -1).astype(v_pool.dtype), mode="drop")
+        with profiling.region("cache.gather"):
+            s = jnp.arange(MP * page)
+            allocated = jnp.take_along_axis(
+                page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
+            valid = (s[None, :] <= positions[:, None]) & allocated
         ctx = self._attend_rows(q, gather_pages(k_pool, page_table),
                                 gather_pages(v_pool, page_table), valid)
-        x = self._add_mixed(x, self.out(ctx))
+        x = self._add_mixed(x, ctx)
         return self._mlp(x, deterministic=True), k_pool, v_pool
 
 
@@ -1349,14 +1380,18 @@ class GptLM(nn.Module):
 
     def _embed(self, input_ids: jax.Array, positions: jax.Array,
                deterministic: bool) -> jax.Array:
-        x = self.word_emb(input_ids)
-        if self.cfg.pos_encoding == "learned":
-            x = x + self.pos_emb(positions)
-        x = self.emb_drop(x, deterministic=deterministic)
-        return x.astype(jnp.dtype(self.cfg.dtype))
+        with profiling.region("embed"):
+            x = self.word_emb(input_ids)
+            if self.cfg.pos_encoding == "learned":
+                x = x + self.pos_emb(positions)
+            x = self.emb_drop(x, deterministic=deterministic)
+            return x.astype(jnp.dtype(self.cfg.dtype))
 
-    def _head(self, x: jax.Array) -> jax.Array:
-        return self.lm_head(self.ln_final(x))
+    def _head(self, x: jax.Array, normed: bool = False) -> jax.Array:
+        """The final norm and the vocabulary projection; ``normed``: the
+        looped stack's stream, which left its last step normed."""
+        with profiling.region("head"):
+            return self.lm_head(x if normed else self.ln_final(x))
 
     def _loop(self, stack, x: jax.Array, carry=None, rows=None):
         """The stack ``loop_steps`` times over the same weights (``cfg.
@@ -1380,12 +1415,12 @@ class GptLM(nn.Module):
         def step(mdl, state, step_in):
             x, carry = state
             t, rows_t = step_in
-            with jax.named_scope("loop.step"):
+            with profiling.region("loop.step"):
                 x, carry, rows_t = stack(mdl, x, carry, t, rows_t)
                 x = mdl.ln_final(x).astype(x.dtype)
             gate = None
             if cfg.exit_gate:
-                with jax.named_scope("loop.exit_gate"):
+                with profiling.region("loop.exit_gate"):
                     gate = mdl.exit_gate(x)[..., 0]
             return (x, carry), (rows_t, gate)
 
@@ -1409,7 +1444,7 @@ class GptLM(nn.Module):
                 for layer in mdl.layers:
                     x = layer(x, deterministic)
                 return x, carry, rows
-            return self.lm_head(self._loop(stack, x)[0])
+            return self._head(self._loop(stack, x)[0], normed=True)
         for layer in self.layers:
             x = layer(x, deterministic)
         return self._head(x)  # [B, S, vocab]
@@ -1551,7 +1586,7 @@ class GptLM(nn.Module):
                     new_pools.append(tuple(entry))
                 return x, new_pools, rows
             x, new_pools, _ = self._loop(stack, x, list(pools))
-            return self.lm_head(x)[:, 0], new_pools
+            return self._head(x, normed=True)[:, 0], new_pools
         new_pools = []
         for layer, entry in zip(self.layers, pools):
             if layer.kind == LINEAR_ATTENTION:
@@ -1609,7 +1644,8 @@ class GptLM(nn.Module):
                     new_caches.append(tuple(entry))
                 return x, carry, new_caches
             x, _, new_caches = self._loop(stack, x, rows=list(caches))
-            return self.lm_head(x[:, -1:])[:, 0], new_caches
+            return (self._head(x[:, -1:], normed=True)[:, 0],
+                    new_caches)
         for layer, entry in zip(self.layers, caches):
             if layer.kind == LINEAR_ATTENTION:
                 x, *entry = layer.linear_prefill(x, *entry, lengths)
@@ -1666,9 +1702,10 @@ def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
     (zeros: :func:`init_kv_pool`), so nothing is filled in afterwards:
     ``mode="fill"`` cost a pass of its own over the gathered rows, 1.4 ms
     a full layer in the hybrid's step (PERF.md, PR 39)."""
-    B, MP = page_table.shape
-    return jnp.take(pool, page_table, axis=0, mode="clip").reshape(
-        B, MP * pool.shape[1], -1)
+    with profiling.region("cache.gather"):
+        B, MP = page_table.shape
+        return jnp.take(pool, page_table, axis=0, mode="clip").reshape(
+            B, MP * pool.shape[1], -1)
 
 
 def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
@@ -1811,16 +1848,17 @@ def lm_loss(logits: jax.Array, tokens: jax.Array,
     token stream shifted left.  Returns (loss, next-token accuracy).
     ``label_smoothing`` mixes the targets with uniform (see ``mlm_loss``).
     """
-    pred = logits[:, :-1]
-    targets = tokens[:, 1:]
-    logp = jax.nn.log_softmax(pred, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    if label_smoothing > 0.0:
-        ll = ((1.0 - label_smoothing) * ll
-              + label_smoothing * jnp.mean(logp, axis=-1))
-    loss = -jnp.mean(ll)
-    acc = jnp.mean((jnp.argmax(pred, -1) == targets).astype(jnp.float32))
-    return loss, acc
+    with profiling.region("loss"):
+        pred = logits[:, :-1]
+        targets = tokens[:, 1:]
+        logp = jax.nn.log_softmax(pred, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        if label_smoothing > 0.0:
+            ll = ((1.0 - label_smoothing) * ll
+                  + label_smoothing * jnp.mean(logp, axis=-1))
+        loss = -jnp.mean(ll)
+        acc = jnp.mean((jnp.argmax(pred, -1) == targets).astype(jnp.float32))
+        return loss, acc
 
 
 def synthetic_lm_batch(seed: int, batch_size: int, seq_len: int,

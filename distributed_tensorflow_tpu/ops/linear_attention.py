@@ -34,6 +34,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..utils import profiling
+
 CHUNK = 64
 F32 = jnp.float32
 _HI = jax.lax.Precision.HIGHEST
@@ -85,7 +87,7 @@ def gated_delta_step(q, k, v, g, beta, state):
     """One token a row.  ``q``, ``k`` [B, H, Dk]; ``v`` [B, H, Dv]; ``g``,
     ``beta`` [B, H]; ``state`` [B, H, Dv, Dk] float32.  Returns
     (``o`` [B, H, Dv] float32, the new state)."""
-    with jax.named_scope("linear_attention.step"):
+    with profiling.region("linear_attention.step"):
         q, k, v = q.astype(F32), k.astype(F32), v.astype(F32)
         decayed = state * jnp.exp(g.astype(F32))[..., None, None]
         u = beta.astype(F32)[..., None] * (
@@ -129,7 +131,7 @@ def gated_delta_chunked(q, k, v, g, beta, state=None, chunk: int = CHUNK):
         x = x.reshape(B, N, chunk, *x.shape[2:])
         return jnp.moveaxis(jnp.moveaxis(x, 3, 1), 2, 0)
 
-    with jax.named_scope("linear_attention.scan"):
+    with profiling.region("linear_attention.scan"):
         q, k, v = chunks(q), chunks(k), chunks(v)
         g, beta = chunks(g), chunks(beta)                  # [N, B, H, C]
         cum = jnp.cumsum(g, axis=-1)

@@ -417,10 +417,12 @@ class DecodeEngine:
             # Per-row keys folded on the ABSOLUTE index being generated:
             # a sampled stream is reproducible for its (seed, position)s
             # no matter which other requests shared the batch.
-            keys = jax.vmap(
-                lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
-                    seeds, positions + 1)
-            nxt = gpt_lib.sample_logits_dynamic(logits, keys, temp, tk, tp)
+            with profiling.region("sample"):
+                keys = jax.vmap(
+                    lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
+                        seeds, positions + 1)
+                nxt = gpt_lib.sample_logits_dynamic(logits, keys, temp, tk,
+                                                    tp)
             if self._sparse_layers:
                 # The histogram rides behind the tokens in the one array
                 # the host fetches anyway: no second copy to wait for.
@@ -462,12 +464,13 @@ class DecodeEngine:
             logits, pools = model.apply(
                 {"params": params}, chunk, pools, tables, positions,
                 method=gpt_lib.GptLM.decode_chunk_paged)
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            keys = jax.vmap(
-                lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
-                    seeds, positions + 1)
-            sampled0 = gpt_lib.sample_logits_dynamic(
-                logits[:, 0], keys, temp, tk, tp)
+            with profiling.region("sample"):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                keys = jax.vmap(
+                    lambda s, p: jax.random.fold_in(jax.random.key(s), p))(
+                        seeds, positions + 1)
+                sampled0 = gpt_lib.sample_logits_dynamic(
+                    logits[:, 0], keys, temp, tk, tp)
             return greedy, sampled0, pools
 
         return jax.jit(spec_step, donate_argnames=("pools",))
@@ -519,8 +522,10 @@ class DecodeEngine:
 
             # An entry is (keys, values), a latent layer's (latents,
             # rotated keys), or (state, convolution tail).
-            return [tuple(land(kind, c, p) for c, p in zip(cache, pool))
-                    for kind, cache, pool in zip(mcfg.kinds, caches, pools)]
+            with profiling.region("cache.write"):
+                return [tuple(land(kind, c, p) for c, p in zip(cache, pool))
+                        for kind, cache, pool
+                        in zip(mcfg.kinds, caches, pools)]
 
         fn = jax.jit(prefill, donate_argnames=("pools",))
         self._prefill_fns[n_pages] = fn
@@ -899,7 +904,13 @@ class DecodeEngine:
         blocking copy back of the step's outputs) and ``.retire`` (the
         per-slot loop and the telemetry).  Their four boundaries are
         stamped once and feed the ``serve_step`` record and the
-        ``serve.decode_round`` span alike."""
+        ``serve.decode_round`` span alike.  The stage is cut once more,
+        at the dispatch, by a stamp and not by child regions (which would
+        take their time out of ``serve.step.stage`` for whoever reads
+        it): ``upload_ms`` (host arrays and the seven uploads) and
+        ``dispatch_ms`` (the call until it returns) add up to
+        ``stage_ms``, and ride as ``upload_us`` / ``dispatch_us`` on the
+        profiler's ``serve.step.retire`` event."""
         self.apply_pending_swap()
         if self.active_slots == 0:
             return []
@@ -939,23 +950,33 @@ class DecodeEngine:
                             and not state.prefilling:
                         chunk[slot, 1:] = state.draft(K - 1)
                         spec_rows += 1
-                greedy, sampled0, self.pools = self._spec_step_fn(
-                    self._tree, jnp.asarray(chunk),
-                    jnp.asarray(self._positions),
-                    jnp.asarray(self._tables), self.pools,
-                    jnp.asarray(self._temp), jnp.asarray(self._top_k),
-                    jnp.asarray(self._top_p), jnp.asarray(self._seeds))
                 self._spec_rows_last_step = spec_rows
             else:
-                nxt, self.pools = self._step_fn(
-                    self._tree, jnp.asarray(self._tokens),
-                    jnp.asarray(self._positions),
-                    jnp.asarray(self._tables), self.pools,
-                    jnp.asarray(self._temp), jnp.asarray(self._top_k),
-                    jnp.asarray(self._top_p), jnp.asarray(self._seeds))
                 self._spec_rows_last_step = 0
+            tokens, positions, tables, temp, top_k, top_p, seeds = (
+                jnp.asarray(a) for a in (
+                    chunk if spec_mode else self._tokens, self._positions,
+                    self._tables, self._temp, self._top_k, self._top_p,
+                    self._seeds))
+            # The stage, cut at the dispatch: host arrays and their seven
+            # uploads before this stamp, the call over the whole parameter
+            # tree until it returns after it.
+            t_uploaded = time.monotonic()
+            if spec_mode:
+                greedy, sampled0, self.pools = self._spec_step_fn(
+                    self._tree, tokens, positions, tables, self.pools,
+                    temp, top_k, top_p, seeds)
+            else:
+                nxt, self.pools = self._step_fn(
+                    self._tree, tokens, positions, tables, self.pools,
+                    temp, top_k, top_p, seeds)
             self._gave_away(given)
         t_staged = time.monotonic()
+        # Whole microseconds, so that the two parts add up to the stage on
+        # the profiler's event (whose stats are whole numbers) and on the
+        # record alike.
+        upload_us = round((t_uploaded - t0) * 1e6)
+        dispatch_us = round((t_staged - t0) * 1e6) - upload_us
         with profiling.annotate("serve.step.fetch"):
             if spec_mode:
                 greedy, nxt = np.asarray(greedy), np.asarray(sampled0)
@@ -985,6 +1006,7 @@ class DecodeEngine:
         with profiling.annotate("serve.step.retire",
                                 pools_in_place=int(in_place),
                                 sampled_lanes=sampled_lanes, **table,
+                                upload_us=upload_us, dispatch_us=dispatch_us,
                                 **(held if self._stateful else {}),
                                 **routed, **looped):
             tracer = tracing.active()
@@ -1082,7 +1104,9 @@ class DecodeEngine:
                 # The region's last boundary: everything of the retire
                 # region but the two emits themselves.
                 split_ms = {
-                    "stage_ms": round((t_staged - t0) * 1e3, 3),
+                    "upload_ms": upload_us / 1e3,
+                    "dispatch_ms": dispatch_us / 1e3,
+                    "stage_ms": (upload_us + dispatch_us) / 1e3,
                     "fetch_ms": round((now - t_staged) * 1e3, 3),
                     "retire_ms": round((time.monotonic() - now) * 1e3, 3)}
             if tracer is not None:

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 import optax
 
+from ..utils import profiling
+
 
 @flax.struct.dataclass
 class TrainState:
@@ -59,10 +61,12 @@ class TrainState:
         )
 
     def apply_gradients(self, grads: Any) -> "TrainState":
-        updates, new_opt_state = self.tx.update(grads, self.opt_state, self.params)
-        new_params = optax.apply_updates(self.params, updates)
-        return self.replace(params=new_params, opt_state=new_opt_state,
-                            global_step=self.global_step + 1)
+        with profiling.region("optimizer"):
+            updates, new_opt_state = self.tx.update(
+                grads, self.opt_state, self.params)
+            new_params = optax.apply_updates(self.params, updates)
+            return self.replace(params=new_params, opt_state=new_opt_state,
+                                global_step=self.global_step + 1)
 
 
 def gradient_descent(learning_rate: float) -> optax.GradientTransformation:

@@ -11,6 +11,10 @@ The reference's nearest artifacts are a plumbed-but-off
   timeline (no-op overhead when no trace is active) and, with a tracer
   installed, in the telemetry stream; the serving engine's turn is marked
   with it (docs/observability.md, "Serving tracing & SLOs");
+- :func:`region` — the same for the DEVICE: a ``jax.named_scope`` whose name
+  is one of :data:`REGIONS`, so that every operation the enclosed code
+  traces carries the name into the compiled program and from there into a
+  profile (docs/observability.md, "Device regions");
 - :class:`Timer` — the reference's ``time_begin``/``time_end`` pattern
   (``distributed.py:133,158``) as a context manager;
 - :func:`device_memory_stats` — per-device HBM usage snapshot, the "is my
@@ -84,6 +88,70 @@ def annotate(name: str, **stats):
     known when the region opens) ride on the profiler event as its stats
     and on the span record as attributes."""
     return _Annotation(name, **stats)
+
+
+#: The device regions' vocabulary: WHAT is computed, never a method's name
+#: or a layer's index, so that a name survives a refactor.  Regions nest
+#: and an operation belongs to the innermost.  ``perfbench/regions.py``
+#: joins a profile's device operations to these names.
+REGIONS = (
+    "embed",                    # the embedding take
+    "attn.qkv",                 # norm-in, projections, rope, the linear
+                                # layer's convolution and decay
+    "cache.write",              # K/V, latent or state rows written
+    "cache.gather",             # the table-wide read of a paged pool
+    "attn.scores",              # scores, mask, softmax, weighted sum
+    "attn.out",                 # out projection, post norm, residual add
+    "mlp",                      # norm, gate / in / out, residual add
+    "head",                     # final norm, the vocabulary projection
+    "sample",                   # key folding, argmax or the sorted path
+    "loss",                     # log-softmax over the logits
+    "optimizer",                # the update rule and its application
+    "linear_attention.scan",    # the gated delta rule over a sequence
+    "linear_attention.step",    # ... over one token a row
+    "mla.expand",               # per-head keys and values from latents
+    "mla.absorb",               # scores over the cached latents themselves
+    "moe.route", "moe.experts", "moe.shared",
+    "loop.step", "loop.exit_gate",
+)
+
+
+class _Region(contextlib.ContextDecorator):
+    """The :func:`region` scope: a ``jax.named_scope`` entered anew each
+    time, so that one instance can decorate a function that several
+    threads trace (``jax.named_scope``'s own object keeps the stack it
+    found on itself)."""
+
+    def __init__(self, name: str):
+        self._name = name
+
+    def _recreate_cm(self) -> "_Region":
+        return _Region(self._name)
+
+    def __enter__(self) -> None:
+        self._scope = jax.named_scope(self._name)
+        self._scope.__enter__()
+
+    def __exit__(self, *exc) -> None:
+        self._scope.__exit__(*exc)
+
+
+def region(name: str) -> _Region:
+    """Named device region: the program's one way to mark one (the twin
+    of :func:`annotate`), around a block (``with``) or a whole function
+    (``@``).  Metadata only: the compiled program is the same with or
+    without it.  A name outside :data:`REGIONS` raises where the region is
+    made: add it to the tuple first, and say in docs/observability.md what
+    it holds.
+
+    Inside the model's helpers a region is a ``with`` block around the
+    body, not a decorator: the wrapper a decorator puts around a module
+    method cost the looped cell's warm set-up 6% of its Python tracing
+    time on the chip's host, the scope itself nothing (PERF.md, PR 41)."""
+    if name not in REGIONS:
+        raise ValueError(f"{name!r} is no device region: "
+                         f"profiling.REGIONS has {REGIONS}")
+    return _Region(name)
 
 
 class Timer:

@@ -1,0 +1,10 @@
+"""The share of the training step's device time in ``optimizer`` (the
+update rule and its application), in the traced steps
+(``perfbench/regions.py``; the step is the program that took most of the
+slice's device time).  A program that places no region gives nothing to read."""
+
+from perfbench import regions
+
+
+def read(ctx):
+    return regions.pct_of_programs(ctx, None, ("optimizer",))

@@ -321,7 +321,10 @@ def test_the_profilers_retire_event_carries_sampled_lanes(monkeypatch):
     engine.admit(Request(PROMPT, 2))
     engine.step()
     retire = [s for n, s in seen if n == "serve.step.retire"]
-    # (beside it PR 39's count of the table: three lanes of 4 pages)
+    # (beside it PR 39's count of the table, three lanes of 4 pages, and
+    # PR 40's two clock readings of the stage, whole microseconds)
+    assert all(isinstance(retire[0].pop(k), int)
+               for k in ("upload_us", "dispatch_us"))
     assert retire == [{"pools_in_place": 1, "sampled_lanes": 2,
                        "table_pages": 3 * 8, "table_pages_held": 12}]
 

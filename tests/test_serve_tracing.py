@@ -777,6 +777,45 @@ def test_serve_step_record_splits_the_step_at_the_regions_boundaries(
         assert lane["parent_id"] in {r["span_id"] for r in rounds}
 
 
+@pytest.mark.parametrize("spec_k", [0, 4], ids=["plain", "speculative"])
+def test_the_stage_is_cut_at_the_dispatch_by_two_counters(
+        model_and_params, capture, monkeypatch, spec_k):
+    """Both arms of the step stamp the clock once between the uploads and
+    the call: ``upload_ms + dispatch_ms = stage_ms`` on the record and the
+    round's span, and the same in whole microseconds on the profiler's
+    retire event, with no region of its own under ``serve.step.stage``."""
+    from distributed_tensorflow_tpu.utils import profiling
+    seen = []
+    real = profiling.annotate
+
+    def spy(name, **stats):
+        seen.append((name, stats))
+        return real(name, **stats)
+    monkeypatch.setattr(profiling, "annotate", spy)
+    model, params = model_and_params
+    engine = DecodeEngine(model, params, EngineConfig(
+        num_slots=2, page_size=4, num_pages=32, max_pages_per_seq=8,
+        spec_k=spec_k), telemetry=capture.telemetry)
+    engine.admit(Request([5, 6, 7, 8, 9], 4, speculative=bool(spec_k)))
+    drain(engine)
+    steps = [f for kind, _, f in capture.records if kind == "serve_step"]
+    events = [stats for name, stats in seen if name == "serve.step.retire"]
+    rounds = capture.spans("serve.decode_round")
+    assert len(steps) == len(events) == len(rounds) >= 1
+    assert any(r["spec_rows"] for r in steps) == bool(spec_k)
+    for rec, event, span in zip(steps, events, rounds):
+        assert rec["upload_ms"] > 0 and rec["dispatch_ms"] > 0
+        assert rec["upload_ms"] + rec["dispatch_ms"] == pytest.approx(
+            rec["stage_ms"], abs=1e-9)
+        assert (event["upload_us"], event["dispatch_us"]) == (
+            round(rec["upload_ms"] * 1e3), round(rec["dispatch_ms"] * 1e3))
+        assert (span["upload_ms"], span["dispatch_ms"]) == (
+            rec["upload_ms"], rec["dispatch_ms"])
+    assert {name for name, _ in seen if name.startswith("serve.step")} == {
+        "serve.step", "serve.step.stage", "serve.step.fetch",
+        "serve.step.retire"}
+
+
 def test_turn_regions_reach_the_stream_nested_under_the_turn(
         model_and_params, capture):
     model, params = model_and_params
