@@ -61,6 +61,16 @@ from its own ``_temp`` array: each ``serve_step`` record says
 ``sampled_lanes``, and :meth:`DecodeEngine.stats` counts
 ``sample_steps_greedy`` / ``sample_steps_sampled``.
 
+The decode step is dispatched ONE STEP AHEAD of the host's reading of it
+(:meth:`DecodeEngine.step`): the one thing step N+1 needs from step N that
+the host cannot know beforehand is the sampled token, and it is handed
+over on the device, so the host's turn (fetch, retire, complete, schedule,
+the next stage) runs while the device works.  Positions, budgets, tables,
+temperatures and seeds the host knows without the tokens.  The step
+program is the serial one's, and so are the tokens; each ``serve_step``
+record says ``steps_ahead`` / ``steps_serial`` / ``lane_steps_discarded``,
+and :meth:`DecodeEngine.stats` sums them.
+
 A page table's entry where a lane holds no page is the SENTINEL,
 ``num_pages``: the pools' one page past the allocator's range, all zeros,
 never written (``gpt_lib.init_kv_pool``).  The step's gather reads it like
@@ -162,16 +172,19 @@ class EngineConfig:
 class _Slot:
     """One live sequence's lane state (host side)."""
 
-    __slots__ = ("request", "prompt_len", "budget", "generated", "spec",
-                 "history", "hist_len", "index", "table", "prefill_pos",
-                 "prefill_target", "prefill_chunks", "prefill_pages",
-                 "t_prefill_start")
+    __slots__ = ("request", "prompt_len", "budget", "generated",
+                 "in_flight", "spec", "history", "hist_len", "index",
+                 "table", "prefill_pos", "prefill_target", "prefill_chunks",
+                 "prefill_pages", "t_prefill_start")
 
     def __init__(self, request: Request, spec_ngram: int = 0):
         self.request = request
         self.prompt_len = len(request.prompt)
         self.budget = request.num_tokens
         self.generated = 0
+        # Steps dispatched with this lane live whose output has not
+        # landed: 0 or 1, and 2 for a moment inside a call that runs ahead.
+        self.in_flight = 0
         # Chunked-prefill bookkeeping: positions [prefill_pos,
         # prefill_target) of the prompt still owe their K/V to the pool.
         # target stays 0 on the whole-bucket path (never prefilling).
@@ -203,6 +216,15 @@ class _Slot:
         and advances by chunks instead of emitting tokens."""
         return self.prefill_pos < self.prefill_target
 
+    @property
+    def due(self) -> bool:
+        """Whether the next dispatch carries this lane: its prompt is
+        resident and the steps landed and in flight leave budget over.  A
+        count the host keeps without any token (a speculative lane, which
+        may land several a step, is only ever asked with none in flight)."""
+        return (not self.prefilling
+                and self.generated + self.in_flight < self.budget)
+
     def draft(self, k: int) -> np.ndarray:
         """[k] drafted continuation tokens for the lane's current tail."""
         return self.index.draft(self.history, self.hist_len, k)
@@ -214,6 +236,33 @@ class _Slot:
         self.history[self.hist_len:self.hist_len + n] = tokens
         self.hist_len += n
         self.index.update(self.history, self.hist_len - 1)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Flight:
+    """One decode step from its dispatch to the landing of its output:
+    the device arrays to fetch, and what the host knew at the dispatch and
+    the step's record says at the landing."""
+
+    out: list                  # [tokens (+ counters)] | [greedy, sampled0]
+    lanes: list                # per slot, the _Slot that rode, else None
+    ahead: bool                # dispatched before the step before landed
+    chunk: Any                 # the speculative arm's [B, K] feed, else None
+    t0: float                  # the stage's start, time.monotonic
+    upload_us: int
+    dispatch_us: int
+    queue_depth: int
+    sampled_lanes: int
+    table: dict
+    held: dict
+    in_place: bool
+    spec_rows: int
+    # Since the dispatch before: admissions, their prompt tokens, and the
+    # milliseconds their prefills (and this turn's chunk) took.
+    admitted: int
+    prompt_tokens: int
+    prefill_ms: float
+    prefill_rows: int
 
 
 class DecodeEngine:
@@ -282,16 +331,26 @@ class DecodeEngine:
         self._seeds = np.zeros((B,), np.int32)
 
         self.step_index = 0
+        # The step dispatched and not yet landed, if any (step()).
+        self._flight: _Flight | None = None
+        self._t_landed = 0.0           # time.monotonic of the last landing
+        # Decode steps dispatched while the step before them was unfetched
+        # and after it was fetched, and lane-steps whose token was dropped
+        # because the lane had left when they landed (step()).
+        self.steps_ahead = 0
+        self.steps_serial = 0
+        self.lane_steps_discarded = 0
         self._admitted_since_step = 0
-        # Since the last step's record: prompt tokens seated, and the
+        # Since the last step's dispatch: prompt tokens seated, and the
         # milliseconds their whole-bucket prefills took.
         self._prompt_tokens_since_step = 0
         self._prefill_ms_since_step = 0.0
         self._spec_accepted_since_step = 0
         self._spec_rows_last_step = 0
-        # Whether every dispatch since the last step's record (its own,
-        # the chunk prefill's, the admissions' prefills) consumed the
-        # pools it was donated, and the steps counted either way.
+        # Whether every dispatch since the last step's (the chunk
+        # prefill's, the admissions' prefills; then the step's own)
+        # consumed the pools it was donated, and the steps counted either
+        # way.
         self._pools_in_place = True
         self.pool_steps_in_place = 0
         self.pool_steps_copied = 0
@@ -311,6 +370,13 @@ class DecodeEngine:
         self.loop = dict.fromkeys(("loop_steps_run", "loop_tokens",
                                    "exit_step_expected_milli"), 0)
         self._step_fn = self._build_step()
+        # The hand-over on the device, a program of its own: a step's
+        # tokens are the first num_slots entries of the output of the step
+        # before (the routing histogram or the loop's counters ride behind
+        # them), but for a lane seated since, which takes the host's seed
+        # token (``seeded`` holds it there and -1 elsewhere).
+        self._hand_over = jax.jit(lambda out, seeded: jnp.where(
+            seeded >= 0, seeded, out[:B]))
         self._spec_step_fn = (self._build_spec_step()
                               if cfg.spec_k else None)
         # Per-bucket prefill programs, LRU-bounded (prefill_cache_cap);
@@ -642,6 +708,12 @@ class DecodeEngine:
 
     def _admit(self, request: Request) -> int:
         cfg = self.config
+        if self._flight is not None:
+            # A step in flight (an arrival nobody saw coming) is waited
+            # for, not landed: the prefill's time below is then the
+            # prefill's own, and the step's tokens are still the next
+            # call's to hand back.
+            self._jax.block_until_ready(self._flight.out)
         slot = next(i for i, s in enumerate(self._slots) if s is None)
         P = len(request.prompt)
         tracer = tracing.active()
@@ -740,11 +812,9 @@ class DecodeEngine:
         request.t_admit = time.perf_counter()
         return slot
 
-    def _retire(self, slot: int, status: str) -> Request:
-        state = self._slots[slot]
-        assert state is not None
-        req = state.request
-        self._slots[slot] = None
+    def _idle_row(self, slot: int) -> None:
+        """The slot's row as an idle lane rides: the sentinel table, so
+        that its writes drop and its state stays, and zeros."""
         self._tables[slot] = self.config.num_pages
         self._tokens[slot] = 0
         self._positions[slot] = 0
@@ -752,6 +822,13 @@ class DecodeEngine:
         self._top_k[slot] = 0
         self._top_p[slot] = 0.0
         self._seeds[slot] = 0
+
+    def _retire(self, slot: int, status: str) -> Request:
+        state = self._slots[slot]
+        assert state is not None
+        req = state.request
+        self._slots[slot] = None
+        self._idle_row(slot)
         self.allocator.free(req.id)
         req.t_done = time.perf_counter()
         if self.telemetry is not None:
@@ -887,9 +964,9 @@ class DecodeEngine:
         return dur_ms, len(rows)
 
     def step(self, queue_depth: int = 0) -> list[Request]:
-        """One decode step over the whole slot batch; returns the requests
-        retired this step (completed/abandoned).  No-op (after adopting a
-        staged swap) when every lane is idle.
+        """One decode step's tokens for every live lane; returns the
+        requests retired this call (completed/abandoned).  No-op (after
+        adopting a staged swap) when every lane is idle.
 
         When at least one active lane opted into speculation the step
         runs the CHUNK program instead: speculative lanes feed their
@@ -898,101 +975,250 @@ class DecodeEngine:
         ride the same dispatch and emit exactly their node-0 sample —
         token-for-token what the plain step would have produced.
 
-        The step is strictly serial with the host, in three regions each
-        marked with :func:`profiling.annotate` under ``serve.step``:
-        ``.stage`` (host arrays, uploads, the dispatch), ``.fetch`` (the
-        blocking copy back of the step's outputs) and ``.retire`` (the
-        per-slot loop and the telemetry).  Their four boundaries are
+        A call LANDS one step (fetches its output and retires it: every
+        lane that rode it gets its token) and, in steady decode,
+        DISPATCHES the step after it first: that step takes the landing
+        step's output as its tokens on the device (a lane seated since
+        takes the host's seed token), so it needs nothing the host has not
+        got, and the device finds it queued when it ends the one before.
+        A call that finds no step in flight (the first after an idle
+        engine, or after a turn that did not run ahead) dispatches the
+        step it lands as well.  What a caller may rely on: every call
+        hands back exactly one step's tokens, the ones a strictly serial
+        loop would give, to every lane that rode that step, so
+        ``req.tokens`` of a plain lane grows by one a call: from the first
+        call after its :meth:`admit`, or from the second where the
+        admission found a step in flight (that call lands the step
+        dispatched before the lane sat and dispatches the lane's first).
+        Between calls one step may be in flight (:meth:`settle` lands it).
+        Not run ahead: a speculative turn (its drafts come from tokens the
+        host has committed), an engine with ``prefill_chunk``, a turn
+        after which no lane has budget left, and a turn in which
+        ``queue_depth > 0`` while a slot is free or frees at a budget in
+        the landing step (the waiting request is seated next turn, and a
+        step run ahead would only stand in its prefill's way).  A lane
+        that reaches its budget rides the step after as an idle row, which
+        the host knows without any token.  A lane that samples its
+        ``eos_id`` is seen when its step lands, one dispatch late: the
+        step already queued carries it once more, into pages it held at
+        that dispatch, and that lane-step's token is dropped
+        (``lane_steps_discarded``).
+
+        Three regions each marked with :func:`profiling.annotate` under
+        ``serve.step``, one of each a call: ``.stage`` (host arrays,
+        uploads and the dispatch of what the call dispatches: one step,
+        two in a call that dispatches the step it lands and the one
+        after, none where only a last step is left to land), ``.fetch``
+        (the blocking copy back of the LANDING step's outputs: in steady
+        decode the step before the one just dispatched) and ``.retire``
+        (the per-slot loop and the telemetry).  A step's boundaries are
         stamped once and feed the ``serve_step`` record and the
-        ``serve.decode_round`` span alike.  The stage is cut once more,
-        at the dispatch, by a stamp and not by child regions (which would
-        take their time out of ``serve.step.stage`` for whoever reads
-        it): ``upload_ms`` (host arrays and the seven uploads) and
-        ``dispatch_ms`` (the call until it returns) add up to
-        ``stage_ms``, and ride as ``upload_us`` / ``dispatch_us`` on the
-        profiler's ``serve.step.retire`` event."""
+        ``serve.decode_round`` span alike.  A step's stage is cut once
+        more, at its dispatch, by a stamp and not by child regions (which
+        would take their time out of ``serve.step.stage`` for whoever
+        reads it): ``upload_ms`` (host arrays, the seven uploads and the
+        hand-over) and ``dispatch_ms`` (the call until it returns) add up
+        to ``stage_ms``, and ride as ``upload_us`` / ``dispatch_us`` on
+        the profiler's ``serve.step.retire`` event of the step they
+        staged, beside ``steps_ahead`` / ``steps_serial`` (which of the
+        two that step was) and ``lane_steps_discarded``."""
         self.apply_pending_swap()
         if self.active_slots == 0:
             return []
         with profiling.annotate("serve.step"):
             return self._step(queue_depth)
 
+    def settle(self) -> list[Request]:
+        """Land the step in flight, if there is one, and return what it
+        retired: afterwards the host has every token the device has made.
+        For whoever stops calling :meth:`step` (the server's loop on its
+        way out)."""
+        flight, self._flight = self._flight, None
+        if flight is None:
+            return []
+        with profiling.annotate("serve.step"):
+            return self._land(flight)
+
+    def _runs_ahead(self, queue_depth: int) -> bool:
+        """After the landing step's dispatch, by what the host can count
+        without its tokens: some lane has budget left for one more step,
+        and no admission is in sight (a request waits and a slot is free,
+        or frees when the landing step retires a lane at its budget)."""
+        due = freeing = False
+        for state in self._slots:
+            if state is None:
+                continue
+            if state.due:
+                due = True
+            else:
+                freeing = True
+        if not due:
+            return False
+        return not (queue_depth > 0 and (freeing or self.free_slots > 0))
+
     def _step(self, queue_depth: int) -> list[Request]:
-        jnp = self._jnp
-        prefill_ms, prefill_rows = self._prefill_ms_since_step, 0
+        chunk_ms, prefill_rows = 0.0, 0
         if self.config.prefill_chunk:
             # Prompt chunks first, decode second: a lane whose frontier
             # reaches P-1 in this dispatch gets its real table installed
             # and its seed token rides the decode dispatch BELOW — its
             # first generated token costs no extra step.
             chunk_ms, prefill_rows = self._advance_prefill()
-            prefill_ms += chunk_ms
         spec_mode = (self._spec_step_fn is not None
                      and self._spec_slots_active())
-        t0 = time.monotonic()
+        landing = self._flight
+        # One stage region a call, around what the call stages: the step
+        # it lands where none was in flight, the step after it where the
+        # engine runs ahead; both in the first call after an idle engine,
+        # neither in a call that has only a last step to land.
         with profiling.annotate("serve.step.stage"):
-            given = self.pools[0][0]
-            # The predicate the sampler evaluates on the device, read off
-            # the host's copy of the same array before anything retires.
-            sampled_lanes = int(np.count_nonzero(self._temp > 0.0))
-            # What the gather of this dispatch reads: all of the table,
-            # of which this many entries are pages and not the sentinel.
-            table = {"table_pages": self._tables.size,
-                     "table_pages_held": int(np.count_nonzero(
-                         self._tables < self.config.num_pages))}
-            if spec_mode:
-                K = self.config.spec_k
-                chunk = np.zeros((self.config.num_slots, K), np.int32)
-                chunk[:, 0] = self._tokens
-                spec_rows = 0
-                for slot, state in enumerate(self._slots):
-                    if state is not None and state.spec \
-                            and not state.prefilling:
-                        chunk[slot, 1:] = state.draft(K - 1)
-                        spec_rows += 1
-                self._spec_rows_last_step = spec_rows
-            else:
-                self._spec_rows_last_step = 0
-            tokens, positions, tables, temp, top_k, top_p, seeds = (
-                jnp.asarray(a) for a in (
-                    chunk if spec_mode else self._tokens, self._positions,
-                    self._tables, self._temp, self._top_k, self._top_p,
-                    self._seeds))
-            # The stage, cut at the dispatch: host arrays and their seven
-            # uploads before this stamp, the call over the whole parameter
-            # tree until it returns after it.
-            t_uploaded = time.monotonic()
-            if spec_mode:
-                greedy, sampled0, self.pools = self._spec_step_fn(
-                    self._tree, tokens, positions, tables, self.pools,
-                    temp, top_k, top_p, seeds)
-            else:
-                nxt, self.pools = self._step_fn(
-                    self._tree, tokens, positions, tables, self.pools,
-                    temp, top_k, top_p, seeds)
-            self._gave_away(given)
+            if landing is None:
+                landing = self._flight = self._dispatch(
+                    queue_depth, None, spec_mode, chunk_ms, prefill_rows)
+            ahead = None
+            if not (spec_mode or self.config.prefill_chunk) \
+                    and self._runs_ahead(queue_depth):
+                ahead = self._dispatch(queue_depth, landing)
+        # From here the landing step is this frame's; what fail_active
+        # would find unfetched is the step dispatched ahead.
+        self._flight = ahead
+        retired = self._land(landing)
+        if ahead is not None and self.active_slots == 0:
+            # Every lane the step ahead carries left at an eos or was
+            # abandoned: nobody would call for it again.
+            self._flight = None
+            retired += self._land(ahead)
+        return retired
+
+    def _dispatch(self, queue_depth: int, after: _Flight | None,
+                  spec_mode: bool = False, chunk_ms: float = 0.0,
+                  prefill_rows: int = 0) -> _Flight:
+        """Stage and dispatch one step over the lanes that are due, inside
+        the call's ``serve.step.stage`` region.  With ``after`` (a plain
+        step in flight) the tokens are that step's output taken on the
+        device, but for a lane that did not ride it, seated since: that one
+        takes the host's; without, every lane takes the host's."""
+        jnp = self._jnp
+        B = self.config.num_slots
+        t0 = time.monotonic()
+        given = self.pools[0][0]
+        lanes = [s if s is not None and s.due else None for s in self._slots]
+        # The predicate the sampler evaluates on the device, read off the
+        # host's copy of the same array.
+        sampled_lanes = int(np.count_nonzero(self._temp > 0.0))
+        # What the gather of this dispatch reads: all of the table, of
+        # which this many entries are pages and not the sentinel.
+        table = {"table_pages": self._tables.size,
+                 "table_pages_held": int(np.count_nonzero(
+                     self._tables < self.config.num_pages))}
+        # What this step's lanes hold in recurrent state.
+        held = {"state_slots": self.allocator.state_slots,
+                "state_bytes": self.allocator.state_bytes}
+        chunk, spec_rows = None, 0
+        if spec_mode:
+            K = self.config.spec_k
+            chunk = np.zeros((B, K), np.int32)
+            chunk[:, 0] = self._tokens
+            for slot, state in enumerate(lanes):
+                if state is not None and state.spec:
+                    chunk[slot, 1:] = state.draft(K - 1)
+                    spec_rows += 1
+            feed = chunk
+        elif after is None:
+            feed = self._tokens.copy()
+        else:
+            feed = np.full((B,), -1, np.int32)
+            for slot, state in enumerate(lanes):
+                if state is not None and after.lanes[slot] is not state:
+                    feed[slot] = self._tokens[slot]
+        self._spec_rows_last_step = spec_rows
+        # Uploaded from copies made here, on the host: the arrays move on
+        # below while this step may still be reading what it was handed
+        # (the CPU backend aliases an aligned NumPy buffer, and jnp.array's
+        # own copy is a device program that reads the alias later).
+        tokens, positions, tables, temp, top_k, top_p, seeds = (
+            jnp.asarray(a) for a in (feed, *map(np.copy, (
+                self._positions, self._tables, self._temp, self._top_k,
+                self._top_p, self._seeds))))
+        if after is not None:
+            tokens = self._hand_over(after.out[0], tokens)
+        # The stage, cut at the dispatch: host arrays, their seven uploads
+        # and the hand-over before this stamp, the call over the whole
+        # parameter tree until it returns after it.
+        t_uploaded = time.monotonic()
+        fn = self._spec_step_fn if spec_mode else self._step_fn
+        *out, self.pools = fn(
+            self._tree, tokens, positions, tables, self.pools,
+            temp, top_k, top_p, seeds)
+        self._gave_away(given)
         t_staged = time.monotonic()
         # Whole microseconds, so that the two parts add up to the stage on
         # the profiler's event (whose stats are whole numbers) and on the
         # record alike.
         upload_us = round((t_uploaded - t0) * 1e6)
         dispatch_us = round((t_staged - t0) * 1e6) - upload_us
+        # The host's arrays now say what the NEXT dispatch feeds: a lane
+        # moves one position on, and one whose budget this step fills
+        # rides the next as an idle row, whatever its token turns out to be.
+        for slot, state in enumerate(lanes):
+            if state is None:
+                continue
+            state.in_flight += 1
+            if state.due:
+                self._positions[slot] += 1
+            else:
+                self._idle_row(slot)
+        flight = _Flight(
+            out=out, lanes=lanes, ahead=after is not None, chunk=chunk,
+            t0=t0, upload_us=upload_us, dispatch_us=dispatch_us,
+            queue_depth=queue_depth, sampled_lanes=sampled_lanes,
+            table=table, held=held,
+            in_place=self._pools_in_place, spec_rows=spec_rows,
+            admitted=self._admitted_since_step,
+            prompt_tokens=self._prompt_tokens_since_step,
+            prefill_ms=self._prefill_ms_since_step + chunk_ms,
+            prefill_rows=prefill_rows)
+        self._admitted_since_step = 0
+        self._prompt_tokens_since_step = 0
+        self._prefill_ms_since_step = 0.0
+        self._pools_in_place = True
+        return flight
+
+    def _land(self, flight: _Flight) -> list[Request]:
+        """Fetch a dispatched step's output and retire it: tokens to the
+        lanes that rode it, finished lanes out, the step's record."""
+        B, K = self.config.num_slots, self.config.spec_k
+        spec_mode = flight.chunk is not None
+        t_fetch = time.monotonic()
         with profiling.annotate("serve.step.fetch"):
             if spec_mode:
-                greedy, nxt = np.asarray(greedy), np.asarray(sampled0)
+                greedy, nxt = (np.asarray(a) for a in flight.out)
             else:
-                nxt = np.asarray(nxt)
-        routed = self._routing_counters(nxt[self.config.num_slots:])
-        looped = self._loop_counters(nxt[self.config.num_slots:])
+                nxt = np.asarray(flight.out[0])
+        routed = self._routing_counters(nxt[B:])
+        looped = self._loop_counters(nxt[B:])
         now = time.monotonic()
-        step_ms = (now - t0) * 1e3
+        # A step dispatched ahead could not start before the one before it
+        # ended, which is when that one landed: its time runs from there,
+        # so that the steps' times tile the host's clock and do not overlap.
+        t_begin = max(flight.t0, self._t_landed) if flight.ahead \
+            else flight.t0
+        self._t_landed = now
+        step_ms = (now - t_begin) * 1e3
         self.step_index += 1
-        # What the lanes of THIS step held in recurrent state, before any
-        # of them retires; on the profiler's event too, where a trace
-        # reader finds it beside the device's operations.
-        held = {"state_slots": self.allocator.state_slots,
-                "state_bytes": self.allocator.state_bytes}
-        in_place = self._pools_in_place
+        # Lane-steps this dispatch spent on a lane that had left (an eos
+        # seen a step late, a request abandoned) by the time it landed.
+        discarded = sum(
+            rode is not None and self._slots[slot] is not rode
+            for slot, rode in enumerate(flight.lanes))
+        ahead = {"steps_ahead": int(flight.ahead),
+                 "steps_serial": int(not flight.ahead),
+                 "lane_steps_discarded": discarded}
+        self.steps_ahead += ahead["steps_ahead"]
+        self.steps_serial += ahead["steps_serial"]
+        self.lane_steps_discarded += discarded
+        in_place, table = flight.in_place, flight.table
+        held, sampled_lanes = flight.held, flight.sampled_lanes
         if in_place:
             self.pool_steps_in_place += 1
         else:
@@ -1006,7 +1232,8 @@ class DecodeEngine:
         with profiling.annotate("serve.step.retire",
                                 pools_in_place=int(in_place),
                                 sampled_lanes=sampled_lanes, **table,
-                                upload_us=upload_us, dispatch_us=dispatch_us,
+                                upload_us=flight.upload_us,
+                                dispatch_us=flight.dispatch_us, **ahead,
                                 **(held if self._stateful else {}),
                                 **routed, **looped):
             tracer = tracing.active()
@@ -1019,7 +1246,7 @@ class DecodeEngine:
                 # as its children carrying their request's trace id, so the
                 # same wall-clock interval appears once on the engine
                 # timeline and once inside every participating request.
-                t_round_unix = _unix_at(t0)
+                t_round_unix = _unix_at(t_begin)
                 round_id = tracer.allocate_id()
             spec_accepted = 0
             retired: list[Request] = []
@@ -1027,18 +1254,23 @@ class DecodeEngine:
                 if state is None:
                     continue
                 req = state.request
+                rode = flight.lanes[slot] is state
+                if rode:
+                    state.in_flight -= 1
                 if req.abandoned:
                     retired.append(self._retire(slot, "abandoned"))
                     continue
-                if state.prefilling:
-                    # Masked passenger: no tokens this step (its decode-row
-                    # writes dropped through the sentinel table).
+                if not rode:
+                    # No token from this step: a prefilling lane rode it
+                    # as a masked passenger (its decode-row writes dropped
+                    # through the sentinel table), a lane seated behind it
+                    # not at all.
                     continue
                 if spec_mode and state.spec:
                     # Longest drafted prefix matching the greedy argmaxes,
                     # plus the free correction token — clamped to the
                     # lane's remaining budget.
-                    row, g = chunk[slot], greedy[slot]
+                    row, g = flight.chunk[slot], greedy[slot]
                     accept = 1
                     while (accept < K and row[accept] == g[accept - 1]
                            and not (req.eos_id is not None
@@ -1085,8 +1317,10 @@ class DecodeEngine:
                 if done_status is not None:
                     retired.append(self._retire(slot, done_status))
                 else:
+                    # The host's token, for a dispatch that follows this
+                    # landing; the position moved one on at the dispatch.
                     self._tokens[slot] = emitted[count - 1]
-                    self._positions[slot] += count
+                    self._positions[slot] += count - 1
             self._spec_accepted_since_step = spec_accepted
             tel = self.telemetry
             if tel is not None:
@@ -1104,39 +1338,37 @@ class DecodeEngine:
                 # The region's last boundary: everything of the retire
                 # region but the two emits themselves.
                 split_ms = {
-                    "upload_ms": upload_us / 1e3,
-                    "dispatch_ms": dispatch_us / 1e3,
-                    "stage_ms": (upload_us + dispatch_us) / 1e3,
-                    "fetch_ms": round((now - t_staged) * 1e3, 3),
+                    "upload_ms": flight.upload_us / 1e3,
+                    "dispatch_ms": flight.dispatch_us / 1e3,
+                    "stage_ms": (flight.upload_us
+                                 + flight.dispatch_us) / 1e3,
+                    "fetch_ms": round((now - t_fetch) * 1e3, 3),
                     "retire_ms": round((time.monotonic() - now) * 1e3, 3)}
             if tracer is not None:
                 tracer.emit_span(
                     "serve.decode_round", t_round_unix, step_ms,
                     step=self.step_index, parent_id=0, span_id=round_id,
                     active_slots=self.active_slots + len(retired),
-                    spec_rows=self._spec_rows_last_step,
+                    spec_rows=flight.spec_rows,
                     model_step=self.model_step, **split_ms)
             if tel is not None:
                 tel.emit("serve_step", step=self.step_index,
                          active_slots=self.active_slots + len(retired),
-                         admitted=self._admitted_since_step,
-                         retired=len(retired), queue_depth=queue_depth,
+                         admitted=flight.admitted,
+                         retired=len(retired), queue_depth=flight.queue_depth,
                          kv_pages_in_use=self.allocator.pages_in_use,
                          kv_pages_total=self.config.num_pages,
                          **held, pools_in_place=in_place, **table,
-                         sampled_lanes=sampled_lanes, **routed, **looped,
-                         t_start=round(t0, 6),
+                         sampled_lanes=sampled_lanes, **ahead,
+                         **routed, **looped,
+                         t_start=round(flight.t0, 6),
                          step_ms=round(step_ms, 3), **split_ms,
-                         spec_rows=self._spec_rows_last_step,
+                         spec_rows=flight.spec_rows,
                          spec_accepted=spec_accepted,
-                         prompt_tokens=self._prompt_tokens_since_step,
-                         prefill_rows=prefill_rows,
-                         prefill_ms=round(prefill_ms, 3),
+                         prompt_tokens=flight.prompt_tokens,
+                         prefill_rows=flight.prefill_rows,
+                         prefill_ms=round(flight.prefill_ms, 3),
                          model_step=self.model_step)
-        self._admitted_since_step = 0
-        self._prompt_tokens_since_step = 0
-        self._prefill_ms_since_step = 0.0
-        self._pools_in_place = True
         return retired
 
     def _routing_counters(self, counts: np.ndarray) -> dict:
@@ -1186,8 +1418,14 @@ class DecodeEngine:
                 continue
             state.request.error = error
             out.append(self._retire(slot, "error"))
-        if any(leaf.is_deleted()
-               for leaf in self._jax.tree.leaves(self.pools)):
+        # A step in flight is waited for and dropped: its lanes are gone.
+        # Where it, or the step before it, is what failed, the pools it
+        # returned are no better than the deleted ones.
+        flight, self._flight = self._flight, None
+        try:
+            self._jax.block_until_ready(
+                (flight.out if flight is not None else (), self.pools))
+        except Exception:  # noqa: BLE001 — deleted, or the failure itself
             self.pools = self._fresh_pools()
             self._pools_in_place = True
         return out
@@ -1225,6 +1463,13 @@ class DecodeEngine:
             # place, and steps in which JAX copied them instead.
             "pool_steps_in_place": self.pool_steps_in_place,
             "pool_steps_copied": self.pool_steps_copied,
+            # Steps dispatched while the step before them was unfetched,
+            # steps dispatched after it was fetched, and lane-steps whose
+            # token was dropped (an eos seen a step late, an abandoned
+            # request).
+            "steps_ahead": self.steps_ahead,
+            "steps_serial": self.steps_serial,
+            "lane_steps_discarded": self.lane_steps_discarded,
             # Steps whose lanes were all greedy, where the sampler is an
             # argmax, and steps in which some lane sampled.
             "sample_steps_greedy": self.sample_steps_greedy,

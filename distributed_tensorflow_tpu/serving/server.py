@@ -184,6 +184,10 @@ class ServingServer:
                     self._wake.wait(timeout=0.5)
                 stop = self._stop
             if stop:
+                # A step the engine dispatched ahead is not left unfetched
+                # on the device: its tokens land, and what it finishes is
+                # answered.
+                self._complete_all(self.engine.settle())
                 self._slo_tick(force=True)
                 break
             if self._have_work():

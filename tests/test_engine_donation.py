@@ -156,16 +156,20 @@ def test_a_donated_engine_serves_the_undonated_engines_tokens(
 def test_a_view_of_a_leaf_turns_the_donation_into_a_copy(config):
     """The silent fallback, pinned: while a ``np.asarray`` view holds a
     leaf's buffer JAX copies the pools, warns of nothing, and the engine
-    counts the step as copied; with the view gone it donates again."""
+    counts the step as copied; with the view gone it donates again.  A
+    call counts the step it LANDS: the one dispatched under the view is
+    dispatched one call and counted the next (``DecodeEngine.step``)."""
     engine = engine_of(config)
     engine.admit(request(engine))
     engine.step()
     view = np.asarray(engine.pools[0][0])
     engine.step()
-    assert (engine.pool_steps_in_place, engine.pool_steps_copied) == (1, 1)
+    assert (engine.pool_steps_in_place, engine.pool_steps_copied) == (2, 0)
     del view
     engine.step()
     assert (engine.pool_steps_in_place, engine.pool_steps_copied) == (2, 1)
+    engine.step()
+    assert (engine.pool_steps_in_place, engine.pool_steps_copied) == (3, 1)
 
 
 # ------------------------------------------------------ the failure path

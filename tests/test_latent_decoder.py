@@ -293,6 +293,8 @@ def test_retire_region_carries_the_counters_for_a_sparse_model_only(
     assert set(retire[0]) == {"pools_in_place", "sampled_lanes",
                               "table_pages", "table_pages_held",
                               "upload_us", "dispatch_us",
+                              "steps_ahead", "steps_serial",
+                              "lane_steps_discarded",
                               "experts_touched", "expert_slots",
                               "expert_tokens_max", "routed_tokens"}
     assert retire[0]["pools_in_place"] == 1
@@ -305,7 +307,9 @@ def test_retire_region_carries_the_counters_for_a_sparse_model_only(
     serve(engine, Request([1, 2, 3], 2))
     assert [untimed(s) for n, s in seen if n == "serve.step.retire"] == [
         {"pools_in_place": 1, "sampled_lanes": 0, "table_pages": 3 * 12,
-         "table_pages_held": 1}] * 2
+         "table_pages_held": 1, "steps_ahead": ahead,
+         "steps_serial": 1 - ahead, "lane_steps_discarded": 0}
+        for ahead in (0, 1)]
     assert engine.stats()["moe"]["expert_slots"] == 0
 
 

@@ -299,7 +299,8 @@ def test_one_request_at_temperature_moves_the_counter():
             stats["sample_steps_sampled"]) == (6, 3)
     lanes = [f["sampled_lanes"] for kind, f in records
              if kind == "serve_step"]
-    assert lanes == [0, 0, 1, 1, 1, 0, 0, 0, 0]
+    # (two calls had dispatched three steps when the hot lane was seated)
+    assert lanes == [0, 0, 0, 1, 1, 1, 0, 0, 0]
     # A retired lane's temperature is cleared: the next tenant of the
     # slot, greedy, is back on the argmax.
     engine.admit(Request(PROMPT, 2))
@@ -325,8 +326,11 @@ def test_the_profilers_retire_event_carries_sampled_lanes(monkeypatch):
     # PR 40's two clock readings of the stage, whole microseconds)
     assert all(isinstance(retire[0].pop(k), int)
                for k in ("upload_us", "dispatch_us"))
+    # (and this PR's three of the hand-over: a first step is a serial one)
     assert retire == [{"pools_in_place": 1, "sampled_lanes": 2,
-                       "table_pages": 3 * 8, "table_pages_held": 12}]
+                       "table_pages": 3 * 8, "table_pages_held": 12,
+                       "steps_ahead": 0, "steps_serial": 1,
+                       "lane_steps_discarded": 0}]
 
 
 def with_sampler(engine, fn, monkeypatch):

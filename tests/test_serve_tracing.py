@@ -739,8 +739,10 @@ REGIONS = {"serve.turn": None, "serve.schedule": "serve.turn",
 
 def test_serve_step_record_splits_the_step_at_the_regions_boundaries(
         model_and_params, capture):
-    """One set of four stamps feeds the ``serve_step`` record and the
-    ``serve.decode_round`` span; a whole-bucket prefill counts."""
+    """One set of stamps feeds the ``serve_step`` record and the
+    ``serve.decode_round`` span; a whole-bucket prefill counts.  A call
+    lands one step; the first call staged two (the one it landed and the
+    one after, ahead), the second one, the last none."""
     model, params = model_and_params
     engine = DecodeEngine(model, params, EngineConfig(
         num_slots=2, page_size=4, num_pages=32, max_pages_per_seq=8),
@@ -755,16 +757,32 @@ def test_serve_step_record_splits_the_step_at_the_regions_boundaries(
     steps = [f for kind, _, f in capture.records if kind == "serve_step"]
     rounds = capture.spans("serve.decode_round")
     assert len(steps) == len(walls) == len(rounds) == 3
-    for rec, span, (t0, t1) in zip(steps, rounds, walls):
-        assert t0 <= rec["t_start"] <= t1
+    assert [(r["steps_serial"], r["steps_ahead"]) for r in steps] == [
+        (1, 0), (0, 1), (0, 1)]
+    staged_in = [walls[0], walls[0], walls[1]]
+    for rec, span, (t0, t1), (s0, s1) in zip(steps, rounds, walls,
+                                             staged_in):
+        assert s0 <= rec["t_start"] <= s1
         split = [rec[k] for k in ("stage_ms", "fetch_ms", "retire_ms")]
         assert all(ms >= 0 for ms in split) and rec["stage_ms"] > 0
-        assert sum(split) <= (t1 - t0) * 1e3 + 0.002   # each rounded to 1 us
-        assert rec["stage_ms"] + rec["fetch_ms"] == pytest.approx(
-            rec["step_ms"], abs=0.002)
+        # The landing's two regions lie in the call that landed the step,
+        # its stage in the call that dispatched it.
+        assert rec["fetch_ms"] + rec["retire_ms"] \
+            <= (t1 - t0) * 1e3 + 0.002              # each rounded to 1 us
+        assert rec["stage_ms"] <= (s1 - s0) * 1e3 + 0.002
         assert [span[k] for k in ("stage_ms", "fetch_ms", "retire_ms")] \
             == split
         assert span["dur_ms"] == rec["step_ms"]
+    # The serial step's time runs from its stage's start to its tokens on
+    # the host, and holds the next step's stage between its own stage and
+    # its fetch; a step dispatched ahead runs from the landing before it,
+    # so that the rounds tile the clock.
+    first = steps[0]
+    assert first["stage_ms"] + steps[1]["stage_ms"] + first["fetch_ms"] \
+        <= first["step_ms"] + 0.002
+    for before, span in zip(rounds, rounds[1:]):
+        assert span["t_unix"] == pytest.approx(
+            before["t_unix"] + before["dur_ms"] / 1e3, abs=2e-3)
     # Both admissions, whole-bucket prefills, are on the first record only.
     assert [r["prompt_tokens"] for r in steps] == [7, 0, 0]
     assert [r["admitted"] for r in steps] == [2, 0, 0]
