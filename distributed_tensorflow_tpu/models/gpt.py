@@ -29,6 +29,10 @@ from ..utils import profiling
 
 FULL_ATTENTION = "full_attention"
 LINEAR_ATTENTION = "linear_attention"
+#: A full layer's parameters under another mask and another cache entry:
+#: a token attends its ``GptConfig.sliding_window`` most recent
+#: predecessors, and a paged pool holds a ring of that many rows a lane.
+SLIDING_ATTENTION = "sliding_attention"
 #: What ``GptConfig.kinds`` calls a full-attention layer under
 #: ``latent_kv_rank`` (never written in ``layer_kinds``).
 LATENT_ATTENTION = "latent_attention"
@@ -92,9 +96,23 @@ class GptConfig:
     # RMSNorm over the whole projected q and over the whole projected k
     # (all heads together) in the softmax-attention layers.
     qk_norm: bool = False
+    # RMSNorm over each HEAD's entries of q and of k (one scale vector of
+    # ``head_dim`` for q, one for k), before any rotation.
+    qk_head_norm: bool = False
+    # A head's size where it is not ``hidden_size // num_heads`` (0): the
+    # q projection is then ``num_heads * head_size`` wide, whatever the
+    # stream's width.
+    head_size: int = 0
+    # A gate on the softmax-attention layers' output: one more projection
+    # of the mixer's input, as wide as q, whose sigmoid multiplies the
+    # heads' contexts entry by entry before the out projection.
+    attn_output_gate: bool = False
+    # The embedding's output times sqrt(hidden_size).
+    scale_embedding: bool = False
     # The token mixer of each layer, ``num_layers`` of FULL_ATTENTION /
-    # LINEAR_ATTENTION; empty = every layer full attention (the same
-    # parameter tree and the same programs as before the field existed).
+    # LINEAR_ATTENTION / SLIDING_ATTENTION; empty = every layer full
+    # attention (the same parameter tree and the same programs as before
+    # the field existed).
     # A linear-attention layer is the gated delta rule of
     # ops/linear_attention.py: per sequence it keeps a fixed-size
     # recurrent state and a convolution tail where a full layer keeps
@@ -102,6 +120,19 @@ class GptConfig:
     # layer's.  Only GptLM.__call__, .prefill and .decode_paged carry
     # that state; every other cache path refuses such a config by name.
     layer_kinds: tuple = ()
+    # A SLIDING_ATTENTION layer has a full layer's parameters and attends
+    # only keys with ``0 <= pos_q - pos_k < sliding_window``.  Where a full
+    # layer's paged pool holds a sequence's every row, its pool holds a
+    # RING of ``ring_pages(page_size)`` pages a decode slot
+    # (:func:`init_kv_pool`): position ``p`` in ring page ``(p //
+    # page_size) % ring_pages``.  Only GptLM.__call__, .prefill and
+    # .decode_paged know the kind; every other cache path refuses it by
+    # name.  (``attention_window`` below is the older, global form: one
+    # window for ALL layers on the unpaged paths, and no kind.)
+    sliding_window: int = 0
+    # The kinds of layer that rotate q and k under pos_encoding="rope";
+    # empty = all of them.
+    rope_kinds: tuple = ()
     linear_num_heads: int = 0          # key heads = value heads
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
@@ -162,7 +193,18 @@ class GptConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.head_size or self.hidden_size // self.num_heads
+
+    @property
+    def window_layers(self) -> int:
+        return self.layer_kinds.count(SLIDING_ATTENTION)
+
+    def ring_pages(self, page_size: int) -> int:
+        """Pages of ``page_size`` rows a decode slot holds of a sliding
+        layer's pool: the window and a page more, so that the rows a
+        prompt's padding (under a page of it) writes fall on positions the
+        window has left."""
+        return -(-self.sliding_window // page_size) + 1
 
     @property
     def num_kv_heads(self) -> int:
@@ -200,9 +242,17 @@ class GptConfig:
     def refuse_state_layers(self, path: str) -> None:
         """Called first by every cache path that holds per-head K and V
         rows around a dense MLP and walks the stack once: no place for a
-        linear-attention layer's recurrent state, for a latent row, for a
+        linear-attention layer's recurrent state, for a sliding layer's
+        ring beside the full layers' rows, for a latent row, for a
         routed-expert MLP's histogram and idle lanes, nor for the rows of
         a weight-shared loop's further steps."""
+        if self.window_layers:
+            raise ValueError(
+                f"{path} holds one kind of cache entry for every layer and "
+                f"GptConfig.layer_kinds has {self.window_layers} "
+                "sliding_attention layer(s), whose entry is a ring; the "
+                "paths that hold both are GptLM.__call__, GptLM.prefill and "
+                "GptLM.decode_paged")
         if self.has_state_layers:
             raise ValueError(
                 f"{path} does not carry a linear-attention layer's "
@@ -242,18 +292,41 @@ class GptConfig:
                                     or self.attention_window):
             raise ValueError(
                 "loop_steps > 1 walks full-attention layers around dense "
-                "MLPs: it composes with none of layer_kinds, "
-                "latent_kv_rank, num_experts and attention_window")
+                "MLPs: it composes with none of layer_kinds (a linear or a "
+                "sliding_attention layer among them), latent_kv_rank, "
+                "num_experts and attention_window")
         if self.layer_kinds:
-            bad = sorted(set(self.layer_kinds)
-                         - {FULL_ATTENTION, LINEAR_ATTENTION})
+            known = (FULL_ATTENTION, LINEAR_ATTENTION, SLIDING_ATTENTION)
+            bad = sorted(set(self.layer_kinds) - set(known))
             if bad or len(self.layer_kinds) != self.num_layers:
                 raise ValueError(
-                    f"layer_kinds must name {FULL_ATTENTION!r} or "
-                    f"{LINEAR_ATTENTION!r} for each of num_layers="
-                    f"{self.num_layers} layers, got "
+                    f"layer_kinds must name one of {known} for each of "
+                    f"num_layers={self.num_layers} layers, got "
                     f"{len(self.layer_kinds)} entries"
                     + (f" with {bad}" if bad else ""))
+        if bool(self.window_layers) != bool(self.sliding_window) \
+                or self.sliding_window < 0:
+            raise ValueError(
+                "sliding_window >= 1 is the window of the sliding_attention "
+                "layers in layer_kinds: one needs the other (got "
+                f"sliding_window={self.sliding_window} and "
+                f"{self.window_layers} such layer(s))")
+        if self.window_layers and (self.has_state_layers
+                                   or self.attention_window):
+            raise ValueError(
+                "a sliding_attention layer composes with full_attention "
+                "layers, grouped-query heads and routed experts; not with a "
+                "linear_attention layer (no path carries a ring beside a "
+                "recurrent state) nor with attention_window (the one window "
+                "of ALL layers on the unpaged paths)")
+        if set(self.rope_kinds) - set(self.kinds) \
+                or (self.rope_kinds and self.pos_encoding != "rope"):
+            raise ValueError(
+                f"rope_kinds {self.rope_kinds} names the kinds of layer "
+                "that rotate under pos_encoding='rope': each must be a kind "
+                f"this config has, {sorted(set(self.kinds))}")
+        if self.head_size < 0 or self.head_dim < 1:
+            raise ValueError(f"head_size must be >= 0, got {self.head_size}")
         if self.has_state_layers:
             if min(self.linear_num_heads, self.linear_key_head_dim,
                    self.linear_value_head_dim) < 1 \
@@ -265,7 +338,8 @@ class GptConfig:
             if self.attention_window or self.attn_int8:
                 raise ValueError(
                     "layer_kinds with a linear_attention layer composes "
-                    "with neither attention_window nor attn_int8")
+                    "with full_attention layers only: neither with "
+                    "attention_window nor with attn_int8")
         if self.latent_kv_rank:
             if min(self.latent_q_rank, self.qk_nope_head_dim,
                    self.qk_rope_head_dim, self.v_head_dim) < 1 \
@@ -281,13 +355,16 @@ class GptConfig:
                     f"v_head_dim, got {self.qk_nope_head_dim} + "
                     f"{self.qk_rope_head_dim} and {self.v_head_dim}")
             if self.layer_kinds or self.kv_heads or self.attention_window \
-                    or self.attn_int8 or self.qk_norm \
+                    or self.attn_int8 or self.qk_norm or self.qk_head_norm \
+                    or self.head_size or self.attn_output_gate \
                     or self.pos_encoding != "none":
                 raise ValueError(
-                    "latent_kv_rank composes with none of layer_kinds, "
-                    "kv_heads, attention_window, attn_int8 and qk_norm, "
-                    "and rotates inside the mixer: pos_encoding must be "
-                    "'none'")
+                    "latent_kv_rank makes every layer a latent one, with "
+                    "head sizes and norms of its own: it composes with none "
+                    "of layer_kinds (so with no sliding_attention layer), "
+                    "kv_heads, attention_window, attn_int8, qk_norm, "
+                    "qk_head_norm, head_size and attn_output_gate, and "
+                    "rotates inside the mixer: pos_encoding must be 'none'")
         if self.num_experts:
             if not 1 <= self.experts_per_token <= self.num_experts \
                     or self.expert_intermediate_size < 1 \
@@ -299,12 +376,12 @@ class GptConfig:
                     "num_shared_experts >= 0 and 0 <= first_dense_layers "
                     "<= num_layers")
             if self.activation != "swiglu" or self.matmul_int8 \
-                    or self.norm_placement != "pre":
+                    or self.norm_placement == "post":
                 raise ValueError(
                     "num_experts: the experts are gated SiLU MLPs behind a "
                     "norm on their input (activation='swiglu', "
-                    "norm_placement='pre'), and matmul_int8 has no grouped "
-                    "form")
+                    "norm_placement 'pre' or 'sandwich'), and matmul_int8 "
+                    "has no grouped form")
         if self.activation not in ("gelu", "swiglu"):
             raise ValueError(f"Unknown activation {self.activation!r}; "
                              "one of ('gelu', 'swiglu')")
@@ -409,7 +486,9 @@ class GptBlock(nn.Module):
     and the KV-cached ``decode_step`` share the same parameters.
 
     ``kind`` selects the token mixer: softmax attention over cached keys and
-    values (FULL_ATTENTION), the gated delta rule over a recurrent state
+    values (FULL_ATTENTION; SLIDING_ATTENTION the same parameters under a
+    banded mask, its paged pool a ring), the gated delta rule over a
+    recurrent state
     (LINEAR_ATTENTION: ``linear_mix`` / ``linear_prefill`` /
     ``linear_decode_step``) or softmax attention over one cached latent row
     a token (LATENT_ATTENTION: ``latent_mix`` / ``latent_prefill`` /
@@ -529,7 +608,10 @@ class GptBlock(nn.Module):
             self.kv_proj = nn.DenseGeneral((2, cfg.num_kv_heads,
                                             cfg.head_dim), **proj_kw)
         self.out = nn.DenseGeneral(cfg.hidden_size, axis=(-2, -1), **proj_kw)
-        if cfg.qk_norm:
+        if cfg.attn_output_gate:
+            self.gate_proj = nn.DenseGeneral((cfg.num_heads, cfg.head_dim),
+                                             use_bias=False, **proj_kw)
+        if cfg.qk_norm or cfg.qk_head_norm:
             self.q_norm = RMSNorm()
             self.k_norm = RMSNorm()
 
@@ -562,10 +644,26 @@ class GptBlock(nn.Module):
             return x
         return self.ln_attn(x).astype(jnp.dtype(self.cfg.dtype))
 
+    @property
+    def window(self) -> int:
+        """This layer's attention window, 0 for none: its kind's, or the
+        one ``attention_window`` gives all layers."""
+        if self.kind == SLIDING_ATTENTION:
+            return self.cfg.sliding_window
+        return self.cfg.attention_window
+
     def _add_mixed(self, x: jax.Array, ctx: jax.Array,
                    deterministic: bool = True) -> jax.Array:
         """The token mixer's ``ctx`` through its out projection, and the
-        residual add."""
+        residual add.  Under ``attn_output_gate`` a softmax-attention
+        layer's ``ctx`` [B, T, H, D] is first multiplied by the sigmoid of
+        the gate's projection of the mixer's input."""
+        if self.cfg.attn_output_gate and self.kind in (FULL_ATTENTION,
+                                                       SLIDING_ATTENTION):
+            with profiling.region("attn.gate"):
+                gate = nn.sigmoid(
+                    self.gate_proj(self._mixer_in(x)).astype(jnp.float32))
+                ctx = (ctx * gate).astype(ctx.dtype)
         with profiling.region("attn.out"):
             y = self.out(ctx)
             if self.cfg.norm_placement == "post":
@@ -590,7 +688,10 @@ class GptBlock(nn.Module):
             if cfg.qk_norm:
                 q = self.q_norm(q.reshape(*q.shape[:2], -1)).reshape(q.shape)
                 k = self.k_norm(k.reshape(*k.shape[:2], -1)).reshape(k.shape)
-            if cfg.pos_encoding == "rope":
+            if cfg.qk_head_norm:
+                q, k = self.q_norm(q), self.k_norm(k)
+            if cfg.pos_encoding == "rope" and (
+                    not cfg.rope_kinds or self.kind in cfg.rope_kinds):
                 if positions is None:
                     positions = jnp.arange(x.shape[1])
                 q = apply_rope(q, positions, cfg.rope_base)
@@ -634,6 +735,8 @@ class GptBlock(nn.Module):
             with profiling.region("moe.shared"):
                 y = y + self.shared_out(
                     nn.silu(self.shared_gate(h)) * self.shared_in(h))
+        if cfg.norm_placement == "sandwich":
+            y = self.ln_mlp_post(y).astype(x.dtype)
         return x + self.drop(y, deterministic=deterministic)
 
     def _mlp(self, x: jax.Array, deterministic: bool,
@@ -697,8 +800,7 @@ class GptBlock(nn.Module):
         with profiling.region("attn.scores"):
             ctx = dot_product_attention(
                 q, self._expand_kv(k), self._expand_kv(v), causal=True,
-                window=self.cfg.attention_window,
-                backend=self.cfg.attention_backend)
+                window=self.window, backend=self.cfg.attention_backend)
         x = self._add_mixed(x, ctx, deterministic)
         return self._mlp(x, deterministic)
 
@@ -963,7 +1065,7 @@ class GptBlock(nn.Module):
         with profiling.region("attn.scores"):
             ctx = dot_product_attention(
                 q, self._expand_kv(k), self._expand_kv(v), causal=True,
-                window=self.cfg.attention_window, backend=backend)
+                window=self.window, backend=backend)
         x = self._add_mixed(x, ctx)
         return self._mlp(x, deterministic=True), k_cache, v_cache
 
@@ -1262,9 +1364,10 @@ class GptBlock(nn.Module):
         cfg = self.cfg
         if cfg.attention_window:
             raise ValueError(
-                "paged decode needs full-cache addressing (position == "
-                "logical slot); the windowed ring cache is not pageable — "
-                "use sequential decode_step instead")
+                "the paged chunk needs position == logical slot: "
+                "GptConfig.attention_window, one window for all layers, is "
+                "the unpaged paths' (and a sliding_attention layer's ring "
+                "is GptLM.decode_paged's alone)")
         sentinel, page = k_pool.shape[0] - 1, k_pool.shape[1]
         B, MP = page_table.shape
         K = x.shape[1]
@@ -1322,25 +1425,41 @@ class GptBlock(nn.Module):
         :meth:`decode_chunk`).
 
         Distinct slots never share a page (the allocator's invariant), so
-        the per-row scatter has no duplicate indices.  Full-cache
-        addressing only — position == logical slot — so the windowed ring
-        cache is rejected like :meth:`decode_chunk`.
+        the per-row scatter has no duplicate indices.
+
+        A SLIDING_ATTENTION layer's ``page_table`` [B, RP] is the row's
+        RING (``RP = cfg.ring_pages(page_size)``; its pool is its own, the
+        sentinel that pool's last page): position ``p`` lives in ring page
+        ``(p // page_size) % RP``, so ring slot ``s`` holds the newest
+        position ``<= positions[b]`` congruent to ``s`` modulo the ring's
+        ``RP * page_size`` rows, and counts only where that position is at
+        least 0 and less than ``sliding_window`` behind.  A row a newer
+        token overwrote is thereby never attended, what a prompt's padding
+        wrote (under a page past the prompt) lies further back than the
+        window, and a slot not yet written reads, through a sentinel
+        entry, zeros under a weight of zero.  The gather is over the ring,
+        ``RP * page_size`` rows a lane whatever the context.
+
+        The global ``attention_window`` (one window for ALL layers, no
+        kind) stays with the unpaged paths: here it is refused.
         """
         cfg = self.cfg
         if cfg.attention_window:
             raise ValueError(
-                "paged decode needs full-cache addressing (position == "
-                "logical slot); the windowed ring cache is not pageable — "
-                "use sequential decode_step instead")
+                "paged decode holds a window as a KIND of layer "
+                "(layer_kinds 'sliding_attention' with sliding_window, a "
+                "ring of pages a lane); GptConfig.attention_window, one "
+                "window for all layers, is the unpaged paths'")
         sentinel, page = k_pool.shape[0] - 1, k_pool.shape[1]
         B, MP = page_table.shape
+        ring = self.kind == SLIDING_ATTENTION
         q, k, v = self._qkv(x, positions=positions[:, None])  # [B,1,*,D]
         with profiling.region("cache.write"):
             lpage = (positions // page).astype(jnp.int32)
             off = (positions % page).astype(jnp.int32)
+            lpage = lpage % MP if ring else jnp.clip(lpage, 0, MP - 1)
             phys = written_pages(jnp.take_along_axis(
-                page_table, jnp.clip(lpage, 0, MP - 1)[:, None],
-                axis=1)[:, 0], k_pool.shape[0])
+                page_table, lpage[:, None], axis=1)[:, 0], k_pool.shape[0])
             k_pool = k_pool.at[phys, off].set(
                 k.reshape(B, -1).astype(k_pool.dtype), mode="drop")
             v_pool = v_pool.at[phys, off].set(
@@ -1349,7 +1468,13 @@ class GptBlock(nn.Module):
             s = jnp.arange(MP * page)
             allocated = jnp.take_along_axis(
                 page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
-            valid = (s[None, :] <= positions[:, None]) & allocated
+            if ring:
+                # How far behind the row's position slot s's newest row is.
+                behind = (positions[:, None] - s[None, :]) % (MP * page)
+                valid = ((behind < cfg.sliding_window)
+                         & (behind <= positions[:, None]) & allocated)
+            else:
+                valid = (s[None, :] <= positions[:, None]) & allocated
         ctx = self._attend_rows(q, gather_pages(k_pool, page_table),
                                 gather_pages(v_pool, page_table), valid)
         x = self._add_mixed(x, ctx)
@@ -1382,6 +1507,8 @@ class GptLM(nn.Module):
                deterministic: bool) -> jax.Array:
         with profiling.region("embed"):
             x = self.word_emb(input_ids)
+            if self.cfg.scale_embedding:
+                x = x.astype(jnp.float32) * self.cfg.hidden_size ** 0.5
             if self.cfg.pos_encoding == "learned":
                 x = x + self.pos_emb(positions)
             x = self.emb_drop(x, deterministic=deterministic)
@@ -1551,7 +1678,8 @@ class GptLM(nn.Module):
         return self._head(x)[:, 0], new_caches
 
     def decode_paged(self, token: jax.Array, pools, page_tables: jax.Array,
-                     positions: jax.Array, live: jax.Array | None = None):
+                     positions: jax.Array, live: jax.Array | None = None,
+                     window_tables: jax.Array | None = None):
         """One token PER ROW against per-layer paged KV pools (see
         ``GptBlock.decode_step_paged``).  ``token`` [B]; ``pools``:
         [(k_pool, v_pool)] per layer; ``page_tables`` [B, MP] shared by
@@ -1565,8 +1693,16 @@ class GptLM(nn.Module):
         routes a row that ``live`` says is no sequence nowhere (without
         ``live`` every row is routed).  With ``cfg.loop_steps`` > 1 the
         stack is walked that many times (:meth:`_loop`), step ``t`` writing
-        and attending its own run of pages of each layer's pool.  Returns
-        (logits [B, vocab], new pools)."""
+        and attending its own run of pages of each layer's pool.  A
+        sliding-attention layer's entry is a pool of its own geometry,
+        addressed through ``window_tables`` [B, ring pages], the rows' rings
+        (``GptBlock.decode_step_paged``).  Returns (logits [B, vocab], new
+        pools)."""
+        if self.cfg.window_layers and window_tables is None:
+            raise ValueError(
+                "GptLM.decode_paged needs window_tables= [B, ring pages] "
+                "for a config whose layer_kinds has a sliding_attention "
+                "layer: its pool is a ring a row, not a run of pages")
         if self.cfg.has_state_layers and live is None:
             raise ValueError(
                 "GptLM.decode_paged needs live= [B] for a config whose "
@@ -1595,8 +1731,10 @@ class GptLM(nn.Module):
                 x, *entry = layer.latent_decode_step_paged(
                     x, *entry, page_tables, positions, live)
             else:
-                x, *entry = layer.decode_step_paged(x, *entry, page_tables,
-                                                    positions)
+                x, *entry = layer.decode_step_paged(
+                    x, *entry, window_tables
+                    if layer.kind == SLIDING_ATTENTION else page_tables,
+                    positions)
             new_pools.append(tuple(entry))
         return self._head(x)[:, 0], new_pools
 
@@ -1619,7 +1757,10 @@ class GptLM(nn.Module):
         past ``lengths[b]`` before reading it (the paged engine's
         contract), and the returned logits are the last PADDED position's.
         A latent-attention layer writes every position's row and takes no
-        ``lengths``.  With ``cfg.loop_steps`` > 1 a layer's entry has a
+        ``lengths``.  A sliding-attention layer's entry may be shorter than
+        the prompt (:func:`init_kv_cache` with ``ring_rows``): it then
+        comes back as a ring of the prompt's last rows, position ``p`` at
+        row ``p % rows``.  With ``cfg.loop_steps`` > 1 a layer's entry has a
         leading axis of that length, a slice a loop step
         (:func:`init_kv_cache`)."""
         B, P = tokens.shape
@@ -1709,7 +1850,7 @@ def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
 
 
 def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
-                  dtype=None):
+                  dtype=None, ring_rows: int = 0):
     """Per-layer (k, v) cache arrays [B, max_len, H, D]; with
     ``cfg.loop_steps`` > 1 [loop_steps, B, max_len, H, D], a loop step's
     own keys and values a slice of the leading axis.
@@ -1725,14 +1866,25 @@ def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
     out-of-band keys are unreachable anyway, so cache bytes — and every
     decode step's cache reads — stay O(window) no matter how long the
     prompt or generation runs.
+
+    A SLIDING_ATTENTION layer's entry (``GptLM.prefill``'s, on its way to
+    the paged pool) is a ring of ``min(max_len, ring_rows)`` entries where
+    the full layers beside it hold ``max_len``; ``ring_rows`` is the rows
+    of a decode slot's ring of pages, ``cfg.ring_pages(page_size) *
+    page_size``, and without it the window itself.
     """
     if cfg.attention_window:
         max_len = min(max_len, cfg.attention_window)
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
-    lead = (batch_size, max_len) if cfg.loop_steps == 1 \
-        else (cfg.loop_steps, batch_size, max_len)
+
+    def lead(kind):
+        rows = max_len if kind != SLIDING_ATTENTION \
+            else min(max_len, ring_rows or cfg.sliding_window)
+        return (batch_size, rows) if cfg.loop_steps == 1 \
+            else (cfg.loop_steps, batch_size, rows)
+
     return [_state_entry(cfg, batch_size) if kind == LINEAR_ATTENTION
-            else _rows_entry(cfg, kind, lead, dtype)
+            else _rows_entry(cfg, kind, lead(kind), dtype)
             for kind in cfg.kinds]
 
 
@@ -1757,14 +1909,18 @@ def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype,
     return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
 
 
-def kv_row_bytes_per_token(cfg: GptConfig, dtype=None) -> int:
-    """Bytes ONE cached token holds over all layers' pages (a
-    linear-attention layer holds none; a weight-shared loop holds a row a
-    step a layer)."""
+def kv_row_bytes_per_token(cfg: GptConfig, dtype=None,
+                           window: bool = False) -> int:
+    """Bytes ONE cached token holds over all layers' pages that grow with
+    the sequence (a linear-attention layer holds none; a weight-shared
+    loop holds a row a step a layer); with ``window`` over the
+    sliding-attention layers' rings instead, which hold a token only
+    while it is inside the window."""
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
     return cfg.loop_steps * sum(
         x.size * x.dtype.itemsize
-        for kind in cfg.kinds if kind != LINEAR_ATTENTION
+        for kind in cfg.kinds
+        if kind != LINEAR_ATTENTION and (kind == SLIDING_ATTENTION) == window
         for x in jax.eval_shape(
             lambda k=kind: _rows_entry(cfg, k, (1,), dtype)))
 
@@ -1826,17 +1982,34 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
     array a layer and not one a step: the scatter and the gather address a
     step's run by an offset, the same two operations as without a loop, and
     the compiled step carries ``num_layers`` pairs of buffers through its
-    loop in place."""
+    loop in place.
+
+    A SLIDING_ATTENTION layer's pool is SMALL and of its own geometry:
+    ``num_slots * cfg.ring_pages(page_size)`` pages and its own sentinel
+    page after them, [num_slots * ring_pages + 1, page_size, G * D]: a
+    ring of ``sliding_window + page_size`` rows a decode slot whatever the
+    context (``GptBlock.decode_step_paged``), its pages handed out by the
+    allocator's second count (``serving/kv_pool.py``)."""
     if cfg.attention_window:
-        raise ValueError("paged KV pools need full-cache addressing; "
-                         "sliding-window checkpoints are not pageable")
-    if cfg.has_state_layers and num_slots < 1:
+        raise ValueError(
+            "a paged pool holds a window as a KIND of layer (layer_kinds "
+            "'sliding_attention' with sliding_window); "
+            "GptConfig.attention_window, one window for all layers, is the "
+            "unpaged paths'")
+    if (cfg.has_state_layers or cfg.window_layers) and num_slots < 1:
         raise ValueError("init_kv_pool needs num_slots >= 1 for a config "
-                         "whose layer_kinds has a linear_attention layer")
+                         "whose layer_kinds has a linear_attention or a "
+                         "sliding_attention layer")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
+
+    def pages(kind):
+        if kind == SLIDING_ATTENTION:
+            return num_slots * cfg.ring_pages(page_size) + 1
+        return cfg.loop_steps * num_pages + 1
+
     return [_state_entry(cfg, num_slots) if kind == LINEAR_ATTENTION
-            else _rows_entry(cfg, kind, (cfg.loop_steps * num_pages + 1,
-                                         page_size), dtype, flat=True)
+            else _rows_entry(cfg, kind, (pages(kind), page_size), dtype,
+                             flat=True)
             for kind in cfg.kinds]
 
 

@@ -79,6 +79,17 @@ can make from its own ``_tables``: each ``serve_step`` record says
 ``table_pages`` (slots x ``max_pages_per_seq``) and ``table_pages_held``
 (the entries that name a page a lane owns), and
 :meth:`DecodeEngine.stats` sums both.
+
+A model with SLIDING-WINDOW layers among its full ones
+(``GptConfig.layer_kinds``) holds pages of two kinds: each full layer's
+pool as above, and each window layer's small pool of ``num_slots`` rings
+of ``ring_pages`` pages, the window and a page more a lane whatever its
+context.  The allocator counts the two apart and admission needs room in
+both; a lane has a table of each kind and the step uploads both; a prefill
+lands a prompt's last rows on the lane's ring.  ``table_pages`` /
+``table_pages_held`` stay the full tables'; the rings' own ride beside
+them as ``window_table_pages`` / ``window_table_pages_held``, with
+``window_pages_in_use`` / ``window_pages_peak``.
 """
 
 from __future__ import annotations
@@ -279,9 +290,12 @@ class DecodeEngine:
         self.telemetry = telemetry
         mcfg = model.cfg
         if mcfg.attention_window:
-            raise ValueError("the paged serving engine needs full-cache "
-                             "addressing; sliding-window checkpoints are "
-                             "not pageable")
+            raise ValueError(
+                "the paged engine serves a window as a KIND of layer "
+                "(GptConfig.layer_kinds 'sliding_attention' with "
+                "sliding_window: a ring of pages a lane); "
+                "GptConfig.attention_window, one window for all layers, "
+                "is the unpaged paths'")
         # Positions must stay addressable by the position table (rope-less
         # checkpoints) — the engine's logical capacity is the tighter of
         # the page-table span and the model's max_position.
@@ -299,8 +313,13 @@ class DecodeEngine:
         # layer, and the step hands back, behind its tokens, how many
         # steps each lane ran and where its exit gate expects it to leave.
         self._loop_steps = mcfg.loop_steps
+        # Layers whose pool is a ring of pages a lane (sliding windows),
+        # and that ring's pages: 0 and 0 for a model without such layers.
+        self._window_layers = mcfg.window_layers
+        self._ring_pages = mcfg.ring_pages(cfg.page_size) \
+            if self._window_layers else 0
         if cfg.spec_k or cfg.prefill_chunk:
-            # Neither carries a recurrent state, a latent row or a
+            # Neither carries a recurrent state, a ring, a latent row or a
             # routed-expert MLP, nor walks a weight-shared loop; a no-op
             # for any other model.
             on = "spec_k" if cfg.spec_k else "prefill_chunk"
@@ -318,13 +337,21 @@ class DecodeEngine:
         self.allocator = PageAllocator(
             cfg.num_pages, cfg.page_size,
             state_bytes_per_slot=gpt_lib.state_bytes_per_slot(mcfg),
-            row_bytes_per_token=row_bytes)
+            row_bytes_per_token=row_bytes,
+            window_pages=cfg.num_slots * self._ring_pages,
+            ring_pages=self._ring_pages,
+            window_row_bytes_per_token=gpt_lib.kv_row_bytes_per_token(
+                mcfg, self._cache_dtype, window=True))
 
         B, MP = cfg.num_slots, cfg.max_pages_per_seq
         self._slots: list[_Slot | None] = [None] * B
         self._tokens = np.zeros((B,), np.int32)
         self._positions = np.zeros((B,), np.int32)
         self._tables = np.full((B, MP), cfg.num_pages, np.int32)
+        # The lanes' rings in the window layers' pools, that pool's own
+        # sentinel where a lane holds no page; no row without such layers.
+        self._window_tables = np.full(
+            (B, self._ring_pages), self.allocator.window_pages, np.int32)
         self._temp = np.zeros((B,), np.float32)
         self._top_k = np.zeros((B,), np.int32)
         self._top_p = np.zeros((B,), np.float32)
@@ -363,6 +390,9 @@ class DecodeEngine:
         # lane holds (the others read the sentinel's page of zeros).
         self.table_pages = 0
         self.table_pages_held = 0
+        # The same two sums over the window tables (the rings).
+        self.window_table_pages = 0
+        self.window_table_pages_held = 0
         # Running sums of the steps' routing counters (_routing_counters).
         self.moe = dict.fromkeys(("experts_touched", "expert_slots",
                                   "expert_tokens_max", "routed_tokens"), 0)
@@ -469,6 +499,10 @@ class DecodeEngine:
         def step(tree, tokens, positions, tables, pools, temp, tk, tp,
                  seeds):
             params = self._dequant(tree)
+            # With window layers ``tables`` is the pair (full, rings).
+            rings = {}
+            if self._window_layers:
+                tables, rings["window_tables"] = tables
             # An idle lane's table is all sentinel: its page writes drop
             # by themselves, its recurrent state has to be told.
             looped = model.cfg.loop_steps > 1
@@ -478,7 +512,7 @@ class DecodeEngine:
                 {"mutable": ["loop"]} if looped else {}
             out = model.apply(
                 {"params": params}, tokens, pools, tables, positions, *live,
-                method=gpt_lib.GptLM.decode_paged, **sown)
+                method=gpt_lib.GptLM.decode_paged, **rings, **sown)
             (logits, pools), aux = out if sown else (out, None)
             # Per-row keys folded on the ABSOLUTE index being generated:
             # a sampled stream is reproducible for its (seed, position)s
@@ -557,21 +591,32 @@ class DecodeEngine:
         page = self.config.page_size
         p_len = n_pages * page
 
-        def prefill(tree, tokens, pools, phys, slot=None, absorb=None):
+        ring_held = min(n_pages, self._ring_pages)
+
+        def prefill(tree, tokens, pools, phys, slot=None, absorb=None,
+                    ring=None):
             """``slot`` and ``absorb``, for a model with recurrent layers
             only: the lane's slot and how many tokens its state absorbs.
             The state starts from zeros INSIDE this program and lands on
             the slot's row whole, so nothing of the row's last tenant
-            survives."""
+            survives.  ``ring``, for a model with window layers only: the
+            lane's ring pages that this prompt reaches, in ring order."""
             params = self._dequant(tree)
-            caches = gpt_lib.init_kv_cache(mcfg, 1, p_len,
-                                           dtype=self._cache_dtype)
+            caches = gpt_lib.init_kv_cache(
+                mcfg, 1, p_len, dtype=self._cache_dtype,
+                ring_rows=self._ring_pages * page)
             lengths = () if absorb is None else (absorb[None],)
             _, caches = model.apply({"params": params}, tokens, caches,
                                     *lengths, method=gpt_lib.GptLM.prefill)
             def land(kind, cache, pool):
                 if kind == gpt_lib.LINEAR_ATTENTION:
                     return pool.at[slot].set(cache[0])
+                if kind == gpt_lib.SLIDING_ATTENTION:
+                    # The prompt's last rows, position p at ring row
+                    # p % (ring_pages * page): whole ring pages.
+                    return pool.at[
+                        gpt_lib.written_pages(ring, pool.shape[0])].set(
+                        cache[0].reshape(ring_held, page, -1), mode="drop")
                 if mcfg.loop_steps > 1:
                     # A run of pages a loop step: [R, 1, P, G, D] lands
                     # on the R runs of the prompt's pages.
@@ -586,8 +631,9 @@ class DecodeEngine:
                     gpt_lib.written_pages(phys, pool.shape[0])].set(
                     cache[0].reshape(n_pages, page, -1), mode="drop")
 
-            # An entry is (keys, values), a latent layer's (latents,
-            # rotated keys), or (state, convolution tail).
+            # An entry is (keys, values) of a run of pages or of a ring, a
+            # latent layer's (latents, rotated keys), or (state,
+            # convolution tail).
             with profiling.region("cache.write"):
                 return [tuple(land(kind, c, p) for c, p in zip(cache, pool))
                         for kind, cache, pool
@@ -730,6 +776,8 @@ class DecodeEngine:
                 trace=request.trace, request_id=request.id,
                 tenant=request.tenant, pages=len(pages))
         n_prefill = self.allocator.pages_for(P)
+        # The lane's ring in the window layers' pool (empty without such).
+        ring_table = self.allocator.window_table(request.id)
         chunked = cfg.prefill_chunk > 0
         if not chunked:
             # Whole-bucket prefill (legacy): one forward over the whole
@@ -747,10 +795,12 @@ class DecodeEngine:
                 # lane seats with the state after tokens 0..P-2.
                 seat = (np.int32(slot), np.int32(P - 1)) \
                     if self._stateful else ()
+                ring = {"ring": self._jnp.asarray(
+                    ring_table[:n_prefill])} if self._window_layers else {}
                 given = self.pools[0][0]
                 self.pools = self._prefill_fn(n_prefill)(
                     self._tree, self._jnp.asarray(toks), self.pools,
-                    self._jnp.asarray(phys), *seat)
+                    self._jnp.asarray(phys), *seat, **ring)
                 self._gave_away(given)
             except Exception:
                 self.allocator.free(request.id)
@@ -779,7 +829,9 @@ class DecodeEngine:
                     loop_steps=self._loop_steps,
                     cache_rows=self._loop_steps * (
                         len(self.pools) - self._state_layers),
-                    row_bytes=self.allocator.row_bytes_per_token)
+                    row_bytes=self.allocator.row_bytes_per_token,
+                    window_layers=self._window_layers,
+                    ring_pages=self._ring_pages)
         spec = bool(cfg.spec_k) and request.speculative
         state = _Slot(request, cfg.spec_ngram if spec else 0)
         state.table = self.allocator.page_table(request.id,
@@ -801,6 +853,7 @@ class DecodeEngine:
             # the lane goes live immediately — no program runs, no
             # serve.prefill span (nothing prefilled).
             self._tables[slot] = state.table
+            self._window_tables[slot] = ring_table
         self._tokens[slot] = request.prompt[-1]
         self._positions[slot] = P - 1
         self._temp[slot] = request.temperature
@@ -816,6 +869,7 @@ class DecodeEngine:
         """The slot's row as an idle lane rides: the sentinel table, so
         that its writes drop and its state stays, and zeros."""
         self._tables[slot] = self.config.num_pages
+        self._window_tables[slot] = self.allocator.window_pages
         self._tokens[slot] = 0
         self._positions[slot] = 0
         self._temp[slot] = 0.0
@@ -1111,6 +1165,14 @@ class DecodeEngine:
         table = {"table_pages": self._tables.size,
                  "table_pages_held": int(np.count_nonzero(
                      self._tables < self.config.num_pages))}
+        if self._window_layers:
+            # The rings' tables apart, and the window pool's occupancy.
+            table.update(
+                window_table_pages=self._window_tables.size,
+                window_table_pages_held=int(np.count_nonzero(
+                    self._window_tables < self.allocator.window_pages)),
+                window_pages_in_use=self.allocator.window_pages_in_use,
+                window_pages_peak=self.allocator.window_peak_in_use)
         # What this step's lanes hold in recurrent state.
         held = {"state_slots": self.allocator.state_slots,
                 "state_bytes": self.allocator.state_bytes}
@@ -1142,6 +1204,8 @@ class DecodeEngine:
                 self._top_p, self._seeds))))
         if after is not None:
             tokens = self._hand_over(after.out[0], tokens)
+        if self._window_layers:
+            tables = (tables, jnp.asarray(self._window_tables.copy()))
         # The stage, cut at the dispatch: host arrays, their seven uploads
         # and the hand-over before this stamp, the call over the whole
         # parameter tree until it returns after it.
@@ -1229,6 +1293,9 @@ class DecodeEngine:
             self.sample_steps_greedy += 1
         self.table_pages += table["table_pages"]
         self.table_pages_held += table["table_pages_held"]
+        self.window_table_pages += table.get("window_table_pages", 0)
+        self.window_table_pages_held += table.get(
+            "window_table_pages_held", 0)
         with profiling.annotate("serve.step.retire",
                                 pools_in_place=int(in_place),
                                 sampled_lanes=sampled_lanes, **table,
@@ -1478,6 +1545,11 @@ class DecodeEngine:
             # them that named a held page and not the sentinel's zeros.
             "table_pages": self.table_pages,
             "table_pages_held": self.table_pages_held,
+            # The same two over the window layers' ring tables; zeros for
+            # a model without such layers (their pool's occupancy and peak
+            # are under "kv_pool" / "window").
+            "window_table_pages": self.window_table_pages,
+            "window_table_pages_held": self.window_table_pages_held,
             # Running sums of the steps' routing counters; zeros for a
             # model whose MLPs are all dense.
             "moe": dict(self.moe),

@@ -24,6 +24,16 @@ needs.  This module owns the page bookkeeping on the host:
   indexed by the engine's slot and not by page.  A sequence holds its row
   exactly as long as it holds pages, so the snapshot reports both.
 
+- Pages of a SECOND kind, for a model with sliding-window layers
+  (``GptConfig.layer_kinds``): such a layer's pool is small and of its own
+  geometry, and a sequence holds of it a fixed RING of at most
+  ``ring_pages`` pages (the window and a page more) however long it grows,
+  beside the run of pages that grows with it in the full layers' pools.
+  The two kinds are allocated, extended and freed together, one call for
+  both, and counted apart: two free lists, two high-water marks, two
+  tables a sequence (:meth:`PageAllocator.page_table`,
+  :meth:`PageAllocator.window_table`); admission needs room in both.
+
 Device tensors never live here: the allocator hands out page indices and
 sentinel-padded page tables; :mod:`.engine` owns the arrays.
 """
@@ -64,18 +74,41 @@ class PageAllocator:
     whose pools hold that many runs of ``num_pages`` pages under the one
     page table this allocator hands out); reported, never used to decide
     anything.
+
+    ``window_pages`` / ``ring_pages``: the pages of the sliding-window
+    layers' pools (one count for all of them: they share a geometry and a
+    table) and the most of them a sequence holds, its ring; 0 for a model
+    without such layers, and then nothing below differs from a
+    one-kind allocator.  The sentinel of a window table is
+    ``window_pages``, that pool's own page of zeros.
+    ``window_row_bytes_per_token``: bytes a token inside the window holds
+    over those layers; reported only.
     """
 
     def __init__(self, num_pages: int, page_size: int,
                  state_bytes_per_slot: int = 0,
-                 row_bytes_per_token: int = 0):
+                 row_bytes_per_token: int = 0, window_pages: int = 0,
+                 ring_pages: int = 0, window_row_bytes_per_token: int = 0):
         if num_pages < 1 or page_size < 1:
             raise ValueError(f"need positive pool geometry, got "
                              f"{num_pages} pages x {page_size} slots")
+        if window_pages < 0 or bool(window_pages) != bool(ring_pages) \
+                or ring_pages > window_pages:
+            raise ValueError(
+                f"a window pool needs 1 <= ring_pages <= window_pages, got "
+                f"{ring_pages} and {window_pages}")
         self.num_pages = int(num_pages)
         self.page_size = int(page_size)
         self.state_bytes_per_slot = int(state_bytes_per_slot)
         self.row_bytes_per_token = int(row_bytes_per_token)
+        self.window_pages = int(window_pages)
+        self.ring_pages = int(ring_pages)
+        self.window_row_bytes_per_token = int(window_row_bytes_per_token)
+        # The second kind's free list and owners, in the first's discipline.
+        self._window_free: collections.deque[int] = collections.deque(
+            range(window_pages))
+        self._window_owned: dict[object, list[int]] = {}
+        self._window_peak_in_use = 0
         self._peak_sequences = 0
         # Never-used pages dispense lowest-first; freed pages append to the
         # right and are reused oldest-freed-first once the fresh run is
@@ -106,6 +139,15 @@ class PageAllocator:
         return self._peak_in_use
 
     @property
+    def window_pages_in_use(self) -> int:
+        return self.window_pages - len(self._window_free)
+
+    @property
+    def window_peak_in_use(self) -> int:
+        """High-water mark of :attr:`window_pages_in_use`."""
+        return self._window_peak_in_use
+
+    @property
     def sequences(self) -> int:
         return len(self._owned)
 
@@ -121,6 +163,11 @@ class PageAllocator:
     def pages_for(self, tokens: int) -> int:
         """Pages needed to hold ``tokens`` token slots."""
         return -(-int(tokens) // self.page_size)
+
+    def window_pages_for(self, tokens: int) -> int:
+        """Pages of the window pool a sequence of ``tokens`` token slots
+        holds: its run until that is a whole ring, then the ring."""
+        return min(self.pages_for(tokens), self.ring_pages)
 
     def utilization(self) -> float:
         """Fraction of the pool's pages currently reserved."""
@@ -147,7 +194,8 @@ class PageAllocator:
     # ------------------------------------------------------ alloc / free
 
     def can_alloc(self, tokens: int) -> bool:
-        return self.pages_for(tokens) <= len(self._free)
+        return (self.pages_for(tokens) <= len(self._free)
+                and self.window_pages_for(tokens) <= len(self._window_free))
 
     def alloc(self, seq_id, tokens: int) -> list[int]:
         """Reserve pages covering ``tokens`` token slots for ``seq_id``.
@@ -164,10 +212,19 @@ class PageAllocator:
                 raise OutOfPages(
                     f"need {need} page(s) for {tokens} tokens, "
                     f"{len(self._free)} free of {self.num_pages}")
+            ring = self.window_pages_for(tokens)
+            if ring > len(self._window_free):
+                raise OutOfPages(
+                    f"need {ring} window page(s) for {tokens} tokens, "
+                    f"{len(self._window_free)} free of {self.window_pages}")
             pages = [self._free.popleft() for _ in range(need)]
             self._owned[seq_id] = pages
+            self._window_owned[seq_id] = [
+                self._window_free.popleft() for _ in range(ring)]
             self._reserved_tokens[seq_id] = int(tokens)
             self._peak_in_use = max(self._peak_in_use, self.pages_in_use)
+            self._window_peak_in_use = max(self._window_peak_in_use,
+                                           self.window_pages_in_use)
             self._peak_sequences = max(self._peak_sequences,
                                        len(self._owned))
             return list(pages)
@@ -185,13 +242,20 @@ class PageAllocator:
                 self._reserved_tokens[seq_id] = max(
                     self._reserved_tokens[seq_id], int(tokens))
                 return []
-            if need > len(self._free):
+            ring = self._window_owned[seq_id]
+            more = self.window_pages_for(tokens) - len(ring)
+            if need > len(self._free) or more > len(self._window_free):
                 raise OutOfPages(
-                    f"extend needs {need} page(s), {len(self._free)} free")
+                    f"extend needs {need} page(s) and {more} window "
+                    f"page(s), {len(self._free)} and "
+                    f"{len(self._window_free)} free")
             fresh = [self._free.popleft() for _ in range(need)]
             have.extend(fresh)
+            ring.extend(self._window_free.popleft() for _ in range(more))
             self._reserved_tokens[seq_id] = int(tokens)
             self._peak_in_use = max(self._peak_in_use, self.pages_in_use)
+            self._window_peak_in_use = max(self._window_peak_in_use,
+                                           self.window_pages_in_use)
             return fresh
 
     def free(self, seq_id) -> int:
@@ -201,6 +265,7 @@ class PageAllocator:
         with self._lock:
             pages = self._owned.pop(seq_id, None)
             self._reserved_tokens.pop(seq_id, None)
+            self._window_free.extend(self._window_owned.pop(seq_id, ()))
             if not pages:
                 return 0
             self._free.extend(pages)
@@ -217,6 +282,16 @@ class PageAllocator:
                 f"sequence {seq_id!r} holds {len(pages)} pages > "
                 f"max_pages={max_pages}")
         row = np.full((max_pages,), self.num_pages, np.int32)
+        row[:len(pages)] = pages
+        return row
+
+    def window_table(self, seq_id) -> np.ndarray:
+        """[ring_pages] int32 row of the sequence's RING in the window
+        pool, ring page ``j`` at entry ``j``, padded with that pool's
+        sentinel (``window_pages``) where the sequence is shorter than a
+        ring."""
+        row = np.full((self.ring_pages,), self.window_pages, np.int32)
+        pages = self._window_owned.get(seq_id, ())
         row[:len(pages)] = pages
         return row
 
@@ -244,6 +319,17 @@ class PageAllocator:
                 "state_bytes": self.state_bytes,
                 "state_bytes_peak": (self._peak_sequences
                                      * self.state_bytes_per_slot),
+                # The sliding-window layers' pool, counted apart (zeros
+                # for a model without such layers); everything above is
+                # the full layers' pool.
+                "window": {
+                    "num_pages": self.window_pages,
+                    "ring_pages": self.ring_pages,
+                    "pages_in_use": self.window_pages_in_use,
+                    "peak_in_use": self._window_peak_in_use,
+                    "free_pages": len(self._window_free),
+                    "row_bytes_per_token": self.window_row_bytes_per_token,
+                },
             }
 
 

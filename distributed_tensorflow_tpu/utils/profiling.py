@@ -101,6 +101,8 @@ REGIONS = (
     "cache.write",              # K/V, latent or state rows written
     "cache.gather",             # the table-wide read of a paged pool
     "attn.scores",              # scores, mask, softmax, weighted sum
+    "attn.gate",                # the output gate: its projection of the
+                                # mixer's input, the sigmoid, the product
     "attn.out",                 # out projection, post norm, residual add
     "mlp",                      # norm, gate / in / out, residual add
     "head",                     # final norm, the vocabulary projection

@@ -218,14 +218,29 @@ def test_sync_step_with_a_model_axis_keeps_the_dense_fallback(
 # ------------------------------------- a recurrent state beside the pages
 
 
+def bare_engine(model, econf, stateful=False, sparse_layers=0):
+    """A ``DecodeEngine`` that holds its closures and no array: what
+    ``_build_step`` and ``_prefill_fn`` read of ``self``."""
+    from distributed_tensorflow_tpu.serving.engine import DecodeEngine
+    engine = DecodeEngine.__new__(DecodeEngine)
+    engine._jax, engine._jnp, engine.model, engine.config = (
+        jax, jnp, model, econf)
+    engine._stateful, engine._cache_dtype = stateful, None
+    engine._sparse_layers = sparse_layers
+    engine._window_layers = model.cfg.window_layers
+    engine._ring_pages = model.cfg.ring_pages(econf.page_size) \
+        if engine._window_layers else 0
+    engine._prefill_fns, engine._prefill_evictions = {}, 0
+    return engine
+
+
 def serving_programs(one_chip, cfg, buckets, stateful, num_pages=1856,
                      max_pages_per_seq=232):
     """``cfg`` as the engine compiles it under ``longprompt_closed16``'s
     settings (or another pool's): the decode step over 8 slots and a
     whole-bucket prefill a page count, with the engine's own closures
     (their shapes described, nothing placed)."""
-    from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
-                                                           EngineConfig)
+    from distributed_tensorflow_tpu.serving.engine import EngineConfig
     model = gpt_lib.GptLM(cfg)
     econf = EngineConfig(num_slots=8, page_size=16, num_pages=num_pages,
                          max_pages_per_seq=max_pages_per_seq)
@@ -234,12 +249,7 @@ def serving_programs(one_chip, cfg, buckets, stateful, num_pages=1856,
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, dtype or x.dtype, sharding=one_chip), tree)
 
-    engine = DecodeEngine.__new__(DecodeEngine)     # closures, no arrays
-    engine._jax, engine._jnp, engine.model, engine.config = (
-        jax, jnp, model, econf)
-    engine._stateful, engine._cache_dtype = stateful, None
-    engine._sparse_layers = 0
-    engine._prefill_fns, engine._prefill_evictions = {}, 0
+    engine = bare_engine(model, econf, stateful)
     tree = described(jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
         jnp.bfloat16)
@@ -403,8 +413,7 @@ def test_latent_and_routed_expert_programs_compile_for_v5e(one_chip):
     histogram behind the tokens) and a whole-bucket prefill (the flash
     kernel at a head of 256, the grouped products at 4 x 1,024 rows), pools
     donated."""
-    from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
-                                                           EngineConfig)
+    from distributed_tensorflow_tpu.serving.engine import EngineConfig
     from perfbench import spec, worker
     config = spec.load_json(os.path.join(spec.HERE, "configs",
                                          "glm-4.7-flash.json"))
@@ -419,12 +428,7 @@ def test_latent_and_routed_expert_programs_compile_for_v5e(one_chip):
         return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
             x.shape, dtype or x.dtype, sharding=one_chip), tree)
 
-    engine = DecodeEngine.__new__(DecodeEngine)     # closures, no arrays
-    engine._jax, engine._jnp, engine.model, engine.config = (
-        jax, jnp, model, econf)
-    engine._stateful, engine._cache_dtype, engine._sparse_layers = (
-        False, None, 2)
-    engine._prefill_fns, engine._prefill_evictions = {}, 0
+    engine = bare_engine(model, econf, sparse_layers=2)
     tree = described(jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
         jnp.bfloat16)
@@ -507,3 +511,82 @@ def test_looped_serving_programs_compile_for_v5e(one_chip):
     # (what a loop step gathers of a pool: 8 lanes of 48 pages)
     assert gathered_selects(step, 8 * 48 * 16 * 2048 * 2) == []
     assert prefills[16].as_text().count("tpu_custom_call") == 2
+
+
+# ---------------------------- a ring of pages a lane beside the paged pool
+
+
+def test_window_and_full_serving_programs_compile_for_v5e(one_chip):
+    """The leading dense layer (sliding), one sparse sliding layer and the
+    sparse full layer at the published widths of
+    ``perfbench/configs/trinity-mini.json`` under ``longdoc_closed32``'s
+    engine settings: the decode step over 16 slots GATHERS A RING of 129
+    pages a lane in a window layer (2,064 rows, whatever the context) and
+    the table of 2,080 pages in the full layer, each pool donated and
+    aliased, no pass blanking what was gathered; the prefill keeps the
+    flash kernel (a band of 2,048) in both sliding layers and drops the
+    last layer's mixer and experts, whose context a prefill never reads."""
+    from distributed_tensorflow_tpu.serving.engine import EngineConfig
+    from perfbench import spec, worker
+    config = spec.load_json(os.path.join(spec.HERE, "configs",
+                                         "trinity-mini.json"))
+    kinds = (gpt_lib.SLIDING_ATTENTION,) * 2 + (gpt_lib.FULL_ATTENTION,)
+    cfg = dataclasses.replace(worker.gpt_config(
+        {"config": config, "config_file": "trinity-mini.json"}),
+        num_layers=3, layer_kinds=kinds, vocab_size=32768)
+    model = gpt_lib.GptLM(cfg)
+    traffic = spec.load_json(os.path.join(spec.HERE, "traffic",
+                                          "longdoc_closed32.json"))
+    econf = EngineConfig(**traffic["engine"])
+
+    def described(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+    engine = bare_engine(model, econf, sparse_layers=2)
+    assert (engine._window_layers, engine._ring_pages) == (2, 129)
+    tree = described(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+        jnp.bfloat16)
+    pools = described(jax.eval_shape(lambda: gpt_lib.init_kv_pool(
+        cfg, econf.num_pages, econf.page_size, num_slots=econf.num_slots)))
+    leaves = jax.tree.leaves(pools)
+    # a window layer: 16 rings of 129 pages and its sentinel's page
+    assert [x.shape for x in leaves] == [(16 * 129 + 1, 16, 512)] * 4 + [
+        (33280 + 1, 16, 512)] * 2
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,  # noqa: E731
+                                          sharding=one_chip)
+    B, MP, RP = econf.num_slots, econf.max_pages_per_seq, 129
+    lowered = engine._build_step().lower(
+        tree, i32(B), i32(B), (i32(B, MP), i32(B, RP)), pools, f32(B),
+        i32(B), f32(B), i32(B))
+    # 16 tokens and behind them 2 layers x 128 experts of histogram
+    assert lowered.out_info[0].shape == (B + 2 * 128,)
+    step = lowered.compile()
+    text = step.as_text()
+    gathers = set(re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text))
+    # what each kind of layer gathers of its pool: the ring, the table
+    assert "bf16[16,129,16,512]" in gathers
+    assert "bf16[16,2080,16,512]" in gathers
+    assert not any(g.startswith("bf16[16,2080") for g in gathers
+                   - {"bf16[16,2080,16,512]"})
+    assert text.count("tpu_custom_call") == 2 * 3
+    # no pass over a ring's gathered rows, nor over the table's (the one
+    # select of that size is the mask over the full layer's float32 scores)
+    assert [line for line in gathered_selects(step, 16 * 129 * 16 * 512 * 2)
+            if " = f32[16,32,33280]" not in line] == []
+    prefill = engine._prefill_fn(256).lower(
+        tree, i32(1, 4096), pools, i32(256), ring=i32(129)).compile()
+    # two banded flash calls and ONE sparse layer's grouped products
+    assert prefill.as_text().count("tpu_custom_call") == 2 + 3
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    for program in (step, prefill):
+        mem = program.memory_analysis()
+        assert mem.temp_size_in_bytes < 2e9
+        assert mem.alias_size_in_bytes == pool_bytes
+        header = program.as_text().split("\n", 1)[0]
+        assert header.count("may-alias") + header.count(
+            "must-alias") == len(leaves)
+        assert relayouts(program, leaves[0].size * 2) == []

@@ -40,6 +40,15 @@ FORMS = {
         first_dense_layers=1),
     "looped": dict(pos_encoding="rope", activation="swiglu", norm="rmsnorm",
                    norm_placement="sandwich", loop_steps=3, exit_gate=True),
+    "sliding": dict(
+        num_layers=3, pos_encoding="rope", kv_heads=2, head_size=32,
+        activation="swiglu", norm="rmsnorm", norm_placement="sandwich",
+        layer_kinds=("sliding_attention",) * 2 + ("full_attention",),
+        sliding_window=16, rope_kinds=("sliding_attention",),
+        qk_head_norm=True, attn_output_gate=True, scale_embedding=True,
+        num_experts=8, experts_per_token=2, expert_intermediate_size=32,
+        num_shared_experts=1, routed_scaling_factor=2.826,
+        first_dense_layers=1),
 }
 SHARED = {"embed", "attn.qkv", "cache.write", "attn.out", "mlp"}
 STEP = SHARED | {"cache.gather", "head", "sample"}
@@ -59,6 +68,11 @@ NAMES = {
     ("looped", "step"): STEP | {"attn.scores", "loop.step",
                                 "loop.exit_gate"},
     ("looped", "prefill"): SHARED | {"attn.scores", "loop.step"},
+    ("sliding", "step"): STEP | {"attn.scores", "attn.gate", "moe.route",
+                                 "moe.experts", "moe.shared"},
+    ("sliding", "prefill"): SHARED | {"attn.scores", "attn.gate",
+                                      "moe.route", "moe.experts",
+                                      "moe.shared"},
     ("dense", "train"): (SHARED - {"cache.write"}) | {
         "attn.scores", "head", "loss", "optimizer"},
 }
@@ -83,13 +97,17 @@ def lowered(form: str, program: str):
         return jax.jit(update).lower(state, i32(2, 16))
     engine = DecodeEngine(model, params, EngineConfig(
         num_slots=2, page_size=8, num_pages=16, max_pages_per_seq=4))
+    # A lane's ring beside its run of pages, where layers are windowed.
+    rings = cfg.ring_pages(8) if cfg.window_layers else 0
     if program == "step":
+        tables = (i32(2, 4), i32(2, rings)) if rings else i32(2, 4)
         return engine._step_fn.lower(
-            engine._tree, i32(2), i32(2), i32(2, 4), engine.pools, f32(2),
+            engine._tree, i32(2), i32(2), tables, engine.pools, f32(2),
             i32(2), f32(2), i32(2))
     lane = (i32(), i32()) if cfg.has_state_layers else ()
+    ring = {"ring": i32(min(2, rings))} if rings else {}
     return engine._prefill_fn(2).lower(
-        engine._tree, i32(1, 16), engine.pools, i32(2), *lane)
+        engine._tree, i32(1, 16), engine.pools, i32(2), *lane, **ring)
 
 
 def named_in(text: str) -> set:
@@ -139,7 +157,7 @@ def test_a_name_outside_the_vocabulary_raises_where_the_region_is_made():
         @profiling.region("nope")
         def never(x):
             return x
-    assert len(set(profiling.REGIONS)) == len(profiling.REGIONS) == 20
+    assert len(set(profiling.REGIONS)) == len(profiling.REGIONS) == 21
 
 
 def test_regions_nest_and_the_innermost_is_last():
