@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 from ..ops import linear_attention as linear_ops
 from ..ops import routed_experts as experts_ops
 from ..ops.attention import dot_product_attention
+from ..ops.pallas import paged_attention as paged_ops
 from ..parallel.sharding import ShardingRules
 from ..utils import profiling
 
@@ -1427,6 +1428,14 @@ class GptBlock(nn.Module):
         Distinct slots never share a page (the allocator's invariant), so
         the per-row scatter has no duplicate indices.
 
+        TWO forms of the read, the same sums (:func:`paged_kernel_attends`
+        chooses by what it can observe; PR 45): on a TPU under a Pallas
+        configuration the paged-attention kernel copies a lane's HELD
+        pages up to its position's out of the pools, once, and scores
+        them chunk by chunk (an idle lane costs nothing; no page a lane
+        does not own is touched); otherwise, and on the CPU, the plain
+        form below gathers every entry of the table and masks.
+
         A SLIDING_ATTENTION layer's ``page_table`` [B, RP] is the row's
         RING (``RP = cfg.ring_pages(page_size)``; its pool is its own, the
         sentinel that pool's last page): position ``p`` lives in ring page
@@ -1464,19 +1473,28 @@ class GptBlock(nn.Module):
                 k.reshape(B, -1).astype(k_pool.dtype), mode="drop")
             v_pool = v_pool.at[phys, off].set(
                 v.reshape(B, -1).astype(v_pool.dtype), mode="drop")
-        with profiling.region("cache.gather"):
-            s = jnp.arange(MP * page)
-            allocated = jnp.take_along_axis(
-                page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
-            if ring:
-                # How far behind the row's position slot s's newest row is.
-                behind = (positions[:, None] - s[None, :]) % (MP * page)
-                valid = ((behind < cfg.sliding_window)
-                         & (behind <= positions[:, None]) & allocated)
-            else:
-                valid = (s[None, :] <= positions[:, None]) & allocated
-        ctx = self._attend_rows(q, gather_pages(k_pool, page_table),
-                                gather_pages(v_pool, page_table), valid)
+        if paged_kernel_attends(cfg, k_pool):
+            # The same sums over the pages the lane HOLDS, read once where
+            # they lie (ops/pallas/paged_attention.py).
+            with profiling.region("attn.scores"):
+                ctx = paged_ops.paged_attention(
+                    q[:, 0], k_pool, v_pool, page_table, positions,
+                    window=cfg.sliding_window if ring else 0)[:, None]
+        else:
+            with profiling.region("cache.gather"):
+                s = jnp.arange(MP * page)
+                allocated = jnp.take_along_axis(
+                    page_table, (s[None, :] // page), axis=1) < sentinel
+                if ring:
+                    # How far behind the row's position slot s's newest
+                    # row is.
+                    behind = (positions[:, None] - s[None, :]) % (MP * page)
+                    valid = ((behind < cfg.sliding_window)
+                             & (behind <= positions[:, None]) & allocated)
+                else:
+                    valid = (s[None, :] <= positions[:, None]) & allocated
+            ctx = self._attend_rows(q, gather_pages(k_pool, page_table),
+                                    gather_pages(v_pool, page_table), valid)
         x = self._add_mixed(x, ctx)
         return self._mlp(x, deterministic=True), k_pool, v_pool
 
@@ -1835,6 +1853,26 @@ def written_pages(pages: jax.Array, rows: int) -> jax.Array:
     names it (an idle or passenger lane's, a page never allocated) goes
     one past the pool, where ``.at[...].set(mode="drop")`` drops it."""
     return jnp.where(pages < rows - 1, pages, rows)
+
+
+def paged_kernel_attends(cfg: GptConfig, pool) -> bool:
+    """Whether ``GptBlock.decode_step_paged`` attends a K/V ``pool`` (an
+    array or its shape and type) through the paged-attention kernel: where
+    the configuration asks for Pallas kernels, the backend is a TPU and
+    the pool is one the kernel can walk (``paged_ops.supports``: a float8
+    pool's page of 16 rows is half a tile).  Otherwise, and so on the CPU,
+    the plain form: :func:`gather_pages` and ``GptBlock._attend_rows``."""
+    return (cfg.attention_backend == "pallas"
+            and jax.default_backend() == "tpu"
+            and paged_ops.supports(pool, cfg.head_dim))
+
+
+def paged_kernel_layers(cfg: GptConfig, pools) -> int:
+    """The layers of ``cfg`` whose entry of ``pools``
+    (:func:`init_kv_pool`) the decode step attends through the kernel."""
+    return sum(kind in (FULL_ATTENTION, SLIDING_ATTENTION)
+               and paged_kernel_attends(cfg, entry[0])
+               for kind, entry in zip(cfg.kinds, pools))
 
 
 def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:

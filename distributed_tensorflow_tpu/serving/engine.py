@@ -78,7 +78,16 @@ any page, so how much of a step's gather reads zeros is a count the host
 can make from its own ``_tables``: each ``serve_step`` record says
 ``table_pages`` (slots x ``max_pages_per_seq``) and ``table_pages_held``
 (the entries that name a page a lane owns), and
-:meth:`DecodeEngine.stats` sums both.
+:meth:`DecodeEngine.stats` sums both.  Where the step attends its K/V pools
+through the paged-attention kernel (``gpt_lib.paged_kernel_attends``: a
+Pallas configuration on a TPU) it reads no table whole: a lane's pages up
+to its position's, and no page of an idle lane.  ``attn_pages_read`` is
+that walk's length summed over the lanes, counted from the host's own
+``_positions`` and ``_tables`` whichever path runs, and
+``attn_kernel_layers`` the layers of the step program on the kernel's path
+(0 on a CPU, and in a speculative turn, which attends a chunk the plain
+way): ``attn_pages_read / table_pages`` is the share of the table the step
+pays for on the kernel, beside ``table_pages_held / table_pages``.
 
 A model with SLIDING-WINDOW layers among its full ones
 (``GptConfig.layer_kinds``) holds pages of two kinds: each full layer's
@@ -89,7 +98,8 @@ both; a lane has a table of each kind and the step uploads both; a prefill
 lands a prompt's last rows on the lane's ring.  ``table_pages`` /
 ``table_pages_held`` stay the full tables'; the rings' own ride beside
 them as ``window_table_pages`` / ``window_table_pages_held``, with
-``window_pages_in_use`` / ``window_pages_peak``.
+``window_pages_in_use`` / ``window_pages_peak``, and the rings' walk as
+``window_attn_pages_read``.
 """
 
 from __future__ import annotations
@@ -103,6 +113,7 @@ import numpy as np
 
 from ..models import gpt as gpt_lib
 from ..models.drafting import NGramIndex
+from ..ops.pallas.paged_attention import pages_walked
 from ..ops.quant import (load_inference_tree, prepare_inference_tree,
                          resolve_kv_dtype, validate_quantize)
 from ..utils import profiling, tracing
@@ -334,6 +345,10 @@ class DecodeEngine:
         self.model_step = 0            # checkpoint step the weights carry
         self.swaps = 0
         self.pools = self._fresh_pools()
+        # Layers of the step program that attend their pool through the
+        # paged-attention kernel: every K/V layer on a TPU under a Pallas
+        # configuration, none on a CPU.
+        self._kernel_layers = gpt_lib.paged_kernel_layers(mcfg, self.pools)
         self.allocator = PageAllocator(
             cfg.num_pages, cfg.page_size,
             state_bytes_per_slot=gpt_lib.state_bytes_per_slot(mcfg),
@@ -393,6 +408,13 @@ class DecodeEngine:
         # The same two sums over the window tables (the rings).
         self.window_table_pages = 0
         self.window_table_pages_held = 0
+        # The pages a held-pages-only read visits (a lane's up to its
+        # position's, none of an idle lane), summed over the steps, of the
+        # full tables and of the rings; and the layer-steps that read that
+        # way, through the paged-attention kernel.
+        self.attn_pages_read = 0
+        self.window_attn_pages_read = 0
+        self.attn_kernel_layers = 0
         # Running sums of the steps' routing counters (_routing_counters).
         self.moe = dict.fromkeys(("experts_touched", "expert_slots",
                                   "expert_tokens_max", "routed_tokens"), 0)
@@ -1162,15 +1184,26 @@ class DecodeEngine:
         sampled_lanes = int(np.count_nonzero(self._temp > 0.0))
         # What the gather of this dispatch reads: all of the table, of
         # which this many entries are pages and not the sentinel.
+        # And what a read of held pages only visits, which the step makes
+        # on the kernel's path (a chunk of drafts is attended the plain way).
+        page = self.config.page_size
         table = {"table_pages": self._tables.size,
                  "table_pages_held": int(np.count_nonzero(
-                     self._tables < self.config.num_pages))}
+                     self._tables < self.config.num_pages)),
+                 "attn_pages_read": int(pages_walked(
+                     self._tables, self._positions, self.config.num_pages,
+                     page).sum()),
+                 "attn_kernel_layers":
+                     0 if spec_mode else self._kernel_layers}
         if self._window_layers:
             # The rings' tables apart, and the window pool's occupancy.
             table.update(
                 window_table_pages=self._window_tables.size,
                 window_table_pages_held=int(np.count_nonzero(
                     self._window_tables < self.allocator.window_pages)),
+                window_attn_pages_read=int(pages_walked(
+                    self._window_tables, self._positions,
+                    self.allocator.window_pages, page).sum()),
                 window_pages_in_use=self.allocator.window_pages_in_use,
                 window_pages_peak=self.allocator.window_peak_in_use)
         # What this step's lanes hold in recurrent state.
@@ -1296,6 +1329,9 @@ class DecodeEngine:
         self.window_table_pages += table.get("window_table_pages", 0)
         self.window_table_pages_held += table.get(
             "window_table_pages_held", 0)
+        self.attn_pages_read += table["attn_pages_read"]
+        self.window_attn_pages_read += table.get("window_attn_pages_read", 0)
+        self.attn_kernel_layers += table["attn_kernel_layers"]
         with profiling.annotate("serve.step.retire",
                                 pools_in_place=int(in_place),
                                 sampled_lanes=sampled_lanes, **table,
@@ -1550,6 +1586,12 @@ class DecodeEngine:
             # are under "kv_pool" / "window").
             "window_table_pages": self.window_table_pages,
             "window_table_pages_held": self.window_table_pages_held,
+            # Pages a read of held pages only visits (full tables, rings),
+            # and the layer-steps that read so, on the paged-attention
+            # kernel: 0 on a CPU.
+            "attn_pages_read": self.attn_pages_read,
+            "window_attn_pages_read": self.window_attn_pages_read,
+            "attn_kernel_layers": self.attn_kernel_layers,
             # Running sums of the steps' routing counters; zeros for a
             # model whose MLPs are all dense.
             "moe": dict(self.moe),

@@ -331,11 +331,22 @@ def gathered_selects(program, floor: int) -> list:
     return _instructions(program.as_text(), "select", floor)
 
 
+def table_gathers(program, floor: int) -> list:
+    """The ``gather`` instructions of a compiled program whose result
+    holds ``floor`` bytes or more: a page table's pages side by side
+    (``gpt_lib.gather_pages``), what every paged K/V layer built twice a
+    step until the paged-attention kernel read a lane's held pages where
+    they lie (PR 45).  The step's other gathers (a token's embedding, a
+    table's entry) are rows, not pages."""
+    return _instructions(program.as_text(), "gather", floor)
+
+
 def pools_donated_and_uncopied(programs, pools, n_leaves, temp_below):
     """The engine donates its pools: every leaf comes out in the buffer it
     went in by, and nothing else does; and no program re-lays out as many
     bytes as the decode step gathers of a K/V pool (8 lanes of 232 pages:
-    all but one page of it), nor blanks them: the hybrid's sixteen pool
+    all but one page of it), nor blanks them, nor (the step) gathers a
+    lane's share of them: the hybrid's sixteen pool
     copies a step before PR 37, the two passes over the gathered rows that
     a head axis split off them AFTER the gather costs instead, the dense
     model's heads-major copy of them, and the zero-fill of sentinel
@@ -357,6 +368,8 @@ def pools_donated_and_uncopied(programs, pools, n_leaves, temp_below):
             "must-alias") == len(leaves)
         assert relayouts(program, floor) == []
     assert gathered_selects(programs[0], floor) == []
+    # ... nor gathers them at all (PR 45): the kernel copies held pages.
+    assert table_gathers(programs[0], floor // 8) == []
 
 
 def test_hybrid_serving_programs_compile_for_v5e(one_chip):
@@ -365,12 +378,13 @@ def test_hybrid_serving_programs_compile_for_v5e(one_chip):
     layer's prefill where the bucket's length lets it in: 1,024 tokens do,
     1,600 do not (``_layout_ok``; D6's silent fallback).  The K/V pools'
     row is flat, [1856 + 1, 16, 30 * 128] with the sentinel's page of
-    zeros, the chip keeps it as the step indexes it and the step attends
-    the gathered rows as they are: no pool-sized relayout (four copies a
-    full layer with a head axis of 30; PR 37) and no pass that blanks the
-    gathered rows (two a full layer; PR 39)."""
+    zeros, the chip keeps it as the step indexes it and the step's ONE
+    full layer reads it through the paged-attention kernel, one Mosaic
+    call (PR 45): no pool-sized relayout (four copies a full layer with a
+    head axis of 30; PR 37), no pass that blanks gathered rows (two a
+    full layer; PR 39) and no gather of the table."""
     step, prefills, pools = hybrid_serving_programs(one_chip, (64, 100))
-    assert step.as_text().count("tpu_custom_call") == 0
+    assert step.as_text().count("tpu_custom_call") == 1
     # (A full layer that is the model's LAST layer loses its call: its
     # attention output feeds only logits the prefill throws away, so XLA
     # keeps its K/V and drops the rest.  Here a linear layer follows it.)
@@ -384,10 +398,10 @@ def test_hybrid_serving_programs_compile_for_v5e(one_chip):
 def test_dense_serving_programs_keep_their_pools_for_v5e(one_chip):
     """The same step and prefill at ``perfbench/configs/mistral-7b.json``'s
     widths (8 K/V heads of 128, two layers): the flat row [1857, 16, 1024]
-    is scattered into in place as its four-axis form was, and attended
-    flat it loses that form's heads-major copy of the gathered rows (two a
-    layer), so a later pool shape or gather cannot bring a relayout or a
-    blanking pass to either configuration without a red test."""
+    is scattered into in place as its four-axis form was, and the step
+    reads it where it lies, one paged-attention call a layer (PR 45), so a
+    later pool shape or gather cannot bring a relayout, a blanking pass or
+    a table-wide gather to either configuration without a red test."""
     cfg = gpt_lib.GptConfig(
         vocab_size=32000, hidden_size=4096, num_layers=2, num_heads=32,
         kv_heads=8, intermediate_size=14336, max_position=4096,
@@ -398,6 +412,7 @@ def test_dense_serving_programs_keep_their_pools_for_v5e(one_chip):
     assert [x.shape for x in jax.tree.leaves(pools)] == [(1857, 16, 1024)] * 4
     # The flash kernel in both layers' prefill but the last's (above).
     assert prefills[64].as_text().count("tpu_custom_call") == 1
+    assert step.as_text().count("tpu_custom_call") == 2
     pools_donated_and_uncopied((step, *prefills.values()), pools,
                                n_leaves=2 * 2, temp_below=2e9)
 
@@ -482,8 +497,10 @@ def test_looped_serving_programs_compile_for_v5e(one_chip):
     four runs of pages and the sentinel's page in one array [4 x 384 + 1,
     16, 2048] which the loop carries in place (donated, aliased, no
     relayout of a pool's size in the body or outside it, no pass that
-    blanks what a loop step gathered of its run), and the prefill keeps the
-    flash kernel in every layer of the loop."""
+    blanks what a loop step gathered of its run, and since PR 45 no gather
+    of a run: a paged-attention call a layer inside the loop, walking the
+    offset table), and the prefill keeps the flash kernel in every layer
+    of the loop."""
     from perfbench import spec, worker
     config = spec.load_json(os.path.join(spec.HERE, "configs",
                                          "ouro-2.6b.json"))
@@ -507,9 +524,10 @@ def test_looped_serving_programs_compile_for_v5e(one_chip):
             "must-alias") == len(leaves)
         assert relayouts(program, pool_bytes // len(leaves),
                          bodies=True) == []
-    assert step.as_text().count("tpu_custom_call") == 0
-    # (what a loop step gathers of a pool: 8 lanes of 48 pages)
+    assert step.as_text().count("tpu_custom_call") == 2
+    # (what a loop step gathered of a pool: 8 lanes of 48 pages)
     assert gathered_selects(step, 8 * 48 * 16 * 2048 * 2) == []
+    assert table_gathers(step, 48 * 16 * 2048 * 2) == []
     assert prefills[16].as_text().count("tpu_custom_call") == 2
 
 
@@ -520,10 +538,11 @@ def test_window_and_full_serving_programs_compile_for_v5e(one_chip):
     """The leading dense layer (sliding), one sparse sliding layer and the
     sparse full layer at the published widths of
     ``perfbench/configs/trinity-mini.json`` under ``longdoc_closed32``'s
-    engine settings: the decode step over 16 slots GATHERS A RING of 129
+    engine settings: the decode step over 16 slots reads A RING of 129
     pages a lane in a window layer (2,064 rows, whatever the context) and
-    the table of 2,080 pages in the full layer, each pool donated and
-    aliased, no pass blanking what was gathered; the prefill keeps the
+    the held pages of a table of 2,080 in the full layer, each through a
+    paged-attention call (PR 45: until then a gather of either, whole),
+    each pool donated and aliased; the prefill keeps the
     flash kernel (a band of 2,048) in both sliding layers and drops the
     last layer's mixer and experts, whose context a prefill never reads."""
     from distributed_tensorflow_tpu.serving.engine import EngineConfig
@@ -566,17 +585,12 @@ def test_window_and_full_serving_programs_compile_for_v5e(one_chip):
     assert lowered.out_info[0].shape == (B + 2 * 128,)
     step = lowered.compile()
     text = step.as_text()
-    gathers = set(re.findall(r"= (\w+\[[\d,]*\])\S* gather\(", text))
-    # what each kind of layer gathers of its pool: the ring, the table
-    assert "bf16[16,129,16,512]" in gathers
-    assert "bf16[16,2080,16,512]" in gathers
-    assert not any(g.startswith("bf16[16,2080") for g in gathers
-                   - {"bf16[16,2080,16,512]"})
-    assert text.count("tpu_custom_call") == 2 * 3
-    # no pass over a ring's gathered rows, nor over the table's (the one
-    # select of that size is the mask over the full layer's float32 scores)
-    assert [line for line in gathered_selects(step, 16 * 129 * 16 * 512 * 2)
-            if " = f32[16,32,33280]" not in line] == []
+    # three grouped products a sparse layer, a paged-attention call a layer
+    assert text.count("tpu_custom_call") == 2 * 3 + 3
+    # neither kind of layer gathers its pool (a lane's ring: 129 pages), and
+    # nothing is left to blank or to mask at the table's size
+    assert table_gathers(step, 129 * 16 * 512 * 2) == []
+    assert gathered_selects(step, 16 * 129 * 16 * 512 * 2) == []
     prefill = engine._prefill_fn(256).lower(
         tree, i32(1, 4096), pools, i32(256), ring=i32(129)).compile()
     # two banded flash calls and ONE sparse layer's grouped products

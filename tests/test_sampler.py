@@ -326,9 +326,13 @@ def test_the_profilers_retire_event_carries_sampled_lanes(monkeypatch):
     # PR 40's two clock readings of the stage, whole microseconds)
     assert all(isinstance(retire[0].pop(k), int)
                for k in ("upload_us", "dispatch_us"))
-    # (and this PR's three of the hand-over: a first step is a serial one)
+    # (and this PR's three of the hand-over: a first step is a serial one;
+    # and PR 45's two: of a lane's 4 reserved pages the prompt reaches 3,
+    # which is what a read of held pages only would visit, and on a CPU no
+    # layer reads so)
     assert retire == [{"pools_in_place": 1, "sampled_lanes": 2,
                        "table_pages": 3 * 8, "table_pages_held": 12,
+                       "attn_pages_read": 9, "attn_kernel_layers": 0,
                        "steps_ahead": 0, "steps_serial": 1,
                        "lane_steps_discarded": 0}]
 

@@ -5,7 +5,9 @@ step's gather reads it like any page, so no pass blanks the gathered rows,
 and what holds is exact: **a lane's output depends on no page it does not
 own at that step**, non-finite values there included, in every paged form
 (K/V rows in a dense and in a hybrid decoder, the K/V chunk, a loop step's
-run of pages, a latent row's two parts).  On the parent of PR 39 the K/V
+run of pages, a latent row's two parts; since PR 45 also where the step
+attends its pools through the paged-attention kernel, which COPIES no page
+a lane does not own).  On the parent of PR 39 the K/V
 cases hold too (``mode="fill"`` wrote the zeros) and the latent ones FAIL:
 its gather clipped a sentinel entry onto the pool's last real page, whose
 rows reach every lane's weighted sum as ``0 x row``.
@@ -19,6 +21,7 @@ import numpy as np
 import pytest
 
 from distributed_tensorflow_tpu.models import gpt as gpt_lib
+from distributed_tensorflow_tpu.ops.pallas import paged_attention as paged_ops
 from distributed_tensorflow_tpu.serving.engine import (DecodeEngine,
                                                        EngineConfig)
 from distributed_tensorflow_tpu.serving.scheduler import Request
@@ -37,6 +40,12 @@ CONFIGS = {
         linear_num_heads=2, linear_key_head_dim=8, linear_value_head_dim=16),
     "looped": dict(pos_encoding="rope", activation="swiglu", norm="rmsnorm",
                    norm_placement="sandwich", loop_steps=3, exit_gate=True),
+    # The paged-attention kernel's shapes: a head fills 128 lanes, and (in
+    # float32) a page whole tiles of 8 rows.  Steered onto the kernel's
+    # path below, under the TPU interpreter.
+    "kernel": dict(pos_encoding="rope", num_heads=2, kv_heads=1,
+                   head_size=128, activation="swiglu", norm="rmsnorm",
+                   attention_backend="pallas"),
     "latent": dict(
         num_layers=3, pos_encoding="none", activation="swiglu",
         norm="rmsnorm", rope_base=1e6, latent_kv_rank=32, latent_q_rank=48,
@@ -66,12 +75,12 @@ def paged(kind):
     return kind != gpt_lib.LINEAR_ATTENTION
 
 
-def junk_pools(cfg, seed=0):
+def junk_pools(cfg, seed=0, page=PAGE):
     """Pools as a server that has run for a while holds them: every page
     but the sentinel's holds what some owner wrote (a freed page is never
     blanked), every slot a recurrent state."""
     keys = iter(jax.random.split(jax.random.key(seed), 64))
-    pools = gpt_lib.init_kv_pool(cfg, PAGES, PAGE, num_slots=len(TABLES))
+    pools = gpt_lib.init_kv_pool(cfg, PAGES, page, num_slots=len(TABLES))
     return [tuple(
         jax.random.normal(next(keys), x.shape, x.dtype).at[-1].set(0)
         if paged(kind) else jax.random.normal(next(keys), x.shape,
@@ -101,7 +110,9 @@ def sentinel_pages_are_zero(cfg, pools):
 
 
 def run(model, params, program, pools):
-    tables, positions = jnp.asarray(TABLES), jnp.asarray(POSITIONS)
+    # (the same rows of each lane's pages whatever the page's size)
+    tables = jnp.asarray(TABLES)
+    positions = jnp.asarray(POSITIONS * (pools[-1][0].shape[1] // PAGE))
     if program == "chunk":
         # Lane 0's four tokens run past its two pages (positions 8, 9 have
         # no page), lane 1's past its table (16: no entry at all).
@@ -116,28 +127,47 @@ def run(model, params, program, pools):
 
 
 FORMS = [("dense", "step"), ("dense", "chunk"), ("hybrid", "step"),
-         ("looped", "step"), ("latent", "step")]
+         ("looped", "step"), ("latent", "step"), ("kernel", "step")]
+
+
+def on_the_kernels_path(monkeypatch):
+    """What a TPU decides by its backend, steered here: the step attends
+    every pool the kernel can walk through it (interpreted off the chip)."""
+    monkeypatch.setattr(
+        gpt_lib, "paged_kernel_attends",
+        lambda cfg, pool: paged_ops.supports(pool, cfg.head_dim))
 
 
 @pytest.mark.parametrize("value", [np.nan, np.inf], ids=["nan", "inf"])
 @pytest.mark.parametrize("name,program", FORMS,
                          ids=["-".join(f) for f in FORMS])
 def test_a_lanes_logits_depend_on_no_page_it_does_not_own(
-        name, program, value):
+        name, program, value, monkeypatch):
     model, params = model_of(name)
     cfg = model.cfg
-    pools = junk_pools(cfg)
+    page = PAGE
+    if name == "kernel":
+        on_the_kernels_path(monkeypatch)
+        monkeypatch.setattr(paged_ops, "_CHUNK_MAX", 128)
+        page = 2 * PAGE
+        calls = []
+        real = paged_ops.paged_attention
+        monkeypatch.setattr(paged_ops, "paged_attention", lambda *a, **kw: (
+            calls.append(kw), real(*a, **kw))[1])
+    pools = junk_pools(cfg, page=page)
     assert sentinel_pages_are_zero(cfg, pools)
     assert all(x.shape[0] == cfg.loop_steps * PAGES + 1
                for kind, entry in zip(cfg.kinds, pools) if paged(kind)
                for x in entry)
-    want, after = jax.jit(lambda p: run(model, params, program, p))(pools)
+    step = jax.jit(lambda p: run(model, params, program, p))
+    want, after = step(pools)
+    if name == "kernel":
+        assert len(calls) == cfg.num_layers        # traced once, a call a layer
     want = np.asarray(want)
     assert np.isfinite(want[:3]).all() and np.abs(want[:3]).max() > 0.1
     assert sentinel_pages_are_zero(cfg, after)
     for lane in range(3):
-        got, after = jax.jit(lambda p: run(model, params, program, p))(
-            poisoned(cfg, pools, lane, value))
+        got, after = step(poisoned(cfg, pools, lane, value))
         got = np.asarray(got)
         assert got.dtype == want.dtype
         np.testing.assert_array_equal(got[lane].view(np.uint32),
@@ -177,16 +207,18 @@ class Rows:
 
 def engine_of(name, records=None, **kw):
     model, params = model_of(name)
-    return DecodeEngine(model, params, EngineConfig(
-        num_slots=3, page_size=PAGE, num_pages=PAGES, max_pages_per_seq=MP,
-        **kw), telemetry=None if records is None else Telemetry(records))
+    return DecodeEngine(model, params, EngineConfig(**{
+        "num_slots": 3, "page_size": PAGE, "num_pages": PAGES,
+        "max_pages_per_seq": MP, **kw}),
+        telemetry=None if records is None else Telemetry(records))
 
 
 @pytest.mark.parametrize("name,kw", [
     ("dense", {}), ("dense", {"spec_k": 4}), ("dense", {"prefill_chunk": 3}),
-    ("hybrid", {}), ("looped", {}), ("latent", {})],
+    ("hybrid", {}), ("looped", {}), ("latent", {}),
+    ("kernel", {"page_size": 2 * PAGE})],
     ids=["dense", "dense-spec", "dense-chunked", "hybrid", "looped",
-         "latent"])
+         "latent", "kernel"])
 def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
         name, kw, monkeypatch):
     """A short run with admissions and retirements, a slot reused, a
@@ -196,7 +228,14 @@ def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
     allocator never hands it out, and ``table_pages`` /
     ``table_pages_held`` on the ``serve_step`` record, on the profiler's
     retire event and in ``engine.stats()`` are a NumPy count of the table
-    each dispatch was handed."""
+    each dispatch was handed; ``attn_pages_read`` beside them a count of
+    the pages a lane holds up to its position's (what the paged-attention
+    kernel copies), and ``attn_kernel_layers`` the layers of the dispatched
+    program that read so: none on the CPU's plain path, every K/V layer in
+    the case steered onto the kernel's."""
+    if name == "kernel":
+        on_the_kernels_path(monkeypatch)
+        monkeypatch.setattr(paged_ops, "_CHUNK_MAX", 128)
     seen = []
     real = profiling.annotate
     monkeypatch.setattr(profiling, "annotate", lambda name, **stats: (
@@ -208,16 +247,24 @@ def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
     assert engine.stats()["kv_pool"]["num_pages"] == PAGES
     counted = []
 
-    def counting(fn):
+    page = engine.config.page_size
+
+    def counting(fn, kernel_layers):
         def dispatch(tree, tokens, positions, tables, *rest):
-            table = np.asarray(tables)
-            counted.append({"table_pages": table.size,
-                            "table_pages_held": int((table < PAGES).sum())})
+            table, at = np.asarray(tables), np.asarray(positions)
+            # a lane's held pages at or before its position's page
+            upto = np.arange(MP)[None, :] <= at[:, None] // page
+            counted.append({
+                "table_pages": table.size,
+                "table_pages_held": int((table < PAGES).sum()),
+                "attn_pages_read": int(((table < PAGES) & upto).sum()),
+                "attn_kernel_layers": kernel_layers})
             return fn(tree, tokens, positions, tables, *rest)
         return dispatch
-    engine._step_fn = counting(engine._step_fn)
+    engine._step_fn = counting(
+        engine._step_fn, cfg.num_layers if name == "kernel" else 0)
     if engine._spec_step_fn is not None:
-        engine._spec_step_fn = counting(engine._spec_step_fn)
+        engine._spec_step_fn = counting(engine._spec_step_fn, 0)
 
     rng = np.random.default_rng(39)
     waiting = [Request(rng.integers(0, 64, P).tolist(), n,
@@ -243,4 +290,13 @@ def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
     # some entries were held and some read the sentinel's page
     assert 0 < stats["table_pages_held"] < stats["table_pages"]
     assert all(c["table_pages"] == 3 * MP for c in counted)
+    # the walk is held pages, and fewer where a reservation runs ahead of
+    # a lane's position or a lane has left (an idle row holds nothing)
+    assert stats["attn_pages_read"] == sum(
+        c["attn_pages_read"] for c in counted)
+    assert 0 < stats["attn_pages_read"] <= stats["table_pages_held"]
+    assert stats["attn_kernel_layers"] == sum(
+        c["attn_kernel_layers"] for c in counted) == (
+            cfg.num_layers * len(counted) if name == "kernel" else 0)
+    assert stats["window_attn_pages_read"] == 0
     assert stats["pool_steps_copied"] == 0

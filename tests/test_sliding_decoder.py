@@ -463,9 +463,10 @@ def test_an_allocator_without_window_layers_is_the_one_kind_allocator():
 def test_the_window_counters_are_a_numpy_count(model_and_params,
                                                monkeypatch):
     """``window_table_pages`` / ``_held`` / ``window_pages_in_use`` /
-    ``_peak`` on the ``serve_step`` record, on the profiler's retire event
-    and in ``engine.stats()`` against a count of the ring tables each
-    dispatch was handed; the full tables' two beside them, unchanged; the
+    ``_peak`` and ``window_attn_pages_read`` on the ``serve_step`` record,
+    on the profiler's retire event and in ``engine.stats()`` against a
+    count of the ring tables and positions each dispatch was handed; the
+    full tables' counters beside them; the
     routing counters over 4 x 128 slots; the prefill span's new fields."""
     seen = []
     real = profiling.annotate
@@ -482,11 +483,20 @@ def test_the_window_counters_are_a_numpy_count(model_and_params,
             # a lane holds its ring from its admission to its retirement,
             # a step longer than its table's row names it
             seated = [s for s in engine._slots if s is not None]
+            # what a read of held pages only visits: those at or before
+            # the page of a lane's position, which in a ring that has gone
+            # round is every page (PR 45)
+            at = np.asarray(positions)[:, None] // PAGE
             counted.append({
                 "table_pages": table.size,
                 "table_pages_held": int((table < 64).sum()),
+                "attn_pages_read": int(((table < 64) & (
+                    np.arange(table.shape[1])[None, :] <= at)).sum()),
+                "attn_kernel_layers": 0,
                 "window_table_pages": rings.size,
                 "window_table_pages_held": int((rings < 3 * RING).sum()),
+                "window_attn_pages_read": int(((rings < 3 * RING) & (
+                    np.arange(RING)[None, :] <= at)).sum()),
                 "window_pages_in_use": sum(
                     min(-(-(s.prompt_len + s.budget) // PAGE), RING)
                     for s in seated)})
@@ -508,8 +518,12 @@ def test_the_window_counters_are_a_numpy_count(model_and_params,
     assert [r["window_pages_peak"] for r in steps] == peaks.tolist()
     stats = engine.stats()
     for key in ("table_pages", "table_pages_held", "window_table_pages",
-                "window_table_pages_held"):
+                "window_table_pages_held", "attn_pages_read",
+                "window_attn_pages_read", "attn_kernel_layers"):
         assert stats[key] == sum(c[key] for c in counted)
+    # a lane inside the window walks less than its ring, one past it all
+    assert 0 < stats["window_attn_pages_read"] \
+        < stats["window_table_pages_held"]
     assert 0 < stats["window_table_pages_held"] < stats["window_table_pages"]
     assert all(c["window_table_pages"] == 3 * RING for c in counted)
     assert stats["kv_pool"]["window"]["peak_in_use"] == peaks[-1] > RING
