@@ -37,6 +37,14 @@ SLIDING_ATTENTION = "sliding_attention"
 #: What ``GptConfig.kinds`` calls a full-attention layer under
 #: ``latent_kv_rank`` (never written in ``layer_kinds``).
 LATENT_ATTENTION = "latent_attention"
+#: A gated short convolution in place of attention: ``[B | C | X] = a W_in``,
+#: ``y = C * conv(B * X)`` over ``GptConfig.short_conv_kernel_dim`` causal
+#: depthwise taps, ``x + y W_out``.  A sequence keeps the last ``taps - 1``
+#: rows of ``B * X`` and nothing else, whatever its length.
+SHORT_CONV = "short_conv"
+#: The kinds whose cache entry is one fixed-size row a sequence (a decode
+#: slot's) and no pages.
+STATE_KINDS = (LINEAR_ATTENTION, SHORT_CONV)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -111,9 +119,9 @@ class GptConfig:
     # The embedding's output times sqrt(hidden_size).
     scale_embedding: bool = False
     # The token mixer of each layer, ``num_layers`` of FULL_ATTENTION /
-    # LINEAR_ATTENTION / SLIDING_ATTENTION; empty = every layer full
-    # attention (the same parameter tree and the same programs as before
-    # the field existed).
+    # LINEAR_ATTENTION / SLIDING_ATTENTION / SHORT_CONV; empty = every
+    # layer full attention (the same parameter tree and the same programs
+    # as before the field existed).
     # A linear-attention layer is the gated delta rule of
     # ops/linear_attention.py: per sequence it keeps a fixed-size
     # recurrent state and a convolution tail where a full layer keeps
@@ -138,6 +146,16 @@ class GptConfig:
     linear_key_head_dim: int = 0
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 4    # taps of the causal depthwise conv
+    # Taps of a SHORT_CONV layer's causal depthwise convolution over the
+    # stream's ``hidden_size`` channels (no bias): such a layer projects
+    # its normed input to three thirds ``[B | C | X]``, runs the taps over
+    # ``B * X`` and multiplies by ``C`` before its out projection.  Its
+    # cache entry is the last ``short_conv_kernel_dim - 1`` rows of
+    # ``B * X`` a sequence, one row a decode slot in a paged pool, no
+    # float32 state and no pages.  It composes with full_attention layers
+    # (grouped heads, qk_head_norm, rotation) and with routed experts;
+    # only GptLM.__call__, .prefill and .decode_paged carry the tail.
+    short_conv_kernel_dim: int = 0
     # beta = 2 * sigmoid(.) instead of sigmoid(.): the state transition
     # I - beta k k^T may then have an eigenvalue in (-1, 0).
     linear_allow_neg_eigval: bool = False
@@ -232,7 +250,14 @@ class GptConfig:
 
     @property
     def has_state_layers(self) -> bool:
-        return LINEAR_ATTENTION in self.layer_kinds
+        """Whether a layer keeps a fixed-size row a sequence beside the
+        pages: a recurrent state with its convolution tail, or a short
+        convolution's tail alone."""
+        return any(kind in STATE_KINDS for kind in self.layer_kinds)
+
+    @property
+    def conv_layers(self) -> int:
+        return self.layer_kinds.count(SHORT_CONV)
 
     @property
     def linear_conv_channels(self) -> int:
@@ -243,10 +268,11 @@ class GptConfig:
     def refuse_state_layers(self, path: str) -> None:
         """Called first by every cache path that holds per-head K and V
         rows around a dense MLP and walks the stack once: no place for a
-        linear-attention layer's recurrent state, for a sliding layer's
-        ring beside the full layers' rows, for a latent row, for a
-        routed-expert MLP's histogram and idle lanes, nor for the rows of
-        a weight-shared loop's further steps."""
+        linear-attention layer's recurrent state, for a short
+        convolution's tail, for a sliding layer's ring beside the full
+        layers' rows, for a latent row, for a routed-expert MLP's
+        histogram and idle lanes, nor for the rows of a weight-shared
+        loop's further steps."""
         if self.window_layers:
             raise ValueError(
                 f"{path} holds one kind of cache entry for every layer and "
@@ -254,14 +280,17 @@ class GptConfig:
                 "sliding_attention layer(s), whose entry is a ring; the "
                 "paths that hold both are GptLM.__call__, GptLM.prefill and "
                 "GptLM.decode_paged")
-        if self.has_state_layers:
-            raise ValueError(
-                f"{path} does not carry a linear-attention layer's "
-                f"recurrent state and GptConfig.layer_kinds has "
-                f"{self.layer_kinds.count(LINEAR_ATTENTION)} such layer(s); "
-                "the paths that do are GptLM.__call__, GptLM.prefill and "
-                "GptLM.decode_paged (the serving engine's whole-bucket "
-                "prefill and its decode step)")
+        for kind, what in ((LINEAR_ATTENTION, "recurrent state"),
+                           (SHORT_CONV, "convolution tail")):
+            if kind in self.layer_kinds:
+                raise ValueError(
+                    f"{path} does not carry a {kind} layer's {what} (a row "
+                    f"a sequence, no pages) and GptConfig.layer_kinds has "
+                    f"{self.layer_kinds.count(kind)} such layer(s); the "
+                    "paths that do are GptLM.__call__, GptLM.prefill and "
+                    "GptLM.decode_paged (the serving engine's whole-bucket "
+                    "prefill and its decode step), where it lies beside "
+                    "full_attention layers' pages")
         for field, what in (("latent_kv_rank", "a latent-attention row"),
                             ("num_experts", "a routed-expert MLP")):
             if getattr(self, field):
@@ -293,11 +322,13 @@ class GptConfig:
                                     or self.attention_window):
             raise ValueError(
                 "loop_steps > 1 walks full-attention layers around dense "
-                "MLPs: it composes with none of layer_kinds (a linear or a "
-                "sliding_attention layer among them), latent_kv_rank, "
-                "num_experts and attention_window")
+                "MLPs: it composes with none of layer_kinds (a "
+                "linear_attention, a sliding_attention or a short_conv "
+                "layer among them), latent_kv_rank, num_experts and "
+                "attention_window")
         if self.layer_kinds:
-            known = (FULL_ATTENTION, LINEAR_ATTENTION, SLIDING_ATTENTION)
+            known = (FULL_ATTENTION, LINEAR_ATTENTION, SLIDING_ATTENTION,
+                     SHORT_CONV)
             bad = sorted(set(self.layer_kinds) - set(known))
             if bad or len(self.layer_kinds) != self.num_layers:
                 raise ValueError(
@@ -317,9 +348,9 @@ class GptConfig:
             raise ValueError(
                 "a sliding_attention layer composes with full_attention "
                 "layers, grouped-query heads and routed experts; not with a "
-                "linear_attention layer (no path carries a ring beside a "
-                "recurrent state) nor with attention_window (the one window "
-                "of ALL layers on the unpaged paths)")
+                "linear_attention or a short_conv layer (no path carries a "
+                "ring beside a state row) nor with attention_window (the "
+                "one window of ALL layers on the unpaged paths)")
         if set(self.rope_kinds) - set(self.kinds) \
                 or (self.rope_kinds and self.pos_encoding != "rope"):
             raise ValueError(
@@ -328,7 +359,7 @@ class GptConfig:
                 f"this config has, {sorted(set(self.kinds))}")
         if self.head_size < 0 or self.head_dim < 1:
             raise ValueError(f"head_size must be >= 0, got {self.head_size}")
-        if self.has_state_layers:
+        if LINEAR_ATTENTION in self.layer_kinds:
             if min(self.linear_num_heads, self.linear_key_head_dim,
                    self.linear_value_head_dim) < 1 \
                     or self.linear_conv_kernel_dim < 2:
@@ -336,11 +367,22 @@ class GptConfig:
                     "a linear_attention layer needs linear_num_heads, "
                     "linear_key_head_dim, linear_value_head_dim >= 1 and "
                     "linear_conv_kernel_dim >= 2")
-            if self.attention_window or self.attn_int8:
-                raise ValueError(
-                    "layer_kinds with a linear_attention layer composes "
-                    "with full_attention layers only: neither with "
-                    "attention_window nor with attn_int8")
+        if bool(self.conv_layers) != bool(self.short_conv_kernel_dim) \
+                or self.short_conv_kernel_dim == 1 \
+                or self.short_conv_kernel_dim < 0:
+            raise ValueError(
+                "short_conv_kernel_dim >= 2 is the taps of the short_conv "
+                "layers in layer_kinds: one needs the other (got "
+                f"short_conv_kernel_dim={self.short_conv_kernel_dim} and "
+                f"{self.conv_layers} such layer(s))")
+        if self.has_state_layers and (self.attention_window
+                                      or self.attn_int8):
+            raise ValueError(
+                "layer_kinds with a linear_attention or a short_conv layer "
+                "composes with full_attention layers (grouped-query heads, "
+                "qk_head_norm, rotation; a short_conv layer with routed "
+                "experts too): neither with attention_window nor with "
+                "attn_int8")
         if self.latent_kv_rank:
             if min(self.latent_q_rank, self.qk_nope_head_dim,
                    self.qk_rope_head_dim, self.v_head_dim) < 1 \
@@ -362,7 +404,8 @@ class GptConfig:
                 raise ValueError(
                     "latent_kv_rank makes every layer a latent one, with "
                     "head sizes and norms of its own: it composes with none "
-                    "of layer_kinds (so with no sliding_attention layer), "
+                    "of layer_kinds (so with no sliding_attention and no "
+                    "short_conv layer), "
                     "kv_heads, attention_window, attn_int8, qk_norm, "
                     "qk_head_norm, head_size and attn_output_gate, and "
                     "rotates inside the mixer: pos_encoding must be 'none'")
@@ -409,13 +452,13 @@ def infer_arch_from_layer0(layer0: dict) -> dict:
     same model from the same tree): swiglu adds a gate matrix, rmsnorm's
     norm params carry no bias, GQA's kv projection is [in, 2, G, D].
     A block tells nothing of its neighbours' kinds, so a checkpoint with a
-    linear-attention layer is refused."""
-    if "A_log" in layer0:
+    linear-attention or a short-convolution layer is refused."""
+    if "conv_taps" in layer0:
         raise ValueError(
             "infer_arch_from_layer0 cannot infer GptConfig.layer_kinds: "
-            "layer0 is a linear_attention layer and says nothing of the "
-            "other layers' kinds; build the GptConfig from the run's "
-            "configuration file")
+            "layer0 is a linear_attention or a short_conv layer and says "
+            "nothing of the other layers' kinds; build the GptConfig from "
+            "the run's configuration file")
     if "kv_a" in layer0 or "router" in layer0:
         raise ValueError(
             "infer_arch_from_layer0 cannot infer a latent-attention or "
@@ -493,7 +536,9 @@ class GptBlock(nn.Module):
     (LINEAR_ATTENTION: ``linear_mix`` / ``linear_prefill`` /
     ``linear_decode_step``) or softmax attention over one cached latent row
     a token (LATENT_ATTENTION: ``latent_mix`` / ``latent_prefill`` /
-    ``latent_decode_step_paged``).  ``sparse`` selects the MLP: the dense
+    ``latent_decode_step_paged``) or a gated short convolution over a tail
+    of its inputs (SHORT_CONV: ``conv_mix`` / ``conv_prefill`` /
+    ``conv_decode_step``).  ``sparse`` selects the MLP: the dense
     one, or routed experts beside shared ones.  Norms and the residual
     path are shared."""
 
@@ -518,8 +563,22 @@ class GptBlock(nn.Module):
             self._setup_linear(dtype)
         elif self.kind == LATENT_ATTENTION:
             self._setup_latent(dtype)
+        elif self.kind == SHORT_CONV:
+            self._setup_conv(dtype)
         else:
             self._setup_attention(dtype)
+
+    def _setup_conv(self, dtype):
+        cfg = self.cfg
+        # The three thirds [B | C | X] of the mixer's input, side by side.
+        self.in_proj = nn.Dense(3 * cfg.hidden_size, dtype=dtype,
+                                use_bias=False)
+        # Depthwise taps over the stream's channels, oldest first; no bias.
+        self.conv_taps = self.param(
+            "conv_taps", nn.initializers.normal(
+                cfg.short_conv_kernel_dim ** -0.5),
+            (cfg.short_conv_kernel_dim, cfg.hidden_size))
+        self.out = nn.Dense(cfg.hidden_size, dtype=dtype, use_bias=False)
 
     def _setup_latent(self, dtype):
         cfg = self.cfg
@@ -797,6 +856,8 @@ class GptBlock(nn.Module):
             return self.linear_mix(x, deterministic)
         if self.kind == LATENT_ATTENTION:
             return self.latent_mix(x, deterministic)
+        if self.kind == SHORT_CONV:
+            return self.conv_mix(x, deterministic)
         q, k, v = self._qkv(x)
         with profiling.region("attn.scores"):
             ctx = dot_product_attention(
@@ -804,6 +865,59 @@ class GptBlock(nn.Module):
                 window=self.window, backend=self.cfg.attention_backend)
         x = self._add_mixed(x, ctx, deterministic)
         return self._mlp(x, deterministic)
+
+    # ---------------------------------------------- short convolution
+
+    def _conv_gated(self, x: jax.Array, tail: jax.Array | None):
+        """The gated convolution of ``x`` [B, T, hidden] after ``tail``
+        [B, K-1, hidden] (zeros when None): returns (``u = B * X``
+        [B, T, hidden], what the taps read and a tail keeps, and ``y = C *
+        conv(u)``), both in the compute type.  The product, the taps and
+        the gate are float32 from the projections' type; ``u`` is rounded
+        to the compute type BEFORE the taps read it, so that a row read
+        back from a tail is the row the whole-sequence form read."""
+        with profiling.region("attn.qkv"):
+            gate_in, gate_out, value = jnp.split(
+                self.in_proj(self._mixer_in(x)), 3, axis=-1)
+        dtype = jnp.dtype(self.cfg.dtype)
+        u = (gate_in.astype(jnp.float32)
+             * value.astype(jnp.float32)).astype(dtype)
+        y = gate_out.astype(jnp.float32) * linear_ops.causal_conv(
+            u, self.conv_taps, tail)
+        return u, y.astype(dtype)
+
+    def conv_mix(self, x: jax.Array, deterministic: bool = True):
+        """The whole sequence from an empty tail (the training forward)."""
+        with profiling.region("short_conv.mix"):
+            _, y = self._conv_gated(x, None)
+        return self._mlp(self._add_mixed(x, y, deterministic), deterministic)
+
+    def conv_prefill(self, x: jax.Array, tail: jax.Array,
+                     lengths: jax.Array):
+        """``x`` [B, T, hidden] through the block after ``tail`` (an empty
+        sequence's: zeros): returns (y, the ``K - 1`` rows of ``B * X``
+        before position ``lengths[b]``; zeros stand before position 0).
+        The convolution is causal, so padding past ``lengths[b]`` reaches
+        no real position; ``y`` at or past it is meaningless."""
+        with profiling.region("short_conv.mix"):
+            u, y = self._conv_gated(x, tail)
+        with profiling.region("cache.write"):
+            new_tail = linear_ops.conv_tail(
+                jnp.concatenate([tail, u], axis=1),
+                lengths + tail.shape[1], tail.shape[1])
+        return self._mlp(self._add_mixed(x, y), True), new_tail
+
+    def conv_decode_step(self, x: jax.Array, tail: jax.Array,
+                         live: jax.Array):
+        """One token a row: ``x`` [B, 1, hidden] against ``tail``
+        [B, K-1, hidden].  A row where ``live`` [B] is False keeps its
+        tail bit for bit, and a routed-expert MLP routes it nowhere."""
+        with profiling.region("short_conv.step"):
+            u, y = self._conv_gated(x, tail)
+        with profiling.region("cache.write"):
+            shifted = jnp.concatenate([tail[:, 1:], u], axis=1)
+            tail = jnp.where(live[:, None, None], shifted, tail)
+        return self._mlp(self._add_mixed(x, y), True, live), tail
 
     # ---------------------------------------------- linear attention
 
@@ -1404,7 +1518,8 @@ class GptBlock(nn.Module):
 
     def decode_step_paged(self, x: jax.Array, k_pool: jax.Array,
                           v_pool: jax.Array, page_table: jax.Array,
-                          positions: jax.Array):
+                          positions: jax.Array,
+                          live: jax.Array | None = None):
         """One token per row against a PAGED KV pool — the serving tier's
         decode body (:mod:`..serving.engine`).
 
@@ -1451,6 +1566,10 @@ class GptBlock(nn.Module):
 
         The global ``attention_window`` (one window for ALL layers, no
         kind) stays with the unpaged paths: here it is refused.
+
+        ``live`` [B] (optional) is the routed-expert MLP's: a row that is
+        no sequence is routed nowhere.  The pools need none: an idle row's
+        table is all sentinel and its write drops.
         """
         cfg = self.cfg
         if cfg.attention_window:
@@ -1496,7 +1615,7 @@ class GptBlock(nn.Module):
             ctx = self._attend_rows(q, gather_pages(k_pool, page_table),
                                     gather_pages(v_pool, page_table), valid)
         x = self._add_mixed(x, ctx)
-        return self._mlp(x, deterministic=True), k_pool, v_pool
+        return self._mlp(x, True, live), k_pool, v_pool
 
 
 class GptLM(nn.Module):
@@ -1706,10 +1825,13 @@ class GptLM(nn.Module):
         layer's entry of ``pools`` is (state [B, H, Dv, Dk], conv tail
         [B, K-1, channels]), indexed by ROW and not by page, and ``live``
         [B] says which rows are sequences: a row that is not keeps its
-        entry bit for bit (see :func:`init_kv_pool`).  A latent-attention
+        entry bit for bit (see :func:`init_kv_pool`).  A short-convolution
+        layer's entry is its tail alone, (tail [B, K-1, hidden],), held
+        the same way.  A latent-attention
         layer's entry is its two pools of row parts; a routed-expert MLP
         routes a row that ``live`` says is no sequence nowhere (without
-        ``live`` every row is routed).  With ``cfg.loop_steps`` > 1 the
+        ``live`` every row is routed): one mask for the experts and for
+        the state rows.  With ``cfg.loop_steps`` > 1 the
         stack is walked that many times (:meth:`_loop`), step ``t`` writing
         and attending its own run of pages of each layer's pool.  A
         sliding-attention layer's entry is a pool of its own geometry,
@@ -1724,8 +1846,9 @@ class GptLM(nn.Module):
         if self.cfg.has_state_layers and live is None:
             raise ValueError(
                 "GptLM.decode_paged needs live= [B] for a config whose "
-                "layer_kinds has a linear_attention layer: a recurrent "
-                "state has no sentinel page to drop an idle row's write")
+                "layer_kinds has a linear_attention or a short_conv layer: "
+                "a state row has no sentinel page to drop an idle row's "
+                "write")
         x = self._embed(token[:, None], positions[:, None], True)
         if self.cfg.loop_steps > 1:
             def stack(mdl, x, pools, t, rows):
@@ -1745,6 +1868,8 @@ class GptLM(nn.Module):
         for layer, entry in zip(self.layers, pools):
             if layer.kind == LINEAR_ATTENTION:
                 x, *entry = layer.linear_decode_step(x, *entry, live)
+            elif layer.kind == SHORT_CONV:
+                x, *entry = layer.conv_decode_step(x, *entry, live)
             elif layer.kind == LATENT_ATTENTION:
                 x, *entry = layer.latent_decode_step_paged(
                     x, *entry, page_tables, positions, live)
@@ -1752,7 +1877,7 @@ class GptLM(nn.Module):
                 x, *entry = layer.decode_step_paged(
                     x, *entry, window_tables
                     if layer.kind == SLIDING_ATTENTION else page_tables,
-                    positions)
+                    positions, live)
             new_pools.append(tuple(entry))
         return self._head(x)[:, 0], new_pools
 
@@ -1765,9 +1890,10 @@ class GptLM(nn.Module):
         excluded from the cache write (REQUIRED for ring caches, see
         ``GptBlock.prefill``).
 
-        With a linear-attention layer in ``layer_kinds`` (caches from
-        :func:`init_kv_cache`: such a layer's entry is its state and its
-        convolution tail) ``lengths`` is required and bounds what those
+        With a linear-attention or a short-convolution layer in
+        ``layer_kinds`` (caches from :func:`init_kv_cache`: such a layer's
+        entry is its state and its convolution tail, or its tail alone)
+        ``lengths`` is required and bounds what those
         layers absorb: their state comes back as after token
         ``lengths[b] - 1`` and padding changes nothing.  The full layers
         then write every position of the padded prompt as they do without
@@ -1793,8 +1919,8 @@ class GptLM(nn.Module):
         if stateful and lengths is None:
             raise ValueError(
                 "GptLM.prefill needs lengths= [B] for a config whose "
-                "layer_kinds has a linear_attention layer: padding must "
-                "not enter a recurrent state")
+                "layer_kinds has a linear_attention or a short_conv layer: "
+                "padding must not enter a state row")
         if self.cfg.loop_steps > 1:
             def stack(mdl, x, carry, t, caches):
                 new_caches = []
@@ -1808,6 +1934,8 @@ class GptLM(nn.Module):
         for layer, entry in zip(self.layers, caches):
             if layer.kind == LINEAR_ATTENTION:
                 x, *entry = layer.linear_prefill(x, *entry, lengths)
+            elif layer.kind == SHORT_CONV:
+                x, *entry = layer.conv_prefill(x, *entry, lengths)
             elif layer.kind == LATENT_ATTENTION:
                 x, *entry = layer.latent_prefill(x, *entry)
             else:
@@ -1921,7 +2049,7 @@ def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
         return (batch_size, rows) if cfg.loop_steps == 1 \
             else (cfg.loop_steps, batch_size, rows)
 
-    return [_state_entry(cfg, batch_size) if kind == LINEAR_ATTENTION
+    return [_state_entry(cfg, kind, batch_size) if kind in STATE_KINDS
             else _rows_entry(cfg, kind, lead(kind), dtype)
             for kind in cfg.kinds]
 
@@ -1950,37 +2078,47 @@ def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype,
 def kv_row_bytes_per_token(cfg: GptConfig, dtype=None,
                            window: bool = False) -> int:
     """Bytes ONE cached token holds over all layers' pages that grow with
-    the sequence (a linear-attention layer holds none; a weight-shared
-    loop holds a row a step a layer); with ``window`` over the
+    the sequence (a linear-attention or a short-convolution layer holds
+    none; a weight-shared loop holds a row a step a layer); with ``window`` over the
     sliding-attention layers' rings instead, which hold a token only
     while it is inside the window."""
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
     return cfg.loop_steps * sum(
         x.size * x.dtype.itemsize
         for kind in cfg.kinds
-        if kind != LINEAR_ATTENTION and (kind == SLIDING_ATTENTION) == window
+        if kind not in STATE_KINDS and (kind == SLIDING_ATTENTION) == window
         for x in jax.eval_shape(
             lambda k=kind: _rows_entry(cfg, k, (1,), dtype)))
 
 
-def _state_entry(cfg: GptConfig, rows: int):
-    """A linear-attention layer's cache entry for ``rows`` sequences, all
-    empty: (state [rows, H, Dv, Dk] float32, the convolution's tail
-    [rows, K-1, channels] — the raw q/k/v projections of the last K-1
-    tokens, in the type they were computed in)."""
+def _state_entry(cfg: GptConfig, kind: str, rows: int):
+    """The cache entry of a layer that keeps one fixed-size row a sequence,
+    for ``rows`` sequences, all empty.  A linear-attention layer's: (state
+    [rows, H, Dv, Dk] float32, the convolution's tail [rows, K-1,
+    channels]: the raw q/k/v projections of the last K-1 tokens, in the
+    type they were computed in).  A short-convolution layer's: (the tail
+    [rows, K-1, hidden],): the last K-1 rows of ``B * X`` in the compute
+    type, and no matrix."""
+    dtype = jnp.dtype(cfg.dtype)
+    if kind == SHORT_CONV:
+        return (jnp.zeros((rows, cfg.short_conv_kernel_dim - 1,
+                           cfg.hidden_size), dtype),)
     return (jnp.zeros((rows, cfg.linear_num_heads,
                        cfg.linear_value_head_dim, cfg.linear_key_head_dim),
                       jnp.float32),
             jnp.zeros((rows, cfg.linear_conv_kernel_dim - 1,
-                       cfg.linear_conv_channels), jnp.dtype(cfg.dtype)))
+                       cfg.linear_conv_channels), dtype))
 
 
 def state_bytes_per_slot(cfg: GptConfig) -> int:
-    """Bytes of recurrent state and convolution tail ONE sequence holds
-    over all linear-attention layers (0 for a config without any)."""
-    n = cfg.kinds.count(LINEAR_ATTENTION)
-    return n * sum(x.size * x.dtype.itemsize
-                   for x in jax.eval_shape(lambda: _state_entry(cfg, 1)))
+    """Bytes ONE sequence holds in rows of its own beside the pages, over
+    all layers that keep one: a linear-attention layer's recurrent state
+    and convolution tail, a short-convolution layer's tail (0 for a config
+    without either)."""
+    return sum(x.size * x.dtype.itemsize
+               for kind in cfg.kinds if kind in STATE_KINDS
+               for x in jax.eval_shape(
+                   lambda k=kind: _state_entry(cfg, k, 1)))
 
 
 def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
@@ -2009,7 +2147,8 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
 
     A linear-attention layer holds no pages: its entry is one fixed-size
     row per decode SLOT (``num_slots`` of them: state float32, convolution
-    tail), whatever the sequence's length.  A latent-attention layer's entry
+    tail), whatever the sequence's length.  A short-convolution layer's is
+    its tail alone, ``[num_slots, taps - 1, hidden]``.  A latent-attention layer's entry
     is its row's two parts, [num_pages + 1, page_size, latent_kv_rank] and
     [num_pages + 1, page_size, qk_rope_head_dim] (:func:`_rows_entry`).
 
@@ -2036,8 +2175,8 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
             "unpaged paths'")
     if (cfg.has_state_layers or cfg.window_layers) and num_slots < 1:
         raise ValueError("init_kv_pool needs num_slots >= 1 for a config "
-                         "whose layer_kinds has a linear_attention or a "
-                         "sliding_attention layer")
+                         "whose layer_kinds has a linear_attention, a "
+                         "short_conv or a sliding_attention layer")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
 
     def pages(kind):
@@ -2045,7 +2184,7 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
             return num_slots * cfg.ring_pages(page_size) + 1
         return cfg.loop_steps * num_pages + 1
 
-    return [_state_entry(cfg, num_slots) if kind == LINEAR_ATTENTION
+    return [_state_entry(cfg, kind, num_slots) if kind in STATE_KINDS
             else _rows_entry(cfg, kind, (pages(kind), page_size), dtype,
                              flat=True)
             for kind in cfg.kinds]

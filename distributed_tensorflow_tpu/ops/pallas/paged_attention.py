@@ -25,7 +25,13 @@ row into VMEM, several in flight, a chunk ahead of the one it scores:
   takes;
 * grouped heads: a query head is widened to the whole row, zero outside
   its kv head's D lanes, as ``_attend_rows`` does, so the two products are
-  plain matmuls over ``[tokens, G * D]`` and no row is ever re-laid out.
+  plain matmuls over ``[tokens, G * D]`` and no row is ever re-laid out;
+* a head that DIVIDES 128 (64: two kv heads a lane tile) goes through the
+  same body: the flat row is presented as ``G * D / 128`` kv "heads" of
+  128 and a query head widened to 128, zeros in its neighbours' part of
+  the tile, so a score is ``q . k`` exactly; the head's own D lanes of the
+  128-wide context are taken after the call (:func:`_widen` /
+  :func:`_own_part`).
 
 Rows of a lane's own pages past its position, left by a former owner,
 count under a weight of exactly zero; the rows of a chunk past the walk
@@ -65,11 +71,14 @@ def _interpret() -> bool:
 def supports(pool: jax.Array, head_dim: int) -> bool:
     """Whether the kernel can walk ``pool``: a page is whole sublane tiles
     of its type (16 rows of bfloat16, 8 of float32; a float8 page of 16 is
-    half a tile), a kv head whole lanes of 128."""
+    half a tile), a kv head whole lane tiles of 128, or a whole part of
+    one (64, 32: a head that divides 128) where the flat row is whole
+    tiles."""
     tile = {4: 8, 2: 16}.get(pool.dtype.itemsize)
     return (tile is not None and pool.ndim == 3 and pool.shape[1] % tile == 0
-            and 128 % pool.shape[1] == 0 and head_dim % 128 == 0
-            and pool.shape[2] % head_dim == 0)
+            and 128 % pool.shape[1] == 0
+            and (head_dim % 128 == 0 or 128 % head_dim == 0)
+            and pool.shape[2] % head_dim == 0 and pool.shape[2] % 128 == 0)
 
 
 def pages_walked(page_table, positions, sentinel: int, page: int):
@@ -265,6 +274,33 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
         interpret=_interpret())
 
 
+def _tile_part(heads: int, kv_heads: int, head_dim: int) -> jax.Array:
+    """[H]: which ``head_dim`` lanes of its lane tile of 128 a query head's
+    kv head lies at (kv head ``g`` is part ``g % (128 // head_dim)`` of
+    tile ``g // (128 // head_dim)`` of the flat row)."""
+    return (jnp.arange(heads) // (heads // kv_heads)) % (128 // head_dim)
+
+
+def _widen(q: jax.Array, kv_heads: int) -> jax.Array:
+    """``q`` [B, H, D] with D a whole part of 128 -> [B, H, 128]: each
+    query head in its kv head's D lanes of the lane tile that head shares
+    with its neighbours, zeros in the rest."""
+    B, H, D = q.shape
+    own = _tile_part(H, kv_heads, D)[:, None] == jnp.arange(128 // D)
+    return jnp.where(own[None, :, :, None], q[:, :, None, :],
+                     jnp.zeros((), q.dtype)).reshape(B, H, 128)
+
+
+def _own_part(ctx: jax.Array, kv_heads: int, head_dim: int) -> jax.Array:
+    """The head's own ``head_dim`` lanes of a 128-wide context
+    [B, H, 128] (:func:`_widen`'s placement)."""
+    B, H, _ = ctx.shape
+    return jnp.take_along_axis(
+        ctx.reshape(B, H, 128 // head_dim, head_dim),
+        _tile_part(H, kv_heads, head_dim)[None, :, None, None],
+        axis=2)[:, :, 0]
+
+
 # Traced ONCE a shape, whichever layers call it: a step program of 48
 # layers holds one kernel and 48 calls of it (traced a layer at a time, an
 # earlier form of the kernel took the looped step's trace from 1.8 to 15.6 s
@@ -272,17 +308,21 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 @functools.partial(jax.jit, static_argnames=("window", "pages", "interpret"))
 def _paged_attention(q, k_pool, v_pool, page_table, positions, *,
                      window: int, pages: int, interpret: bool):
-    B, H, D = q.shape
+    head_dim = q.shape[-1]
     page, row = k_pool.shape[1], k_pool.shape[2]
+    if head_dim < 128:
+        # Two (or four) kv heads a lane tile: the kernel sees heads of 128.
+        q = _widen(q, row // head_dim)
+    B, H, D = q.shape
     sentinel = k_pool.shape[0] - 1
     n = pages_walked(page_table, positions, sentinel, page)
     held = jnp.sum((page_table < sentinel) & (
         jnp.arange(page_table.shape[1])[None, :] < n[:, None]), axis=1)
     T = pages * page
     buffers = 4 * T * row * k_pool.dtype.itemsize
-    return pl.pallas_call(
+    ctx = pl.pallas_call(
         functools.partial(_kernel, pages=pages, kv_heads=row // D,
-                          window=window, scale=1.0 / D ** 0.5),
+                          window=window, scale=1.0 / head_dim ** 0.5),
         out_shape=jax.ShapeDtypeStruct((B, H, D), q.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3, grid=(1,),
@@ -301,3 +341,6 @@ def _paged_attention(q, k_pool, v_pool, page_table, positions, *,
         name="paged_attention",
     )(jnp.concatenate([n, held.astype(jnp.int32)]), page_table, positions,
       q, k_pool, v_pool)
+    if head_dim < 128:
+        ctx = _own_part(ctx, row // head_dim, head_dim)
+    return ctx

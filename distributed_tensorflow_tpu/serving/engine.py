@@ -88,6 +88,9 @@ that walk's length summed over the lanes, counted from the host's own
 (0 on a CPU, and in a speculative turn, which attends a chunk the plain
 way): ``attn_pages_read / table_pages`` is the share of the table the step
 pays for on the kernel, beside ``table_pages_held / table_pages``.
+``lanes_live`` is the seated lanes of the dispatch, the rows whose table
+names a page (the step's own ``live`` mask, counted on the host): over
+``num_slots`` it is the batch's occupancy.
 
 A model with SLIDING-WINDOW layers among its full ones
 (``GptConfig.layer_kinds``) holds pages of two kinds: each full layer's
@@ -311,10 +314,13 @@ class DecodeEngine:
         # checkpoints) — the engine's logical capacity is the tighter of
         # the page-table span and the model's max_position.
         self.capacity = min(cfg.max_seq_len, mcfg.max_position)
-        # Layers that keep a recurrent state a slot beside the pages
-        # (GptConfig.layer_kinds).  Only the whole-bucket prefill and the
-        # plain decode step carry it.
-        self._state_layers = mcfg.kinds.count(gpt_lib.LINEAR_ATTENTION)
+        # Layers that keep a row a slot beside the pages
+        # (GptConfig.layer_kinds): a recurrent state with its convolution
+        # tail, or a short convolution's tail alone (counted apart too).
+        # Only the whole-bucket prefill and the plain decode step carry it.
+        self._state_layers = sum(kind in gpt_lib.STATE_KINDS
+                                 for kind in mcfg.kinds)
+        self._conv_layers = mcfg.conv_layers
         self._stateful = self._state_layers > 0
         # Layers whose MLP is routed experts: the step hands their routing
         # histogram [sparse layers, experts] back with its tokens.
@@ -415,6 +421,9 @@ class DecodeEngine:
         self.attn_pages_read = 0
         self.window_attn_pages_read = 0
         self.attn_kernel_layers = 0
+        # The seated lanes of the dispatched steps, summed (over
+        # ``engine_step`` x ``num_slots``: the batch's occupancy).
+        self.lanes_live = 0
         # Running sums of the steps' routing counters (_routing_counters).
         self.moe = dict.fromkeys(("experts_touched", "expert_slots",
                                   "expert_tokens_max", "routed_tokens"), 0)
@@ -617,8 +626,9 @@ class DecodeEngine:
 
         def prefill(tree, tokens, pools, phys, slot=None, absorb=None,
                     ring=None):
-            """``slot`` and ``absorb``, for a model with recurrent layers
-            only: the lane's slot and how many tokens its state absorbs.
+            """``slot`` and ``absorb``, for a model with state layers
+            (a recurrent state, a short convolution's tail) only: the
+            lane's slot and how many tokens its state absorbs.
             The state starts from zeros INSIDE this program and lands on
             the slot's row whole, so nothing of the row's last tenant
             survives.  ``ring``, for a model with window layers only: the
@@ -631,7 +641,7 @@ class DecodeEngine:
             _, caches = model.apply({"params": params}, tokens, caches,
                                     *lengths, method=gpt_lib.GptLM.prefill)
             def land(kind, cache, pool):
-                if kind == gpt_lib.LINEAR_ATTENTION:
+                if kind in gpt_lib.STATE_KINDS:
                     return pool.at[slot].set(cache[0])
                 if kind == gpt_lib.SLIDING_ATTENTION:
                     # The prompt's last rows, position p at ring row
@@ -654,8 +664,8 @@ class DecodeEngine:
                     cache[0].reshape(n_pages, page, -1), mode="drop")
 
             # An entry is (keys, values) of a run of pages or of a ring, a
-            # latent layer's (latents, rotated keys), or (state,
-            # convolution tail).
+            # latent layer's (latents, rotated keys), (state, convolution
+            # tail) or a short convolution's (tail,).
             with profiling.region("cache.write"):
                 return [tuple(land(kind, c, p) for c, p in zip(cache, pool))
                         for kind, cache, pool
@@ -846,6 +856,7 @@ class DecodeEngine:
                     tenant=request.tenant, bucket=n_prefill,
                     pages=n_prefill, prompt_tokens=P, chunks=1,
                     state_layers=self._state_layers,
+                    conv_layers=self._conv_layers,
                     sparse_layers=self._sparse_layers,
                     latent_row_bytes=self._latent_row_bytes,
                     loop_steps=self._loop_steps,
@@ -1194,7 +1205,11 @@ class DecodeEngine:
                      self._tables, self._positions, self.config.num_pages,
                      page).sum()),
                  "attn_kernel_layers":
-                     0 if spec_mode else self._kernel_layers}
+                     0 if spec_mode else self._kernel_layers,
+                 # The seated lanes of this dispatch: the rows the step's
+                 # own ``live`` mask will find (a table that names a page).
+                 "lanes_live": int(np.count_nonzero(
+                     self._tables[:, 0] < self.config.num_pages))}
         if self._window_layers:
             # The rings' tables apart, and the window pool's occupancy.
             table.update(
@@ -1206,7 +1221,7 @@ class DecodeEngine:
                     self.allocator.window_pages, page).sum()),
                 window_pages_in_use=self.allocator.window_pages_in_use,
                 window_pages_peak=self.allocator.window_peak_in_use)
-        # What this step's lanes hold in recurrent state.
+        # What this step's lanes hold in state rows beside their pages.
         held = {"state_slots": self.allocator.state_slots,
                 "state_bytes": self.allocator.state_bytes}
         chunk, spec_rows = None, 0
@@ -1332,6 +1347,7 @@ class DecodeEngine:
         self.attn_pages_read += table["attn_pages_read"]
         self.window_attn_pages_read += table.get("window_attn_pages_read", 0)
         self.attn_kernel_layers += table["attn_kernel_layers"]
+        self.lanes_live += table["lanes_live"]
         with profiling.annotate("serve.step.retire",
                                 pools_in_place=int(in_place),
                                 sampled_lanes=sampled_lanes, **table,
@@ -1558,8 +1574,9 @@ class DecodeEngine:
                 "cap": self.config.prefill_cache_cap,
                 "evictions": self._prefill_evictions,
             },
-            # Recurrent state beside the pages (linear-attention layers):
-            # its peak and its bytes a slot are in the pool's snapshot.
+            # State rows beside the pages (linear-attention layers'
+            # recurrent state, short-convolution layers' tails): the peak
+            # and the bytes a slot are in the pool's snapshot.
             "state_slots": self.allocator.state_slots,
             "state_bytes": self.allocator.state_bytes,
             # Steps whose every dispatch wrote the donated pools in
@@ -1592,6 +1609,8 @@ class DecodeEngine:
             "attn_pages_read": self.attn_pages_read,
             "window_attn_pages_read": self.window_attn_pages_read,
             "attn_kernel_layers": self.attn_kernel_layers,
+            # Seated lanes summed over the dispatched steps.
+            "lanes_live": self.lanes_live,
             # Running sums of the steps' routing counters; zeros for a
             # model whose MLPs are all dense.
             "moe": dict(self.moe),

@@ -19,8 +19,9 @@ needs.  This module owns the page bookkeeping on the host:
   the occupancy view the telemetry bus publishes every engine step.
 
 - A sequence's OTHER memory is accounted here too: a model with
-  linear-attention layers keeps, per resident sequence, one fixed-size row
-  of recurrent state per such layer (``state_bytes_per_slot`` in all),
+  linear-attention or short-convolution layers keeps, per resident
+  sequence, one fixed-size row per such layer (a recurrent state with its
+  convolution tail, or a tail alone; ``state_bytes_per_slot`` in all),
   indexed by the engine's slot and not by page.  A sequence holds its row
   exactly as long as it holds pages, so the snapshot reports both.
 
@@ -65,9 +66,10 @@ class PageAllocator:
     ``num_pages``; a freed page keeps what its last owner wrote, under a
     weight of zero until its next owner overwrites it.
 
-    ``state_bytes_per_slot``: bytes of recurrent state (and convolution
-    tail) a resident sequence holds beside its pages, over all the model's
-    linear-attention layers; 0 for a model that has none.
+    ``state_bytes_per_slot``: bytes a resident sequence holds in rows of
+    its own beside its pages, over all the model's layers that keep one
+    (a linear-attention layer's recurrent state and convolution tail, a
+    short-convolution layer's tail alone); 0 for a model that has none.
     ``row_bytes_per_token``: bytes one cached token holds over all layers'
     pools (keys and values a head, or one latent row; a row a LOOP STEP a
     layer where the stack is walked several times over the same weights,
@@ -153,7 +155,8 @@ class PageAllocator:
 
     @property
     def state_slots(self) -> int:
-        """Resident sequences holding a row of recurrent state."""
+        """Resident sequences holding a state row (a recurrent state, a
+        convolution tail) beside their pages."""
         return len(self._owned) if self.state_bytes_per_slot else 0
 
     @property

@@ -111,6 +111,9 @@ REGIONS = (
     "optimizer",                # the update rule and its application
     "linear_attention.scan",    # the gated delta rule over a sequence
     "linear_attention.step",    # ... over one token a row
+    "short_conv.mix",           # the gated short convolution over a
+                                # sequence: B * X, the taps, C *
+    "short_conv.step",          # ... over one token a row and its tail
     "mla.expand",               # per-head keys and values from latents
     "mla.absorb",               # scores over the cached latents themselves
     "moe.route", "moe.experts", "moe.shared",

@@ -604,3 +604,76 @@ def test_window_and_full_serving_programs_compile_for_v5e(one_chip):
         assert header.count("may-alias") + header.count(
             "must-alias") == len(leaves)
         assert relayouts(program, leaves[0].size * 2) == []
+
+
+def test_conv_and_head64_serving_programs_compile_for_v5e(one_chip):
+    """The leading dense convolution layer, one sparse attention layer and
+    one sparse convolution layer at the published widths of
+    ``perfbench/configs/lfm2-24b-a2b.json`` under ``agents_closed128``'s
+    engine settings: the decode step reads the attention layer's pool
+    through ONE paged-attention call at heads of 64 (two kv heads a lane
+    tile: the kernel sees 4 kv "heads" of 128 in the flat row of 512) and
+    gathers no table; a convolution layer's entry is a tail a slot, [slots,
+    2, 2048], donated and aliased like the pools; the prefill keeps the
+    flash kernel at head 64 and drops the last layer's out projection and
+    experts, of which a prefill needs the tail alone."""
+    from distributed_tensorflow_tpu.serving.engine import EngineConfig
+    from perfbench import spec, worker
+    config = spec.load_json(os.path.join(spec.HERE, "configs",
+                                         "lfm2-24b-a2b.json"))
+    kinds = (gpt_lib.SHORT_CONV, gpt_lib.FULL_ATTENTION, gpt_lib.SHORT_CONV)
+    cfg = dataclasses.replace(worker.gpt_config(
+        {"config": config, "config_file": "lfm2-24b-a2b.json"}),
+        num_layers=3, layer_kinds=kinds, vocab_size=32768)
+    assert cfg.head_dim == 64 and cfg.num_kv_heads == 8
+    model = gpt_lib.GptLM(cfg)
+    traffic = spec.load_json(os.path.join(spec.HERE, "traffic",
+                                          "agents_closed128.json"))
+    econf = EngineConfig(**traffic["engine"])
+
+    def described(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+    engine = bare_engine(model, econf, stateful=True, sparse_layers=2)
+    tree = described(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+        jnp.bfloat16)
+    pools = described(jax.eval_shape(lambda: gpt_lib.init_kv_pool(
+        cfg, econf.num_pages, econf.page_size, num_slots=econf.num_slots)))
+    leaves = jax.tree.leaves(pools)
+    B, MP = econf.num_slots, econf.max_pages_per_seq
+    assert [x.shape for x in leaves] == [
+        (B, 2, 2048), (12288 + 1, 16, 512), (12288 + 1, 16, 512),
+        (B, 2, 2048)]
+    assert gpt_lib.paged_kernel_layers(cfg, pools) == 1
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,  # noqa: E731
+                                          sharding=one_chip)
+    lowered = engine._build_step().lower(
+        tree, i32(B), i32(B), i32(B, MP), pools, f32(B), i32(B), f32(B),
+        i32(B))
+    # the lanes' tokens and behind them 2 layers x 64 experts of histogram
+    assert lowered.out_info[0].shape == (B + 2 * 64,)
+    step = lowered.compile()
+    # three grouped products a sparse layer, ONE paged-attention call
+    assert step.as_text().count("tpu_custom_call") == 2 * 3 + 1
+    # no lane's table is gathered (192 pages of 16 rows of 512), nothing
+    # is blanked or masked at that size
+    assert table_gathers(step, MP * 16 * 512 * 2) == []
+    assert gathered_selects(step, B * MP * 16 * 512 * 2) == []
+    prefill = engine._prefill_fn(128).lower(
+        tree, i32(1, 2048), pools, i32(128), i32(), i32()).compile()
+    # one flash call (head 64) and ONE sparse layer's grouped products:
+    # the last layer's experts feed the logits alone
+    assert prefill.as_text().count("tpu_custom_call") == 1 + 3
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    for program in (step, prefill):
+        mem = program.memory_analysis()
+        assert mem.temp_size_in_bytes < 2e9
+        assert mem.alias_size_in_bytes == pool_bytes
+        header = program.as_text().split("\n", 1)[0]
+        assert header.count("may-alias") + header.count(
+            "must-alias") == len(leaves)
+        assert relayouts(program, leaves[1].size * 2) == []

@@ -49,6 +49,12 @@ FORMS = {
         num_experts=8, experts_per_token=2, expert_intermediate_size=32,
         num_shared_experts=1, routed_scaling_factor=2.826,
         first_dense_layers=1),
+    "conv": dict(
+        num_layers=3, pos_encoding="rope", kv_heads=2, activation="swiglu",
+        norm="rmsnorm", rope_base=1e6, qk_head_norm=True,
+        layer_kinds=("short_conv", "full_attention", "short_conv"),
+        short_conv_kernel_dim=3, num_experts=8, experts_per_token=2,
+        expert_intermediate_size=32, first_dense_layers=1),
 }
 SHARED = {"embed", "attn.qkv", "cache.write", "attn.out", "mlp"}
 STEP = SHARED | {"cache.gather", "head", "sample"}
@@ -73,6 +79,10 @@ NAMES = {
     ("sliding", "prefill"): SHARED | {"attn.scores", "attn.gate",
                                       "moe.route", "moe.experts",
                                       "moe.shared"},
+    ("conv", "step"): STEP | {"attn.scores", "short_conv.step",
+                              "moe.route", "moe.experts"},
+    ("conv", "prefill"): SHARED | {"attn.scores", "short_conv.mix",
+                                   "moe.route", "moe.experts"},
     ("dense", "train"): (SHARED - {"cache.write"}) | {
         "attn.scores", "head", "loss", "optimizer"},
 }
@@ -157,7 +167,7 @@ def test_a_name_outside_the_vocabulary_raises_where_the_region_is_made():
         @profiling.region("nope")
         def never(x):
             return x
-    assert len(set(profiling.REGIONS)) == len(profiling.REGIONS) == 21
+    assert len(set(profiling.REGIONS)) == len(profiling.REGIONS) == 23
 
 
 def test_regions_nest_and_the_innermost_is_last():

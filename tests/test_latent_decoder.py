@@ -293,7 +293,7 @@ def test_retire_region_carries_the_counters_for_a_sparse_model_only(
     assert set(retire[0]) == {"pools_in_place", "sampled_lanes",
                               "table_pages", "table_pages_held",
                               "attn_pages_read", "attn_kernel_layers",
-                              "upload_us", "dispatch_us",
+                              "lanes_live", "upload_us", "dispatch_us",
                               "steps_ahead", "steps_serial",
                               "lane_steps_discarded",
                               "experts_touched", "expert_slots",
@@ -309,7 +309,7 @@ def test_retire_region_carries_the_counters_for_a_sparse_model_only(
     assert [untimed(s) for n, s in seen if n == "serve.step.retire"] == [
         {"pools_in_place": 1, "sampled_lanes": 0, "table_pages": 3 * 12,
          "table_pages_held": 1, "attn_pages_read": 1,
-         "attn_kernel_layers": 0, "steps_ahead": ahead,
+         "attn_kernel_layers": 0, "lanes_live": 1, "steps_ahead": ahead,
          "steps_serial": 1 - ahead, "lane_steps_discarded": 0}
         for ahead in (0, 1)]
     assert engine.stats()["moe"]["expert_slots"] == 0

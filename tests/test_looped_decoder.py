@@ -356,7 +356,7 @@ def test_retire_region_and_prefill_span_say_what_the_loop_holds(
     assert set(retire[0]) == {"pools_in_place", "sampled_lanes",
                               "table_pages", "table_pages_held",
                               "attn_pages_read", "attn_kernel_layers",
-                              "upload_us", "dispatch_us",
+                              "lanes_live", "upload_us", "dispatch_us",
                               "steps_ahead", "steps_serial",
                               "lane_steps_discarded",
                               "loop_steps_run", "loop_tokens",
@@ -378,7 +378,7 @@ def test_retire_region_and_prefill_span_say_what_the_loop_holds(
             for n, s in seen if n == "serve.step.retire"] == [
         {"pools_in_place": 1, "sampled_lanes": 0, "table_pages": 3 * 8,
          "table_pages_held": 2, "attn_pages_read": 1,
-         "attn_kernel_layers": 0, "steps_ahead": ahead,
+         "attn_kernel_layers": 0, "lanes_live": 1, "steps_ahead": ahead,
          "steps_serial": 1 - ahead, "lane_steps_discarded": 0}
         for ahead in (0, 1)]
     assert engine.stats()["loop"]["loop_tokens"] == 0

@@ -6,7 +6,9 @@ the plain form it stands in for on the chip,
 On the CPU the kernel runs under the TPU interpreter, which executes the
 page copies, their semaphores and the DYNAMIC trip counts as written (this
 JAX's interpreter lowers them: no all-pages form was needed), at toy sizes:
-heads of 128 (the kernel's lanes), pages of 16 rows, chunks of 8 pages so
+heads of 128 (the kernel's lanes) and heads of 64 and 32 (two and four kv
+heads a lane tile, the query widened to the tile: the scores are ``q . k``
+exactly), pages of 16 rows, chunks of 8 pages so
 that a long lane walks several chunks and ends inside one.  Every page no
 lane of a case holds is NaN: the kernel copies no such page, and the plain
 form, which reads only held pages and the sentinel's zeros, is computed on
@@ -21,13 +23,13 @@ import pytest
 from distributed_tensorflow_tpu.models import gpt as gpt_lib
 from distributed_tensorflow_tpu.ops.pallas import paged_attention as paged_ops
 
-D, PAGE, POOL = 128, 16, 66
+PAGE, POOL = 16, 66
 S = POOL                                        # the sentinel
 
 
 def plain(q, k_pool, v_pool, table, positions, kv_heads, window):
     """``decode_step_paged``'s CPU path from the mask on."""
-    B, H, _ = q.shape
+    B, H, D = q.shape
     cfg = gpt_lib.GptConfig(
         vocab_size=8, hidden_size=H * D, num_layers=1, num_heads=H,
         kv_heads=kv_heads, intermediate_size=8, max_position=8,
@@ -60,7 +62,7 @@ def table_of(lanes, MP, pool=POOL):
 
 
 #: name: heads, kv heads, window, table width, pages held a lane, positions,
-#: loop step (of 3, or None).
+#: loop step (of 3, or None), and the head's size where it is not 128.
 CASES = {
     # A lane of three chunks that ends inside one; an idle lane; a lane of
     # ONE token; a length that ends exactly on a page's last row (and the
@@ -83,12 +85,26 @@ CASES = {
     # A HOLE in a lane's walk (no engine makes one): the entry reads the
     # sentinel's zeros and its rows do not count, as in the plain form.
     "hole": (4, 2, 0, 24, [12, 2], [12 * PAGE - 2, 17], None),
+    # Heads of 64: 8 query heads in groups of 4 over 2 kv heads, ONE lane
+    # tile a row (the tile's two halves are two kv heads), and 4 kv heads
+    # in two tiles; a ring at that head; and heads of 32, four a tile.
+    "full-head64-one-tile": (8, 2, 0, 24, [20, 0, 1, 8, 9],
+                             [20 * PAGE - 3, 0, 0, 8 * PAGE - 1, 8 * PAGE],
+                             None, 64),
+    "full-head64-two-tiles": (8, 4, 0, 24, [20, 0, 1, 8, 9],
+                              [20 * PAGE - 3, 0, 0, 8 * PAGE - 1, 8 * PAGE],
+                              None, 64),
+    "ring-head64": (4, 2, 128, 9, [2, 9, 9, 0, 9],
+                    [20, 131, 2 * 144 + 77, 0, 143], None, 64),
+    "full-head32": (8, 4, 0, 24, [20, 0, 3], [20 * PAGE - 3, 0, 33],
+                    None, 32),
 }
 
 
 @pytest.mark.parametrize("name", list(CASES))
 def test_the_kernel_gives_what_the_plain_form_gives(name, monkeypatch):
-    H, G, window, MP, lanes, positions, loop_step = CASES[name]
+    H, G, window, MP, lanes, positions, loop_step, *head = CASES[name]
+    D = head[0] if head else 128
     monkeypatch.setattr(paged_ops, "_CHUNK_MAX", 128)    # 8 pages a chunk
     keys = jax.random.split(jax.random.key(45), 3)
     q = jax.random.normal(keys[0], (len(lanes), H, D), jnp.float32)
@@ -130,17 +146,23 @@ def test_the_kernel_gives_what_the_plain_form_gives(name, monkeypatch):
 
 
 def test_pools_the_kernel_cannot_walk_are_refused():
-    """A float8 page of 16 rows is half a tile, a head of 64 half a lane:
+    """A float8 page of 16 rows is half a tile; a head that neither fills
+    lane tiles of 128 nor divides one, or whose flat row is not whole
+    tiles (three kv heads of 64), cannot be presented as heads of 128:
     ``supports`` says so and the program keeps the plain form there."""
     ok = jax.ShapeDtypeStruct((9, 16, 256), jnp.bfloat16)
     assert paged_ops.supports(ok, 128)
-    assert not paged_ops.supports(ok, 64)
+    assert paged_ops.supports(ok, 64) and paged_ops.supports(ok, 32)
+    assert not paged_ops.supports(
+        jax.ShapeDtypeStruct((9, 16, 192), jnp.bfloat16), 64)
+    assert not paged_ops.supports(
+        jax.ShapeDtypeStruct((9, 16, 384), jnp.bfloat16), 96)
     assert not paged_ops.supports(
         jax.ShapeDtypeStruct((9, 16, 256), jnp.float8_e4m3fn), 128)
     assert not paged_ops.supports(
         jax.ShapeDtypeStruct((9, 12, 256), jnp.bfloat16), 128)
     with pytest.raises(ValueError, match="cannot walk"):
         paged_ops.paged_attention(
-            jnp.zeros((1, 2, 64)), jnp.zeros((9, 16, 128)),
-            jnp.zeros((9, 16, 128)), jnp.zeros((1, 2), jnp.int32),
+            jnp.zeros((1, 3, 64)), jnp.zeros((9, 16, 192)),
+            jnp.zeros((9, 16, 192)), jnp.zeros((1, 2), jnp.int32),
             jnp.zeros((1,), jnp.int32))

@@ -329,10 +329,11 @@ def test_the_profilers_retire_event_carries_sampled_lanes(monkeypatch):
     # (and this PR's three of the hand-over: a first step is a serial one;
     # and PR 45's two: of a lane's 4 reserved pages the prompt reaches 3,
     # which is what a read of held pages only would visit, and on a CPU no
-    # layer reads so)
+    # layer reads so; and PR 46's one: the three lanes are seated)
     assert retire == [{"pools_in_place": 1, "sampled_lanes": 2,
                        "table_pages": 3 * 8, "table_pages_held": 12,
                        "attn_pages_read": 9, "attn_kernel_layers": 0,
+                       "lanes_live": 3,
                        "steps_ahead": 0, "steps_serial": 1,
                        "lane_steps_discarded": 0}]
 

@@ -46,6 +46,17 @@ CONFIGS = {
     "kernel": dict(pos_encoding="rope", num_heads=2, kv_heads=1,
                    head_size=128, activation="swiglu", norm="rmsnorm",
                    attention_backend="pallas"),
+    # The same path at heads of 64, two kv heads a lane tile (a flat row of
+    # 128), under a short-convolution layer whose entry is a tail a slot,
+    # and routed experts: the kernel sees one kv "head" of 128.
+    "kernel64": dict(pos_encoding="rope", hidden_size=64, num_heads=4,
+                     kv_heads=2, head_size=64, qk_head_norm=True,
+                     activation="swiglu", norm="rmsnorm",
+                     attention_backend="pallas",
+                     layer_kinds=("short_conv", "full_attention"),
+                     short_conv_kernel_dim=3, num_experts=4,
+                     experts_per_token=2, expert_intermediate_size=16,
+                     first_dense_layers=1),
     "latent": dict(
         num_layers=3, pos_encoding="none", activation="swiglu",
         norm="rmsnorm", rope_base=1e6, latent_kv_rank=32, latent_q_rank=48,
@@ -72,7 +83,7 @@ def model_of(name):
 
 
 def paged(kind):
-    return kind != gpt_lib.LINEAR_ATTENTION
+    return kind not in gpt_lib.STATE_KINDS
 
 
 def junk_pools(cfg, seed=0, page=PAGE):
@@ -127,7 +138,8 @@ def run(model, params, program, pools):
 
 
 FORMS = [("dense", "step"), ("dense", "chunk"), ("hybrid", "step"),
-         ("looped", "step"), ("latent", "step"), ("kernel", "step")]
+         ("looped", "step"), ("latent", "step"), ("kernel", "step"),
+         ("kernel64", "step")]
 
 
 def on_the_kernels_path(monkeypatch):
@@ -146,7 +158,7 @@ def test_a_lanes_logits_depend_on_no_page_it_does_not_own(
     model, params = model_of(name)
     cfg = model.cfg
     page = PAGE
-    if name == "kernel":
+    if name.startswith("kernel"):
         on_the_kernels_path(monkeypatch)
         monkeypatch.setattr(paged_ops, "_CHUNK_MAX", 128)
         page = 2 * PAGE
@@ -161,8 +173,9 @@ def test_a_lanes_logits_depend_on_no_page_it_does_not_own(
                for x in entry)
     step = jax.jit(lambda p: run(model, params, program, p))
     want, after = step(pools)
-    if name == "kernel":
-        assert len(calls) == cfg.num_layers        # traced once, a call a layer
+    if name.startswith("kernel"):
+        # traced once, a call a K/V layer
+        assert len(calls) == sum(map(paged, cfg.kinds))
     want = np.asarray(want)
     assert np.isfinite(want[:3]).all() and np.abs(want[:3]).max() > 0.1
     assert sentinel_pages_are_zero(cfg, after)
