@@ -1064,9 +1064,13 @@ class GptBlock(nn.Module):
                                  positions: jax.Array,
                                  live: jax.Array | None = None):
         """One token a row against the PAGED pools of latent rows
-        ([num_pages + 1, page_size, latent_kv_rank] and [.., rope];
-        addressing, sentinel page and masks as in
-        :meth:`decode_step_paged`), in the
+        ([num_pages + 1, page_size, latent_kv_rank] and the rotated keys'
+        [num_pages + 1, page_size / 2, 2 * rope], two tokens a row of 128
+        lanes: :func:`init_kv_pool`; addressing, sentinel page and masks
+        as in :meth:`decode_step_paged`, and its TWO forms of the read:
+        the latent kernel over the pages a lane holds where
+        :func:`paged_kernel_attends` says so, else the gather of every
+        entry of the table), in the
         ABSORBED form: with ``kv_b`` split a head into W^K [latent, nope]
         and W^V [latent, v], the query's un-rotated part is folded through
         W^K into the latent's space and scored against the cached latents
@@ -1077,6 +1081,7 @@ class GptBlock(nn.Module):
         cfg = self.cfg
         sentinel, page = latent_pool.shape[0] - 1, latent_pool.shape[1]
         MP = page_table.shape[1]
+        rope, rows = cfg.qk_rope_head_dim, key_pool.shape[1]
         q_nope, q_rot, latent, k_rot = self._latent_q_row(
             x, positions[:, None])
         with profiling.region("cache.write"):
@@ -1087,34 +1092,78 @@ class GptBlock(nn.Module):
                 axis=1)[:, 0], latent_pool.shape[0])
             latent_pool = latent_pool.at[phys, off].set(
                 latent[:, 0].astype(latent_pool.dtype), mode="drop")
-            key_pool = key_pool.at[phys, off].set(
-                k_rot[:, 0].astype(key_pool.dtype), mode="drop")
-        with profiling.region("cache.gather"):
-            s = jnp.arange(MP * page)
-            allocated = jnp.take_along_axis(
-                page_table, (s[None, :] // page), axis=1) < sentinel  # [B, S]
-            valid = (s[None, :] <= positions[:, None]) & allocated
+            fresh, row = k_rot[:, 0].astype(key_pool.dtype), off
+            if rows < page:
+                # Two tokens a row of 128 lanes (``init_kv_pool``): the
+                # row as it is with this token's part of it replaced.
+                row = off % rows
+                part = jnp.arange(key_pool.shape[2])[None, :] // rope
+                fresh = jnp.where(
+                    part == (off // rows)[:, None],
+                    jnp.tile(fresh, (1, page // rows)),
+                    key_pool.at[phys, row].get(mode="clip"))
+            key_pool = key_pool.at[phys, row].set(fresh, mode="drop")
         compute = q_nope.dtype
-        with profiling.region("mla.absorb"):
-            w_k, w_v = jnp.split(
+
+        def absorbed():
+            # W^K [latent, H, nope] and W^V [latent, H, v] of ``kv_b``.
+            return jnp.split(
                 self.kv_b.variables["params"]["kernel"].astype(compute),
                 [cfg.qk_nope_head_dim], axis=-1)
-            latents = gather_pages(latent_pool, page_table).astype(compute)
-            scale = 1.0 / jnp.sqrt(jnp.float32(
-                cfg.qk_nope_head_dim + cfg.qk_rope_head_dim))
-            logits = (jnp.einsum(
-                "bhc,bsc->bhs", jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_k),
-                latents, preferred_element_type=jnp.float32) + jnp.einsum(
-                "bhr,bsr->bhs", q_rot[:, 0],
-                gather_pages(key_pool, page_table).astype(compute),
-                preferred_element_type=jnp.float32)) * scale
-            logits = jnp.where(valid[:, None, :], logits,
-                               jnp.finfo(jnp.float32).min)
-            weights = jax.nn.softmax(logits, axis=-1).astype(compute)
-            mean = jnp.einsum("bhs,bsc->bhc", weights, latents)
+
+        if paged_kernel_attends(cfg, latent_pool, key_pool):
+            # The same sums over the pages the lane HOLDS, each copied
+            # once: its latents are keys and values both
+            # (ops/pallas/paged_attention.py).
+            with profiling.region("mla.absorb"):
+                w_k, w_v = absorbed()
+                q_lat = jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_k)
+            with profiling.region("attn.scores"):
+                mean = paged_ops.latent_paged_attention(
+                    q_lat, q_rot[:, 0], latent_pool, key_pool, page_table,
+                    positions,
+                    scale=1.0 / (cfg.qk_nope_head_dim + rope) ** 0.5)
+        else:
+            with profiling.region("cache.gather"):
+                s = jnp.arange(MP * page)
+                allocated = jnp.take_along_axis(
+                    page_table, (s[None, :] // page), axis=1) < sentinel
+                valid = (s[None, :] <= positions[:, None]) & allocated
+            with profiling.region("mla.absorb"):
+                w_k, w_v = absorbed()
+                latents = gather_pages(latent_pool, page_table).astype(
+                    compute)
+                scale = 1.0 / jnp.sqrt(jnp.float32(
+                    cfg.qk_nope_head_dim + rope))
+                logits = (jnp.einsum(
+                    "bhc,bsc->bhs",
+                    jnp.einsum("bhd,chd->bhc", q_nope[:, 0], w_k), latents,
+                    preferred_element_type=jnp.float32) + jnp.einsum(
+                    "bhr,bsr->bhs", q_rot[:, 0],
+                    self._gathered_keys(key_pool, page_table).astype(compute),
+                    preferred_element_type=jnp.float32)) * scale
+                logits = jnp.where(valid[:, None, :], logits,
+                                   jnp.finfo(jnp.float32).min)
+                weights = jax.nn.softmax(logits, axis=-1).astype(compute)
+                mean = jnp.einsum("bhs,bsc->bhc", weights, latents)
+        with profiling.region("mla.absorb"):
             ctx = jnp.einsum("bhc,chd->bhd", mean, w_v)
         x = self._add_mixed(x, ctx[:, None])
         return self._mlp(x, True, live), latent_pool, key_pool
+
+    def _gathered_keys(self, key_pool: jax.Array,
+                       page_table: jax.Array) -> jax.Array:
+        """Every entry of the table's rotated keys side by side, a row a
+        token [B, MP * page_size, rope], whichever way the pool holds a
+        page of them (:func:`init_kv_pool`)."""
+        keys = gather_pages(key_pool, page_table)
+        rope = self.cfg.qk_rope_head_dim
+        if key_pool.shape[2] == rope:
+            return keys
+        B, MP = page_table.shape
+        return paged_ops.unpack_keys(
+            keys.reshape(B, MP, *key_pool.shape[1:]), rope).reshape(
+                B, -1, rope)
 
     def _write_prefill(self, cache: jax.Array, fresh: jax.Array) -> jax.Array:
         """Write the prompt's K or V rows into the cache.
@@ -1983,23 +2032,32 @@ def written_pages(pages: jax.Array, rows: int) -> jax.Array:
     return jnp.where(pages < rows - 1, pages, rows)
 
 
-def paged_kernel_attends(cfg: GptConfig, pool) -> bool:
+def paged_kernel_attends(cfg: GptConfig, pool, key_pool=None) -> bool:
     """Whether ``GptBlock.decode_step_paged`` attends a K/V ``pool`` (an
-    array or its shape and type) through the paged-attention kernel: where
-    the configuration asks for Pallas kernels, the backend is a TPU and
-    the pool is one the kernel can walk (``paged_ops.supports``: a float8
-    pool's page of 16 rows is half a tile).  Otherwise, and so on the CPU,
-    the plain form: :func:`gather_pages` and ``GptBlock._attend_rows``."""
+    array or its shape and type) through the paged-attention kernel, or,
+    with ``key_pool``, ``GptBlock.latent_decode_step_paged`` a latent
+    layer's two pools through the latent one: where the configuration asks
+    for Pallas kernels, the backend is a TPU and the pools are such as the
+    kernel can walk (``paged_ops.supports`` / ``supports_latent``: a
+    float8 pool's page of 16 rows is half a tile).  Otherwise, and so on
+    the CPU, the plain form: :func:`gather_pages` and a softmax over every
+    entry of the table."""
     return (cfg.attention_backend == "pallas"
             and jax.default_backend() == "tpu"
-            and paged_ops.supports(pool, cfg.head_dim))
+            and (paged_ops.supports(pool, cfg.head_dim) if key_pool is None
+                 else paged_ops.supports_latent(pool, key_pool)))
 
 
 def paged_kernel_layers(cfg: GptConfig, pools) -> int:
     """The layers of ``cfg`` whose entry of ``pools``
-    (:func:`init_kv_pool`) the decode step attends through the kernel."""
-    return sum(kind in (FULL_ATTENTION, SLIDING_ATTENTION)
-               and paged_kernel_attends(cfg, entry[0])
+    (:func:`init_kv_pool`) the decode step attends through a kernel."""
+    def attends(kind, entry):
+        if kind == LATENT_ATTENTION:
+            return paged_kernel_attends(cfg, *entry)
+        return (kind in (FULL_ATTENTION, SLIDING_ATTENTION)
+                and paged_kernel_attends(cfg, entry[0]))
+
+    return sum(attends(kind, entry)
                for kind, entry in zip(cfg.kinds, pools))
 
 
@@ -2068,8 +2126,18 @@ def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype,
     the PAGES minor-most and every decode step copied every pool into the
     indexed order and back (8.2 of a step's 24.2 ms; PERF.md, PR 35)."""
     if kind == LATENT_ATTENTION:
+        rope = cfg.qk_rope_head_dim
+        if flat:
+            # A page's rotated keys in whole lanes of 128, two tokens a
+            # row (``paged_ops.key_rows``): 64 entries a row the chip laid
+            # out with the PAGES minor-most and copied into the indexed
+            # order and back twice a layer a step (PERF.md, PR 47).
+            rows = paged_ops.key_rows(lead[-1], rope)
+            keys = (*lead[:-1], rows, lead[-1] // rows * rope)
+        else:
+            keys = (*lead, rope)
         return (jnp.zeros((*lead, cfg.latent_kv_rank), dtype),
-                jnp.zeros((*lead, cfg.qk_rope_head_dim), dtype))
+                jnp.zeros(keys, dtype))
     shape = ((*lead, cfg.num_kv_heads * cfg.head_dim) if flat
              else (*lead, cfg.num_kv_heads, cfg.head_dim))
     return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
@@ -2150,7 +2218,11 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
     tail), whatever the sequence's length.  A short-convolution layer's is
     its tail alone, ``[num_slots, taps - 1, hidden]``.  A latent-attention layer's entry
     is its row's two parts, [num_pages + 1, page_size, latent_kv_rank] and
-    [num_pages + 1, page_size, qk_rope_head_dim] (:func:`_rows_entry`).
+    the rotated keys, ``128 // qk_rope_head_dim`` tokens a row of whole
+    lanes where the page divides so (at 64: [num_pages + 1, page_size / 2,
+    128], token ``o`` of a page in row ``o % 8`` at lanes ``o // 8 * 64``;
+    ``paged_ops.key_rows`` / ``pack_keys``), else [num_pages + 1,
+    page_size, qk_rope_head_dim] (:func:`_rows_entry`).
 
     With ``cfg.loop_steps`` > 1 a layer's pool holds ``loop_steps`` runs of
     ``num_pages`` pages and the one sentinel page after the last,

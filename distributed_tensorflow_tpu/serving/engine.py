@@ -84,10 +84,14 @@ Pallas configuration on a TPU) it reads no table whole: a lane's pages up
 to its position's, and no page of an idle lane.  ``attn_pages_read`` is
 that walk's length summed over the lanes, counted from the host's own
 ``_positions`` and ``_tables`` whichever path runs, and
-``attn_kernel_layers`` the layers of the step program on the kernel's path
-(0 on a CPU, and in a speculative turn, which attends a chunk the plain
-way): ``attn_pages_read / table_pages`` is the share of the table the step
-pays for on the kernel, beside ``table_pages_held / table_pages``.
+``attn_kernel_layers`` the layers of the step program on a kernel's path
+(``gpt_lib.paged_kernel_layers``: K/V layers and, since PR 47, LATENT
+layers, whose two pools the latent kernel of the same file walks, traced
+like the K/V kernel under the device region ``attn.scores``; 0 on a CPU,
+over a float8 pool, and in a speculative turn, which attends a chunk the
+plain way): ``attn_pages_read / table_pages`` is the share of the table
+the step pays for on the kernel, beside ``table_pages_held /
+table_pages``.
 ``lanes_live`` is the seated lanes of the dispatch, the rows whose table
 names a page (the step's own ``live`` mask, counted on the host): over
 ``num_slots`` it is the batch's occupancy.
@@ -116,7 +120,7 @@ import numpy as np
 
 from ..models import gpt as gpt_lib
 from ..models.drafting import NGramIndex
-from ..ops.pallas.paged_attention import pages_walked
+from ..ops.pallas.paged_attention import pack_keys, pages_walked
 from ..ops.quant import (load_inference_tree, prepare_inference_tree,
                          resolve_kv_dtype, validate_quantize)
 from ..utils import profiling, tracing
@@ -659,9 +663,11 @@ class DecodeEngine:
                     return pool.at[
                         gpt_lib.written_pages(runs, pool.shape[0])].set(
                         cache.reshape(R * n_pages, page, -1), mode="drop")
+                # (A latent layer's rotated keys lie two tokens a row.)
                 return pool.at[
                     gpt_lib.written_pages(phys, pool.shape[0])].set(
-                    cache[0].reshape(n_pages, page, -1), mode="drop")
+                    pack_keys(cache[0].reshape(n_pages, page, -1),
+                              pool.shape[1]), mode="drop")
 
             # An entry is (keys, values) of a run of pages or of a ring, a
             # latent layer's (latents, rotated keys), (state, convolution

@@ -462,21 +462,28 @@ def test_latent_and_routed_expert_programs_compile_for_v5e(one_chip):
     step = lowered.compile()
     prefill = engine._prefill_fn(64).lower(
         tree, i32(1, 1024), pools, i32(64)).compile()
-    assert step.as_text().count("tpu_custom_call") == 2 * 3
-    # (what the step gathers of a latent pool: 16 lanes of 528 pages)
-    assert gathered_selects(step, 16 * 528 * 16 * 512 * 2) == []
+    # Three grouped products a sparse layer and, since PR 47, a call of
+    # the latent paged-attention kernel a layer.
+    assert step.as_text().count("tpu_custom_call") == 2 * 3 + 3
+    assert gpt_lib.paged_kernel_layers(cfg, pools) == 3
+    # (what the step gathered of a latent pool: 16 lanes of 528 pages; of
+    # the rotated keys' an eighth of that)
+    gathered = 16 * 528 * 16 * 512 * 2
+    assert gathered_selects(step, gathered) == []
+    assert table_gathers(step, gathered // 8 // 16) == []
     # The last layer's mixer and experts feed only logits the prefill
     # throws away: two flash calls and ONE layer's grouped products stay.
     assert prefill.as_text().count("tpu_custom_call") == 2 + 3
     leaves = jax.tree.leaves(pools)
+    # (The rotated keys two tokens a row of 128 lanes: at [8449, 16, 64]
+    # the chip laid that pool out with the PAGES minor-most, and the step
+    # copied it into the indexed order and back, sixteen copies a step:
+    # PR 47.  Now every pool is held as it is indexed and as the kernel
+    # reads it, in its own bytes.)
     assert [x.shape for x in leaves] == [(8449, 16, 512),
-                                         (8449, 16, 64)] * 3
-    # (512 fills whole lanes of 128; the chip lays the rotated keys' 64 out
-    # with the PAGES minor-most, as it did, in whole lanes of 128 pages:
-    # the sentinel's page makes 8,449 of them, held as 8,576)
-    pool_bytes = sum(
-        (x.shape[0] if x.shape[-1] % 128 == 0 else -(-x.shape[0] // 128) * 128)
-        * x.size // x.shape[0] * x.dtype.itemsize for x in leaves)
+                                         (8449, 8, 128)] * 3
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    assert pool_bytes == 3 * 8449 * 16 * 1152
     for program in (step, prefill):
         mem = program.memory_analysis()
         assert mem.temp_size_in_bytes < 2e9
@@ -484,6 +491,8 @@ def test_latent_and_routed_expert_programs_compile_for_v5e(one_chip):
         header = program.as_text().split("\n", 1)[0]
         assert header.count("may-alias") + header.count(
             "must-alias") == len(leaves)
+        # no copy of a pool, the keys' (a ninth of a layer's row) included
+        assert relayouts(program, 8448 * 8 * 128 * 2) == []
 
 
 # ------------------------- a stack walked four times over the same weights

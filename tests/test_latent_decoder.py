@@ -201,6 +201,91 @@ def test_pool_is_one_row_a_token_and_the_allocator_says_its_bytes(
          "config_file": mistral})) == 65536
 
 
+#: Latent layers at shapes that PACK the rotated keys (a rope of 64 in
+#: pages of 16: two tokens a row of 128 lanes) and that the latent kernel
+#: can walk (latents of 128, whole lane tiles).
+PACKED = dict(vocab_size=64, hidden_size=40, num_layers=2, num_heads=5,
+              intermediate_size=48, max_position=256, dtype="float32",
+              pos_encoding="none", activation="swiglu", norm="rmsnorm",
+              rope_base=1e6, latent_kv_rank=128, latent_q_rank=48,
+              qk_nope_head_dim=32, qk_rope_head_dim=64, v_head_dim=96,
+              attention_backend="pallas")
+
+
+def test_rotated_keys_two_a_row_serve_the_full_forward_on_both_forms(
+        monkeypatch):
+    """At the published rope of 64 a page of 16 holds its rotated keys in 8
+    rows of 128 lanes (PR 47), in the row's own bytes.  Through
+    ``DecodeEngine`` (the prefill lands a prompt's keys packed, the step
+    rewrites one token's half of a row) the plain form serves the argmax
+    of the model's full forward, prompts that end inside a page, on its
+    last row and in a row's second half; and steered onto the latent
+    kernel (the TPU interpreter) the same engine serves the same tokens
+    and counts its layers."""
+    from distributed_tensorflow_tpu.ops.pallas import (
+        paged_attention as paged_ops)
+    model = gpt_lib.GptLM(gpt_lib.GptConfig(**PACKED))
+    params = model.init(jax.random.key(47),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    econf = EngineConfig(num_slots=3, page_size=16, num_pages=24,
+                         max_pages_per_seq=6)
+
+    def served(engine):
+        reqs = [Request(np.random.default_rng(n).integers(0, 64, n).tolist(),
+                        9) for n in (5, 16, 29, 41)]
+        serve(engine, *reqs[:3])
+        serve(engine, reqs[3])                       # freed pages, reused
+        return reqs
+
+    engine = DecodeEngine(model, params, econf)
+    assert [tuple(x.shape for x in e) for e in engine.pools] == [
+        ((25, 16, 128), (25, 8, 128))] * 2
+    assert engine.stats()["kv_pool"]["row_bytes_per_token"] == 192 * 4 * 2
+    plain = served(engine)
+    assert engine.stats()["attn_kernel_layers"] == 0
+    full = jax.jit(lambda t: model.apply({"params": params}, t)[0])
+    for r in plain:
+        seq = r.prompt + r.tokens
+        want = np.asarray(full(jnp.asarray([seq + [0] * (64 - len(seq))])))
+        assert r.tokens == want[len(r.prompt) - 1:len(seq) - 1].argmax(
+            -1).tolist()
+    monkeypatch.setattr(
+        gpt_lib, "paged_kernel_attends",
+        lambda cfg, pool, key_pool=None: paged_ops.supports_latent(
+            pool, key_pool))
+    monkeypatch.setattr(paged_ops, "_CHUNK_MAX", 128)
+    engine = DecodeEngine(model, params, econf)
+    assert [r.tokens for r in served(engine)] == [r.tokens for r in plain]
+    stats = engine.stats()
+    assert stats["attn_kernel_layers"] == 2 * (
+        stats["steps_ahead"] + stats["steps_serial"]) > 0
+
+
+def test_kernel_layers_count_a_latent_layer_by_what_the_code_observes(
+        monkeypatch):
+    """``paged_kernel_layers`` (the record's ``attn_kernel_layers``) counts
+    a LATENT layer where its step takes the kernel: a Pallas configuration,
+    a TPU backend and pools the kernel can walk.  A CPU, another backend of
+    attention, float8 rows (the lower-precision control) and rotated keys a
+    row a token (the rehearsal's shapes) keep the plain form and count 0."""
+    cfg = gpt_lib.GptConfig(**{**PACKED, "dtype": "bfloat16"})
+    pools = lambda cfg, page=16, dtype=None: jax.eval_shape(  # noqa: E731
+        lambda: gpt_lib.init_kv_pool(cfg, 24, page, dtype=dtype))
+    assert gpt_lib.paged_kernel_layers(cfg, pools(cfg)) == 0     # a CPU
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert gpt_lib.paged_kernel_layers(cfg, pools(cfg)) == 2
+    assert gpt_lib.paged_kernel_attends(cfg, *pools(cfg)[0])
+    assert gpt_lib.paged_kernel_layers(
+        cfg, pools(cfg, dtype=jnp.float8_e4m3fn)) == 0
+    xla = dataclasses.replace(cfg, attention_backend="xla")
+    assert gpt_lib.paged_kernel_layers(xla, pools(xla)) == 0
+    narrow = dataclasses.replace(cfg, qk_nope_head_dim=88,
+                                 qk_rope_head_dim=8)
+    assert [x.shape for x in pools(narrow, 8)[0]] == [(25, 8, 128),
+                                                      (25, 8, 8)]
+    assert gpt_lib.paged_kernel_layers(narrow, pools(narrow, 8)) == 0
+
+
 def test_the_lower_precision_control_runs_on_stacked_experts_and_rows(
         cfg, ref, model_and_params):
     """int8 weights (the experts' stacked kernels too) and float8 rows: the
@@ -426,8 +511,13 @@ def test_default_config_keeps_its_tree_and_its_kinds():
 #: branch of ``_rows_entry`` alone: this cell's programs were its control.
 #: Renewed in PR 39, which gives the latent pools the sentinel's page of
 #: zeros as it gives every paged pool (a sentinel entry of the table read
-#: ANOTHER lane's latents before; ``tests/test_sentinel_page.py``).  They
-#: hold for this sandbox's jax.
+#: ANOTHER lane's latents before; ``tests/test_sentinel_page.py``).  PR 47
+#: left them as they were: at the rehearsal's rope of 8 the rotated keys'
+#: pool stays a row a token, and the plain form lowers as it did (at a rope
+#: of 64 that pool holds two tokens a row of 128 lanes and the CPU's
+#: lowering differs by that shape alone:
+#: ``test_rotated_keys_two_a_row_serve_the_full_forward_on_both_forms``).
+#: They hold for this sandbox's jax.
 LATENT_GOLDEN = {
     "": ("4c441d479ce85551e7cba276e1441bf1",
          "67b20a056996e2faf982299f7e1d7d84"),

@@ -13,6 +13,11 @@ that a long lane walks several chunks and ends inside one.  Every page no
 lane of a case holds is NaN: the kernel copies no such page, and the plain
 form, which reads only held pages and the sentinel's zeros, is computed on
 the same pools.
+
+The LATENT kernel of the same file (PR 47) likewise against
+``GptBlock.latent_decode_step_paged``'s plain form from the mask on: one
+pool of latents that are keys and values both, one of rotated keys held
+two tokens a row of 128 lanes, 20 heads over the one shared row.
 """
 
 import jax
@@ -166,3 +171,118 @@ def test_pools_the_kernel_cannot_walk_are_refused():
             jnp.zeros((1, 3, 64)), jnp.zeros((9, 16, 192)),
             jnp.zeros((9, 16, 192)), jnp.zeros((1, 2), jnp.int32),
             jnp.zeros((1,), jnp.int32))
+
+
+# ------------------------------------------------- one latent row a token
+
+
+def plain_latent(q_lat, q_rot, latent_pool, key_pool, table, positions,
+                 scale):
+    """``latent_decode_step_paged``'s CPU path from the mask on."""
+    B, MP = table.shape
+    rope = q_rot.shape[-1]
+    s = jnp.arange(MP * PAGE)
+    allocated = jnp.take_along_axis(table, s[None, :] // PAGE,
+                                    axis=1) < latent_pool.shape[0] - 1
+    valid = (s[None, :] <= positions[:, None]) & allocated
+    latents = gpt_lib.gather_pages(latent_pool, table)
+    keys = paged_ops.unpack_keys(
+        gpt_lib.gather_pages(key_pool, table).reshape(
+            B, MP, *key_pool.shape[1:]), rope).reshape(B, MP * PAGE, rope)
+    logits = (jnp.einsum("bhc,bsc->bhs", q_lat, latents)
+              + jnp.einsum("bhr,bsr->bhs", q_rot, keys)) * scale
+    logits = jnp.where(valid[:, None, :], logits, jnp.finfo(jnp.float32).min)
+    return jnp.einsum("bhs,bsc->bhc", jax.nn.softmax(logits, axis=-1),
+                      latents)
+
+
+#: name: heads, the latents' width, table width, pages held a lane,
+#: positions, the table's entries made holes.
+LATENT_CASES = {
+    # 20 heads (padded to 24 in the wrapper, the padding dropped); a lane
+    # of three chunks that ends inside one; an idle lane; a lane of ONE
+    # token; a length that ends exactly on a page's last row (and the
+    # chunk's); a lane that ends on a chunk's first row.
+    "heads20": (20, 256, 24, [20, 0, 1, 8, 9],
+                [20 * PAGE - 3, 0, 0, 8 * PAGE - 1, 8 * PAGE], ()),
+    # Whole tiles of heads at the published width of 512.
+    "heads8-wide": (8, 512, 24, [17, 3], [17 * PAGE - 9, 40], ()),
+    # A HOLE in a lane's walk (no engine makes one).
+    "hole": (20, 128, 24, [12, 2], [12 * PAGE - 2, 17], ((0, 3), (0, 9))),
+}
+
+
+@pytest.mark.parametrize("name", list(LATENT_CASES))
+def test_the_latent_kernel_gives_what_the_plain_form_gives(name, monkeypatch):
+    H, C, MP, lanes, positions, holes = LATENT_CASES[name]
+    rope, scale = 64, 1.0 / (192 + 64) ** 0.5
+    monkeypatch.setattr(paged_ops, "_CHUNK_MAX", 128)    # 8 pages a chunk
+    keys = jax.random.split(jax.random.key(47), 4)
+    q_lat = jax.random.normal(keys[0], (len(lanes), H, C), jnp.float32)
+    q_rot = jax.random.normal(keys[1], (len(lanes), H, rope), jnp.float32)
+    latent_pool = jax.random.normal(
+        keys[2], (POOL + 1, PAGE, C), jnp.float32).at[-1].set(0)
+    rows = paged_ops.key_rows(PAGE, rope)
+    key_pool = paged_ops.pack_keys(jax.random.normal(
+        keys[3], (POOL + 1, PAGE, rope), jnp.float32).at[-1].set(0), rows)
+    assert key_pool.shape == (POOL + 1, PAGE // 2, 128)
+    assert paged_ops.supports_latent(latent_pool, key_pool)
+    table = table_of(lanes, MP)
+    for hole in holes:
+        table[hole] = S
+    held = np.zeros(POOL + 1, bool)
+    held[table[table < S]] = True
+    held[S] = True                          # nobody's, and zeros
+    poison = jnp.asarray(~held)[:, None, None]
+    latent_pool, key_pool = (jnp.where(poison, jnp.nan, x)
+                             for x in (latent_pool, key_pool))
+    positions = np.asarray(positions, np.int32)
+    args = (q_lat, q_rot, latent_pool, key_pool, jnp.asarray(table),
+            jnp.asarray(positions))
+    want = np.asarray(plain_latent(*args, scale))
+    got = np.asarray(jax.jit(lambda *a: paged_ops.latent_paged_attention(
+        *a, scale=scale))(*args))
+    assert got.shape == want.shape == (len(lanes), H, C)
+    assert np.isfinite(want).all() and np.isfinite(got).all()
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-5)
+    for b, n in enumerate(lanes):
+        assert (np.abs(got[b]).max() > 1e-3) == (n > 0)
+
+
+def test_rotated_keys_lie_two_tokens_a_row_of_whole_lanes():
+    """Token ``o`` of a page of 16 in row ``o % 8`` at lanes ``o // 8 *
+    64``; a shape that does not pack (a rope of 8 in a page of 8: the
+    rehearsal's) stays a row a token; packing is undone exactly."""
+    assert paged_ops.key_rows(16, 64) == 8 and paged_ops.key_rows(32, 64) == 16
+    assert paged_ops.key_rows(8, 8) == 8 and paged_ops.key_rows(4, 8) == 4
+    keys = jnp.arange(3 * 16 * 64, dtype=jnp.float32).reshape(3, 16, 64)
+    packed = paged_ops.pack_keys(keys, 8)
+    assert packed.shape == (3, 8, 128)
+    for o in (0, 5, 8, 15):
+        np.testing.assert_array_equal(
+            packed[1, o % 8, o // 8 * 64:o // 8 * 64 + 64], keys[1, o])
+    np.testing.assert_array_equal(paged_ops.unpack_keys(packed, 64), keys)
+    assert paged_ops.pack_keys(keys, 16) is keys
+    assert paged_ops.unpack_keys(keys, 64) is keys
+
+
+def test_latent_pools_the_kernel_cannot_walk_are_refused():
+    """A float8 page of 16 rows is half a tile, latents that do not fill
+    lane tiles or rotated keys a row a token cannot be walked:
+    ``supports_latent`` says so and the program keeps the plain form."""
+    pools = lambda dtype, c=512, keys=(8, 128): (  # noqa: E731
+        jax.ShapeDtypeStruct((9, 16, c), dtype),
+        jax.ShapeDtypeStruct((9, *keys), dtype))
+    assert paged_ops.supports_latent(*pools(jnp.bfloat16))
+    assert paged_ops.supports_latent(*pools(jnp.float32))
+    assert not paged_ops.supports_latent(*pools(jnp.float8_e4m3fn))
+    assert not paged_ops.supports_latent(*pools(jnp.bfloat16, c=576))
+    assert not paged_ops.supports_latent(*pools(jnp.bfloat16, keys=(16, 64)))
+    assert not paged_ops.supports_latent(*pools(jnp.bfloat16, keys=(4, 128)))
+    with pytest.raises(ValueError, match="cannot walk"):
+        paged_ops.latent_paged_attention(
+            jnp.zeros((1, 4, 512)), jnp.zeros((1, 4, 64)),
+            jnp.zeros((9, 16, 512), jnp.float8_e4m3fn),
+            jnp.zeros((9, 8, 128), jnp.float8_e4m3fn),
+            jnp.zeros((1, 2), jnp.int32), jnp.zeros((1,), jnp.int32),
+            scale=1.0)

@@ -7,8 +7,8 @@ own at that step**, non-finite values there included, in every paged form
 (K/V rows in a dense and in a hybrid decoder, the K/V chunk, a loop step's
 run of pages, a latent row's two parts; since PR 45 also where the step
 attends its pools through the paged-attention kernel, which COPIES no page
-a lane does not own).  On the parent of PR 39 the K/V
-cases hold too (``mode="fill"`` wrote the zeros) and the latent ones FAIL:
+a lane does not own, and since PR 47 through the latent layers' kernel).
+On the parent of PR 39 the K/V cases hold too (``mode="fill"`` wrote the zeros) and the latent ones FAIL:
 its gather clipped a sentinel entry onto the pool's last real page, whose
 rows reach every lane's weighted sum as ``0 x row``.
 
@@ -57,6 +57,17 @@ CONFIGS = {
                      short_conv_kernel_dim=3, num_experts=4,
                      experts_per_token=2, expert_intermediate_size=16,
                      first_dense_layers=1),
+    # A latent layer's two pools on ITS kernel's path (PR 47): latents in
+    # whole lane tiles, a rotated key of 64 so that two tokens fill a row
+    # of the keys' pool, pages of 16.
+    "latent-kernel": dict(
+        num_layers=3, pos_encoding="none", activation="swiglu",
+        norm="rmsnorm", rope_base=1e6, latent_kv_rank=128, latent_q_rank=48,
+        qk_nope_head_dim=32, qk_rope_head_dim=64, v_head_dim=96,
+        num_heads=5, hidden_size=40, num_experts=8, experts_per_token=2,
+        expert_intermediate_size=32, num_shared_experts=1,
+        routed_scaling_factor=1.8, first_dense_layers=1,
+        attention_backend="pallas"),
     "latent": dict(
         num_layers=3, pos_encoding="none", activation="swiglu",
         norm="rmsnorm", rope_base=1e6, latent_kv_rank=32, latent_q_rank=48,
@@ -139,7 +150,11 @@ def run(model, params, program, pools):
 
 FORMS = [("dense", "step"), ("dense", "chunk"), ("hybrid", "step"),
          ("looped", "step"), ("latent", "step"), ("kernel", "step"),
-         ("kernel64", "step")]
+         ("kernel64", "step"), ("latent-kernel", "step")]
+#: The page each kernel case runs at: whole sublane tiles of float32, and
+#: for the rotated keys' half-page of rows as well.
+KERNEL_PAGE = {"kernel": 2 * PAGE, "kernel64": 2 * PAGE,
+               "latent-kernel": 4 * PAGE}
 
 
 def on_the_kernels_path(monkeypatch):
@@ -147,7 +162,9 @@ def on_the_kernels_path(monkeypatch):
     every pool the kernel can walk through it (interpreted off the chip)."""
     monkeypatch.setattr(
         gpt_lib, "paged_kernel_attends",
-        lambda cfg, pool: paged_ops.supports(pool, cfg.head_dim))
+        lambda cfg, pool, key_pool=None: (
+            paged_ops.supports(pool, cfg.head_dim) if key_pool is None
+            else paged_ops.supports_latent(pool, key_pool)))
 
 
 @pytest.mark.parametrize("value", [np.nan, np.inf], ids=["nan", "inf"])
@@ -158,14 +175,16 @@ def test_a_lanes_logits_depend_on_no_page_it_does_not_own(
     model, params = model_of(name)
     cfg = model.cfg
     page = PAGE
-    if name.startswith("kernel"):
+    if name in KERNEL_PAGE:
         on_the_kernels_path(monkeypatch)
         monkeypatch.setattr(paged_ops, "_CHUNK_MAX", 128)
-        page = 2 * PAGE
+        page = KERNEL_PAGE[name]
         calls = []
-        real = paged_ops.paged_attention
-        monkeypatch.setattr(paged_ops, "paged_attention", lambda *a, **kw: (
-            calls.append(kw), real(*a, **kw))[1])
+        for entry in ("paged_attention", "latent_paged_attention"):
+            monkeypatch.setattr(
+                paged_ops, entry,
+                lambda *a, real=getattr(paged_ops, entry), **kw: (
+                    calls.append(kw), real(*a, **kw))[1])
     pools = junk_pools(cfg, page=page)
     assert sentinel_pages_are_zero(cfg, pools)
     assert all(x.shape[0] == cfg.loop_steps * PAGES + 1
@@ -173,8 +192,8 @@ def test_a_lanes_logits_depend_on_no_page_it_does_not_own(
                for x in entry)
     step = jax.jit(lambda p: run(model, params, program, p))
     want, after = step(pools)
-    if name.startswith("kernel"):
-        # traced once, a call a K/V layer
+    if name in KERNEL_PAGE:
+        # traced once, a call a K/V (or latent) layer
         assert len(calls) == sum(map(paged, cfg.kinds))
     want = np.asarray(want)
     assert np.isfinite(want[:3]).all() and np.abs(want[:3]).max() > 0.1
@@ -229,9 +248,10 @@ def engine_of(name, records=None, **kw):
 @pytest.mark.parametrize("name,kw", [
     ("dense", {}), ("dense", {"spec_k": 4}), ("dense", {"prefill_chunk": 3}),
     ("hybrid", {}), ("looped", {}), ("latent", {}),
-    ("kernel", {"page_size": 2 * PAGE})],
+    ("kernel", {"page_size": 2 * PAGE}),
+    ("latent-kernel", {"page_size": 4 * PAGE})],
     ids=["dense", "dense-spec", "dense-chunked", "hybrid", "looped",
-         "latent", "kernel"])
+         "latent", "kernel", "latent-kernel"])
 def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
         name, kw, monkeypatch):
     """A short run with admissions and retirements, a slot reused, a
@@ -244,9 +264,9 @@ def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
     each dispatch was handed; ``attn_pages_read`` beside them a count of
     the pages a lane holds up to its position's (what the paged-attention
     kernel copies), and ``attn_kernel_layers`` the layers of the dispatched
-    program that read so: none on the CPU's plain path, every K/V layer in
-    the case steered onto the kernel's."""
-    if name == "kernel":
+    program that read so: none on the CPU's plain path, every K/V layer
+    (every latent layer, since PR 47) in a case steered onto a kernel's."""
+    if name in KERNEL_PAGE:
         on_the_kernels_path(monkeypatch)
         monkeypatch.setattr(paged_ops, "_CHUNK_MAX", 128)
     seen = []
@@ -275,7 +295,7 @@ def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
             return fn(tree, tokens, positions, tables, *rest)
         return dispatch
     engine._step_fn = counting(
-        engine._step_fn, cfg.num_layers if name == "kernel" else 0)
+        engine._step_fn, cfg.num_layers if name in KERNEL_PAGE else 0)
     if engine._spec_step_fn is not None:
         engine._spec_step_fn = counting(engine._spec_step_fn, 0)
 
@@ -310,6 +330,6 @@ def test_the_engine_never_writes_the_sentinels_page_and_counts_its_table(
     assert 0 < stats["attn_pages_read"] <= stats["table_pages_held"]
     assert stats["attn_kernel_layers"] == sum(
         c["attn_kernel_layers"] for c in counted) == (
-            cfg.num_layers * len(counted) if name == "kernel" else 0)
+            cfg.num_layers * len(counted) if name in KERNEL_PAGE else 0)
     assert stats["window_attn_pages_read"] == 0
     assert stats["pool_steps_copied"] == 0
