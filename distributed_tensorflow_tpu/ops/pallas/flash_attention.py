@@ -15,15 +15,15 @@ Layout/grid design (pallas_guide.md idioms):
 - scores/stats stay entirely in VMEM; fp32 throughout
   (``preferred_element_type``) regardless of input dtype.
 
-Differentiation: the kernel is wrapped in ``jax.custom_vjp``.  The backward
-pass is **blockwise pallas too** (FlashAttention-2 style): the forward saves
-the per-row logsumexp alongside the output, and two kernels accumulate
-dk/dv (grid over K blocks, scanning Q) and dq (grid over Q blocks, scanning
-K) entirely in VMEM — O(S) HBM in sequence length end to end, no [S, S]
-score materialization in either direction.  The only dense fallback is the
-top-level one in :func:`flash_attention` (sequence length not divisible by
-8), which routes the whole op — forward and backward — through the dense
-XLA formulation.
+Differentiation: ``jax.custom_vjp``; the backward pass is blockwise pallas
+too (FlashAttention-2 style, O(S) HBM, no [S, S] scores in either direction).
+The forward saves the per-row logsumexp; ONE kernel (``_dqkv_kernel``: grid
+over K blocks, scanning Q) rebuilds a tile's p and ds once and accumulates dk,
+dv and, in a whole-row VMEM scratch, dq.  Where that dq row (S x D in whole
+lanes, float32) exceeds ``_DQ_ROW_BYTES`` two kernels do it, dk/dv and dq
+(grid over Q blocks, scanning K), each rebuilding every tile: the row's
+bytes choose, nothing else.  The only dense fallback is the top-level one
+in :func:`flash_attention`, which routes forward and backward through XLA.
 
 On non-TPU backends the kernels run in interpreter mode, so CPU CI covers
 them.
@@ -490,6 +490,77 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
+# The largest dq row ([S, D] in float32, D in whole lanes: what VMEM holds)
+# that the one backward kernel keeps for a whole (batch, head) walk; a longer
+# row takes the two kernels above.  4 MiB is S 8,192 at D 128.  Beside the
+# row Mosaic holds its output block twice (in the array's type), and all of
+# that comes ON TOP of what ``_dkv_kernel`` needs at the same blocks (13 of
+# this generation's 16 MiB of scoped VMEM at blocks of 1,024), so the call
+# asks for the default limit plus the row's bytes: 28 MiB at most of 128.
+_DQ_ROW_BYTES = 4 * 1024 * 1024
+_SCOPED_VMEM_BYTES = 16 * 1024 * 1024
+
+
+def _dqkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+                 dq_ref, dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, scale,
+                 causal, block_q, block_k, nq, nkb, skip_empty, window=0,
+                 band=0):
+    """All three gradients from ONE rebuild of a tile's ``p`` and ``ds``.
+
+    ``_dkv_kernel``'s walk (grid (BH, nk, nq|band), q innermost) with the dq
+    row of the whole (batch, head) in scratch beside dk/dv: a tile adds
+    ``scale * ds k`` to its q block's rows, and the row is written out once,
+    at the walk's last step.  For one q block the K blocks still arrive in
+    ascending order (the outer grid dimension), so every sum runs in the
+    order the two kernels run it."""
+    ik = pl.program_id(1)
+    iq = ik + pl.program_id(2) if band else pl.program_id(2)
+    first = pl.program_id(2) == 0
+    last = pl.program_id(2) == pl.num_programs(2) - 1
+
+    @pl.when(first & (ik == 0))
+    def _init_row():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(first)
+    def _init():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    def _compute():
+        p, ds, do, q, k = _bwd_block(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, mask_ref,
+            scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+            iq=iq, ik=ik, nq=nq, nkb=nkb, window=window)
+        dv_scr[:] += jax.lax.dot_general(p, do, (((0,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+        dk_scr[:] += jax.lax.dot_general(ds, q, (((0,), (0,)), ((), ())),
+                                         preferred_element_type=jnp.float32)
+        # A band's top edge hands in q blocks past the last (skipped on the
+        # chip, all-masked zeros under the interpreter): clip the rows.
+        iq_c = jnp.clip(iq, 0, nq - 1) if band else iq
+        rows = (slice(None) if nq == 1 else
+                pl.ds(pl.multiple_of(iq_c * block_q, block_q), block_q))
+        dq_scr[rows, :] += scale * jax.lax.dot_general(
+            ds, k, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    if band and skip_empty:
+        pl.when(iq <= nq - 1)(_compute)
+    else:
+        _causal_guard(_compute, skip_empty=skip_empty, iq=iq, ik=ik,
+                      block_q=block_q, block_k=block_k, window=window)
+
+    @pl.when(last)
+    def _emit():
+        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(last & (ik == nkb - 1))
+    def _emit_row():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
+
+
 def _flash_backward(q, k, v, kv_mask, o, lse, g, *, causal: bool,
                     window: int = 0):
     B, S, H, D = q.shape
@@ -558,33 +629,53 @@ def _flash_backward(q, k, v, kv_mask, o, lse, g, *, causal: bool,
         return kernel, in_specs, inputs
 
     # dk/dv: grid (BH, nk, nq) — Q innermost, accumulated in VMEM scratch.
-    kernel, in_specs, inputs = build(_dkv_kernel, q_minor=True)
-    dk, dv = pl.pallas_call(
-        kernel,
+    dkv = dict(
         out_shape=[jax.ShapeDtypeStruct((B * H, S, D), k.dtype),
                    jax.ShapeDtypeStruct((B * H, S, D), v.dtype)],
-        grid=(B * H, nkb, band or nq),
-        in_specs=in_specs,
         out_specs=[pl.BlockSpec((1, block_k, D),
                                 lambda bh, ik, iq: (bh, ik, 0),
                                 memory_space=pltpu.VMEM)] * 2,
-        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32)] * 2,
-        interpret=interpret,
-    )(*inputs)
+        scratch_shapes=[pltpu.VMEM((block_k, D), jnp.float32)] * 2)
+    dq_shape = jax.ShapeDtypeStruct((B * H, S, D), q.dtype)
 
-    # dq: grid (BH, nq, nk) — K innermost.
-    kernel, in_specs, inputs = build(_dq_kernel, q_minor=False)
-    dq = pl.pallas_call(
-        kernel,
-        out_shape=jax.ShapeDtypeStruct((B * H, S, D), q.dtype),
-        grid=(B * H, nq, band or nkb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, D),
-                               lambda bh, iq, ik: (bh, iq, 0),
-                               memory_space=pltpu.VMEM),
-        scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        interpret=interpret,
-    )(*inputs)
+    row = S * -(-D // _LANE) * _LANE        # a dq row's elements in VMEM
+    if row * 4 <= _DQ_ROW_BYTES:
+        # The dq row fits beside them: one walk feeds all three gradients.
+        kernel, in_specs, inputs = build(_dqkv_kernel, q_minor=True)
+        dq, dk, dv = pl.pallas_call(
+            kernel,
+            out_shape=[dq_shape] + dkv["out_shape"],
+            grid=(B * H, nkb, band or nq),
+            in_specs=in_specs,
+            out_specs=[pl.BlockSpec((1, S, D), lambda bh, ik, iq: (bh, 0, 0),
+                                    memory_space=pltpu.VMEM)]
+            + dkv["out_specs"],
+            scratch_shapes=[pltpu.VMEM((S, D), jnp.float32)]
+            + dkv["scratch_shapes"],
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=_SCOPED_VMEM_BYTES
+                + row * (4 + 2 * q.dtype.itemsize)),
+            interpret=interpret,
+        )(*inputs)
+    else:
+        kernel, in_specs, inputs = build(_dkv_kernel, q_minor=True)
+        dk, dv = pl.pallas_call(
+            kernel, grid=(B * H, nkb, band or nq), in_specs=in_specs,
+            interpret=interpret, **dkv)(*inputs)
+
+        # dq: grid (BH, nq, nk) — K innermost.
+        kernel, in_specs, inputs = build(_dq_kernel, q_minor=False)
+        dq = pl.pallas_call(
+            kernel,
+            out_shape=dq_shape,
+            grid=(B * H, nq, band or nkb),
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((1, block_q, D),
+                                   lambda bh, iq, ik: (bh, iq, 0),
+                                   memory_space=pltpu.VMEM),
+            scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
+            interpret=interpret,
+        )(*inputs)
 
     return (_from_bh(dq, B, H), _from_bh(dk, B, H), _from_bh(dv, B, H))
 

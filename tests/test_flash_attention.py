@@ -204,6 +204,77 @@ def test_flash_1024_block_branch_matches_dense():
     np.testing.assert_allclose(got_w, want_w, rtol=1e-5, atol=1e-5)
 
 
+# ------------------------------- one backward kernel where the dq row fits VMEM
+
+def _backward_forms(monkeypatch, q, k, v, kv_mask, g, causal, window):
+    """The gradients by the one kernel and by the dk/dv and dq kernels."""
+    from distributed_tensorflow_tpu.ops.pallas import flash_attention as fa
+    o, lse = fa._flash_forward(q, k, v, kv_mask, causal=causal, window=window)
+    args = (q, k, v, kv_mask, o, lse, g)
+    one = fa._flash_backward(*args, causal=causal, window=window)
+    monkeypatch.setattr(fa, "_DQ_ROW_BYTES", 0)     # no row fits: two kernels
+    two = fa._flash_backward(*args, causal=causal, window=window)
+    return one, two
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("D", [64, 128])
+@pytest.mark.parametrize("S", [64, 1024], ids=["one_block", "two_blocks"])
+@pytest.mark.parametrize("masked", [False, True], ids=["nomask", "kv_mask"])
+@pytest.mark.parametrize("causal", [True, False], ids=["causal", "full"])
+def test_one_backward_kernel_equals_the_two_bit_for_bit(
+        monkeypatch, causal, masked, S, D, dtype):
+    """A tile's p and ds are rebuilt once and feed all three gradients; the
+    sums run in the two kernels' order, so nothing may differ at all."""
+    ks = jax.random.split(jax.random.PRNGKey(S + D), 4)
+    q, k, v, g = (jax.random.normal(x, (2, S, 2, D), dtype) for x in ks)
+    kv_mask = None
+    if masked:   # row 0 fully masked, row 1 ragged
+        kv_mask = (jax.random.uniform(jax.random.PRNGKey(5), (2, S)) > 0.3)
+        kv_mask = kv_mask.at[1, 0].set(True).at[0].set(False)
+    one, two = _backward_forms(monkeypatch, q, k, v, kv_mask, g, causal, 0)
+    for a, b in zip(one, two):
+        assert a.dtype == b.dtype == dtype
+        assert not np.any(np.isnan(np.asarray(a, np.float32)))
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+    if masked:
+        np.testing.assert_array_equal(np.asarray(one[0][0], np.float32), 0.0)
+
+
+@pytest.mark.parametrize("window", [128, 512, 700])
+def test_one_backward_kernel_walks_a_band_as_the_two_do(monkeypatch, window):
+    """The sliding window's banded grid (q blocks ik .. ik + band - 1 a K
+    block, clipped at the top edge) through the same body."""
+    ks = jax.random.split(jax.random.PRNGKey(window), 4)
+    q, k, v, g = (jax.random.normal(x, (1, 2048, 2, 64), jnp.bfloat16)
+                  for x in ks)
+    kv_mask = jnp.ones((1, 2048), bool).at[:, 2000:].set(False)
+    one, two = _backward_forms(monkeypatch, q, k, v, kv_mask, g, True, window)
+    for a, b in zip(one, two):
+        np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                      np.asarray(b, np.float32))
+
+
+@pytest.mark.parametrize("shape,window,calls", [
+    ((8, 1024, 16, 64), 0, 1),      # the training cells' row: 512 KB of lanes
+    ((1, 8192, 16, 128), 1024, 1),  # 4 MiB: the largest row the kernel keeps
+    ((1, 16384, 2, 128), 0, 2),     # 8 MiB: dk/dv and dq kernels
+    ((1, 16384, 2, 64), 1024, 2),   # heads of 64 fill whole lanes in VMEM
+], ids=["s1024_d64", "s8192_d128_window", "s16384_d128", "s16384_d64_window"])
+def test_the_rows_bytes_choose_the_backward_form(shape, window, calls):
+    from distributed_tensorflow_tpu.ops.pallas import flash_attention as fa
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    B, S, H, D = shape
+    stats = jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda q, k, v, o, lse, g: fa._flash_backward(
+        q, k, v, None, o, lse, g, causal=True, window=window))(
+            x, x, x, x, stats, x)
+    assert [name for name, _ in primitives(jaxpr.jaxpr)].count(
+        "pallas_call") == calls
+
+
 # ------------------------------------ the call site on a mesh of several devices
 
 @pytest.mark.parametrize("axes,batch,mapped", [
@@ -227,9 +298,9 @@ def test_flash_maps_the_kernel_over_the_ambient_meshs_batch_axes(
         with ambient_mesh(mesh):
             return jax.value_and_grad(loss, argnums=(0, 1, 2))(q, k, v)
 
-    # One kernel forward, the dq and the dk/dv kernel backward.
+    # One kernel forward, one backward (dq, dk and dv from one walk).
     assert kernel_placement(fwd_bwd, q, k, v, kv_mask) == (
-        (3, 0) if mapped else (0, 3))
+        (2, 0) if mapped else (0, 2))
     # No mesh ambient: the plain call, as ever.
     assert kernel_placement(
         lambda *a: flash_attention(*a, causal=True), q, k, v) == (0, 1)

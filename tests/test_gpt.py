@@ -361,15 +361,15 @@ def test_pallas_step_on_a_data_mesh_maps_its_kernels_over_the_batch():
     bundle, batch = _pallas_step_inputs(mesh)
     step = sync_lib.build_sync_train_step(mesh, bundle.loss_fn, donate=False)
     state = replicate_state(mesh, bundle.state)
-    # Per layer one kernel forward and two backward, all inside a shard_map.
+    # Per layer one kernel forward and one backward, all inside a shard_map.
     layers = gpt_lib.mini().num_layers
-    assert kernel_placement(step, state, batch) == (3 * layers, 0)
+    assert kernel_placement(step, state, batch) == (2 * layers, 0)
     mapped = step(state, batch)
 
     one = mesh_lib.create_mesh(data=1, devices=jax.devices()[:1])
     bundle1, batch1 = _pallas_step_inputs(one)
     step1 = sync_lib.build_sync_train_step(one, bundle1.loss_fn, donate=False)
-    assert kernel_placement(step1, bundle1.state, batch1) == (0, 3 * layers)
+    assert kernel_placement(step1, bundle1.state, batch1) == (0, 2 * layers)
     _assert_same_step(mapped,
                       step1(replicate_state(one, bundle1.state), batch1))
 
@@ -413,7 +413,7 @@ def test_pallas_step_on_a_mesh_computes_what_the_unmapped_step_did(case):
         step = sync_lib.build_sync_train_step(
             mesh, bundle.loss_fn, needs_rng=needs_rng, donate=False)
         before = jax.jit(body)
-    calls = 3 * gpt_lib.mini().num_layers
+    calls = 2 * gpt_lib.mini().num_layers
     assert kernel_placement(step, state, batch) == (
         (0, calls) if case == "model_axis" else (calls, 0))
     assert kernel_placement(before, state, batch) == (0, calls)
