@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Callable
 
 import flax.linen as nn
 import jax
@@ -42,9 +43,6 @@ LATENT_ATTENTION = "latent_attention"
 #: depthwise taps, ``x + y W_out``.  A sequence keeps the last ``taps - 1``
 #: rows of ``B * X`` and nothing else, whatever its length.
 SHORT_CONV = "short_conv"
-#: The kinds whose cache entry is one fixed-size row a sequence (a decode
-#: slot's) and no pages.
-STATE_KINDS = (LINEAR_ATTENTION, SHORT_CONV)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -529,18 +527,15 @@ class GptBlock(nn.Module):
     """One pre-LN decoder block; ``setup``-style so the training ``__call__``
     and the KV-cached ``decode_step`` share the same parameters.
 
-    ``kind`` selects the token mixer: softmax attention over cached keys and
+    ``kind`` selects the token mixer, and its row of :data:`KINDS` names
+    the mixer's three forms here: softmax attention over cached keys and
     values (FULL_ATTENTION; SLIDING_ATTENTION the same parameters under a
     banded mask, its paged pool a ring), the gated delta rule over a
-    recurrent state
-    (LINEAR_ATTENTION: ``linear_mix`` / ``linear_prefill`` /
-    ``linear_decode_step``) or softmax attention over one cached latent row
-    a token (LATENT_ATTENTION: ``latent_mix`` / ``latent_prefill`` /
-    ``latent_decode_step_paged``) or a gated short convolution over a tail
-    of its inputs (SHORT_CONV: ``conv_mix`` / ``conv_prefill`` /
-    ``conv_decode_step``).  ``sparse`` selects the MLP: the dense
-    one, or routed experts beside shared ones.  Norms and the residual
-    path are shared."""
+    recurrent state (LINEAR_ATTENTION), softmax attention over one cached
+    latent row a token (LATENT_ATTENTION) or a gated short convolution
+    over a tail of its inputs (SHORT_CONV).  ``sparse`` selects the MLP:
+    the dense one, or routed experts beside shared ones.  Norms and the
+    residual path are shared."""
 
     cfg: GptConfig
     kind: str = FULL_ATTENTION
@@ -559,14 +554,7 @@ class GptBlock(nn.Module):
         else:
             self._setup_mlp(dtype)
         self.drop = nn.Dropout(cfg.dropout_rate)
-        if self.kind == LINEAR_ATTENTION:
-            self._setup_linear(dtype)
-        elif self.kind == LATENT_ATTENTION:
-            self._setup_latent(dtype)
-        elif self.kind == SHORT_CONV:
-            self._setup_conv(dtype)
-        else:
-            self._setup_attention(dtype)
+        getattr(self, KINDS[self.kind].setup)(dtype)
 
     def _setup_conv(self, dtype):
         cfg = self.cfg
@@ -852,12 +840,10 @@ class GptBlock(nn.Module):
             return x + self.drop(h, deterministic=deterministic)
 
     def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
-        if self.kind == LINEAR_ATTENTION:
-            return self.linear_mix(x, deterministic)
-        if self.kind == LATENT_ATTENTION:
-            return self.latent_mix(x, deterministic)
-        if self.kind == SHORT_CONV:
-            return self.conv_mix(x, deterministic)
+        return getattr(self, KINDS[self.kind].mix)(x, deterministic)
+
+    def attention_mix(self, x: jax.Array, deterministic: bool = True):
+        """The whole sequence, nothing cached (the training forward)."""
         q, k, v = self._qkv(x)
         with profiling.region("attn.scores"):
             ctx = dot_product_attention(
@@ -1047,9 +1033,11 @@ class GptBlock(nn.Module):
         return self._mlp(self._add_mixed(x, y, deterministic), deterministic)
 
     def latent_prefill(self, x: jax.Array, latent_cache: jax.Array,
-                       key_cache: jax.Array):
+                       key_cache: jax.Array, lengths: None = None):
         """The prompt's P tokens in one causal pass, their rows written to
-        the caches ([B, M, latent_kv_rank] and [B, M, rope]) at [0, P)."""
+        the caches ([B, M, latent_kv_rank] and [B, M, rope]) at [0, P):
+        every position of a padded prompt, so ``lengths`` (the place every
+        kind's prefill form has for it) is None, ``GptLM.prefill`` sees."""
         backend = ("xla" if self.cfg.attention_backend in ("ring", "ulysses")
                    else self.cfg.attention_backend)
         y, latent, k_rot = self._latent_attend(x, backend)
@@ -1866,26 +1854,19 @@ class GptLM(nn.Module):
     def decode_paged(self, token: jax.Array, pools, page_tables: jax.Array,
                      positions: jax.Array, live: jax.Array | None = None,
                      window_tables: jax.Array | None = None):
-        """One token PER ROW against per-layer paged KV pools (see
-        ``GptBlock.decode_step_paged``).  ``token`` [B]; ``pools``:
-        [(k_pool, v_pool)] per layer; ``page_tables`` [B, MP] shared by
-        every layer of a row (each layer has its own pool tensor, the
-        same page geometry); ``positions`` [B].  A linear-attention
-        layer's entry of ``pools`` is (state [B, H, Dv, Dk], conv tail
-        [B, K-1, channels]), indexed by ROW and not by page, and ``live``
-        [B] says which rows are sequences: a row that is not keeps its
-        entry bit for bit (see :func:`init_kv_pool`).  A short-convolution
-        layer's entry is its tail alone, (tail [B, K-1, hidden],), held
-        the same way.  A latent-attention
-        layer's entry is its two pools of row parts; a routed-expert MLP
-        routes a row that ``live`` says is no sequence nowhere (without
-        ``live`` every row is routed): one mask for the experts and for
-        the state rows.  With ``cfg.loop_steps`` > 1 the
-        stack is walked that many times (:meth:`_loop`), step ``t`` writing
-        and attending its own run of pages of each layer's pool.  A
-        sliding-attention layer's entry is a pool of its own geometry,
-        addressed through ``window_tables`` [B, ring pages], the rows' rings
-        (``GptBlock.decode_step_paged``).  Returns (logits [B, vocab], new
+        """One token PER ROW against the per-layer pools of
+        :func:`init_kv_pool`, each layer through the step form and the
+        table its row of :data:`KINDS` names.  ``token`` [B]; ``positions``
+        [B]; ``page_tables`` [B, MP] is shared by every layer that holds a
+        run of pages (each has its own pool tensor, the same page
+        geometry), ``window_tables`` [B, ring pages], the rows' rings, by
+        the sliding-attention layers; an entry that is a row a slot is
+        indexed by ROW.  ``live`` [B] says which rows are sequences: a row
+        that is not keeps its state entry bit for bit and is routed to no
+        expert (without ``live`` every row is routed).  With
+        ``cfg.loop_steps`` > 1 the stack is walked that many times
+        (:meth:`_loop`), step ``t`` writing and attending its own run of
+        pages of each layer's pool.  Returns (logits [B, vocab], new
         pools)."""
         if self.cfg.window_layers and window_tables is None:
             raise ValueError(
@@ -1905,29 +1886,13 @@ class GptLM(nn.Module):
                 tables = loop_step_pages(page_tables, t,
                                          pools[0][0].shape[0],
                                          mdl.cfg.loop_steps)
-                new_pools = []
-                for layer, entry in zip(mdl.layers, pools):
-                    x, *entry = layer.decode_step_paged(x, *entry, tables,
-                                                        positions)
-                    new_pools.append(tuple(entry))
-                return x, new_pools, rows
+                return *_step_layers(mdl, x, pools, {"pages": tables},
+                                     positions, live), rows
             x, new_pools, _ = self._loop(stack, x, list(pools))
             return self._head(x, normed=True)[:, 0], new_pools
-        new_pools = []
-        for layer, entry in zip(self.layers, pools):
-            if layer.kind == LINEAR_ATTENTION:
-                x, *entry = layer.linear_decode_step(x, *entry, live)
-            elif layer.kind == SHORT_CONV:
-                x, *entry = layer.conv_decode_step(x, *entry, live)
-            elif layer.kind == LATENT_ATTENTION:
-                x, *entry = layer.latent_decode_step_paged(
-                    x, *entry, page_tables, positions, live)
-            else:
-                x, *entry = layer.decode_step_paged(
-                    x, *entry, window_tables
-                    if layer.kind == SLIDING_ATTENTION else page_tables,
-                    positions, live)
-            new_pools.append(tuple(entry))
+        x, new_pools = _step_layers(
+            self, x, pools, {"pages": page_tables, "ring": window_tables},
+            positions, live)
         return self._head(x)[:, 0], new_pools
 
     def prefill(self, tokens: jax.Array, caches,
@@ -1963,37 +1928,51 @@ class GptLM(nn.Module):
                 "latent_kv_rank: a latent layer writes every position of "
                 "the padded prompt")
         x = self._embed(tokens, jnp.arange(P)[None], True)
-        new_caches = []
-        stateful = self.cfg.has_state_layers
-        if stateful and lengths is None:
+        if self.cfg.has_state_layers and lengths is None:
             raise ValueError(
                 "GptLM.prefill needs lengths= [B] for a config whose "
                 "layer_kinds has a linear_attention or a short_conv layer: "
                 "padding must not enter a state row")
         if self.cfg.loop_steps > 1:
             def stack(mdl, x, carry, t, caches):
-                new_caches = []
-                for layer, entry in zip(mdl.layers, caches):
-                    x, *entry = layer.prefill(x, *entry, lengths)
-                    new_caches.append(tuple(entry))
-                return x, carry, new_caches
+                x, caches = _prefill_layers(mdl, x, caches, lengths)
+                return x, carry, caches
             x, _, new_caches = self._loop(stack, x, rows=list(caches))
             return (self._head(x[:, -1:], normed=True)[:, 0],
                     new_caches)
-        for layer, entry in zip(self.layers, caches):
-            if layer.kind == LINEAR_ATTENTION:
-                x, *entry = layer.linear_prefill(x, *entry, lengths)
-            elif layer.kind == SHORT_CONV:
-                x, *entry = layer.conv_prefill(x, *entry, lengths)
-            elif layer.kind == LATENT_ATTENTION:
-                x, *entry = layer.latent_prefill(x, *entry)
-            else:
-                x, *entry = layer.prefill(x, *entry,
-                                          None if stateful else lengths)
-            new_caches.append(tuple(entry))
+        x, new_caches = _prefill_layers(self, x, caches, lengths)
         # Only the LAST position's logits matter — slice before the
         # [hidden, vocab] head so its matmul runs on one position, not P.
         return self._head(x[:, -1:])[:, 0], new_caches
+
+
+def _prefill_layers(mdl: GptLM, x: jax.Array, caches, lengths):
+    """``x`` [B, P, hidden] through every layer's prefill form (its row of
+    :data:`KINDS`) against its entry of ``caches``.  A state row absorbs
+    the tokens before ``lengths``; the rows beside one are written at every
+    position of the padded prompt, as without ``lengths``."""
+    stateful, new_caches = mdl.cfg.has_state_layers, []
+    for layer, entry in zip(mdl.layers, caches):
+        row = KINDS[layer.kind]
+        x, *entry = getattr(layer, row.prefill)(
+            x, *entry, lengths if row.table is None or not stateful else None)
+        new_caches.append(tuple(entry))
+    return x, new_caches
+
+
+def _step_layers(mdl: GptLM, x: jax.Array, pools, tables: dict,
+                 positions: jax.Array, live):
+    """``x`` [B, 1, hidden] through every layer's paged decode step (its
+    row of :data:`KINDS`) against its entry of ``pools``: a kind that
+    holds pages is addressed through the table its row names, a kind that
+    holds a row a slot through none."""
+    new_pools = []
+    for layer, entry in zip(mdl.layers, pools):
+        row = KINDS[layer.kind]
+        where = () if row.table is None else (tables[row.table], positions)
+        x, *entry = getattr(layer, row.step)(x, *entry, *where, live)
+        new_pools.append(tuple(entry))
+    return x, new_pools
 
 
 def exit_masses(gates: jax.Array | None, steps: int,
@@ -2051,14 +2030,9 @@ def paged_kernel_attends(cfg: GptConfig, pool, key_pool=None) -> bool:
 def paged_kernel_layers(cfg: GptConfig, pools) -> int:
     """The layers of ``cfg`` whose entry of ``pools``
     (:func:`init_kv_pool`) the decode step attends through a kernel."""
-    def attends(kind, entry):
-        if kind == LATENT_ATTENTION:
-            return paged_kernel_attends(cfg, *entry)
-        return (kind in (FULL_ATTENTION, SLIDING_ATTENTION)
-                and paged_kernel_attends(cfg, entry[0]))
-
-    return sum(attends(kind, entry)
-               for kind, entry in zip(cfg.kinds, pools))
+    reads = [KINDS[kind].kernel_reads for kind in cfg.kinds]
+    return sum(bool(n) and paged_kernel_attends(cfg, *entry[:n])
+               for n, entry in zip(reads, pools))
 
 
 def gather_pages(pool: jax.Array, page_table: jax.Array) -> jax.Array:
@@ -2101,81 +2075,130 @@ def init_kv_cache(cfg: GptConfig, batch_size: int, max_len: int,
         max_len = min(max_len, cfg.attention_window)
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
 
-    def lead(kind):
-        rows = max_len if kind != SLIDING_ATTENTION \
+    def lead(table):
+        rows = max_len if table != "ring" \
             else min(max_len, ring_rows or cfg.sliding_window)
         return (batch_size, rows) if cfg.loop_steps == 1 \
             else (cfg.loop_steps, batch_size, rows)
 
-    return [_state_entry(cfg, kind, batch_size) if kind in STATE_KINDS
-            else _rows_entry(cfg, kind, lead(kind), dtype)
-            for kind in cfg.kinds]
+    return [row.entry(cfg, batch_size) if row.table is None
+            else row.entry(cfg, lead(row.table), dtype)
+            for row in map(KINDS.get, cfg.kinds)]
 
 
-def _rows_entry(cfg: GptConfig, kind: str, lead: tuple, dtype,
-                flat: bool = False):
-    """A layer's cache entry of one row a token, zeroed, ``lead`` being the
-    axes that address a token: (keys, values) [*lead, G, D] a full-attention
-    layer, or with ``flat`` (the paged pool's form, :func:`init_kv_pool`)
-    [*lead, G * D]; a latent one the row's two parts, (the normed latent
-    [*lead, latent_kv_rank], the rotated key all heads share [*lead,
+def _kv_entry(cfg: GptConfig, lead: tuple, dtype, flat: bool = False):
+    """A softmax-attention layer's cache entry of one row a token, zeroed,
+    ``lead`` being the axes that address a token: (keys, values)
+    [*lead, G, D], or with ``flat`` (the paged pool's form,
+    :func:`init_kv_pool`) [*lead, G * D]."""
+    shape = ((*lead, cfg.num_kv_heads * cfg.head_dim) if flat
+             else (*lead, cfg.num_kv_heads, cfg.head_dim))
+    return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+
+
+def _latent_entry(cfg: GptConfig, lead: tuple, dtype, flat: bool = False):
+    """A latent layer's: the row's two parts, (the normed latent [*lead,
+    latent_kv_rank], the rotated key all heads share [*lead,
     qk_rope_head_dim]): no head axis and no values of their own,
     ``latent_row_dim`` entries a token together.  Two arrays and not one
     of their sum: 512 entries fill whole lanes of 128 and the chip keeps
     the array as it is indexed, where it laid one array of 576 out with
     the PAGES minor-most and every decode step copied every pool into the
     indexed order and back (8.2 of a step's 24.2 ms; PERF.md, PR 35)."""
-    if kind == LATENT_ATTENTION:
-        rope = cfg.qk_rope_head_dim
-        if flat:
-            # A page's rotated keys in whole lanes of 128, two tokens a
-            # row (``paged_ops.key_rows``): 64 entries a row the chip laid
-            # out with the PAGES minor-most and copied into the indexed
-            # order and back twice a layer a step (PERF.md, PR 47).
-            rows = paged_ops.key_rows(lead[-1], rope)
-            keys = (*lead[:-1], rows, lead[-1] // rows * rope)
-        else:
-            keys = (*lead, rope)
-        return (jnp.zeros((*lead, cfg.latent_kv_rank), dtype),
-                jnp.zeros(keys, dtype))
-    shape = ((*lead, cfg.num_kv_heads * cfg.head_dim) if flat
-             else (*lead, cfg.num_kv_heads, cfg.head_dim))
-    return (jnp.zeros(shape, dtype), jnp.zeros(shape, dtype))
+    rope = cfg.qk_rope_head_dim
+    if flat:
+        # A page's rotated keys in whole lanes of 128, two tokens a row
+        # (``paged_ops.key_rows``): 64 entries a row the chip laid out
+        # with the PAGES minor-most and copied into the indexed order and
+        # back twice a layer a step (PERF.md, PR 47).
+        rows = paged_ops.key_rows(lead[-1], rope)
+        keys = (*lead[:-1], rows, lead[-1] // rows * rope)
+    else:
+        keys = (*lead, rope)
+    return (jnp.zeros((*lead, cfg.latent_kv_rank), dtype),
+            jnp.zeros(keys, dtype))
+
+
+def _linear_entry(cfg: GptConfig, rows: int):
+    """A linear-attention layer's, for ``rows`` sequences, all empty:
+    (state [rows, H, Dv, Dk] float32, the convolution's tail [rows, K-1,
+    channels]: the raw q/k/v projections of the last K-1 tokens, in the
+    type they were computed in)."""
+    return (jnp.zeros((rows, cfg.linear_num_heads,
+                       cfg.linear_value_head_dim, cfg.linear_key_head_dim),
+                      jnp.float32),
+            jnp.zeros((rows, cfg.linear_conv_kernel_dim - 1,
+                       cfg.linear_conv_channels), jnp.dtype(cfg.dtype)))
+
+
+def _conv_entry(cfg: GptConfig, rows: int):
+    """A short-convolution layer's: (the tail [rows, K-1, hidden],), the
+    last K-1 rows of ``B * X`` in the compute type, and no matrix."""
+    return (jnp.zeros((rows, cfg.short_conv_kernel_dim - 1,
+                       cfg.hidden_size), jnp.dtype(cfg.dtype)),)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What the model knows of one kind of layer, a row of :data:`KINDS`:
+    ``GptBlock``'s methods by name (looked up on the layer where a program
+    is traced; nothing is wrapped) and what the layer keeps of a sequence.
+    A further kind is one row and the methods it names."""
+
+    setup: str        # the mixer's parameters: (dtype)
+    mix: str          # the whole sequence, nothing cached: (x, deterministic)
+    prefill: str      # the prompt into a contiguous entry: (x, *entry, lengths)
+    step: str         # a token a row against the pool's entry:
+    #                   (x, *entry, [table, positions,] live)
+    # The entry, zeroed: (cfg, lead, dtype, flat) of a kind that holds a
+    # row a token, (cfg, rows) of one that holds a fixed-size row a
+    # sequence (a decode slot's) and no pages.
+    entry: Callable
+    # What addresses the entry in a paged pool: "pages" (a run of the
+    # pool's pages a sequence), "ring" (a ring of a pool of its own, a
+    # decode slot's) or None (a row a slot: no sentinel page drops an idle
+    # row's write, so its step needs ``live``).
+    table: str | None = "pages"
+    # How many of the entry's pools :func:`paged_kernel_attends` is asked
+    # about, 0 where no kernel attends the kind.
+    kernel_reads: int = 0
+
+
+_SOFTMAX = ("_setup_attention", "attention_mix", "prefill",
+            "decode_step_paged", _kv_entry)
+KINDS = {
+    FULL_ATTENTION: LayerKind(*_SOFTMAX, "pages", 1),
+    SLIDING_ATTENTION: LayerKind(*_SOFTMAX, "ring", 1),
+    LATENT_ATTENTION: LayerKind(
+        "_setup_latent", "latent_mix", "latent_prefill",
+        "latent_decode_step_paged", _latent_entry, "pages", 2),
+    LINEAR_ATTENTION: LayerKind(
+        "_setup_linear", "linear_mix", "linear_prefill",
+        "linear_decode_step", _linear_entry, None),
+    SHORT_CONV: LayerKind("_setup_conv", "conv_mix", "conv_prefill",
+                          "conv_decode_step", _conv_entry, None),
+}
+#: The kinds whose cache entry is one fixed-size row a sequence (a decode
+#: slot's) and no pages.
+STATE_KINDS = tuple(kind for kind, row in KINDS.items() if row.table is None)
+
+
+def _entry_bytes(entry: Callable) -> int:
+    return sum(x.size * x.dtype.itemsize for x in jax.eval_shape(entry))
 
 
 def kv_row_bytes_per_token(cfg: GptConfig, dtype=None,
                            window: bool = False) -> int:
     """Bytes ONE cached token holds over all layers' pages that grow with
     the sequence (a linear-attention or a short-convolution layer holds
-    none; a weight-shared loop holds a row a step a layer); with ``window`` over the
-    sliding-attention layers' rings instead, which hold a token only
-    while it is inside the window."""
+    none; a weight-shared loop holds a row a step a layer); with ``window``
+    over the sliding-attention layers' rings instead, which hold a token
+    only while it is inside the window."""
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
     return cfg.loop_steps * sum(
-        x.size * x.dtype.itemsize
-        for kind in cfg.kinds
-        if kind not in STATE_KINDS and (kind == SLIDING_ATTENTION) == window
-        for x in jax.eval_shape(
-            lambda k=kind: _rows_entry(cfg, k, (1,), dtype)))
-
-
-def _state_entry(cfg: GptConfig, kind: str, rows: int):
-    """The cache entry of a layer that keeps one fixed-size row a sequence,
-    for ``rows`` sequences, all empty.  A linear-attention layer's: (state
-    [rows, H, Dv, Dk] float32, the convolution's tail [rows, K-1,
-    channels]: the raw q/k/v projections of the last K-1 tokens, in the
-    type they were computed in).  A short-convolution layer's: (the tail
-    [rows, K-1, hidden],): the last K-1 rows of ``B * X`` in the compute
-    type, and no matrix."""
-    dtype = jnp.dtype(cfg.dtype)
-    if kind == SHORT_CONV:
-        return (jnp.zeros((rows, cfg.short_conv_kernel_dim - 1,
-                           cfg.hidden_size), dtype),)
-    return (jnp.zeros((rows, cfg.linear_num_heads,
-                       cfg.linear_value_head_dim, cfg.linear_key_head_dim),
-                      jnp.float32),
-            jnp.zeros((rows, cfg.linear_conv_kernel_dim - 1,
-                       cfg.linear_conv_channels), dtype))
+        _entry_bytes(lambda row=row: row.entry(cfg, (1,), dtype))
+        for row in map(KINDS.get, cfg.kinds)
+        if row.table == ("ring" if window else "pages"))
 
 
 def state_bytes_per_slot(cfg: GptConfig) -> int:
@@ -2183,10 +2206,8 @@ def state_bytes_per_slot(cfg: GptConfig) -> int:
     all layers that keep one: a linear-attention layer's recurrent state
     and convolution tail, a short-convolution layer's tail (0 for a config
     without either)."""
-    return sum(x.size * x.dtype.itemsize
-               for kind in cfg.kinds if kind in STATE_KINDS
-               for x in jax.eval_shape(
-                   lambda k=kind: _state_entry(cfg, k, 1)))
+    return sum(_entry_bytes(lambda row=row: row.entry(cfg, 1))
+               for row in map(KINDS.get, cfg.kinds) if row.table is None)
 
 
 def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
@@ -2222,7 +2243,7 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
     lanes where the page divides so (at 64: [num_pages + 1, page_size / 2,
     128], token ``o`` of a page in row ``o % 8`` at lanes ``o // 8 * 64``;
     ``paged_ops.key_rows`` / ``pack_keys``), else [num_pages + 1,
-    page_size, qk_rope_head_dim] (:func:`_rows_entry`).
+    page_size, qk_rope_head_dim] (:func:`_latent_entry`).
 
     With ``cfg.loop_steps`` > 1 a layer's pool holds ``loop_steps`` runs of
     ``num_pages`` pages and the one sentinel page after the last,
@@ -2251,15 +2272,138 @@ def init_kv_pool(cfg: GptConfig, num_pages: int, page_size: int,
                          "short_conv or a sliding_attention layer")
     dtype = jnp.dtype(cfg.dtype) if dtype is None else jnp.dtype(dtype)
 
-    def pages(kind):
-        if kind == SLIDING_ATTENTION:
-            return num_slots * cfg.ring_pages(page_size) + 1
-        return cfg.loop_steps * num_pages + 1
+    pages = {"ring": num_slots * cfg.ring_pages(page_size) + 1,
+             "pages": cfg.loop_steps * num_pages + 1}
+    return [row.entry(cfg, num_slots) if row.table is None
+            else row.entry(cfg, (pages[row.table], page_size), dtype,
+                           flat=True)
+            for row in map(KINDS.get, cfg.kinds)]
 
-    return [_state_entry(cfg, kind, num_slots) if kind in STATE_KINDS
-            else _rows_entry(cfg, kind, (pages(kind), page_size), dtype,
-                             flat=True)
-            for kind in cfg.kinds]
+
+def land_prefill(cfg: GptConfig, caches, pools, page_size: int,
+                 phys: jax.Array, slot=None, ring=None):
+    """One prompt's ``caches`` (``GptLM.prefill``'s over a batch of one,
+    from :func:`init_kv_cache`) written onto ``pools``
+    (:func:`init_kv_pool`), each layer's entry by what addresses it
+    (:data:`KINDS`): a row a slot whole onto row ``slot``, so that nothing
+    of the row's last tenant survives; a ring's rows, the prompt's last
+    (position p at ring row p % the ring's rows), onto the whole ring
+    pages ``ring`` (the lane's ring pages this prompt reaches, in ring
+    order); a run's onto the prompt's physical pages ``phys``, under a
+    weight-shared loop onto the ``loop_steps`` runs of them.  A page the
+    table does not name (the sentinel) drops."""
+    def land(table, cache, pool):
+        if table is None:
+            return pool.at[slot].set(cache[0])
+        pages, R = ring if table == "ring" else phys, cfg.loop_steps
+        if R > 1:
+            # A run of pages a loop step: [R, 1, P, G, D] lands on the R
+            # runs of the prompt's pages.
+            pages = loop_step_pages(
+                phys[None, :], jnp.arange(R)[:, None], pool.shape[0],
+                R).reshape(-1)
+        # (A latent layer's rotated keys lie two tokens a row.)
+        return pool.at[written_pages(pages, pool.shape[0])].set(
+            paged_ops.pack_keys(
+                (cache if R > 1 else cache[0]).reshape(
+                    pages.shape[0], page_size, -1), pool.shape[1]),
+            mode="drop")
+
+    with profiling.region("cache.write"):
+        return [tuple(land(KINDS[kind].table, c, p)
+                      for c, p in zip(cache, pool))
+                for kind, cache, pool in zip(cfg.kinds, caches, pools)]
+
+
+@dataclasses.dataclass(frozen=True)
+class PoolGeometry:
+    """What a server of ``cfg`` has to know of its pools to size, fill and
+    step them, and what its spans say of them (:func:`pool_geometry`)."""
+
+    row_bytes: int           # a cached token's, over the paged layers
+    window_row_bytes: int    # and over the rings
+    state_bytes: int         # a decode slot's rows beside the pages
+    latent_row_bytes: int    # ``row_bytes`` where the rows are latent, else 0
+    ring_pages: int          # pages of a slot's ring; 0 without such layers
+    state_layers: int        # layers that keep a row a slot,
+    conv_layers: int         # those of them that keep a tail alone
+    sparse_layers: int       # layers whose MLP is routed experts
+    window_layers: int       # layers whose pool is a ring
+    loop_steps: int          # times the stack is applied to a token
+    cache_rows: int          # rows a cached token holds: steps x paged layers
+    # The collections ``GptLM.decode_paged`` is applied with mutable and
+    # :func:`pack_step_output` reads, each a rider behind the tokens.
+    riders: tuple
+
+    @property
+    def needs_live(self) -> bool:
+        """Whether the step takes ``live`` [B]: a state row has no sentinel
+        page, a routed token of an idle row no expert, and a loop's
+        counters no lane to skip otherwise."""
+        return bool(self.state_layers or self.riders)
+
+
+def pool_geometry(cfg: GptConfig, page_size: int, dtype=None) -> PoolGeometry:
+    """``cfg``'s pools in pages of ``page_size`` rows of ``dtype`` (the
+    compute type when None), as :func:`init_kv_pool` makes them."""
+    row_bytes = kv_row_bytes_per_token(cfg, dtype)
+    state_layers = sum(kind in STATE_KINDS for kind in cfg.kinds)
+    sparse_layers = sum(cfg.sparse_layers)
+    return PoolGeometry(
+        row_bytes=row_bytes,
+        window_row_bytes=kv_row_bytes_per_token(cfg, dtype, window=True),
+        state_bytes=state_bytes_per_slot(cfg),
+        latent_row_bytes=row_bytes if cfg.latent_kv_rank else 0,
+        ring_pages=cfg.ring_pages(page_size) if cfg.window_layers else 0,
+        state_layers=state_layers, conv_layers=cfg.conv_layers,
+        sparse_layers=sparse_layers, window_layers=cfg.window_layers,
+        loop_steps=cfg.loop_steps,
+        cache_rows=cfg.loop_steps * (cfg.num_layers - state_layers),
+        riders=("routing",) * bool(sparse_layers)
+        + ("loop",) * (cfg.loop_steps > 1))
+
+
+def pack_step_output(cfg: GptConfig, tokens: jax.Array, sown: dict,
+                     live: jax.Array | None) -> jax.Array:
+    """The decode step's ONE int32 array: ``tokens`` [B] and behind them
+    what ``GptLM.decode_paged`` sowed into ``PoolGeometry.riders``, in the
+    array the host fetches anyway (no second copy to wait for);
+    :func:`unpack_step_output` takes it apart.  ``routing``: the routed
+    layers' histograms [sparse layers x experts], live lanes only.
+    ``loop``: two numbers a lane, the loop steps it ran and its expected
+    exit step, the sum of t x (mass leaving at step t), float32 bit for
+    bit in the array's int32; an idle lane reads 0 and 0.0."""
+    out = tokens
+    if "routing" in sown:
+        counts = [sown["routing"][f"layer{i}"]["counts"][0]
+                  for i, sparse in enumerate(cfg.sparse_layers) if sparse]
+        out = jnp.concatenate([out, *counts])
+    if "loop" in sown:
+        loop = sown["loop"]
+        masses = loop["exit_mass"][0][..., 0]                    # [R, B]
+        at = jnp.arange(1, masses.shape[0] + 1, dtype=masses.dtype)
+        expected = jnp.where(live, at @ masses, 0.0)
+        out = jnp.concatenate([
+            out, jnp.where(live, loop["steps_run"][0], 0),
+            jax.lax.bitcast_convert_type(expected, jnp.int32)])
+    return out
+
+
+def unpack_step_output(cfg: GptConfig, out, lanes: int):
+    """(tokens [lanes], the riders by name) of :func:`pack_step_output`'s
+    array, fetched or on the device: ``routing_counts`` [sparse layers x
+    experts]; ``loop_steps_run`` [lanes] and ``exit_step_expected`` [lanes]
+    float32.  A model has the names its configuration gives it."""
+    tokens, behind, riders = out[:lanes], out[lanes:], {}
+    routed = sum(cfg.sparse_layers) * cfg.num_experts
+    if routed:
+        riders["routing_counts"] = behind[:routed].reshape(-1,
+                                                           cfg.num_experts)
+    if cfg.loop_steps > 1:
+        ran, expected = behind[routed:].reshape(2, lanes)
+        riders.update(loop_steps_run=ran,
+                      exit_step_expected=expected.view(np.float32))
+    return tokens, riders
 
 
 def lm_loss(logits: jax.Array, tokens: jax.Array,

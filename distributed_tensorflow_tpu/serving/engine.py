@@ -46,20 +46,15 @@ rebound from a call's result in the statement that makes the call, and
 nothing may keep the tree it passed in: those arrays are deleted.  JAX
 falls back to a copy, silently, when something else still holds a buffer
 (a ``np.asarray`` view of a leaf does on the CPU), so every dispatch reads
-``is_deleted()`` of one leaf it gave away and the ``serve_step`` record
-says ``pools_in_place`` (:meth:`DecodeEngine.stats`:
-``pool_steps_in_place`` / ``pool_steps_copied``).  A program that raises
-after its dispatch consumed the pools leaves them deleted;
+``is_deleted()`` of one leaf it gave away.  A program that raises after
+its dispatch consumed the pools leaves them deleted;
 :meth:`DecodeEngine.fail_active` builds them anew.
 
 Sampling is ``gpt_lib.sample_logits_dynamic`` inside the step's one
 compiled program.  It sorts the vocabulary only where a lane of the batch
 has ``temperature > 0``; a batch of greedy lanes takes an argmax and
 nothing else (a ``lax.cond`` on the temperatures the step is handed
-anyway, so no second program and no option).  The host knows the same
-from its own ``_temp`` array: each ``serve_step`` record says
-``sampled_lanes``, and :meth:`DecodeEngine.stats` counts
-``sample_steps_greedy`` / ``sample_steps_sampled``.
+anyway, so no second program and no option).
 
 The decode step is dispatched ONE STEP AHEAD of the host's reading of it
 (:meth:`DecodeEngine.step`): the one thing step N+1 needs from step N that
@@ -67,46 +62,28 @@ the host cannot know beforehand is the sampled token, and it is handed
 over on the device, so the host's turn (fetch, retire, complete, schedule,
 the next stage) runs while the device works.  Positions, budgets, tables,
 temperatures and seeds the host knows without the tokens.  The step
-program is the serial one's, and so are the tokens; each ``serve_step``
-record says ``steps_ahead`` / ``steps_serial`` / ``lane_steps_discarded``,
-and :meth:`DecodeEngine.stats` sums them.
+program is the serial one's, and so are the tokens.
 
-A page table's entry where a lane holds no page is the SENTINEL,
-``num_pages``: the pools' one page past the allocator's range, all zeros,
-never written (``gpt_lib.init_kv_pool``).  The step's gather reads it like
-any page, so how much of a step's gather reads zeros is a count the host
-can make from its own ``_tables``: each ``serve_step`` record says
-``table_pages`` (slots x ``max_pages_per_seq``) and ``table_pages_held``
-(the entries that name a page a lane owns), and
-:meth:`DecodeEngine.stats` sums both.  Where the step attends its K/V pools
-through the paged-attention kernel (``gpt_lib.paged_kernel_attends``: a
-Pallas configuration on a TPU) it reads no table whole: a lane's pages up
-to its position's, and no page of an idle lane.  ``attn_pages_read`` is
-that walk's length summed over the lanes, counted from the host's own
-``_positions`` and ``_tables`` whichever path runs, and
-``attn_kernel_layers`` the layers of the step program on a kernel's path
-(``gpt_lib.paged_kernel_layers``: K/V layers and, since PR 47, LATENT
-layers, whose two pools the latent kernel of the same file walks, traced
-like the K/V kernel under the device region ``attn.scores``; 0 on a CPU,
-over a float8 pool, and in a speculative turn, which attends a chunk the
-plain way): ``attn_pages_read / table_pages`` is the share of the table
-the step pays for on the kernel, beside ``table_pages_held /
-table_pages``.
-``lanes_live`` is the seated lanes of the dispatch, the rows whose table
-names a page (the step's own ``live`` mask, counted on the host): over
-``num_slots`` it is the batch's occupancy.
+What a layer of the model keeps in the pools is the model's to know
+(``gpt_lib.KINDS``).  The engine asks it for the pools
+(``gpt_lib.init_kv_pool``), for a prefill's caches to be landed on them
+(``gpt_lib.land_prefill``) and for a step (``GptLM.decode_paged``, its
+output taken apart by ``gpt_lib.unpack_step_output``), and reads what it
+must size and feed off one record, ``gpt_lib.pool_geometry``.  Which page
+a lane gets is the engine's: a page table's entry where a lane holds no
+page is the SENTINEL, ``num_pages`` (the pools' one page past the
+allocator's range, all zeros, never written), and where the geometry has
+a ring (sliding-window layers) the allocator counts pages of two kinds
+apart, admission needs room in both, a lane has a table of each kind and
+the step uploads both.
 
-A model with SLIDING-WINDOW layers among its full ones
-(``GptConfig.layer_kinds``) holds pages of two kinds: each full layer's
-pool as above, and each window layer's small pool of ``num_slots`` rings
-of ``ring_pages`` pages, the window and a page more a lane whatever its
-context.  The allocator counts the two apart and admission needs room in
-both; a lane has a table of each kind and the step uploads both; a prefill
-lands a prompt's last rows on the lane's ring.  ``table_pages`` /
-``table_pages_held`` stay the full tables'; the rings' own ride beside
-them as ``window_table_pages`` / ``window_table_pages_held``, with
-``window_pages_in_use`` / ``window_pages_peak``, and the rings' walk as
-``window_attn_pages_read``.
+What a step counts (the donation, the sampler's arm, the hand-over, the
+tables' and the rings' entries, the lanes, the routing and the loop) is
+ONE record a step, ``_Flight.counters``, built at the dispatch and
+finished at the landing; the ``serve.step.retire`` event, the
+``serve_step`` record and the sums of :meth:`DecodeEngine.stats` are views
+of it, made by one function (:func:`_views`).
+docs/observability.md lists every name, what it counts and where it goes.
 """
 
 from __future__ import annotations
@@ -120,7 +97,7 @@ import numpy as np
 
 from ..models import gpt as gpt_lib
 from ..models.drafting import NGramIndex
-from ..ops.pallas.paged_attention import pack_keys, pages_walked
+from ..ops.pallas.paged_attention import pages_walked
 from ..ops.quant import (load_inference_tree, prepare_inference_tree,
                          resolve_kv_dtype, validate_quantize)
 from ..utils import profiling, tracing
@@ -273,25 +250,86 @@ class _Flight:
     the device arrays to fetch, and what the host knew at the dispatch and
     the step's record says at the landing."""
 
-    out: list                  # [tokens (+ counters)] | [greedy, sampled0]
+    out: list                  # [tokens (+ riders)] | [greedy, sampled0]
     lanes: list                # per slot, the _Slot that rode, else None
     ahead: bool                # dispatched before the step before landed
     chunk: Any                 # the speculative arm's [B, K] feed, else None
     t0: float                  # the stage's start, time.monotonic
-    upload_us: int
-    dispatch_us: int
     queue_depth: int
-    sampled_lanes: int
-    table: dict
-    held: dict
-    in_place: bool
-    spec_rows: int
+    # What the step counts, by the names its sinks show (:func:`_views`):
+    # the dispatch's here, the landing's added to it there.
+    counters: dict
     # Since the dispatch before: admissions, their prompt tokens, and the
     # milliseconds their prefills (and this turn's chunk) took.
     admitted: int
     prompt_tokens: int
     prefill_ms: float
     prefill_rows: int
+
+
+#: Of a step's counters, the READINGS (a level, a clock): the event and
+#: the record say them, no running sum adds them up.
+_READINGS = ("state_slots", "state_bytes", "window_pages_in_use",
+             "window_pages_peak", "upload_us", "dispatch_us", "spec_rows")
+#: The sums :meth:`DecodeEngine.stats` shows flat, and under ``moe`` and
+#: ``loop``: every name for every model, 0 where it never counts.
+_SUMS = ("pool_steps_in_place", "pool_steps_copied", "steps_ahead",
+         "steps_serial", "lane_steps_discarded", "sample_steps_greedy",
+         "sample_steps_sampled", "table_pages", "table_pages_held",
+         "window_table_pages", "window_table_pages_held", "attn_pages_read",
+         "window_attn_pages_read", "attn_kernel_layers", "lanes_live")
+_MOE = ("experts_touched", "expert_slots", "expert_tokens_max",
+        "routed_tokens")
+_LOOP = ("loop_steps_run", "loop_tokens", "exit_step_expected_milli")
+
+
+def _views(counters: dict, stateful: bool) -> tuple[dict, dict, dict]:
+    """A landed step's counters as its three sinks take them: the
+    ``serve.step.retire`` event's stats (whole numbers; the state rows only
+    of a model that has them), the ``serve_step`` record's fields (the
+    stage's two parts ride there in milliseconds) and what the running
+    sums add (a step is counted once, by which way its donation and its
+    sampler went).  The only place a name changes."""
+    in_place, sampled = counters["pools_in_place"], counters["sampled_lanes"]
+    hidden = ("spec_rows",) if stateful else (
+        "spec_rows", "state_slots", "state_bytes")
+    event = {k: v for k, v in counters.items() if k not in hidden}
+    record = {k: v for k, v in counters.items()
+              if k not in ("upload_us", "dispatch_us")}
+    record["pools_in_place"] = bool(in_place)
+    sums = {k: v for k, v in counters.items() if k not in _READINGS}
+    del sums["pools_in_place"], sums["sampled_lanes"]
+    sums["pool_steps_in_place" if in_place else "pool_steps_copied"] = 1
+    sums["sample_steps_sampled" if sampled else "sample_steps_greedy"] = 1
+    return event, record, sums
+
+
+def _rider_counters(riders: dict) -> dict:
+    """A landed step's counters from what rode behind its tokens
+    (``gpt_lib.unpack_step_output``); a model without the rider is without
+    the names.  Of the routing histogram ([sparse layers x experts], live
+    lanes only), over the sparse layers: routed experts that got a token,
+    how many there are, the most tokens one expert got in one layer, and
+    all the (token, expert) pairs (live lanes x experts a token x layers).
+    Of a weight-shared loop's two numbers a lane, over the live lanes: the
+    loop steps they ran, how many lanes (tokens) that was, and the sum of
+    their expected exit steps in thousandths (a whole number, as a
+    profiler event's stats are read)."""
+    counted = {}
+    if "routing_counts" in riders:
+        counts = riders["routing_counts"]
+        counted.update(experts_touched=int(np.count_nonzero(counts)),
+                       expert_slots=int(counts.size),
+                       expert_tokens_max=int(counts.max()),
+                       routed_tokens=int(counts.sum()))
+    if "loop_steps_run" in riders:
+        ran = riders["loop_steps_run"]
+        counted.update(
+            loop_steps_run=int(ran.sum()),
+            loop_tokens=int(np.count_nonzero(ran)),
+            exit_step_expected_milli=int(round(
+                1e3 * float(riders["exit_step_expected"].sum()))))
+    return counted
 
 
 class DecodeEngine:
@@ -318,27 +356,6 @@ class DecodeEngine:
         # checkpoints) — the engine's logical capacity is the tighter of
         # the page-table span and the model's max_position.
         self.capacity = min(cfg.max_seq_len, mcfg.max_position)
-        # Layers that keep a row a slot beside the pages
-        # (GptConfig.layer_kinds): a recurrent state with its convolution
-        # tail, or a short convolution's tail alone (counted apart too).
-        # Only the whole-bucket prefill and the plain decode step carry it.
-        self._state_layers = sum(kind in gpt_lib.STATE_KINDS
-                                 for kind in mcfg.kinds)
-        self._conv_layers = mcfg.conv_layers
-        self._stateful = self._state_layers > 0
-        # Layers whose MLP is routed experts: the step hands their routing
-        # histogram [sparse layers, experts] back with its tokens.
-        self._sparse_layers = sum(mcfg.sparse_layers)
-        # Times the stack is applied to a token over the same weights
-        # (GptConfig.loop_steps): a cached token holds that many rows a
-        # layer, and the step hands back, behind its tokens, how many
-        # steps each lane ran and where its exit gate expects it to leave.
-        self._loop_steps = mcfg.loop_steps
-        # Layers whose pool is a ring of pages a lane (sliding windows),
-        # and that ring's pages: 0 and 0 for a model without such layers.
-        self._window_layers = mcfg.window_layers
-        self._ring_pages = mcfg.ring_pages(cfg.page_size) \
-            if self._window_layers else 0
         if cfg.spec_k or cfg.prefill_chunk:
             # Neither carries a recurrent state, a ring, a latent row or a
             # routed-expert MLP, nor walks a weight-shared loop; a no-op
@@ -346,10 +363,16 @@ class DecodeEngine:
             on = "spec_k" if cfg.spec_k else "prefill_chunk"
             mcfg.refuse_state_layers(f"DecodeEngine with EngineConfig.{on}")
         self._cache_dtype = resolve_kv_dtype(cfg.kv_dtype)
-        # Bytes a cached token holds over all layers' pools; the prefill
-        # span names them where they are latent rows (else 0).
-        row_bytes = gpt_lib.kv_row_bytes_per_token(mcfg, self._cache_dtype)
-        self._latent_row_bytes = row_bytes if mcfg.latent_kv_rank else 0
+        # What the pools hold a token, a slot and a ring, the layers by
+        # what they keep, and what rides behind the step's tokens: the
+        # model's to know, this record's to say.
+        self.geometry = geo = gpt_lib.pool_geometry(
+            mcfg, cfg.page_size, self._cache_dtype)
+        # What a whole-bucket prefill's span says of the pools it filled.
+        self._prefill_attrs = {name: getattr(geo, name) for name in (
+            "state_layers", "conv_layers", "sparse_layers",
+            "latent_row_bytes", "loop_steps", "cache_rows", "row_bytes",
+            "window_layers", "ring_pages")}
         self._tree = self._prepare_params(params)
         self._pending: tuple[Any, int] | None = None  # (tree, label step)
         self.model_step = 0            # checkpoint step the weights carry
@@ -361,12 +384,11 @@ class DecodeEngine:
         self._kernel_layers = gpt_lib.paged_kernel_layers(mcfg, self.pools)
         self.allocator = PageAllocator(
             cfg.num_pages, cfg.page_size,
-            state_bytes_per_slot=gpt_lib.state_bytes_per_slot(mcfg),
-            row_bytes_per_token=row_bytes,
-            window_pages=cfg.num_slots * self._ring_pages,
-            ring_pages=self._ring_pages,
-            window_row_bytes_per_token=gpt_lib.kv_row_bytes_per_token(
-                mcfg, self._cache_dtype, window=True))
+            state_bytes_per_slot=geo.state_bytes,
+            row_bytes_per_token=geo.row_bytes,
+            window_pages=cfg.num_slots * geo.ring_pages,
+            ring_pages=geo.ring_pages,
+            window_row_bytes_per_token=geo.window_row_bytes)
 
         B, MP = cfg.num_slots, cfg.max_pages_per_seq
         self._slots: list[_Slot | None] = [None] * B
@@ -376,7 +398,7 @@ class DecodeEngine:
         # The lanes' rings in the window layers' pools, that pool's own
         # sentinel where a lane holds no page; no row without such layers.
         self._window_tables = np.full(
-            (B, self._ring_pages), self.allocator.window_pages, np.int32)
+            (B, geo.ring_pages), self.allocator.window_pages, np.int32)
         self._temp = np.zeros((B,), np.float32)
         self._top_k = np.zeros((B,), np.int32)
         self._top_p = np.zeros((B,), np.float32)
@@ -386,62 +408,26 @@ class DecodeEngine:
         # The step dispatched and not yet landed, if any (step()).
         self._flight: _Flight | None = None
         self._t_landed = 0.0           # time.monotonic of the last landing
-        # Decode steps dispatched while the step before them was unfetched
-        # and after it was fetched, and lane-steps whose token was dropped
-        # because the lane had left when they landed (step()).
-        self.steps_ahead = 0
-        self.steps_serial = 0
-        self.lane_steps_discarded = 0
         self._admitted_since_step = 0
         # Since the last step's dispatch: prompt tokens seated, and the
         # milliseconds their whole-bucket prefills took.
         self._prompt_tokens_since_step = 0
         self._prefill_ms_since_step = 0.0
-        self._spec_accepted_since_step = 0
         self._spec_rows_last_step = 0
         # Whether every dispatch since the last step's (the chunk
         # prefill's, the admissions' prefills; then the step's own)
-        # consumed the pools it was donated, and the steps counted either
-        # way.
+        # consumed the pools it was donated.
         self._pools_in_place = True
-        self.pool_steps_in_place = 0
-        self.pool_steps_copied = 0
-        # Steps whose lanes were all greedy (the sampler took its argmax
-        # arm) and steps with a lane at temperature > 0 (it sorted).
-        self.sample_steps_greedy = 0
-        self.sample_steps_sampled = 0
-        # Running sums over the steps: the entries of the page table the
-        # step gathered through, and those of them that named a page a
-        # lane holds (the others read the sentinel's page of zeros).
-        self.table_pages = 0
-        self.table_pages_held = 0
-        # The same two sums over the window tables (the rings).
-        self.window_table_pages = 0
-        self.window_table_pages_held = 0
-        # The pages a held-pages-only read visits (a lane's up to its
-        # position's, none of an idle lane), summed over the steps, of the
-        # full tables and of the rings; and the layer-steps that read that
-        # way, through the paged-attention kernel.
-        self.attn_pages_read = 0
-        self.window_attn_pages_read = 0
-        self.attn_kernel_layers = 0
-        # The seated lanes of the dispatched steps, summed (over
-        # ``engine_step`` x ``num_slots``: the batch's occupancy).
-        self.lanes_live = 0
-        # Running sums of the steps' routing counters (_routing_counters).
-        self.moe = dict.fromkeys(("experts_touched", "expert_slots",
-                                  "expert_tokens_max", "routed_tokens"), 0)
-        # Running sums of the steps' loop counters (_loop_counters).
-        self.loop = dict.fromkeys(("loop_steps_run", "loop_tokens",
-                                   "exit_step_expected_milli"), 0)
+        # The landed steps' counters, summed by name (:func:`_views`).
+        self._sums: collections.Counter = collections.Counter()
         self._step_fn = self._build_step()
         # The hand-over on the device, a program of its own: a step's
-        # tokens are the first num_slots entries of the output of the step
-        # before (the routing histogram or the loop's counters ride behind
-        # them), but for a lane seated since, which takes the host's seed
-        # token (``seeded`` holds it there and -1 elsewhere).
+        # tokens are those of the output of the step before (riders or
+        # none behind them), but for a lane seated since, which takes the
+        # host's seed token (``seeded`` holds it there and -1 elsewhere).
         self._hand_over = jax.jit(lambda out, seeded: jnp.where(
-            seeded >= 0, seeded, out[:B]))
+            seeded >= 0, seeded,
+            gpt_lib.unpack_step_output(mcfg, out, B)[0]))
         self._spec_step_fn = (self._build_spec_step()
                               if cfg.spec_k else None)
         # Per-bucket prefill programs, LRU-bounded (prefill_cache_cap);
@@ -528,27 +514,26 @@ class DecodeEngine:
     # ----------------------------------------------------- jitted bodies
 
     def _build_step(self):
-        jax, jnp = self._jax, self._jnp
-        model = self.model
+        jax = self._jax
+        model, geo = self.model, self.geometry
 
         def step(tree, tokens, positions, tables, pools, temp, tk, tp,
                  seeds):
             params = self._dequant(tree)
-            # With window layers ``tables`` is the pair (full, rings).
+            # With a ring ``tables`` is the pair (full, rings).
             rings = {}
-            if self._window_layers:
+            if geo.ring_pages:
                 tables, rings["window_tables"] = tables
             # An idle lane's table is all sentinel: its page writes drop
-            # by themselves, its recurrent state has to be told.
-            looped = model.cfg.loop_steps > 1
-            live = ((tables[:, 0] < self.config.num_pages),) \
-                if self._stateful or self._sparse_layers or looped else ()
-            sown = {"mutable": ["routing"]} if self._sparse_layers else \
-                {"mutable": ["loop"]} if looped else {}
+            # by themselves, a state row, a router and a loop's counters
+            # have to be told.
+            live = (tables[:, 0] < self.config.num_pages) \
+                if geo.needs_live else None
+            sown = {"mutable": list(geo.riders)} if geo.riders else {}
             out = model.apply(
-                {"params": params}, tokens, pools, tables, positions, *live,
+                {"params": params}, tokens, pools, tables, positions, live,
                 method=gpt_lib.GptLM.decode_paged, **rings, **sown)
-            (logits, pools), aux = out if sown else (out, None)
+            (logits, pools), aux = out if sown else (out, {})
             # Per-row keys folded on the ABSOLUTE index being generated:
             # a sampled stream is reproducible for its (seed, position)s
             # no matter which other requests shared the batch.
@@ -558,25 +543,7 @@ class DecodeEngine:
                         seeds, positions + 1)
                 nxt = gpt_lib.sample_logits_dynamic(logits, keys, temp, tk,
                                                     tp)
-            if self._sparse_layers:
-                # The histogram rides behind the tokens in the one array
-                # the host fetches anyway: no second copy to wait for.
-                counts = [aux["routing"][f"layer{i}"]["counts"][0]
-                          for i, sparse in enumerate(model.cfg.sparse_layers)
-                          if sparse]
-                nxt = jnp.concatenate([nxt, *counts])
-            if looped:
-                # Two numbers a lane behind the tokens, the same way: the
-                # loop steps it ran, and its expected exit step, the sum
-                # of t x (mass leaving at step t), float32 bit for bit in
-                # the array's int32.  An idle lane reads 0 and 0.0.
-                loop = aux["loop"]
-                masses = loop["exit_mass"][0][..., 0]            # [R, B]
-                at = jnp.arange(1, masses.shape[0] + 1, dtype=masses.dtype)
-                expected = jnp.where(live[0], at @ masses, 0.0)
-                nxt = jnp.concatenate([
-                    nxt, jnp.where(live[0], loop["steps_run"][0], 0),
-                    jax.lax.bitcast_convert_type(expected, jnp.int32)])
+            nxt = gpt_lib.pack_step_output(model.cfg, nxt, aux, live)
             return nxt, pools
 
         return jax.jit(step, donate_argnames=("pools",))
@@ -621,12 +588,10 @@ class DecodeEngine:
         if fn is not None:
             self._prefill_fns.move_to_end(n_pages)
             return fn
-        jax, jnp = self._jax, self._jnp
+        jax = self._jax
         model, mcfg = self.model, self.model.cfg
         page = self.config.page_size
         p_len = n_pages * page
-
-        ring_held = min(n_pages, self._ring_pages)
 
         def prefill(tree, tokens, pools, phys, slot=None, absorb=None,
                     ring=None):
@@ -640,42 +605,12 @@ class DecodeEngine:
             params = self._dequant(tree)
             caches = gpt_lib.init_kv_cache(
                 mcfg, 1, p_len, dtype=self._cache_dtype,
-                ring_rows=self._ring_pages * page)
+                ring_rows=self.geometry.ring_pages * page)
             lengths = () if absorb is None else (absorb[None],)
             _, caches = model.apply({"params": params}, tokens, caches,
                                     *lengths, method=gpt_lib.GptLM.prefill)
-            def land(kind, cache, pool):
-                if kind in gpt_lib.STATE_KINDS:
-                    return pool.at[slot].set(cache[0])
-                if kind == gpt_lib.SLIDING_ATTENTION:
-                    # The prompt's last rows, position p at ring row
-                    # p % (ring_pages * page): whole ring pages.
-                    return pool.at[
-                        gpt_lib.written_pages(ring, pool.shape[0])].set(
-                        cache[0].reshape(ring_held, page, -1), mode="drop")
-                if mcfg.loop_steps > 1:
-                    # A run of pages a loop step: [R, 1, P, G, D] lands
-                    # on the R runs of the prompt's pages.
-                    R = mcfg.loop_steps
-                    runs = gpt_lib.loop_step_pages(
-                        phys[None, :], jnp.arange(R)[:, None],
-                        pool.shape[0], R).reshape(-1)
-                    return pool.at[
-                        gpt_lib.written_pages(runs, pool.shape[0])].set(
-                        cache.reshape(R * n_pages, page, -1), mode="drop")
-                # (A latent layer's rotated keys lie two tokens a row.)
-                return pool.at[
-                    gpt_lib.written_pages(phys, pool.shape[0])].set(
-                    pack_keys(cache[0].reshape(n_pages, page, -1),
-                              pool.shape[1]), mode="drop")
-
-            # An entry is (keys, values) of a run of pages or of a ring, a
-            # latent layer's (latents, rotated keys), (state, convolution
-            # tail) or a short convolution's (tail,).
-            with profiling.region("cache.write"):
-                return [tuple(land(kind, c, p) for c, p in zip(cache, pool))
-                        for kind, cache, pool
-                        in zip(mcfg.kinds, caches, pools)]
+            return gpt_lib.land_prefill(mcfg, caches, pools, page, phys,
+                                        slot, ring)
 
         fn = jax.jit(prefill, donate_argnames=("pools",))
         self._prefill_fns[n_pages] = fn
@@ -831,10 +766,11 @@ class DecodeEngine:
                 # position P-1.  Writing its K/V twice is idempotent;
                 # absorbing it twice into a recurrent state is not, so the
                 # lane seats with the state after tokens 0..P-2.
+                geo = self.geometry
                 seat = (np.int32(slot), np.int32(P - 1)) \
-                    if self._stateful else ()
+                    if geo.state_layers else ()
                 ring = {"ring": self._jnp.asarray(
-                    ring_table[:n_prefill])} if self._window_layers else {}
+                    ring_table[:n_prefill])} if geo.ring_pages else {}
                 given = self.pools[0][0]
                 self.pools = self._prefill_fn(n_prefill)(
                     self._tree, self._jnp.asarray(toks), self.pools,
@@ -861,16 +797,7 @@ class DecodeEngine:
                     trace=request.trace, request_id=request.id,
                     tenant=request.tenant, bucket=n_prefill,
                     pages=n_prefill, prompt_tokens=P, chunks=1,
-                    state_layers=self._state_layers,
-                    conv_layers=self._conv_layers,
-                    sparse_layers=self._sparse_layers,
-                    latent_row_bytes=self._latent_row_bytes,
-                    loop_steps=self._loop_steps,
-                    cache_rows=self._loop_steps * (
-                        len(self.pools) - self._state_layers),
-                    row_bytes=self.allocator.row_bytes_per_token,
-                    window_layers=self._window_layers,
-                    ring_pages=self._ring_pages)
+                    **self._prefill_attrs)
         spec = bool(cfg.spec_k) and request.speculative
         state = _Slot(request, cfg.spec_ngram if spec else 0)
         state.table = self.allocator.page_table(request.id,
@@ -1196,29 +1123,34 @@ class DecodeEngine:
         t0 = time.monotonic()
         given = self.pools[0][0]
         lanes = [s if s is not None and s.due else None for s in self._slots]
-        # The predicate the sampler evaluates on the device, read off the
-        # host's copy of the same array.
-        sampled_lanes = int(np.count_nonzero(self._temp > 0.0))
-        # What the gather of this dispatch reads: all of the table, of
-        # which this many entries are pages and not the sentinel.
-        # And what a read of held pages only visits, which the step makes
-        # on the kernel's path (a chunk of drafts is attended the plain way).
-        page = self.config.page_size
-        table = {"table_pages": self._tables.size,
-                 "table_pages_held": int(np.count_nonzero(
-                     self._tables < self.config.num_pages)),
-                 "attn_pages_read": int(pages_walked(
-                     self._tables, self._positions, self.config.num_pages,
-                     page).sum()),
-                 "attn_kernel_layers":
-                     0 if spec_mode else self._kernel_layers,
-                 # The seated lanes of this dispatch: the rows the step's
-                 # own ``live`` mask will find (a table that names a page).
-                 "lanes_live": int(np.count_nonzero(
-                     self._tables[:, 0] < self.config.num_pages))}
-        if self._window_layers:
+        page, sentinel = self.config.page_size, self.config.num_pages
+        counters = {
+            # The predicate the sampler evaluates on the device, read off
+            # the host's copy of the same array.
+            "sampled_lanes": int(np.count_nonzero(self._temp > 0.0)),
+            # What the gather of this dispatch reads: all of the table, of
+            # which this many entries are pages and not the sentinel.  And
+            # what a read of held pages only visits, which the step makes
+            # on the kernel's path (a chunk of drafts is attended the
+            # plain way).
+            "table_pages": self._tables.size,
+            "table_pages_held": int(np.count_nonzero(
+                self._tables < sentinel)),
+            "attn_pages_read": int(pages_walked(
+                self._tables, self._positions, sentinel, page).sum()),
+            "attn_kernel_layers": 0 if spec_mode else self._kernel_layers,
+            # The seated lanes of this dispatch: the rows the step's own
+            # ``live`` mask will find (a table that names a page).
+            "lanes_live": int(np.count_nonzero(
+                self._tables[:, 0] < sentinel)),
+            "steps_ahead": int(after is not None),
+            "steps_serial": int(after is None),
+            # What this step's lanes hold in state rows beside their pages.
+            "state_slots": self.allocator.state_slots,
+            "state_bytes": self.allocator.state_bytes}
+        if self.geometry.ring_pages:
             # The rings' tables apart, and the window pool's occupancy.
-            table.update(
+            counters.update(
                 window_table_pages=self._window_tables.size,
                 window_table_pages_held=int(np.count_nonzero(
                     self._window_tables < self.allocator.window_pages)),
@@ -1227,9 +1159,6 @@ class DecodeEngine:
                     self.allocator.window_pages, page).sum()),
                 window_pages_in_use=self.allocator.window_pages_in_use,
                 window_pages_peak=self.allocator.window_peak_in_use)
-        # What this step's lanes hold in state rows beside their pages.
-        held = {"state_slots": self.allocator.state_slots,
-                "state_bytes": self.allocator.state_bytes}
         chunk, spec_rows = None, 0
         if spec_mode:
             K = self.config.spec_k
@@ -1258,7 +1187,7 @@ class DecodeEngine:
                 self._top_p, self._seeds))))
         if after is not None:
             tokens = self._hand_over(after.out[0], tokens)
-        if self._window_layers:
+        if self.geometry.ring_pages:
             tables = (tables, jnp.asarray(self._window_tables.copy()))
         # The stage, cut at the dispatch: host arrays, their seven uploads
         # and the hand-over before this stamp, the call over the whole
@@ -1274,7 +1203,10 @@ class DecodeEngine:
         # the profiler's event (whose stats are whole numbers) and on the
         # record alike.
         upload_us = round((t_uploaded - t0) * 1e6)
-        dispatch_us = round((t_staged - t0) * 1e6) - upload_us
+        counters.update(
+            pools_in_place=int(self._pools_in_place), spec_rows=spec_rows,
+            upload_us=upload_us,
+            dispatch_us=round((t_staged - t0) * 1e6) - upload_us)
         # The host's arrays now say what the NEXT dispatch feeds: a lane
         # moves one position on, and one whose budget this step fills
         # rides the next as an idle row, whatever its token turns out to be.
@@ -1288,10 +1220,7 @@ class DecodeEngine:
                 self._idle_row(slot)
         flight = _Flight(
             out=out, lanes=lanes, ahead=after is not None, chunk=chunk,
-            t0=t0, upload_us=upload_us, dispatch_us=dispatch_us,
-            queue_depth=queue_depth, sampled_lanes=sampled_lanes,
-            table=table, held=held,
-            in_place=self._pools_in_place, spec_rows=spec_rows,
+            t0=t0, queue_depth=queue_depth, counters=counters,
             admitted=self._admitted_since_step,
             prompt_tokens=self._prompt_tokens_since_step,
             prefill_ms=self._prefill_ms_since_step + chunk_ms,
@@ -1313,8 +1242,7 @@ class DecodeEngine:
                 greedy, nxt = (np.asarray(a) for a in flight.out)
             else:
                 nxt = np.asarray(flight.out[0])
-        routed = self._routing_counters(nxt[B:])
-        looped = self._loop_counters(nxt[B:])
+        nxt, riders = gpt_lib.unpack_step_output(self.model.cfg, nxt, B)
         now = time.monotonic()
         # A step dispatched ahead could not start before the one before it
         # ended, which is when that one landed: its time runs from there,
@@ -1324,43 +1252,18 @@ class DecodeEngine:
         self._t_landed = now
         step_ms = (now - t_begin) * 1e3
         self.step_index += 1
-        # Lane-steps this dispatch spent on a lane that had left (an eos
-        # seen a step late, a request abandoned) by the time it landed.
-        discarded = sum(
-            rode is not None and self._slots[slot] is not rode
-            for slot, rode in enumerate(flight.lanes))
-        ahead = {"steps_ahead": int(flight.ahead),
-                 "steps_serial": int(not flight.ahead),
-                 "lane_steps_discarded": discarded}
-        self.steps_ahead += ahead["steps_ahead"]
-        self.steps_serial += ahead["steps_serial"]
-        self.lane_steps_discarded += discarded
-        in_place, table = flight.in_place, flight.table
-        held, sampled_lanes = flight.held, flight.sampled_lanes
-        if in_place:
-            self.pool_steps_in_place += 1
-        else:
-            self.pool_steps_copied += 1
-        if sampled_lanes:
-            self.sample_steps_sampled += 1
-        else:
-            self.sample_steps_greedy += 1
-        self.table_pages += table["table_pages"]
-        self.table_pages_held += table["table_pages_held"]
-        self.window_table_pages += table.get("window_table_pages", 0)
-        self.window_table_pages_held += table.get(
-            "window_table_pages_held", 0)
-        self.attn_pages_read += table["attn_pages_read"]
-        self.window_attn_pages_read += table.get("window_attn_pages_read", 0)
-        self.attn_kernel_layers += table["attn_kernel_layers"]
-        self.lanes_live += table["lanes_live"]
-        with profiling.annotate("serve.step.retire",
-                                pools_in_place=int(in_place),
-                                sampled_lanes=sampled_lanes, **table,
-                                upload_us=flight.upload_us,
-                                dispatch_us=flight.dispatch_us, **ahead,
-                                **(held if self._stateful else {}),
-                                **routed, **looped):
+        counters = dict(
+            flight.counters,
+            # Lane-steps this dispatch spent on a lane that had left (an
+            # eos seen a step late, a request abandoned) by its landing.
+            lane_steps_discarded=sum(
+                rode is not None and self._slots[slot] is not rode
+                for slot, rode in enumerate(flight.lanes)),
+            **_rider_counters(riders))
+        event, fields, sums = _views(counters,
+                                     bool(self.geometry.state_layers))
+        self._sums.update(sums)
+        with profiling.annotate("serve.step.retire", **event):
             tracer = tracing.active()
             round_id = 0
             t_round_unix = 0.0
@@ -1446,7 +1349,6 @@ class DecodeEngine:
                     # landing; the position moved one on at the dispatch.
                     self._tokens[slot] = emitted[count - 1]
                     self._positions[slot] += count - 1
-            self._spec_accepted_since_step = spec_accepted
             tel = self.telemetry
             if tel is not None:
                 tel.histogram("serve_step_ms").record(step_ms)
@@ -1462,11 +1364,12 @@ class DecodeEngine:
             if tracer is not None or tel is not None:
                 # The region's last boundary: everything of the retire
                 # region but the two emits themselves.
+                upload_us, dispatch_us = (counters["upload_us"],
+                                          counters["dispatch_us"])
                 split_ms = {
-                    "upload_ms": flight.upload_us / 1e3,
-                    "dispatch_ms": flight.dispatch_us / 1e3,
-                    "stage_ms": (flight.upload_us
-                                 + flight.dispatch_us) / 1e3,
+                    "upload_ms": upload_us / 1e3,
+                    "dispatch_ms": dispatch_us / 1e3,
+                    "stage_ms": (upload_us + dispatch_us) / 1e3,
                     "fetch_ms": round((now - t_fetch) * 1e3, 3),
                     "retire_ms": round((time.monotonic() - now) * 1e3, 3)}
             if tracer is not None:
@@ -1474,7 +1377,7 @@ class DecodeEngine:
                     "serve.decode_round", t_round_unix, step_ms,
                     step=self.step_index, parent_id=0, span_id=round_id,
                     active_slots=self.active_slots + len(retired),
-                    spec_rows=flight.spec_rows,
+                    spec_rows=counters["spec_rows"],
                     model_step=self.model_step, **split_ms)
             if tel is not None:
                 tel.emit("serve_step", step=self.step_index,
@@ -1482,54 +1385,15 @@ class DecodeEngine:
                          admitted=flight.admitted,
                          retired=len(retired), queue_depth=flight.queue_depth,
                          kv_pages_in_use=self.allocator.pages_in_use,
-                         kv_pages_total=self.config.num_pages,
-                         **held, pools_in_place=in_place, **table,
-                         sampled_lanes=sampled_lanes, **ahead,
-                         **routed, **looped,
+                         kv_pages_total=self.config.num_pages, **fields,
                          t_start=round(flight.t0, 6),
                          step_ms=round(step_ms, 3), **split_ms,
-                         spec_rows=flight.spec_rows,
                          spec_accepted=spec_accepted,
                          prompt_tokens=flight.prompt_tokens,
                          prefill_rows=flight.prefill_rows,
                          prefill_ms=round(flight.prefill_ms, 3),
                          model_step=self.model_step)
         return retired
-
-    def _routing_counters(self, counts: np.ndarray) -> dict:
-        """What the routed-expert layers saw this step, from the histogram
-        the step fetched behind its tokens ([sparse layers x experts],
-        live lanes only); empty for a model without such layers.  Over the
-        sparse layers: routed experts that got a token, how many there
-        are, the most tokens one expert got in one layer, and all the
-        (token, expert) pairs (live lanes x experts a token x layers)."""
-        if not self._sparse_layers:
-            return {}
-        routed = {"experts_touched": int(np.count_nonzero(counts)),
-                  "expert_slots": int(counts.size),
-                  "expert_tokens_max": int(counts.max()),
-                  "routed_tokens": int(counts.sum())}
-        for key, value in routed.items():
-            self.moe[key] += value
-        return routed
-
-    def _loop_counters(self, behind: np.ndarray) -> dict:
-        """What a weight-shared loop did this step, from the two numbers a
-        lane the step fetched behind its tokens; empty for a model that
-        walks its stack once.  Over the live lanes: the loop steps they
-        ran, how many lanes (tokens) that was, and the sum of their
-        expected exit steps in thousandths (a whole number, as a
-        profiler event's stats are read)."""
-        if self._loop_steps < 2:
-            return {}
-        ran, expected = behind.reshape(2, -1)
-        looped = {"loop_steps_run": int(ran.sum()),
-                  "loop_tokens": int(np.count_nonzero(ran)),
-                  "exit_step_expected_milli": int(round(
-                      1e3 * float(expected.view(np.float32).sum())))}
-        for key, value in looped.items():
-            self.loop[key] += value
-        return looped
 
     def fail_active(self, error: str) -> list[Request]:
         """Retire every live lane with an error (engine-fatal paths).  A
@@ -1585,43 +1449,10 @@ class DecodeEngine:
             # and the bytes a slot are in the pool's snapshot.
             "state_slots": self.allocator.state_slots,
             "state_bytes": self.allocator.state_bytes,
-            # Steps whose every dispatch wrote the donated pools in
-            # place, and steps in which JAX copied them instead.
-            "pool_steps_in_place": self.pool_steps_in_place,
-            "pool_steps_copied": self.pool_steps_copied,
-            # Steps dispatched while the step before them was unfetched,
-            # steps dispatched after it was fetched, and lane-steps whose
-            # token was dropped (an eos seen a step late, an abandoned
-            # request).
-            "steps_ahead": self.steps_ahead,
-            "steps_serial": self.steps_serial,
-            "lane_steps_discarded": self.lane_steps_discarded,
-            # Steps whose lanes were all greedy, where the sampler is an
-            # argmax, and steps in which some lane sampled.
-            "sample_steps_greedy": self.sample_steps_greedy,
-            "sample_steps_sampled": self.sample_steps_sampled,
-            # Page-table entries the steps gathered through, and those of
-            # them that named a held page and not the sentinel's zeros.
-            "table_pages": self.table_pages,
-            "table_pages_held": self.table_pages_held,
-            # The same two over the window layers' ring tables; zeros for
-            # a model without such layers (their pool's occupancy and peak
-            # are under "kv_pool" / "window").
-            "window_table_pages": self.window_table_pages,
-            "window_table_pages_held": self.window_table_pages_held,
-            # Pages a read of held pages only visits (full tables, rings),
-            # and the layer-steps that read so, on the paged-attention
-            # kernel: 0 on a CPU.
-            "attn_pages_read": self.attn_pages_read,
-            "window_attn_pages_read": self.window_attn_pages_read,
-            "attn_kernel_layers": self.attn_kernel_layers,
-            # Seated lanes summed over the dispatched steps.
-            "lanes_live": self.lanes_live,
-            # Running sums of the steps' routing counters; zeros for a
-            # model whose MLPs are all dense.
-            "moe": dict(self.moe),
-            # Running sums of the steps' loop counters; zeros for a model
-            # that walks its stack once.
-            "loop": dict(self.loop),
+            # The landed steps' counters summed (docs/observability.md
+            # says what each counts); 0 where the model never counts one.
+            **{name: self._sums[name] for name in _SUMS},
+            "moe": {name: self._sums[name] for name in _MOE},
+            "loop": {name: self._sums[name] for name in _LOOP},
             "kv_pool": self.allocator.snapshot(),
         }

@@ -234,11 +234,10 @@ def bare_engine(model, econf, stateful=False, sparse_layers=0):
     engine = DecodeEngine.__new__(DecodeEngine)
     engine._jax, engine._jnp, engine.model, engine.config = (
         jax, jnp, model, econf)
-    engine._stateful, engine._cache_dtype = stateful, None
-    engine._sparse_layers = sparse_layers
-    engine._window_layers = model.cfg.window_layers
-    engine._ring_pages = model.cfg.ring_pages(econf.page_size) \
-        if engine._window_layers else 0
+    engine._cache_dtype = None
+    engine.geometry = geo = gpt_lib.pool_geometry(model.cfg, econf.page_size)
+    assert (bool(geo.state_layers), geo.sparse_layers) == (
+        stateful, sparse_layers)
     engine._prefill_fns, engine._prefill_evictions = {}, 0
     return engine
 
@@ -581,7 +580,8 @@ def test_window_and_full_serving_programs_compile_for_v5e(one_chip):
             x.shape, dtype or x.dtype, sharding=one_chip), tree)
 
     engine = bare_engine(model, econf, sparse_layers=2)
-    assert (engine._window_layers, engine._ring_pages) == (2, 129)
+    assert (engine.geometry.window_layers,
+            engine.geometry.ring_pages) == (2, 129)
     tree = described(jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
         jnp.bfloat16)

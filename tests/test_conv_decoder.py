@@ -264,7 +264,7 @@ def test_a_slot_reused_by_a_shorter_sequence_starts_from_zeros(
     while first[0].t_done is None:
         engine.step()
     engine.settle()
-    assert engine.free_slots == 1 and engine.steps_ahead > 0
+    assert engine.free_slots == 1 and engine.stats()["steps_ahead"] > 0
     assert float(gaps(ref, cfg, first[0]).max()) < GAP_TOL
     left = tails(engine.pools)
     assert np.abs(left[:, 0]).min(axis=(1, 2)).max() > 0   # not reset
@@ -330,8 +330,8 @@ def test_lanes_live_and_state_bytes_are_a_numpy_count(
         tracing.clear()
     assert [len(r.tokens) for r in requests] == [5, 9, 3, 6, 4]
     assert float(gaps(ref, cfg, *requests).max()) < GAP_TOL
-    assert engine.steps_ahead > engine.steps_serial
-    assert engine.pool_steps_copied == 0
+    assert engine.stats()["steps_ahead"] > engine.stats()["steps_serial"]
+    assert engine.stats()["pool_steps_copied"] == 0
     steps = [r for r in records.rows if r.get("kind") == "serve_step"]
     assert len(steps) == len(counted) > 8
     assert [{k: r[k] for k in counted[0]} for r in steps] == counted

@@ -136,7 +136,7 @@ def test_a_slot_reused_and_idle_neighbours_change_nothing(
     before = [np.array(x, copy=True) for x in wide.pools[0]]
     wide.step()
     after = [np.array(x, copy=True) for x in wide.pools[0]]
-    assert wide.pool_steps_copied == 0
+    assert wide.stats()["pool_steps_copied"] == 0
     for b, a in zip(before, after):
         assert (b[1:] == a[1:]).all() and not (b[0] == a[0]).all()
     both = engine_of(model, params, slots=3)

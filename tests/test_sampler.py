@@ -379,7 +379,8 @@ def test_the_engine_serves_the_plain_samplers_tokens_from_one_program(
     want = traffic(plain)
     assert traffic(engine) == want
     assert [len(t) for t in want] == [6, 9, 6, 5, 4]
-    assert engine.sample_steps_sampled > 0 < engine.sample_steps_greedy
+    stats = engine.stats()
+    assert stats["sample_steps_sampled"] > 0 < stats["sample_steps_greedy"]
     # All of it from the one decode step an engine compiles: the arms
     # are inside the program, not two programs the host picks from.
     for fn in (engine._step_fn, engine._spec_step_fn):

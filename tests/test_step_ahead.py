@@ -377,7 +377,7 @@ def test_shutdown_leaves_no_unfetched_step():
     req = Request(PROMPT[:6], 24)
     try:
         srv.submit(req)
-        wait_for(lambda: engine.steps_ahead >= 3)
+        wait_for(lambda: engine.stats()["steps_ahead"] >= 3)
     finally:
         srv.shutdown()
     assert engine._flight is None
