@@ -191,6 +191,21 @@ class GptConfig:
     num_shared_experts: int = 0
     routed_scaling_factor: float = 1.0
     first_dense_layers: int = 0
+    # What a sparse layer's router reads: "mlp_in" (the MLP's own normed
+    # input, the stream AFTER the token mixer) or "mixer_in" (the token
+    # mixer's normed input, the block's INPUT: the choice of experts then
+    # owes nothing to the mixer, two streams reach the sparse MLP, and the
+    # block lays the route down BEFORE the mixer runs,
+    # ``GptBlock._route_ahead``).
+    router_input: str = "mlp_in"
+    # How the router scores: "sigmoid" (above) or "softmax" (the
+    # ``experts_per_token`` largest LOGITS, weighted by a softmax over
+    # those alone; no selection bias, so no ``router_bias`` leaf, and
+    # ``routed_scaling_factor`` must be 1).
+    router_score: str = "sigmoid"
+    # The routed experts' activation on the gate branch: "silu" or "relu"
+    # (``(relu(x Wg) * (x Wu)) Wd``); the shared experts' is SiLU.
+    expert_activation: str = "silu"
     # A weight-shared loop: the stack of ``num_layers`` blocks is applied
     # ``loop_steps`` times over the SAME weights, the final norm after
     # every application (the normed stream is what the next one starts
@@ -417,6 +432,28 @@ class GptConfig:
                     "num_experts, expert_intermediate_size >= 1, "
                     "num_shared_experts >= 0 and 0 <= first_dense_layers "
                     "<= num_layers")
+            if self.router_input not in ("mlp_in", "mixer_in") \
+                    or self.router_score not in experts_ops.SCORES \
+                    or self.expert_activation not in experts_ops.ACTIVATIONS:
+                raise ValueError(
+                    "num_experts: router_input is one of ('mlp_in', "
+                    f"'mixer_in'), router_score of {experts_ops.SCORES}, "
+                    f"expert_activation of {tuple(experts_ops.ACTIVATIONS)}"
+                    f"; got {self.router_input!r}, {self.router_score!r}, "
+                    f"{self.expert_activation!r}")
+            if self.router_score == "softmax" \
+                    and self.routed_scaling_factor != 1.0:
+                raise ValueError(
+                    "router_score='softmax' weighs the chosen experts by a "
+                    "softmax over their logits alone: "
+                    "routed_scaling_factor is the sigmoid score's and must "
+                    f"be 1.0, got {self.routed_scaling_factor}")
+            if self.expert_activation != "silu" and self.num_shared_experts:
+                raise ValueError(
+                    "expert_activation is the ROUTED experts'; a shared "
+                    "expert's gate is SiLU and no configuration has both: "
+                    f"got {self.expert_activation!r} beside "
+                    f"{self.num_shared_experts} shared expert(s)")
             if self.activation != "swiglu" or self.matmul_int8 \
                     or self.norm_placement == "post":
                 raise ValueError(
@@ -424,6 +461,11 @@ class GptConfig:
                     "norm on their input (activation='swiglu', "
                     "norm_placement 'pre' or 'sandwich'), and matmul_int8 "
                     "has no grouped form")
+        elif (self.router_input, self.router_score,
+              self.expert_activation) != ("mlp_in", "sigmoid", "silu"):
+            raise ValueError(
+                "router_input, router_score and expert_activation are the "
+                "routed experts': num_experts is 0")
         if self.activation not in ("gelu", "swiglu"):
             raise ValueError(f"Unknown activation {self.activation!r}; "
                              "one of ('gelu', 'swiglu')")
@@ -535,7 +577,9 @@ class GptBlock(nn.Module):
     latent row a token (LATENT_ATTENTION) or a gated short convolution
     over a tail of its inputs (SHORT_CONV).  ``sparse`` selects the MLP:
     the dense one, or routed experts beside shared ones.  Norms and the
-    residual path are shared."""
+    residual path are shared.  The forms a row of :data:`KINDS` names end
+    at the mixer's residual add; the MLP follows in ``__call__`` and in
+    the two functions that walk the layers' prefill and step forms."""
 
     cfg: GptConfig
     kind: str = FULL_ATTENTION
@@ -591,9 +635,10 @@ class GptBlock(nn.Module):
         # Applied in float32 whatever type the kernel is stored in: the
         # fourth and fifth score of a token are often a rounding apart.
         self.router = nn.Dense(E, dtype=jnp.float32, use_bias=False)
-        # Steers which experts are chosen, never their weights.
-        self.router_bias = self.param("router_bias", nn.initializers.zeros,
-                                      (E,))
+        if cfg.router_score == "sigmoid":
+            # Steers which experts are chosen, never their weights.
+            self.router_bias = self.param("router_bias",
+                                          nn.initializers.zeros, (E,))
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
         self.experts_gate = self.param("experts_gate", init,
@@ -754,11 +799,36 @@ class GptBlock(nn.Module):
             return kv
         return jnp.repeat(kv, groups, axis=2)
 
+    def _route(self, flat: jax.Array):
+        """``flat`` [T, hidden], what the router reads -> (chosen experts
+        [T, k], their weights [T, k]) by the configuration's score."""
+        cfg = self.cfg
+        sigmoid = cfg.router_score == "sigmoid"
+        return experts_ops.route(
+            self.router(flat), self.router_bias if sigmoid else None,
+            cfg.experts_per_token, cfg.routed_scaling_factor,
+            cfg.router_score)
+
+    def _route_ahead(self, x: jax.Array):
+        """Under ``router_input="mixer_in"``, of the block's INPUT ``x``:
+        (the chosen experts [T, k], their weights [T, k]), all a sparse
+        MLP takes from its router, laid down before the token mixer has
+        run: nothing of it waits for the mixer.  None for any other
+        block."""
+        cfg = self.cfg
+        if not self.sparse or cfg.router_input != "mixer_in":
+            return None
+        with profiling.region("moe.route"):
+            return self._route(
+                self._mixer_in(x).reshape(-1, cfg.hidden_size))
+
     def _experts(self, x: jax.Array, deterministic: bool,
-                 live: jax.Array | None = None) -> jax.Array:
+                 live: jax.Array | None = None, routed=None) -> jax.Array:
         """The sparse MLP: every token through its ``experts_per_token``
         routed experts and through the shared ones.  ``live`` [B] (the
-        decode step's): a row that is no sequence is routed nowhere.  How
+        decode step's): a row that is no sequence is routed nowhere.  With
+        ``routed`` (:meth:`_route_ahead`'s, of the block's input) the
+        route is given and the router reads nothing of this stream.  How
         many (token, expert) pairs each expert got is sown as
         ``routing/counts`` [E] for whoever applies the model with that
         collection mutable (the serving engine's step)."""
@@ -766,17 +836,18 @@ class GptBlock(nn.Module):
         dtype = jnp.dtype(cfg.dtype)
         h = self.ln_mlp(x).astype(dtype)
         flat = h.reshape(-1, cfg.hidden_size)
-        with profiling.region("moe.route"):
-            chosen, weights = experts_ops.route(
-                self.router(flat), self.router_bias, cfg.experts_per_token,
-                cfg.routed_scaling_factor)
+        if routed is None:
+            with profiling.region("moe.route"):
+                routed = self._route(flat)
+        chosen, weights = routed
         with profiling.region("moe.experts"):
             rows = None if live is None else jnp.repeat(
                 live, flat.shape[0] // live.shape[0])
             y, counts = experts_ops.routed_experts(
                 flat, chosen, weights, self.experts_gate.astype(dtype),
                 self.experts_up.astype(dtype),
-                self.experts_down.astype(dtype), rows)
+                self.experts_down.astype(dtype), rows,
+                cfg.expert_activation)
         self.sow("routing", "counts", counts)
         y = y.reshape(x.shape)
         if cfg.num_shared_experts:
@@ -788,10 +859,10 @@ class GptBlock(nn.Module):
         return x + self.drop(y, deterministic=deterministic)
 
     def _mlp(self, x: jax.Array, deterministic: bool,
-             live: jax.Array | None = None) -> jax.Array:
+             live: jax.Array | None = None, routed=None) -> jax.Array:
         with profiling.region("mlp"):
             if self.sparse:
-                return self._experts(x, deterministic, live)
+                return self._experts(x, deterministic, live, routed)
             cfg = self.cfg
             post = cfg.norm_placement == "post"
             h = x if post else self.ln_mlp(x).astype(jnp.dtype(cfg.dtype))
@@ -840,7 +911,9 @@ class GptBlock(nn.Module):
             return x + self.drop(h, deterministic=deterministic)
 
     def __call__(self, x: jax.Array, deterministic: bool = True) -> jax.Array:
-        return getattr(self, KINDS[self.kind].mix)(x, deterministic)
+        routed = self._route_ahead(x)
+        x = getattr(self, KINDS[self.kind].mix)(x, deterministic)
+        return self._mlp(x, deterministic, routed=routed)
 
     def attention_mix(self, x: jax.Array, deterministic: bool = True):
         """The whole sequence, nothing cached (the training forward)."""
@@ -849,8 +922,7 @@ class GptBlock(nn.Module):
             ctx = dot_product_attention(
                 q, self._expand_kv(k), self._expand_kv(v), causal=True,
                 window=self.window, backend=self.cfg.attention_backend)
-        x = self._add_mixed(x, ctx, deterministic)
-        return self._mlp(x, deterministic)
+        return self._add_mixed(x, ctx, deterministic)
 
     # ---------------------------------------------- short convolution
 
@@ -876,7 +948,7 @@ class GptBlock(nn.Module):
         """The whole sequence from an empty tail (the training forward)."""
         with profiling.region("short_conv.mix"):
             _, y = self._conv_gated(x, None)
-        return self._mlp(self._add_mixed(x, y, deterministic), deterministic)
+        return self._add_mixed(x, y, deterministic)
 
     def conv_prefill(self, x: jax.Array, tail: jax.Array,
                      lengths: jax.Array):
@@ -891,19 +963,19 @@ class GptBlock(nn.Module):
             new_tail = linear_ops.conv_tail(
                 jnp.concatenate([tail, u], axis=1),
                 lengths + tail.shape[1], tail.shape[1])
-        return self._mlp(self._add_mixed(x, y), True), new_tail
+        return self._add_mixed(x, y), new_tail
 
     def conv_decode_step(self, x: jax.Array, tail: jax.Array,
                          live: jax.Array):
         """One token a row: ``x`` [B, 1, hidden] against ``tail``
         [B, K-1, hidden].  A row where ``live`` [B] is False keeps its
-        tail bit for bit, and a routed-expert MLP routes it nowhere."""
+        tail bit for bit."""
         with profiling.region("short_conv.step"):
             u, y = self._conv_gated(x, tail)
         with profiling.region("cache.write"):
             shifted = jnp.concatenate([tail[:, 1:], u], axis=1)
             tail = jnp.where(live[:, None, None], shifted, tail)
-        return self._mlp(self._add_mixed(x, y), True, live), tail
+        return self._add_mixed(x, y), tail
 
     # ---------------------------------------------- linear attention
 
@@ -941,14 +1013,13 @@ class GptBlock(nn.Module):
     def _linear_close(self, x: jax.Array, h: jax.Array, o: jax.Array,
                       deterministic: bool = True) -> jax.Array:
         """From the rule's output ``o`` [B,T,H,Dv] to the block's: the
-        gated per-head norm, the output projection, the residual add and
-        the MLP."""
+        gated per-head norm, the output projection and the residual
+        add."""
         with profiling.region("attn.out"):
             gate = nn.silu(
                 self.g_proj(h).astype(jnp.float32)).reshape(o.shape)
             y = (self.o_norm(o) * gate).astype(jnp.dtype(self.cfg.dtype))
-        x = self._add_mixed(x, y, deterministic)
-        return self._mlp(x, deterministic)
+        return self._add_mixed(x, y, deterministic)
 
     def linear_mix(self, x: jax.Array, deterministic: bool = True):
         """The whole sequence from an empty state (the training forward)."""
@@ -1030,7 +1101,7 @@ class GptBlock(nn.Module):
     def latent_mix(self, x: jax.Array, deterministic: bool = True):
         """The whole sequence, nothing cached (the training forward)."""
         y, _, _ = self._latent_attend(x, self.cfg.attention_backend)
-        return self._mlp(self._add_mixed(x, y, deterministic), deterministic)
+        return self._add_mixed(x, y, deterministic)
 
     def latent_prefill(self, x: jax.Array, latent_cache: jax.Array,
                        key_cache: jax.Array, lengths: None = None):
@@ -1041,8 +1112,7 @@ class GptBlock(nn.Module):
         backend = ("xla" if self.cfg.attention_backend in ("ring", "ulysses")
                    else self.cfg.attention_backend)
         y, latent, k_rot = self._latent_attend(x, backend)
-        x = self._add_mixed(x, y)
-        return (self._mlp(x, deterministic=True),
+        return (self._add_mixed(x, y),
                 self._write_prefill(latent_cache, latent),
                 self._write_prefill(key_cache, k_rot))
 
@@ -1136,8 +1206,7 @@ class GptBlock(nn.Module):
                 mean = jnp.einsum("bhs,bsc->bhc", weights, latents)
         with profiling.region("mla.absorb"):
             ctx = jnp.einsum("bhc,chd->bhd", mean, w_v)
-        x = self._add_mixed(x, ctx[:, None])
-        return self._mlp(x, True, live), latent_pool, key_pool
+        return self._add_mixed(x, ctx[:, None]), latent_pool, key_pool
 
     def _gathered_keys(self, key_pool: jax.Array,
                        page_table: jax.Array) -> jax.Array:
@@ -1218,8 +1287,7 @@ class GptBlock(nn.Module):
             ctx = dot_product_attention(
                 q, self._expand_kv(k), self._expand_kv(v), causal=True,
                 window=self.window, backend=backend)
-        x = self._add_mixed(x, ctx)
-        return self._mlp(x, deterministic=True), k_cache, v_cache
+        return self._add_mixed(x, ctx), k_cache, v_cache
 
     def _check_ring(self, M: int) -> None:
         if self.cfg.attention_window and M > self.cfg.attention_window:
@@ -1604,9 +1672,9 @@ class GptBlock(nn.Module):
         The global ``attention_window`` (one window for ALL layers, no
         kind) stays with the unpaged paths: here it is refused.
 
-        ``live`` [B] (optional) is the routed-expert MLP's: a row that is
-        no sequence is routed nowhere.  The pools need none: an idle row's
-        table is all sentinel and its write drops.
+        ``live`` [B] (the place every kind's step form has for it) is
+        not read: an idle row's table is all sentinel and its write drops.
+        Returns the stream after the mixer's residual add and the pools.
         """
         cfg = self.cfg
         if cfg.attention_window:
@@ -1651,8 +1719,7 @@ class GptBlock(nn.Module):
                     valid = (s[None, :] <= positions[:, None]) & allocated
             ctx = self._attend_rows(q, gather_pages(k_pool, page_table),
                                     gather_pages(v_pool, page_table), valid)
-        x = self._add_mixed(x, ctx)
-        return self._mlp(x, True, live), k_pool, v_pool
+        return self._add_mixed(x, ctx), k_pool, v_pool
 
 
 class GptLM(nn.Module):
@@ -1954,8 +2021,10 @@ def _prefill_layers(mdl: GptLM, x: jax.Array, caches, lengths):
     stateful, new_caches = mdl.cfg.has_state_layers, []
     for layer, entry in zip(mdl.layers, caches):
         row = KINDS[layer.kind]
+        routed = layer._route_ahead(x)
         x, *entry = getattr(layer, row.prefill)(
             x, *entry, lengths if row.table is None or not stateful else None)
+        x = layer._mlp(x, True, routed=routed)
         new_caches.append(tuple(entry))
     return x, new_caches
 
@@ -1970,7 +2039,9 @@ def _step_layers(mdl: GptLM, x: jax.Array, pools, tables: dict,
     for layer, entry in zip(mdl.layers, pools):
         row = KINDS[layer.kind]
         where = () if row.table is None else (tables[row.table], positions)
+        routed = layer._route_ahead(x)
         x, *entry = getattr(layer, row.step)(x, *entry, *where, live)
+        x = layer._mlp(x, True, live, routed)
         new_pools.append(tuple(entry))
     return x, new_pools
 
@@ -2143,7 +2214,12 @@ class LayerKind:
     """What the model knows of one kind of layer, a row of :data:`KINDS`:
     ``GptBlock``'s methods by name (looked up on the layer where a program
     is traced; nothing is wrapped) and what the layer keeps of a sequence.
-    A further kind is one row and the methods it names."""
+    A further kind is one row and the methods it names.  A form is the
+    token MIXER's, to the residual add behind its out projection; the
+    block's MLP is whoever looks the form up's to run behind it
+    (``GptBlock.__call__``, :func:`_prefill_layers`, :func:`_step_layers`:
+    each lays the route of a block that routes ahead down first,
+    ``GptBlock._route_ahead``)."""
 
     setup: str        # the mixer's parameters: (dtype)
     mix: str          # the whole sequence, nothing cached: (x, deterministic)
@@ -2328,6 +2404,7 @@ class PoolGeometry:
     state_layers: int        # layers that keep a row a slot,
     conv_layers: int         # those of them that keep a tail alone
     sparse_layers: int       # layers whose MLP is routed experts
+    route_ahead_layers: int  # those of them routed before their mixer runs
     window_layers: int       # layers whose pool is a ring
     loop_steps: int          # times the stack is applied to a token
     cache_rows: int          # rows a cached token holds: steps x paged layers
@@ -2356,7 +2433,9 @@ def pool_geometry(cfg: GptConfig, page_size: int, dtype=None) -> PoolGeometry:
         latent_row_bytes=row_bytes if cfg.latent_kv_rank else 0,
         ring_pages=cfg.ring_pages(page_size) if cfg.window_layers else 0,
         state_layers=state_layers, conv_layers=cfg.conv_layers,
-        sparse_layers=sparse_layers, window_layers=cfg.window_layers,
+        sparse_layers=sparse_layers,
+        route_ahead_layers=sparse_layers * (cfg.router_input == "mixer_in"),
+        window_layers=cfg.window_layers,
         loop_steps=cfg.loop_steps,
         cache_rows=cfg.loop_steps * (cfg.num_layers - state_layers),
         riders=("routing",) * bool(sparse_layers)

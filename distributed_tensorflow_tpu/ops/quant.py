@@ -47,13 +47,17 @@ def validate_quantize(name: str) -> str:
     return name
 
 
-def prepare_inference_tree(params: Any, quantize: str) -> Any:
+def prepare_inference_tree(params: Any, quantize: str,
+                           consume: bool = False) -> Any:
     """Host param tree -> the tree an inference path should CARRY across
     dispatches: per-channel int8 + scales under ``quantize="int8"``
     (half the HBM weight bytes), the original tree otherwise.  Pair with
-    :func:`load_inference_tree` inside the jitted consumer."""
+    :func:`load_inference_tree` inside the jitted consumer.  ``consume``:
+    as :func:`quantize_tree` takes it."""
     validate_quantize(quantize)
-    return quantize_tree(params) if quantize == "int8" else params
+    if quantize != "int8":
+        return params
+    return quantize_tree(params, consume=consume)
 
 
 def load_inference_tree(tree: Any, quantize: str, dtype) -> Any:
@@ -95,18 +99,29 @@ def quantize_leaf(w: jax.Array) -> dict:
     return {"q": q, "s": scale}
 
 
-def quantize_tree(params: Any, *, min_size: int = 4096) -> Any:
+def quantize_tree(params: Any, *, min_size: int = 4096,
+                  consume: bool = False) -> Any:
     """Quantize every float leaf with >= ``min_size`` elements.
 
     Small leaves (biases, LayerNorm gains) carry negligible bytes and the
     most precision sensitivity — they stay in their original dtype.
+
+    ``consume``: a device leaf is DELETED once its int8 form is made, so
+    the float tree and the int8 tree never lie whole side by side in the
+    device's memory (a float tree of more than two thirds of it could not
+    be quantized there otherwise).  The caller's tree is then dead: its
+    quantized leaves raise on any further use.
     """
     def leaf(w):
         if (not hasattr(w, "dtype")
                 or not jnp.issubdtype(w.dtype, jnp.floating)
                 or w.ndim < 2 or w.size < min_size):
             return w
-        return quantize_leaf(w)
+        q = quantize_leaf(w)
+        if consume and isinstance(w, jax.Array):
+            jax.block_until_ready(q)
+            w.delete()
+        return q
     return jax.tree.map(leaf, params)
 
 

@@ -2,21 +2,23 @@
 
 Beside :mod:`.moe` (``MoeMlp``: softmax gate, a capacity that DROPS what
 overflows it, one-hot ``[G, S, E, C]`` dispatch, gelu experts, an auxiliary
-loss; the ``bert_moe`` family's layer) this is the layer of the sigmoid-routed
+loss; the ``bert_moe`` family's layer) this is the layer of the routed
 decoders: every token goes to its ``k`` experts whatever the imbalance.
 
-- :func:`route` — ``s = sigmoid(logits)``; the ``k`` largest of ``s + bias``
-  are chosen (``bias``: a selection bias that steers the CHOICE and never
-  enters the weights); the weights are ``s`` at the chosen, divided by their
-  sum, times ``scale``.
+- :func:`route` — ``score="sigmoid"``: ``s = sigmoid(logits)``; the ``k``
+  largest of ``s + bias`` are chosen (``bias``: a selection bias that steers
+  the CHOICE and never enters the weights); the weights are ``s`` at the
+  chosen, divided by their sum, times ``scale``.  ``score="softmax"``: the
+  ``k`` largest LOGITS are chosen and the weights are a softmax over those
+  ``k`` alone; no bias, no scale.
 - :func:`routed_experts` — the (token, expert) pairs sorted by expert, three
   grouped matrix products over the stacked kernels ``[E, in, out]`` (gate,
-  up, down: ``silu(x Wg) * (x Wu)) Wd``), and the rows put back in token
-  order and summed under their weights.  One function for a prefill of
-  thousands of tokens and for a decode step of a few lanes.  Rows of a token
-  that is not ``live`` are sorted behind the last group and belong to no
-  expert: they read no kernel and come back zero.  Also returns how many
-  pairs each expert got, ``[E]`` int32.
+  up, down: ``act(x Wg) * (x Wu)) Wd``, ``act`` SiLU or ReLU), and the rows
+  put back in token order and summed under their weights.  One function for
+  a prefill of thousands of tokens and for a decode step of a few lanes.
+  Rows of a token that is not ``live`` are sorted behind the last group and
+  belong to no expert: they read no kernel and come back zero.  Also returns
+  how many pairs each expert got, ``[E]`` int32.
 - :func:`grouped_matmul` — ``lhs[rows of group g] @ rhs[g]``.  On a TPU the
   Pallas grouped matmul that ships with JAX (``megablox.gmm``): its grid
   visits a row tile once for each group that has rows in it and skips a group
@@ -36,10 +38,25 @@ ROW_TILE = 512
 _SUBLANES = 16
 
 
-def route(logits: jax.Array, bias: jax.Array, k: int,
-          scale: float = 1.0) -> tuple[jax.Array, jax.Array]:
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+SCORES = ("sigmoid", "softmax")
+
+
+def route(logits: jax.Array, bias: jax.Array | None, k: int,
+          scale: float = 1.0,
+          score: str = "sigmoid") -> tuple[jax.Array, jax.Array]:
     """``logits`` [T, E] float32 -> (chosen experts [T, k] int32, their
     weights [T, k] float32)."""
+    if score == "softmax":
+        if bias is not None or scale != 1.0:
+            raise ValueError(
+                "route: a selection bias and a scale are the sigmoid "
+                f"score's; score='softmax' got bias={bias is not None} and "
+                f"scale={scale}")
+        top, chosen = jax.lax.top_k(logits.astype(jnp.float32), k)
+        return chosen.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    if score != "sigmoid":
+        raise ValueError(f"Unknown score {score!r}; one of {SCORES}")
     scores = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, chosen = jax.lax.top_k(scores + bias.astype(jnp.float32), k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
@@ -83,7 +100,7 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
 
 def routed_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
                    gate: jax.Array, up: jax.Array, down: jax.Array,
-                   live: jax.Array | None = None,
+                   live: jax.Array | None = None, activation: str = "silu",
                    **grouped) -> tuple[jax.Array, jax.Array]:
     """``x`` [T, H]; ``chosen`` / ``weights`` [T, k] from :func:`route`;
     ``gate`` / ``up`` [E, H, I] and ``down`` [E, I, H]; ``live`` [T] bool
@@ -103,7 +120,7 @@ def routed_experts(x: jax.Array, chosen: jax.Array, weights: jax.Array,
     order = jnp.argsort(expert, stable=True)
     counts = jnp.zeros((E,), jnp.int32).at[expert].add(1, mode="drop")
     xs = jnp.take(x, jnp.minimum(order // k, T - 1), axis=0)
-    h = jax.nn.silu(grouped_matmul(xs, gate, counts, **grouped)) \
+    h = ACTIVATIONS[activation](grouped_matmul(xs, gate, counts, **grouped)) \
         * grouped_matmul(xs, up, counts, **grouped)
     ys = grouped_matmul(h, down, counts, **grouped)
     ys = jnp.where((jnp.arange(rows) < jnp.sum(counts))[:, None], ys, 0)

@@ -132,6 +132,12 @@ class EngineConfig:
     num_pages: int = 128          # pool pages per layer
     max_pages_per_seq: int = 8    # page-table width (caps seq length)
     quantize: str = ""            # "" | "int8" weight storage
+    # With quantize="int8": the engine CONSUMES the float tree it is given
+    # (and every tree ``swap_params`` is), deleting each device leaf once
+    # its int8 form is made, so that a model of more than two thirds of
+    # the device's memory can be quantized on it; the caller's tree is
+    # dead afterwards (ops/quant.quantize_tree).
+    consume_params: bool = False
     kv_dtype: str = ""            # "" | "bfloat16" | "float8" pool dtype
     # Speculative decode arm (docs/speculative.md): 0 disables; >= 2
     # compiles a second resident step — a spec_k-wide decode_chunk_paged
@@ -160,6 +166,11 @@ class EngineConfig:
         if self.num_slots < 1:
             raise ValueError("num_slots must be >= 1")
         validate_quantize(self.quantize)
+        if self.consume_params and self.quantize != "int8":
+            raise ValueError(
+                "consume_params frees the float leaves that "
+                "quantize='int8' has replaced; without it the engine "
+                "serves the caller's own tree")
         resolve_kv_dtype(self.kv_dtype)  # validates
         if self.spec_k == 1 or self.spec_k < 0:
             raise ValueError(f"spec_k must be 0 (off) or >= 2, "
@@ -277,7 +288,8 @@ _SUMS = ("pool_steps_in_place", "pool_steps_copied", "steps_ahead",
          "steps_serial", "lane_steps_discarded", "sample_steps_greedy",
          "sample_steps_sampled", "table_pages", "table_pages_held",
          "window_table_pages", "window_table_pages_held", "attn_pages_read",
-         "window_attn_pages_read", "attn_kernel_layers", "lanes_live")
+         "window_attn_pages_read", "attn_kernel_layers", "lanes_live",
+         "window_lanes_wrapped")
 _MOE = ("experts_touched", "expert_slots", "expert_tokens_max",
         "routed_tokens")
 _LOOP = ("loop_steps_run", "loop_tokens", "exit_step_expected_milli")
@@ -372,7 +384,7 @@ class DecodeEngine:
         self._prefill_attrs = {name: getattr(geo, name) for name in (
             "state_layers", "conv_layers", "sparse_layers",
             "latent_row_bytes", "loop_steps", "cache_rows", "row_bytes",
-            "window_layers", "ring_pages")}
+            "window_layers", "ring_pages", "route_ahead_layers")}
         self._tree = self._prepare_params(params)
         self._pending: tuple[Any, int] | None = None  # (tree, label step)
         self.model_step = 0            # checkpoint step the weights carry
@@ -449,7 +461,8 @@ class DecodeEngine:
         the shared prepare/load recipe of ops/quant.py."""
         return self._jax.tree.map(
             self._jnp.asarray,
-            prepare_inference_tree(params, self.config.quantize))
+            prepare_inference_tree(params, self.config.quantize,
+                                   self.config.consume_params))
 
     def _dequant(self, tree):
         return load_inference_tree(tree, self.config.quantize,
@@ -1158,7 +1171,13 @@ class DecodeEngine:
                     self._window_tables, self._positions,
                     self.allocator.window_pages, page).sum()),
                 window_pages_in_use=self.allocator.window_pages_in_use,
-                window_pages_peak=self.allocator.window_peak_in_use)
+                window_pages_peak=self.allocator.window_peak_in_use,
+                # The seated lanes whose position has passed the ring's
+                # rows: their ring has gone round and is held whole.
+                window_lanes_wrapped=int(np.count_nonzero(
+                    (self._tables[:, 0] < sentinel) & (
+                        self._positions
+                        >= self.geometry.ring_pages * page))))
         chunk, spec_rows = None, 0
         if spec_mode:
             K = self.config.spec_k

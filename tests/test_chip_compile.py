@@ -695,3 +695,108 @@ def test_conv_and_head64_serving_programs_compile_for_v5e(one_chip):
         assert header.count("may-alias") + header.count(
             "must-alias") == len(leaves)
         assert relayouts(program, leaves[1].size * 2) == []
+
+
+def test_route_ahead_and_heads_of_28_serving_programs_compile_for_v5e(
+        one_chip):
+    """One whole period (full, sliding, sliding, sliding) at the published
+    widths of ``perfbench/configs/smallthinker-21b-a3b.json`` under
+    ``mixed_closed32``'s engine settings: the decode step over 16 slots
+    reads each layer's pool through ONE paged-attention call at 28 query
+    heads in groups of seven, the block as it stands ([16, 28, 128]: Mosaic
+    takes 28 rows, no padding in the wrapper), a ring of 257 pages a lane in
+    a window layer and the held pages of a table of 832 in the full one,
+    gathering neither; each layer's router reads the block's input, and in
+    the compiled program's order its top 6 of 64 stand AHEAD of that
+    layer's attention call.  The
+    12,288-token prefill keeps the flash kernel in the full layer (scored
+    whole, no rotation) and in two bands of 4,096, drops the LAST layer's
+    mixer and experts, and holds under 2 GB of temporaries."""
+    from distributed_tensorflow_tpu.serving.engine import EngineConfig
+    from perfbench import spec, worker
+    config = spec.load_json(os.path.join(spec.HERE, "configs",
+                                         "smallthinker-21b-a3b.json"))
+    whole = worker.gpt_config({"config": config,
+                               "config_file": "smallthinker-21b-a3b.json"})
+    cfg = dataclasses.replace(whole, num_layers=4,
+                              layer_kinds=whole.layer_kinds[:4],
+                              vocab_size=32768)
+    assert cfg.layer_kinds == (gpt_lib.FULL_ATTENTION,) + (
+        gpt_lib.SLIDING_ATTENTION,) * 3
+    assert (cfg.num_heads, cfg.num_kv_heads, cfg.head_dim) == (28, 4, 128)
+    model = gpt_lib.GptLM(cfg)
+    traffic = spec.load_json(os.path.join(spec.HERE, "traffic",
+                                          "mixed_closed32.json"))
+    econf = EngineConfig(**traffic["engine"])
+
+    def described(tree, dtype=None):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, dtype or x.dtype, sharding=one_chip), tree)
+
+    engine = bare_engine(model, econf, sparse_layers=4)
+    geo = engine.geometry
+    assert (geo.window_layers, geo.ring_pages, geo.route_ahead_layers) == (
+        3, 257, 4)
+    tree = described(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"]),
+        jnp.bfloat16)
+    pools = described(jax.eval_shape(lambda: gpt_lib.init_kv_pool(
+        cfg, econf.num_pages, econf.page_size, num_slots=econf.num_slots)))
+    leaves = jax.tree.leaves(pools)
+    assert [x.shape for x in leaves] == [(13312 + 1, 16, 512)] * 2 + [
+        (16 * 257 + 1, 16, 512)] * 6
+    assert gpt_lib.paged_kernel_layers(cfg, pools) == 4
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32,  # noqa: E731
+                                          sharding=one_chip)
+    f32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32,  # noqa: E731
+                                          sharding=one_chip)
+    B, MP, RP = econf.num_slots, econf.max_pages_per_seq, 257
+    lowered = engine._build_step().lower(
+        tree, i32(B), i32(B), (i32(B, MP), i32(B, RP)), pools, f32(B),
+        i32(B), f32(B), i32(B))
+    # 16 tokens and behind them 4 layers x 64 experts of histogram
+    assert lowered.out_info[0].shape == (B + 4 * 64,)
+    step = lowered.compile()
+    text = step.as_text()
+    # three grouped products and a paged-attention call a layer
+    assert text.count("tpu_custom_call") == 4 * 3 + 4
+    assert table_gathers(step, 257 * 16 * 512 * 2) == []
+    assert gathered_selects(step, 16 * 257 * 16 * 512 * 2) == []
+    # the compiled order, layer by layer: the route's first operation,
+    # then the attention's call, then the experts' grouped products
+    entry = text[text.index("ENTRY"):].splitlines()
+
+    def first(layer, region, what=""):
+        return next(i for i, line in enumerate(entry)
+                    if f"layer{layer}." in line and region in line
+                    and what in line)
+    for layer in range(4):
+        route = first(layer, "moe.route", " sort(")
+        attend = first(layer, "attn.scores", "tpu_custom_call")
+        experts = first(layer, "moe.experts", "tpu_custom_call")
+        assert route < attend < experts, (layer, route, attend, experts)
+    prefill = engine._prefill_fn(768).lower(
+        tree, i32(1, 12288), pools, i32(768), ring=i32(257)).compile()
+    # the full layer's flash call and two bands', and THREE layers'
+    # grouped products: the last layer's mixer and experts feed the logits
+    # alone
+    assert prefill.as_text().count("tpu_custom_call") == 3 + 3 * 3
+    pool_bytes = sum(x.size * x.dtype.itemsize for x in leaves)
+    for program in (step, prefill):
+        mem = program.memory_analysis()
+        assert mem.temp_size_in_bytes < 2e9
+        assert mem.alias_size_in_bytes == pool_bytes
+        header = program.as_text().split("\n", 1)[0]
+        assert header.count("may-alias") + header.count(
+            "must-alias") == len(leaves)
+    assert relayouts(step, leaves[2].size * 2) == []
+    # No pool is re-laid out by the prefill either.  What it does re-lay
+    # out, once a whole layer, is the experts' rows put back in token order
+    # on their way to the weighted sum, [12288 x 6, 2560] -> [12288, 6,
+    # 2560] in float32 (755 MB): SIX experts a token fill no sublane tile
+    # of 8, where the 8 and the 4 of the other configurations make that
+    # reshape a bitcast (ROADMAP R1: a measured line for a later PR).
+    moved = relayouts(prefill, leaves[2].size * 2)
+    assert len(moved) == 3 and all(
+        "f32[12288,6,2560]" in line and "._mlp/mlp/" in line
+        for line in moved), moved

@@ -1,8 +1,9 @@
 """What the serving engine takes from the model file, held on toy models of
-the six served forms (grouped-query, hybrid with a recurrent state, latent
+the seven served forms (grouped-query, hybrid with a recurrent state, latent
 rows with routed experts, a weight-shared loop, sliding windows beside full
-layers, short convolutions beside full layers; the last three around routed
-experts as their configurations are):
+layers, short convolutions beside full layers, and sliding windows behind a
+full layer with the route laid down AHEAD of the mixer; the last four around
+routed experts as their configurations are):
 
 - ``gpt_lib.pool_geometry``'s bytes are the bytes of ``init_kv_pool``'s own
   arrays, a token and a slot;
@@ -10,7 +11,9 @@ experts as their configurations are):
   record and the ``serve.step.retire`` event under the names written down
   here from the tree before the counters were one record (PR 49): what
   ``perfbench/`` and ``/statz`` read by name can be neither dropped nor
-  renamed without a red test.
+  renamed without a red test.  (PR 50 added ``window_lanes_wrapped``, the
+  seated lanes whose ring has gone round, to the forms with a ring and to
+  ``engine.stats()``.)
 """
 
 import jax
@@ -56,6 +59,14 @@ FORMS = {
         layer_kinds=(gpt_lib.SHORT_CONV, gpt_lib.FULL_ATTENTION,
                      gpt_lib.SHORT_CONV),
         short_conv_kernel_dim=3, **EXPERTS),
+    "ahead": dict(
+        num_layers=3, kv_heads=2, pos_encoding="rope", **GATED,
+        layer_kinds=(gpt_lib.FULL_ATTENTION,) + (
+            gpt_lib.SLIDING_ATTENTION,) * 2,
+        sliding_window=8, rope_kinds=(gpt_lib.SLIDING_ATTENTION,),
+        num_experts=8, experts_per_token=3, expert_intermediate_size=16,
+        router_input="mixer_in", router_score="softmax",
+        expert_activation="relu"),
 }
 PAGE, PAGES, SLOTS = 4, 48, 3
 PROMPT = [11, 3, 40, 7, 25, 9, 31, 2, 18, 5, 44, 1]
@@ -79,8 +90,8 @@ STATS = set("""
     pool_steps_copied pool_steps_in_place prefill_chunk prefilling_slots
     quantize sample_steps_greedy sample_steps_sampled spec_k spec_rows
     state_bytes state_slots steps_ahead steps_serial swaps table_pages
-    table_pages_held window_attn_pages_read window_table_pages
-    window_table_pages_held""".split())
+    table_pages_held window_attn_pages_read window_lanes_wrapped
+    window_table_pages window_table_pages_held""".split())
 #: What every form's step counts, under the names of both sinks.
 COUNTED = set("""
     attn_kernel_layers attn_pages_read lane_steps_discarded lanes_live
@@ -98,11 +109,12 @@ ROUTED = {"experts_touched", "expert_slots", "expert_tokens_max",
 LOOPED = {"loop_steps_run", "loop_tokens", "exit_step_expected_milli"}
 WINDOW = {"window_table_pages", "window_table_pages_held",
           "window_attn_pages_read", "window_pages_in_use",
-          "window_pages_peak"}
+          "window_pages_peak", "window_lanes_wrapped"}
 #: Beside those, by form: (on the record and the event, on the event alone).
 EXTRA = {"dense": (set(), set()), "hybrid": (set(), STATE),
          "latent": (ROUTED, set()), "looped": (LOOPED, set()),
-         "sliding": (ROUTED | WINDOW, set()), "conv": (ROUTED, STATE)}
+         "sliding": (ROUTED | WINDOW, set()), "conv": (ROUTED, STATE),
+         "ahead": (ROUTED | WINDOW, set())}
 
 
 def model_of(form):

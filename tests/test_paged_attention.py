@@ -103,6 +103,15 @@ CASES = {
                     [20, 131, 2 * 144 + 77, 0, 143], None, 64),
     "full-head32": (8, 4, 0, 24, [20, 0, 3], [20 * PAGE - 3, 0, 33],
                     None, 32),
+    # 28 query heads in groups of SEVEN over 4 kv heads of 128 (a group
+    # that divides no power of two; 28 rows are no whole sublane tiles):
+    # the full table, and a ring with a lane inside the window beside
+    # lanes gone round.
+    "full-groups-of-7": (28, 4, 0, 24, [20, 0, 1, 8, 9],
+                         [20 * PAGE - 3, 0, 0, 8 * PAGE - 1, 8 * PAGE],
+                         None),
+    "ring-groups-of-7": (28, 4, 128, 9, [2, 9, 9, 0, 9],
+                         [20, 131, 2 * 144 + 77, 0, 143], None),
 }
 
 

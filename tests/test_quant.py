@@ -60,6 +60,25 @@ def test_quantize_tree_selects_large_float_matrices():
     assert jax.tree.structure(deq) == jax.tree.structure(tree)
 
 
+def test_a_consumed_tree_loses_the_leaves_it_quantized():
+    """``consume``: a device leaf is deleted once its int8 form is made, a
+    leaf that passes through stays alive (the quantized tree holds it), and
+    the int8 tree is the one ``consume=False`` makes."""
+    rng = np.random.default_rng(3)
+    tree = {"kernel": jnp.asarray(rng.standard_normal((128, 64)),
+                                  jnp.bfloat16),
+            "bias": jnp.zeros((64,)),
+            "host": rng.standard_normal((128, 64)).astype(np.float32)}
+    want = quantize_tree(tree, min_size=4096)
+    got = quantize_tree(tree, min_size=4096, consume=True)
+    assert tree["kernel"].is_deleted() and not tree["bias"].is_deleted()
+    assert got["bias"] is tree["bias"]
+    for name in ("kernel", "host"):        # a host leaf has nothing to free
+        for part in ("q", "s"):
+            np.testing.assert_array_equal(np.asarray(got[name][part]),
+                                          np.asarray(want[name][part]))
+
+
 def test_quantized_bytes_shrink():
     tree = {"w": jnp.zeros((512, 512))}
     raw = 512 * 512 * 4

@@ -299,6 +299,36 @@ def test_engine_int8_fp8_matches_contiguous_quantized_decode(
     assert req.tokens == ref[4:].tolist()
 
 
+def test_an_engine_that_consumes_its_params_serves_the_same_tokens(
+        model_and_params):
+    """``consume_params``: each float leaf that ``quantize="int8"`` has
+    replaced is deleted as its int8 form is made (the float and the int8
+    trees never lie whole side by side); the tokens are the engine's that
+    keeps the caller's tree, the small leaves stay the caller's, and
+    without int8 there is nothing to consume."""
+    model, params = model_and_params
+    kw = dict(num_slots=2, page_size=4, num_pages=32, max_pages_per_seq=8,
+              quantize="int8", kv_dtype="float8")
+    kept = Request([5, 6, 7, 8], 8)
+    engine = DecodeEngine(model, params, EngineConfig(**kw))
+    engine.admit(kept)
+    drain(engine)
+    mine = jax.tree.map(jnp.array, params)         # a copy to give away
+    engine = DecodeEngine(model, mine, EngineConfig(**kw,
+                                                    consume_params=True))
+    req = Request([5, 6, 7, 8], 8)
+    engine.admit(req)
+    drain(engine)
+    assert req.tokens == kept.tokens
+    dead = [leaf.is_deleted() for leaf in jax.tree.leaves(mine)]
+    big = [leaf.ndim >= 2 and leaf.size >= 4096
+           for leaf in jax.tree.leaves(mine)]
+    assert dead == big and not all(dead)
+    assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(params))
+    with pytest.raises(ValueError, match="consume_params"):
+        EngineConfig(consume_params=True)
+
+
 def test_engine_validate_rejects_bad_requests(model_and_params):
     model, params = model_and_params
     engine = DecodeEngine(model, params, EngineConfig(
