@@ -23,8 +23,22 @@ meaningless but finite, an all-zero key included).
 
 The core runs in float32 at ``HIGHEST`` matmul precision whatever the
 activations' type: the triangular solve amplifies what a rounded key puts
-into it, and the state is read back thousands of tokens later.  It is a few
-percent of a layer's operations.  No kernel: XLA lowers all of it.
+into it, and the state is read back thousands of tokens later.
+
+What runs where.  The chunked form is one algorithm on two carriers, chosen
+by what the code observes (no option): on a TPU, for shapes the kernel
+takes, ONE Pallas call a layer (``ops/pallas/gated_delta.py``: a chunk's
+products, the inverse of its triangular system and the state's walk in
+VMEM; nothing the size of the sequence but the output goes back to HBM);
+elsewhere, so on the CPU and in every test's reference column, the XLA
+form below, which is also what the kernel path's backward pass
+differentiates.  As XLA lowers it for a v5e the XLA form is sixteen
+fusions, XLA's blockwise inverse for the solve and a ``while`` of a turn a
+chunk over float32 intermediates of ``[N, B, H, 64, 64..288]``: a few
+percent of a layer's OPERATIONS but, at 3,584 tokens, 5.9 ms of device
+time a layer call (1.6 times the layer's MLP) for the traffic of those
+intermediates (PERF.md, PR 51).  The one-token step is XLA's on every
+backend.
 """
 
 from __future__ import annotations
@@ -35,6 +49,8 @@ import jax
 import jax.numpy as jnp
 
 from ..utils import profiling
+from .pallas import gated_delta as kernel
+from .pallas.flash_attention import _warn_once
 
 CHUNK = 64
 F32 = jnp.float32
@@ -116,11 +132,43 @@ def gated_delta_chunked(q, k, v, g, beta, state=None, chunk: int = CHUNK):
     """The recurrence over ``T`` tokens in chunks.  ``q``, ``k`` [B, T, H,
     Dk]; ``v`` [B, T, H, Dv]; ``g`` (log decay) and ``beta`` [B, T, H];
     ``state`` [B, H, Dv, Dk] float32 or None for zeros.  Returns (``o``
-    [B, T, H, Dv] float32, the state after token T-1)."""
+    [B, T, H, Dv] float32, the state after token T-1).  On a TPU the Pallas
+    kernel carries it (the module's text; its gradient is the XLA form's);
+    a shape the kernel refuses takes the XLA form and says so once."""
+    if state is None:
+        state = jnp.zeros((q.shape[0], q.shape[2], v.shape[-1], q.shape[-1]),
+                          F32)
+    if jax.default_backend() == "tpu":
+        refused = kernel.refusal(q.shape, v.shape, chunk)
+        if not refused:
+            with profiling.region("linear_attention.scan"):
+                return _chunked_kernel(q, k, v, g, beta, state)
+        _warn_once(f"gated-delta: {refused}",
+                   "gated_delta_chunked: the XLA form takes the place of "
+                   f"the Pallas kernel: {refused}")
+    return _chunked_xla(q, k, v, g, beta, state, chunk)
+
+
+@jax.custom_vjp
+def _chunked_kernel(q, k, v, g, beta, state):
+    return kernel.gated_delta(q, k, v, g, beta, state)
+
+
+def _chunked_kernel_fwd(*operands):
+    return _chunked_kernel(*operands), operands
+
+
+def _chunked_kernel_bwd(operands, cotangents):
+    """The XLA form's gradient (it recomputes that form's forward)."""
+    return jax.vjp(_chunked_xla, *operands)[1](cotangents)
+
+
+_chunked_kernel.defvjp(_chunked_kernel_fwd, _chunked_kernel_bwd)
+
+
+def _chunked_xla(q, k, v, g, beta, state, chunk: int = CHUNK):
     B, T, H, Dk = q.shape
     Dv = v.shape[-1]
-    if state is None:
-        state = jnp.zeros((B, H, Dv, Dk), F32)
     pad = -T % chunk
     N = (T + pad) // chunk
 

@@ -381,8 +381,11 @@ def pools_donated_and_uncopied(programs, pools, n_leaves, temp_below):
 
 
 def test_hybrid_serving_programs_compile_for_v5e(one_chip):
-    """The chunked scan, its triangular solve and the one-token rule lower
-    for the chip at 30 heads of 96 x 192, with the flash kernel in the full
+    """The one-token rule lowers for the chip at 30 heads of 96 x 192 and
+    a prefill's chunked rule is ONE Mosaic call a linear layer (PR 51:
+    ``ops/pallas/gated_delta.py``; until then sixteen fusions, XLA's
+    blockwise inverse and a ``while`` of a turn a chunk over float32
+    temporaries of [N, B, H, 64, *]), with the flash kernel in the full
     layer's prefill where the bucket's length lets it in: 1,024 tokens do,
     1,600 do not (``_layout_ok``; D6's silent fallback).  The K/V pools'
     row is flat, [1856 + 1, 16, 30 * 128] with the sentinel's page of
@@ -395,12 +398,49 @@ def test_hybrid_serving_programs_compile_for_v5e(one_chip):
     assert step.as_text().count("tpu_custom_call") == 1
     # (A full layer that is the model's LAST layer loses its call: its
     # attention output feeds only logits the prefill throws away, so XLA
-    # keeps its K/V and drops the rest.  Here a linear layer follows it.)
-    assert prefills[64].as_text().count("tpu_custom_call") == 1
-    assert prefills[100].as_text().count("tpu_custom_call") == 0
+    # keeps its K/V and drops the rest.  Here a linear layer follows it,
+    # and a linear layer's call stays wherever it stands: its state is the
+    # prefill's to return.)
+    linear = 4
+    assert prefills[64].as_text().count("tpu_custom_call") == 1 + linear
+    assert prefills[100].as_text().count("tpu_custom_call") == 0 + linear
+    for n, prefill in prefills.items():
+        text = prefill.as_text()
+        assert " while(" not in text
+        scan = [line for line in text.splitlines()
+                if "linear_attention.scan" in line]
+        assert sum("tpu_custom_call" in line for line in scan) == linear
+        # chunks-major temporaries, [N, 1, 30, 64, *]: none is left, and
+        # the heads-major operands and result of the kernel are the
+        # producers' and the consumer's own layout, not a copy's
+        chunks = -(-n * 16 // 64)
+        assert not [line for line in scan
+                    if f"f32[{chunks},1,30,64" in line.split(" = ", 1)[-1]]
+        assert not [line for line in scan if re.search(
+            r" = \S+ (copy|transpose)\(", line)]
     assert [x.shape for x in pools[3]] == [(1857, 16, 3840)] * 2
     pools_donated_and_uncopied((step, *prefills.values()), pools,
                                n_leaves=2 * 5, temp_below=4e9)
+
+
+@pytest.mark.parametrize("tokens", [1024, 1600, 2400, 3584])
+def test_gated_delta_kernel_compiles_for_v5e(one_chip, tokens):
+    """The chunked rule's kernel alone at the hybrid cell's four buckets,
+    30 heads of 96 x 192: a VMEM or a tiling refusal shows here."""
+    from distributed_tensorflow_tpu.ops import linear_attention
+
+    def f32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    q, v = f32(1, tokens, 30, 96), f32(1, tokens, 30, 192)
+    gate, state = f32(1, tokens, 30), f32(1, 30, 192, 96)
+    program = jax.jit(linear_attention.gated_delta_chunked).lower(
+        q, q, v, gate, gate, state).compile()
+    text = program.as_text()
+    assert text.count("tpu_custom_call") == 1 and " while(" not in text
+    # the operands as they come, the output and nothing a chunk's size more
+    assert program.memory_analysis().temp_size_in_bytes < 3 * 4 * (
+        tokens + 64) * 32 * (2 * 128 + 256)
 
 
 def test_dense_serving_programs_keep_their_pools_for_v5e(one_chip):
